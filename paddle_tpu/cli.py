@@ -91,10 +91,14 @@ def cmd_train(args) -> int:
     feeder = _feeder_for(provider, model)
 
     if args.job == "time":
+        from .core.device import describe_devices
+
         metrics = trainer.time_job(reader, feeder,
                                    batches=args.test_period or 20)
-        print(json.dumps({"job": "time", **{k: round(v, 3)
-                                            for k, v in metrics.items()}}))
+        # the line names the device it timed: a CPU run can never be
+        # read as a chip number
+        print(json.dumps({"job": "time", **describe_devices(),
+                          **{k: round(v, 3) for k, v in metrics.items()}}))
         return 0
     if args.job == "checkgrad":
         batch = next(iter(reader()))
@@ -202,8 +206,9 @@ def cmd_version(_args) -> int:
     import jax
 
     from . import __version__
+    from .core.device import describe_devices
     print(f"paddle_tpu {__version__} (jax {jax.__version__}, "
-          f"backend {jax.default_backend()})")
+          f"devices {describe_devices()})")
     return 0
 
 
@@ -380,6 +385,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     # no-op when its flag is unset (no thread starts)
     from . import observe
     observe.start_from_flags()
+    if args.command == "train":
+        from .core.device import ensure_compile_cache
+        ensure_compile_cache()
     return args.fn(args)
 
 

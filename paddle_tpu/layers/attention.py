@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config.model_config import ParameterConfig
+from ..core.device import batch_local, local_rows
 from ..core.dtypes import current_policy, record_op_precision
 from ..core.sequence import SequenceBatch, like, value_of
 from ..ops.pallas_attention import (flash_attention,
@@ -118,7 +119,6 @@ class MultiHeadAttentionLayer(Layer):
 
         b, tq = q.shape[0], q.shape[1]
         tk = k.shape[1]
-        split = lambda a, t: a.reshape(b, t, heads, dh)
         causal = bool(self.conf.attrs.get("causal", False))
         block_q = int(self.conf.attrs.get("block_q", 512))
         block_k = int(self.conf.attrs.get("block_k", 512))
@@ -148,7 +148,7 @@ class MultiHeadAttentionLayer(Layer):
                 record_attention_dispatch(
                     "unpacked", f"kill_switch:{flag}(packed)")
                 packed = False
-            elif not packed_tileable(b * tq, pbq, pbk):
+            elif not packed_tileable(local_rows(b) * tq, pbq, pbk):
                 # the flattened axis would miss the Pallas tiling gate
                 # and the op-level dense fallback on [1, B·T] builds an
                 # O((B·T)²) score matrix — the padded per-row lowering
@@ -156,23 +156,35 @@ class MultiHeadAttentionLayer(Layer):
                 record_attention_dispatch(
                     "unpacked", "untileable(packed flatten)")
                 packed = False
-        if packed:
-            lengths = kv_len if kv_len is not None \
-                else jnp.full((b,), tq, jnp.int32)
-            seg = segments_from_lengths(lengths, b, tq)
-            pack = lambda a: a.reshape(1, b * tq, heads, dh)
-            # slot = T: rows occupy fixed T-token slots in the flat
-            # layout, so cross-row block pairs are statically dead and
-            # leave the kernel's iteration space entirely (blocks
-            # clamped to the slot width above keep the hint usable)
-            out = flash_attention_packed(
-                pack(q), pack(k), pack(v), seg, causal, pbq, pbk, tq)
-        else:
-            out = flash_attention(
-                split(q, tq), split(k, tk), split(v, tk), kv_len,
-                causal, block_q, block_k)
-        out = out.reshape(b, tq, size) \
-            @ params[f"_{self.name}.wo"].astype(cd)
+
+        # the kernel call, over whatever rows it is handed: under a
+        # multi-device mesh batch_local gives each device its own rows
+        # (a packed flatten then packs one shard's rows, never across)
+        def attend(q, k, v, kv_len):
+            nb = q.shape[0]
+            if packed:
+                lengths = kv_len if kv_len is not None \
+                    else jnp.full((nb,), tq, jnp.int32)
+                seg = segments_from_lengths(lengths, nb, tq)
+                pack = lambda a: a.reshape(1, nb * tq, heads, dh)
+                # slot = T: rows occupy fixed T-token slots in the flat
+                # layout, so cross-row block pairs are statically dead
+                # and leave the kernel's iteration space entirely
+                # (blocks clamped to the slot width above keep the hint
+                # usable)
+                o = flash_attention_packed(
+                    pack(q), pack(k), pack(v), seg, causal, pbq, pbk, tq)
+            else:
+                split = lambda a, t: a.reshape(nb, t, heads, dh)
+                o = flash_attention(
+                    split(q, tq), split(k, tk), split(v, tk), kv_len,
+                    causal, block_q, block_k)
+            return o.reshape(nb, tq, size)
+
+        out = batch_local(attend, (q, k, v, kv_len),
+                          batch_in=(True, True, True, True),
+                          batch_out=True)
+        out = out @ params[f"_{self.name}.wo"].astype(cd)
         out = out.astype(pol.output_dtype)
         if self.conf.with_bias:
             out = out + params[self.bias_name()].astype(out.dtype)

@@ -1,9 +1,8 @@
 """Perf-regression gate over the bench trajectory.
 
-The five committed ``BENCH_r*.json`` artifacts were write-only history:
-nothing compared a new run against them, so a silent 2x regression
-would merge clean.  This module turns a bench run into a guarded
-baseline:
+Bench lines used to be write-only history: nothing compared a new run
+against an old one, so a silent 2x regression would merge clean.  This
+module turns a bench run into a guarded baseline:
 
 - :func:`series_from_line` flattens one bench JSON line into named
   scalar **series** — the headline ``median`` (the attempts/spread

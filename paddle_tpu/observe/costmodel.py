@@ -197,11 +197,31 @@ def _operand_segment(line: str) -> str:
     return line[i + 1:]
 
 
+_BARE_OPERAND_RE = re.compile(r"^\s*%?([\w.\-]+)\s*$")
+
+
+def _typed_operands(segment: str, shapes: Dict[str, str]) -> str:
+    """Operand list with every bare ``%name`` reference given back its
+    shape.  XLA (jaxlib 0.9) prints operands by name only; the cost
+    formulas below read operand shapes from this text, so each name is
+    resolved against the results already defined in its computation
+    (HLO text is def-before-use).  Operands that still carry an inline
+    shape pass through unchanged."""
+    out = []
+    for tok in _split_top_level(segment):
+        m = _BARE_OPERAND_RE.match(tok)
+        if m and m.group(1) in shapes:
+            tok = f"{shapes[m.group(1)]} %{m.group(1)}"
+        out.append(tok)
+    return ",".join(out)
+
+
 def parse_hlo(text: str) -> Dict[str, _Computation]:
     """Optimized HLO module text → ``{computation name: _Computation}``
     (the entry computation has ``is_entry`` set)."""
     comps: Dict[str, _Computation] = {}
     cur: Optional[_Computation] = None
+    shapes: Dict[str, str] = {}       # result shape by name, per comp
     for raw in text.splitlines():
         line = raw.rstrip()
         if cur is None:
@@ -209,6 +229,7 @@ def parse_hlo(text: str) -> Dict[str, _Computation]:
             if head is not None:
                 cur = _Computation(*head)
                 comps[cur.name] = cur
+                shapes = {}
             continue
         if line.strip() == "}":
             cur = None
@@ -224,8 +245,10 @@ def parse_hlo(text: str) -> Dict[str, _Computation]:
                 attrs[key] = am.group(1)
         opn = _OP_NAME_RE.search(line)
         cur.instrs.append(_Instr(
-            name, opcode, result, _operand_segment(line), line,
+            name, opcode, result,
+            _typed_operands(_operand_segment(line), shapes), line,
             opn.group(1) if opn else "", attrs))
+        shapes[name] = result
     return comps
 
 
@@ -560,59 +583,53 @@ def attribute(text: str, known: Iterable[str] = ()) -> Dict[str, Any]:
 
 
 # ------------------------------------------------------------- roofline
-#: device_kind (prefix, lower-cased) → (peak FLOP/s dense bf16-class,
-#: HBM bandwidth B/s).  Published chip specs; unknown kinds fall back
-#: to the CPU row so the verdicts stay defined everywhere.
-_PEAKS_BY_KIND = (
-    ("tpu v6", (918e12, 1640e9)),
-    ("tpu v5p", (459e12, 2765e9)),
-    ("tpu v5e", (197e12, 819e9)),
-    ("tpu v5", (197e12, 819e9)),
-    ("tpu v4", (275e12, 1228e9)),
-    ("tpu v3", (123e12, 900e9)),
-    ("tpu v2", (46e12, 700e9)),
-    # host CPU: order-of-magnitude figures for a modern many-core box —
-    # the verdicts (and the CPU-small baseline lane) only need the
-    # ridge point to sit between elementwise (<1 flop/byte) and matmul
-    # (tens of flops/byte) intensity
-    ("cpu", (2e11, 4e10)),
-)
+#: ``device_kind`` exactly as JAX reports it, lower-cased → (peak FLOP/s
+#: dense bf16, HBM bandwidth B/s).  A kind with no row is an error, not
+#: a default: add the row with its source, or pass both
+#: ``--roofline_peak_*`` flags.
+_PEAKS_BY_KIND = {
+    # what libtpu 0.0.34 reports for a v5e chip (chip run, PR 21);
+    # peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+    # 819 GB/s HBM)
+    "tpu v5 lite": (197e12, 819e9),
+    # host CPU (the test mesh): order-of-magnitude figures so that the
+    # CPU-small lanes' verdicts have a ridge point between elementwise
+    # (<1 flop/byte) and matmul (tens of flops/byte) intensity — never a
+    # device number (ROADMAP S1 removes the row with those lanes)
+    "cpu": (2e11, 4e10),
+}
 
 
 def detect_peaks(device=None) -> Dict[str, Any]:
     """{"flops": peak FLOP/s, "bw": HBM B/s, "ridge": flops/byte,
-    "source": device kind} for the attached accelerator.  The
+    "source", "device_kind"} for the attached accelerator.  The
     ``--roofline_peak_flops`` / ``--roofline_peak_gbps`` flags override
-    detection (0 = auto)."""
-    from ..utils import FLAGS
+    the table (0 = auto).  Raises on a ``device_kind`` the table has no
+    row for (unless both flags are given) and lets a failed device
+    detection propagate: a silent default would put one chip's peaks
+    under another chip's name."""
+    import jax
 
-    kind = "cpu"
-    try:
-        if device is None:
-            import jax
-            device = jax.devices()[0]
-        kind = str(device.device_kind).lower()
-    except Exception as e:  # noqa: BLE001 — peaks resolve backend-less
-        from ..utils.logger import get_logger
+    from ..utils import FLAGS, PaddleTpuError
 
-        get_logger("observe").debug(
-            "device-kind detection failed (%s); using CPU peaks", e)
-    flops, bw = _PEAKS_BY_KIND[-1][1]
-    source = "cpu-default"
-    for prefix, peaks in _PEAKS_BY_KIND:
-        if kind.startswith(prefix):
-            flops, bw = peaks
-            source = prefix
-            break
-    try:
-        if float(FLAGS.get("roofline_peak_flops")) > 0:
-            flops = float(FLAGS.get("roofline_peak_flops"))
-            source = "flag"
-        if float(FLAGS.get("roofline_peak_gbps")) > 0:
-            bw = float(FLAGS.get("roofline_peak_gbps")) * 1e9
-            source = "flag"
-    except KeyError:       # flags module not fully initialized (tests)
-        pass
+    if device is None:
+        device = jax.devices()[0]
+    kind = str(device.device_kind).lower()
+    flag_flops = float(FLAGS.get("roofline_peak_flops"))
+    flag_bw = float(FLAGS.get("roofline_peak_gbps")) * 1e9
+    row = _PEAKS_BY_KIND.get(kind)
+    if row is None and not (flag_flops > 0 and flag_bw > 0):
+        raise PaddleTpuError(
+            f"no peak-FLOP/s / bandwidth row for device_kind {kind!r} "
+            f"(known: {sorted(_PEAKS_BY_KIND)}); add it to "
+            "observe/costmodel._PEAKS_BY_KIND with its source, or pass "
+            "--roofline_peak_flops and --roofline_peak_gbps")
+    flops, bw = row if row is not None else (flag_flops, flag_bw)
+    source = kind
+    if flag_flops > 0:
+        flops, source = flag_flops, "flag"
+    if flag_bw > 0:
+        bw, source = flag_bw, "flag"
     return {"flops": flops, "bw": bw, "ridge": flops / bw,
             "source": source, "device_kind": kind}
 

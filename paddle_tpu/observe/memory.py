@@ -139,11 +139,7 @@ def account(trainer=None, feed=None,
     else:
         live = 0
         other = 0
-        try:
-            arrays = jax.live_arrays()
-        except Exception:  # noqa: BLE001 — older jax / odd backend
-            arrays = []
-        for arr in arrays:
+        for arr in jax.live_arrays():
             nb = int(getattr(arr, "nbytes", 0) or 0)
             live += nb
             if id(arr) not in cat_ids:

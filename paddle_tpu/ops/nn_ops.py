@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..core.device import batch_local, kernel_devices
 from ..core.dtypes import current_policy, record_op_precision
 from ..observe import counter
 from .registry import register_op
@@ -514,9 +515,14 @@ def affine_act_conv2d(z, a, c, w, conv_bias=None, act: str = "relu",
     if is_training and fusable_act and pallas_conv.fusable_fwd(
             zs, ws, stride, padding, dilation, groups, data_format):
         _record_conv_dispatch("affine_act_conv2d", "pallas3x3")
-        out = pallas_conv._affine_conv_core(
-            z.astype(pol.compute_dtype), a.astype(jnp.float32),
-            c.astype(jnp.float32), w.astype(pol.compute_dtype), relu)
+        # one image per grid step: batch-local (the dA/dC/dW sums over
+        # the batch come back through the replicated-input transpose)
+        out = batch_local(
+            lambda z_, a_, c_, w_: pallas_conv._affine_conv_core(
+                z_, a_, c_, w_, relu),
+            (z.astype(pol.compute_dtype), a.astype(jnp.float32),
+             c.astype(jnp.float32), w.astype(pol.compute_dtype)),
+            batch_in=(True, False, False, False), batch_out=True)
         out = out.astype(pol.output_dtype)
     elif is_training and fusable_act and _gemm_prologue_ok(
             zs, ws, stride, padding, dilation, groups, data_format):
@@ -594,10 +600,17 @@ def conv2d_bn(x, w, conv_bias, scale, bias, running_mean, running_var,
 
     pol = current_policy()
     record_op_precision("conv2d_bn")
+    # the fused pair computes the BatchNorm statistics INSIDE its
+    # custom_vjp core, over whatever rows it is handed — not batch-local,
+    # so under a multi-device mesh (where Mosaic must be shard_mapped,
+    # core/device.batch_local) the pair takes the XLA composition, whose
+    # statistics GSPMD reduces across the devices
+    on_mesh = kernel_devices() > 1
     if in_affine is not None:
         a1, c1, act1 = in_affine
         xs, ws = jnp.shape(x), jnp.shape(w)
-        if (is_training and act1 in ("relu", "", "linear")
+        if (is_training and not on_mesh
+                and act1 in ("relu", "", "linear")
                 and pallas_conv.fusable(xs, ws, stride, padding,
                                         dilation, groups, data_format)
                 and pallas_conv.fused_chain_ok(
@@ -616,13 +629,14 @@ def conv2d_bn(x, w, conv_bias, scale, bias, running_mean, running_var,
         # outside the chain family: materialize the affine exactly (the
         # unfused BN apply formula) and continue as a plain conv→BN pair
         x = _affine_apply(x, a1, c1, act1)
-    if not (is_training and pallas_conv.fusable(
+    if on_mesh or not (is_training and pallas_conv.fusable(
             jnp.shape(x), jnp.shape(w), stride, padding, dilation,
             groups, data_format)):
         _record_conv_dispatch(
             "conv2d_bn", "unfused",
             "eval mode" if not is_training
-            else "off-tile shape/stride/layout")
+            else "multi-device mesh (BN statistics span the batch)"
+            if on_mesh else "off-tile shape/stride/layout")
         z = conv2d(x, w, stride=stride, padding=padding,
                    dilation=dilation, groups=groups,
                    data_format=data_format)

@@ -63,6 +63,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.device import pallas_interpret
 from ..observe import counter
 from ..utils import enforce
 from ..utils.logger import get_logger, warn_once
@@ -70,12 +71,6 @@ from ..utils.logger import get_logger, warn_once
 NEG_INF = -1e30
 
 _log = get_logger("ops.attention")
-
-# jax renamed TPUCompilerParams → CompilerParams (0.5.x); resolve once
-# here so every Pallas module runs interpret-mode CI on either version.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def record_attention_dispatch(path: str, reason: str = "") -> None:
     """Count one attention lowering decision (trace-time: once per
@@ -103,10 +98,6 @@ def _choose_block(t: int, want: int) -> int:
     while t % b:
         b //= 2
     return max(b, 1)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------- shared mask helpers
@@ -454,9 +445,9 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
             jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(lengths.astype(jnp.int32), lo, hi, tab, *operands)
     out = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :].reshape(b, h, tq)
@@ -554,9 +545,9 @@ def _fa_forward_grid(q, k, v, lengths, causal, bq, bk):
             jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(lengths.astype(jnp.int32), qh, kh, vh)
     out = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :].reshape(b, h, tq)
@@ -602,11 +593,14 @@ def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
         return _dense_forward(q, k, v, lengths, causal, segments)
     if _block_sparse():
         reason = ""
-        if packed and slot and (slot % bq or slot % bk):
+        if packed and slot and (slot % bq or slot % bk) \
+                and (tq > bq or tk > bk):
             # the slot hint can only drop cross-slot pairs when slots
             # are whole blocks; otherwise the grid keeps the full
             # cross product (windows still skip the compute + DMA,
-            # but every pair is a scheduled step — O(B²) grid growth)
+            # but every pair is a scheduled step — O(B²) grid growth).
+            # A single-block grid (the server's B·T ≤ 512 prefill
+            # buckets) has no cross product to drop: not a fallback.
             reason = "slot hint unusable (blocks straddle slots)"
             warn_once(
                 f"flash_attention_packed_slot:{slot}:{bq}x{bk}",
@@ -804,9 +798,9 @@ def _fa_backward_sparse(q, k, v, lengths, out, lse, do, causal, bq, bk,
                                    hi_[i // nh, j], nk), 0)
 
     common = dict(
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )
     in_specs = [
         pl.BlockSpec((1, bq, d), q_idx),
@@ -975,9 +969,9 @@ def _fa_backward_pallas(q, k, v, lengths, out, lse, do, causal, bq, bk):
     lengths = lengths.astype(jnp.int32)
 
     common = dict(
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -1298,9 +1292,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(lengths, used, page_indices.astype(jnp.int32), qh, kp, vp)[0]
     return out.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)
 

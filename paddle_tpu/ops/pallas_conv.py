@@ -76,7 +76,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_attention import CompilerParams, _interpret  # shared gate
+from ..core.device import pallas_interpret
 
 # VMEM budget for the gate: tiles + resident weights must fit under the
 # 16 MB scoped-vmem cap with headroom for double-buffering.
@@ -255,9 +255,9 @@ def _dx_call(dy, z, coeffs, w, dx_dtype, dz_dtype):
         scratch_shapes=[
             pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dz
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(dy, z, coeffs, wt)
 
 
@@ -388,9 +388,9 @@ def _fwd_call(z, ci, w, out_dtype, relu):
         scratch_shapes=[
             pltpu.VMEM((h + 2, ww + 2, cin), jnp.float32),   # padded x
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(z, ci, w)
 
 
@@ -467,9 +467,9 @@ def _fwd_bwd_call(dy, z, ci, w, relu):
         scratch_shapes=[
             pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dy
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(dy, z, ci, wt)
 
 
@@ -588,9 +588,9 @@ def _chain_bwd_call(dy, z2, co, z1, ci, w, relu):
         scratch_shapes=[
             pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dz2
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(dy, z2, co, z1, ci, wt)
 
 

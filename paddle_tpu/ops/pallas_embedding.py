@@ -2,8 +2,9 @@
 
 The sparse-exchange gather (``parallel/sparse.py``): a batch's deduped
 row-index table rides the grid spec's scalar prefetch, so each grid
-step's HBM→VMEM DMA fetches exactly ONE touched table row — the [V, D]
-table is never streamed, only the K rows the batch actually uses (the
+step's HBM→VMEM DMA fetches exactly the ONE (8, D) tile that holds a
+touched table row — the [V, D] table is never streamed, only the tiles
+of the K rows the batch actually uses (the
 PR 14 pattern: attention pair tables / page tables, transferred to
 row-index prefetch; Ragged Paged Attention lineage).  Pad rows
 (``height`` from ``unique_rows_sorted``, or -1 from ``unique_rows``)
@@ -31,17 +32,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.device import pallas_interpret
 from ..observe import counter
 from ..utils import FLAGS
 from ..utils.logger import get_logger, warn_once
 
 _log = get_logger("ops.embedding")
-
-# jax renamed TPUCompilerParams → CompilerParams (0.5.x); resolve once
-# here so the module runs interpret-mode CI on either version.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def record_embedding_dispatch(path: str, reason: str = "") -> None:
     """Count one embedding-gather lowering decision (trace-time: once
@@ -55,14 +51,19 @@ def record_embedding_dispatch(path: str, reason: str = "") -> None:
     ).inc(path=path, reason=reason)
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+#: Rows per HBM tile of an f32 table: Mosaic moves (8, 128) tiles, so
+#: the kernel fetches the whole 8-row tile that holds a touched row.
+_TILE_ROWS = 8
 
 
-def _gather_kernel(rows_ref, table_ref, out_ref):
-    # the index map already steered this step's DMA to the selected
-    # row; the body is a straight VMEM copy
-    out_ref[:] = table_ref[:]       # ptpu: lint-ok[PT-TRACE] pallas ref
+def _gather_kernel(rows_ref, tile_ref, out_ref):
+    # the index map already steered this step's DMA to the 8-row tile
+    # holding the selected row; pick the row and drop it into this
+    # step's slot of the (revisited, VMEM-resident) output tile
+    i = pl.program_id(0)
+    r = rows_ref[i] % _TILE_ROWS
+    row = tile_ref[0, pl.ds(r, 1), :]
+    out_ref[0, pl.ds(i % _TILE_ROWS, 1), :] = row  # ptpu: lint-ok[PT-TRACE]
 
 
 def gather_rows_reference(table: jax.Array, rows: jax.Array) -> jax.Array:
@@ -77,32 +78,42 @@ def _gather_rows_kernel(table: jax.Array, rows: jax.Array) -> jax.Array:
     v, d = table.shape
     k = rows.shape[0]
     # clamp pads (-1 / height) to a real row index at prefetch time so
-    # the index map stays a pure table lookup
-    safe = jnp.clip(rows.astype(jnp.int32), 0, v - 1)
-    return pl.pallas_call(
+    # the index map stays a pure table lookup; round K up to whole
+    # output tiles (the surplus slots re-read row 0 and are sliced off)
+    k_pad = -(-k // _TILE_ROWS) * _TILE_ROWS
+    safe = jnp.pad(jnp.clip(rows.astype(jnp.int32), 0, v - 1),
+                   (0, k_pad - k))
+    # Mosaic blocks must be (8, 128)-aligned in their last two dims, so
+    # a (1, D) row block is not expressible.  The [V, D] table is viewed
+    # as [V/8, 8, D] — the same bytes, one (8, 128)-tile row group per
+    # leading index — and each grid step DMAs the one tile that holds
+    # its row (sorted rows revisit a tile without a re-DMA).
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(k,),
+            grid=(k_pad,),
             in_specs=[
-                # one touched row per grid step: the scalar-prefetched
-                # index table addresses the (1, D) HBM block directly
-                pl.BlockSpec((1, d), lambda i, rows: (rows[i], 0)),
+                pl.BlockSpec((1, _TILE_ROWS, d),
+                             lambda i, rows: (rows[i] // _TILE_ROWS, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, d), lambda i, rows: (i, 0)),
+            out_specs=pl.BlockSpec((1, _TILE_ROWS, d),
+                                   lambda i, rows: (i // _TILE_ROWS, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((k, d), table.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct(
+            (k_pad // _TILE_ROWS, _TILE_ROWS, d), table.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
-    )(safe, table)
+        interpret=pallas_interpret(),
+    )(safe, table.reshape(v // _TILE_ROWS, _TILE_ROWS, d))
+    return out.reshape(k_pad, d)[:k]
 
 
 def _kernel_fallback_reason(table, rows, allow_kernel: bool) -> str:
     """Why this gather can't run the Pallas kernel ('' = it can)."""
     if not FLAGS.embedding_kernel:
         return "flag_off"
-    if _interpret() and not FLAGS.embedding_kernel_interpret:
+    if pallas_interpret() and not FLAGS.embedding_kernel_interpret:
         # interpret mode emulates the grid step by step (seconds per
         # call at production K) — numerics-contract harness only
         return "no_tpu"
@@ -112,7 +123,7 @@ def _kernel_fallback_reason(table, rows, allow_kernel: bool) -> str:
         return "sharded"
     if table.ndim != 2 or rows.ndim != 1:
         return "rank"
-    if table.shape[1] % 128 != 0:
+    if table.shape[1] % 128 != 0 or table.shape[0] % _TILE_ROWS != 0:
         return "unaligned"
     if table.dtype != jnp.float32:
         return "dtype"

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_attention import CompilerParams, _interpret
+from ..core.device import pallas_interpret
 from .pallas_lstm import (HBLOCK, _from_gate_blocks, _to_gate_blocks,
                           fused_tier as _lstm_fused_tier)
 
@@ -105,9 +105,9 @@ def _fwd_call(xw, mask, w_gates, w_cand, h0):
             jax.ShapeDtypeStruct((t, b, hd3), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((b, hd), jnp.float32)],    # h carry
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(xw, mask, w_gates, w_cand, h0)
 
 
@@ -183,9 +183,9 @@ def _bwd_call(gates, h_prev_seq, mask, w_gates, w_cand, dy):
             jax.ShapeDtypeStruct((b, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((b, hd), jnp.float32)],    # dh carry
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(gates, h_prev_seq, mask, w_gates, w_cand, dy)
 
 
@@ -325,9 +325,9 @@ def _fwd_call_blocked(xur, xc, mask, w_gates, w_cand, h0, hb=HBLOCK):
             pltpu.VMEM((b, hd), jnp.float32),               # r·h staging
             pltpu.VMEM((b, hd), jnp.float32),               # h staging
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(xur, xc, mask, w_gates, w_cand, h0)
 
 
@@ -439,9 +439,9 @@ def _bwd_call_blocked(ur_seq, c_seq, h_prev_seq, mask, w_gates, w_cand,
             pltpu.VMEM((b, hd), jnp.float32),               # drh accum
             pltpu.VMEM((b, hd), jnp.float32),               # dh accum
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(ur_seq, c_seq, h_prev_seq, mask, w_gates, w_cand, dy)
 
 
@@ -486,9 +486,9 @@ def _dw_call_blocked(h_prev_seq, rh_seq, dg_seq, dcp_seq, hb=HBLOCK):
             jax.ShapeDtypeStruct((hd, 2 * hd), jnp.float32),
             jax.ShapeDtypeStruct((hd, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(h_prev_seq, rh_seq, dg_seq, dcp_seq)
 
 

@@ -51,7 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-from .pallas_attention import CompilerParams, _interpret  # shared gate
+from ..core.device import pallas_interpret
 
 
 # Hidden-block width of the blocked tier.  128 = one lane tile, the
@@ -80,14 +80,44 @@ def _blocked_vmem_bytes(b: int, h: int, n_gates: int) -> int:
     return state + w_block_stream
 
 
+# Cap on _single_block_vmem_bytes, set from what Mosaic (libtpu 0.0.34,
+# v5e) did with 35 shapes of these kernels and the conv family inside
+# a jitted program (chip run, PR 21): every shape whose estimate was
+# ≤ 30.5 MiB compiled — (128..256, 512), (512, 256), (2048, 128) here —
+# and every one ≥ 38 MiB was refused ("Ran out of memory in memory
+# space vmem … Scoped allocation with size 16.02M and limit 16.00M" at
+# (512, 512); 20.00M at (1024, 512)).  The estimate is not Mosaic's own
+# sum — that is not observable — so the cap sits below the lowest
+# refusal with margin.
+_SINGLE_BLOCK_VMEM_CAP = 28 * 1024 * 1024
+
+
+def _single_block_vmem_bytes(b: int, h: int, n_gates: int) -> int:
+    """VMEM blocks of the single-block BACKWARD kernel (the larger of
+    the pair), in bytes: per-step streamed blocks (the gate residue in
+    and dxw out, [B, n_gates·H]; the [B, H] state/cotangent streams)
+    double-buffered by the Pallas pipeline, plus the constant-index
+    residents held once (w_hh and the dW_hh accumulator [H, n_gates·H],
+    the boot-state cotangents) and the carry scratches."""
+    f32 = 4
+    n_bh = 5 if n_gates == 4 else 2        # H/C/dy streams: LSTM, GRU
+    n_carry = 2 if n_gates == 4 else 1     # (h, c) or h
+    streamed = (2 * n_gates * h + n_bh * h) * b * f32
+    resident = 2 * n_gates * h * h * f32 + n_carry * b * h * f32
+    scratch = n_carry * b * h * f32
+    return 2 * streamed + resident + scratch
+
+
 def fused_tier(b: int, h: int, n_gates: int = 4):
     """Two-tier Mosaic dispatch predicate, checked on every backend so
     interpret-mode tests exercise the hardware dispatch.
 
-    - ``"fused"`` (h ≤ 512): the round-5 single-block kernels — w_hh
-      [H, 4H] f32 fully VMEM-resident (4 MB at H=512) plus the same-
-      shape dW_hh accumulator stays inside the 16 MB scoped-vmem
-      budget.  Unchanged fast path.
+    - ``"fused"`` (h ≤ 512 and the single-block VMEM estimate under its
+      cap): the round-5 single-block kernels — w_hh [H, 4H] f32 fully
+      VMEM-resident (4 MB at H=512) plus the same-shape dW_hh
+      accumulator.  The estimate grows with the batch: (128, 512) and
+      (256, 512) compile, (512, 512) does not (see
+      ``_SINGLE_BLOCK_VMEM_CAP``).
     - ``"fused_blocked"`` (512 < h, h % HBLOCK == 0, VMEM estimate
       under cap): the round-8 hidden-blocked kernels — grid (T, H/Hb)
       streams [H, n_gates·Hb] weight column blocks while the full
@@ -100,6 +130,8 @@ def fused_tier(b: int, h: int, n_gates: int = 4):
     if b % 8 or h % 128:
         return None
     if h <= 512:
+        if _single_block_vmem_bytes(b, h, n_gates) > _SINGLE_BLOCK_VMEM_CAP:
+            return None
         return "fused"
     from ..utils import FLAGS
 
@@ -209,9 +241,9 @@ def _fwd_call(xw, mask, w_hh, checks, h0, c0):
             pltpu.VMEM((b, hd), jnp.float32),                 # h carry
             pltpu.VMEM((b, hd), jnp.float32),                 # c carry
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(xw, mask, w_hh, checks, h0, c0)
 
 
@@ -317,9 +349,9 @@ def _bwd_call(gates, h_prev_seq, c_prev_seq, c_seq, mask, w_hh, checks,
             pltpu.VMEM((b, hd), jnp.float32),                 # dh carry
             pltpu.VMEM((b, hd), jnp.float32),                 # dc carry
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(gates, h_prev_seq, c_prev_seq, c_seq, mask, w_hh, checks, dy, dyc)
 
 
@@ -481,9 +513,9 @@ def _fwd_call_blocked(xw, mask, w_hh, checks, h0, c0, hb=HBLOCK):
             pltpu.VMEM((b, hd), jnp.float32),                # h staging
             pltpu.VMEM((b, hd), jnp.float32),                # c staging
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(xw, mask, w_hh, checks, h0, c0)
 
 
@@ -593,9 +625,9 @@ def _bwd_call_blocked(gates, c_prev_seq, c_seq, mask, w_hh, checks,
             pltpu.VMEM((b, hd), jnp.float32),               # dh accum
             pltpu.VMEM((b, hd), jnp.float32),               # dc staging
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(gates, c_prev_seq, c_seq, mask, w_hh, checks, dy, dyc)
 
 
@@ -629,9 +661,9 @@ def _dw_call_blocked(h_prev_seq, dgates, hb=HBLOCK):
         ],
         out_specs=pl.BlockSpec((hd, 4 * hb), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((hd, hd4), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(h_prev_seq, dgates)
 
 

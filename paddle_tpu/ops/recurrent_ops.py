@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core.device import batch_local
 from ..core.dtypes import current_policy, record_op_precision
 from ..core.sequence import SequenceBatch
 from ..observe import counter
@@ -53,7 +54,10 @@ def _fallback_reason(b: int, h: int) -> str:
         return "batch not a multiple of 8 (sublane tiling)"
     if h % 128:
         return "hidden not a multiple of 128 (lane tiling)"
-    if h > 512 and not FLAGS.fused_rnn_hblock:
+    if h <= 512:
+        return ("batch x hidden past the single-block kernel's VMEM "
+                "window (Mosaic refuses it)")
+    if not FLAGS.fused_rnn_hblock:
         return ("hidden>512 with the blocked tier disabled "
                 "(--fused_rnn_hblock=false)")
     return ("hidden>512 and past even the blocked tier's "
@@ -198,8 +202,11 @@ def lstm_sequence(seq: SequenceBatch, w_ih, w_hh, bias=None,
             fn = lstm_fused_sequence_blocked \
                 if tier == "fused_blocked" \
                 else lstm_fused_sequence
-            y, cy, fh, fc = fn(
-                xw, mask, w_hh, check_i, check_f, check_o, h0, c0)
+            y, cy, fh, fc = batch_local(
+                fn, (xw, mask, w_hh, check_i, check_f, check_o, h0, c0),
+                batch_in=(True, True, False, False, False, False,
+                          True, True),
+                batch_out=(True, True, True, True))
             final = LstmState(h=fh.astype(pol.output_dtype),
                               c=fc.astype(pol.output_dtype))
             if return_cells:
@@ -279,8 +286,11 @@ def gru_sequence(seq: SequenceBatch, w_ih, w_hh, bias=None, h0=None,
             fn = gru_fused_sequence_blocked \
                 if tier == "fused_blocked" \
                 else gru_fused_sequence
-            y, fh = fn(xw, mask, w_hh[:, :2 * h_dim],
-                       w_hh[:, 2 * h_dim:], h0)
+            y, fh = batch_local(
+                fn, (xw, mask, w_hh[:, :2 * h_dim], w_hh[:, 2 * h_dim:],
+                     h0),
+                batch_in=(True, True, False, False, True),
+                batch_out=(True, True))
             hs = y.astype(pol.output_dtype)
             if reverse:
                 hs = hs[:, ::-1]

@@ -37,26 +37,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils import enforce
 
-# jax.shard_map is the 0.5.x spelling; fall back to the experimental
-# module on older jax so interpret-mode CI runs on either version
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 NEG_INF = -1e30
 
 
 def _as_varying(x, axis_name):
     """Type a replicated value as device-varying over ``axis_name`` so a
     scan carry matches its (idx-dependent) updated value under
-    shard_map.  ``lax.pvary`` was deprecated for ``lax.pcast(...,
-    to='varying')`` mid-0.9; support both spellings.  Pre-0.6 jax has
-    neither and no varying-manual-axes check — identity is correct."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis_name,))
-    return x
+    shard_map."""
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def _block_attn(q, k, v, m_prev, l_prev, o_prev, mask):
@@ -140,7 +128,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "data",
     enforce(q.shape[1] % mesh.shape[axis] == 0,
             f"T={q.shape[1]} not divisible by mesh axis {axis}")
     spec = P(None, axis, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_local_ring, axis_name=axis, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)
@@ -182,7 +170,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "data",
     enforce(q.shape[1] % p == 0,
             f"T={q.shape[1]} not divisible by mesh axis {axis}")
     spec = P(None, axis, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_local_ulysses, axis_name=axis, causal=causal,
                           t_total=q.shape[1]),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)

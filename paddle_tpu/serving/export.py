@@ -58,8 +58,6 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
-# explicit submodule import: pre-0.5 jax does not expose jax.export as
-# an attribute of the bare `import jax`
 import jax.export
 import numpy as np
 

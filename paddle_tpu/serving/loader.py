@@ -29,8 +29,6 @@ from typing import Any, Dict, List
 import time
 
 import jax
-# explicit submodule import: pre-0.5 jax does not expose jax.export as
-# an attribute of the bare `import jax`
 import jax.export
 import jax.numpy as jnp
 import numpy as np

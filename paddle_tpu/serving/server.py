@@ -63,6 +63,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..analysis.lockorder import named_condition
+from ..core.device import ensure_compile_cache
 from ..utils import FLAGS, enforce, get_logger
 from .model import DecoderModel
 from .pagepool import PagePool, PagePoolExhausted, SCRATCH_PAGE, TornSnapshot
@@ -253,6 +254,9 @@ class InferenceServer:
     # ------------------------------------------------------------ lifecycle
     def start(self) -> "InferenceServer":
         if self._thread is None:
+            # every (B, T) prefill bucket is a compile: keep them
+            # across restarts of the serving process
+            ensure_compile_cache()
             self._stop = False
             self._thread = threading.Thread(
                 target=self._loop, name=DECODE_THREAD_NAME, daemon=True)

@@ -272,18 +272,12 @@ class FleetPusherProcess:
     def start(self, ready_timeout_s: float = 60.0) -> "FleetPusherProcess":
         assert self.proc is None or self.proc.poll() is not None, \
             "pusher process already running"
-        repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        env = dict(os.environ)
-        env["PYTHONPATH"] = repo_root + os.pathsep \
-            + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         self.proc = subprocess.Popen(
             [sys.executable, "-c", _PUSHER_SCRIPT,
              self.aggregator_addr, self.fleet_id, str(self.interval_s),
              self.parent_ctx, self.jsonl_path, self.role,
              self.trace_jsonl, self.master_addr],
-            stdout=subprocess.PIPE, text=True, env=env)
+            stdout=subprocess.PIPE, text=True, env=_child_env())
         line = self.proc.stdout.readline()   # blocks until READY
         assert line.startswith("READY"), \
             f"pusher child failed to start: {line!r}"
@@ -377,17 +371,11 @@ class ServeServerProcess:
     def start(self, ready_timeout_s: float = 120.0) -> "ServeServerProcess":
         assert self.proc is None or self.proc.poll() is not None, \
             "serve process already running"
-        repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        env = dict(os.environ)
-        env["PYTHONPATH"] = repo_root + os.pathsep \
-            + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         self.proc = subprocess.Popen(
             [sys.executable, "-c", _SERVE_SCRIPT, self.snapshot_path,
              str(self.max_batch), str(self.n_pages),
              str(self.page_size)],
-            stdout=subprocess.PIPE, text=True, env=env)
+            stdout=subprocess.PIPE, text=True, env=_child_env())
         line = self.proc.stdout.readline()   # blocks until READY
         assert line.startswith("READY"), \
             f"serve child failed to start: {line!r}"
@@ -434,6 +422,12 @@ class ServeServerProcess:
 # protocol of the harnesses above: a READY line on startup, then one
 # progress line per unit of work, read by the parent with a deadline.
 def _child_env() -> dict:
+    """Environment of every chaos child that imports jax.  They run on
+    the CPU by design, not as a fallback: a chip belongs to one process,
+    the parent (pytest, a bench lane) may hold it, and what these
+    children prove — recovery after SIGKILL, torn snapshots, lease
+    replay — does not depend on the device.  ``setdefault`` lets a
+    caller that owns no chip choose otherwise."""
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)

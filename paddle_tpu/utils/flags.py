@@ -104,7 +104,6 @@ class FlagRegistry:
 FLAGS = FlagRegistry()
 
 # Core knobs (reference: paddle/utils/Flags.cpp).
-FLAGS.define("use_tpu", True, "run compute on the TPU backend (else CPU)")
 FLAGS.define("trainer_count", 1, "data-parallel replicas (mesh 'data' axis size)")
 FLAGS.define("trainer_id", 0, "index of this host in a multi-host job")
 FLAGS.define("num_hosts", 1, "number of hosts in the job")

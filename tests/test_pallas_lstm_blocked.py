@@ -188,9 +188,17 @@ def test_blocked_lstm_under_bf16_policy(rng, monkeypatch, hblock_on):
 
 # ---------------------------------------------------- tier resolution
 def test_tier_resolution(hblock_on):
-    # single-block fast path unchanged for h <= 512
+    # single-block fast path for h <= 512 …
     assert pallas_lstm.fused_tier(8, 128) == "fused"
     assert pallas_lstm.fused_tier(128, 512) == "fused"
+    # … while the batch fits: Mosaic compiled (256, 512) and (512, 256)
+    # and refused (512, 512) and (1024, 512) on the v5e (PR 21 sweep)
+    assert pallas_lstm.fused_tier(256, 512) == "fused"
+    assert pallas_lstm.fused_tier(512, 256) == "fused"
+    assert pallas_lstm.fused_tier(512, 512) is None
+    assert pallas_lstm.fused_tier(1024, 512) is None
+    assert pallas_gru.fused_tier(256, 512) == "fused"
+    assert pallas_gru.fused_tier(1024, 512) is None
     # the baseline's big-hidden row lands on the blocked tier
     assert pallas_lstm.fused_tier(128, 1280) == "fused_blocked"
     assert pallas_lstm.fused_tier(128, 2048) == "fused_blocked"
