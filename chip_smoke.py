@@ -40,6 +40,7 @@ import io
 import json
 import math
 import os
+import re
 import sys
 import threading
 import time
@@ -587,6 +588,7 @@ def phase_kernels(S, ctx):
 
     flash = lambda: jax.jit(jax.value_and_grad(flash_loss, (0, 1, 2)))(
         q, k, v)
+    flash_qkv = (q, k, v)
     got = flash()
     with dense_attention():
         want = flash()
@@ -616,12 +618,41 @@ def phase_kernels(S, ctx):
         jax.jit(pa.paged_decode_attention)(q, kp, vp, pidx, lens),
         jax.jit(pa.paged_decode_reference)(q, kp, vp, pidx, lens))
 
+    # the trace can name the kernels: a Mosaic call compiles to an
+    # instruction named after ops/kernels.py's table (its ``name=``),
+    # whatever scope or jitted lambda it sits in — the op events of a
+    # device trace carry that instruction's line.  A rehearsal has no
+    # Mosaic call to look at, only the scope in the lowering's locations
+    from paddle_tpu.ops import kernels as K
+    from paddle_tpu.ops import nn_ops
+
+    z = randn(2, 8, 8, 64, dtype=jnp.bfloat16)
+    w = randn(3, 3, 64, 64, dtype=jnp.bfloat16, scale=0.05)
+    a, c = randn(64), randn(64)
+    with jax.named_scope("__exconv_0__"):     # a layer's scope around it
+        lowered = {
+            K.CONV_BN_FWD: jax.jit(
+                lambda z, a, c, w: nn_ops.affine_act_conv2d(z, a, c, w)
+            ).lower(z, a, c, w),
+            K.FLASH_FWD: jax.jit(lambda q, k, v: pa.flash_attention(
+                q, k, v, None, True, blk, blk)).lower(*flash_qkv)}
+    for name, low in lowered.items():
+        if ctx.args.rehearsal:
+            found = name in low.as_text(debug_info=True)
+        else:
+            found = re.search(K.instruction_pattern(name)
+                              + ".*tpu_custom_call",
+                              low.compile().as_text())
+        check(found, f"no instruction named {name!r} in the compiled "
+                     "program: the device trace cannot name the kernel")
+
     errs = {k: round(e, 5) for k, e in errs.items()}
     bad = {k: e for k, e in errs.items() if not e <= REL_TOL}
     check(not bad, f"kernel != reference beyond {REL_TOL}: {bad} "
                    f"(all: {errs})")
     # no Expect: the references tick their own fallback labels by design
-    return {"rel_err": errs, "tolerance": REL_TOL}, None
+    return {"rel_err": errs, "tolerance": REL_TOL,
+            "named_kernels": sorted(lowered)}, None
 
 
 # ------------------------------------------------------ --all phases

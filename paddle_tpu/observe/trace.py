@@ -90,9 +90,21 @@ def _new_id() -> str:
         return "%016x" % _ids.getrandbits(64)
 
 
+def new_trace_id() -> str:
+    """A trace id for work that starts outside any span (a request a
+    client submits): what :func:`record_span` files its spans under."""
+    return _new_id()
+
+
+def clock_us(t_perf: float) -> float:
+    """A ``time.perf_counter()`` reading on the span clock: wall-clock
+    microseconds (epoch-aligned), the ``ts_us`` of :func:`record_span`."""
+    return (t_perf + _EPOCH_OFFSET_S) * 1e6
+
+
 def now_us() -> float:
     """Wall-clock microseconds on the span clock (epoch-aligned)."""
-    return (time.perf_counter() + _EPOCH_OFFSET_S) * 1e6
+    return clock_us(time.perf_counter())
 
 
 # ------------------------------------------------------------- context

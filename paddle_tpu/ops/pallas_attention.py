@@ -64,6 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.device import pallas_interpret
+from . import kernels as K
 from ..observe import counter
 from ..utils import enforce
 from ..utils.logger import get_logger, warn_once
@@ -370,6 +371,17 @@ def _dense_forward(q, k, v, lengths, causal, segments=None):
     return out.astype(q.dtype), lse
 
 
+def _record_attn_work(kernel, bh, tq, tk, bq, bk, d, causal, slot,
+                      operands, results):
+    """The work account of one flash kernel (``ops/kernels.py``): 4·d
+    FLOPs per (query, key) position of the statically live blocks —
+    QKᵀ and PV forward; dP and dQ, or dV and dK, backward (the scores
+    a backward kernel recomputes are re-done work, not the op's)."""
+    n_pairs = _pair_tables(tq, tk, bq, bk, causal, slot)[0].shape[1]
+    K.record_kernel_work(kernel, 4.0 * d * bq * bk * n_pairs * bh,
+                         operands, results)
+
+
 def _heads_first(a, b, t, h, d):
     """[B, T, H, D] → [B·H, T, D] so one grid row owns one head."""
     return a.transpose(0, 2, 1, 3).reshape(b * h, t, d)
@@ -438,16 +450,21 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
     kernel = functools.partial(
         _fa_pair_kernel, scale=scale, causal=causal, block_q=bq,
         block_k=bk, n_heads=h, packed=segments is not None)
+    out_shape = [
+        jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+        jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
+    ]
+    name = K.FLASH_FWD if segments is None else K.FLASH_FWD_PACKED
+    _record_attn_work(name, b * h, tq, tk, bq, bk, d, causal, slot,
+                      operands, out_shape)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
-        ],
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=pallas_interpret(),
+        name=name,
     )(lengths.astype(jnp.int32), lo, hi, tab, *operands)
     out = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :].reshape(b, h, tq)
@@ -538,16 +555,20 @@ def _fa_forward_grid(q, k, v, lengths, causal, bq, bk):
             pltpu.VMEM((bq, d), jnp.float32),       # output accumulator
         ],
     )
+    out_shape = [
+        jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+        jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
+    ]
+    _record_attn_work(K.FLASH_FWD_GRID, b * h, tq, tk, bq, bk, d, causal,
+                      0, (qh, kh, vh), out_shape)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
-        ],
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=pallas_interpret(),
+        name=K.FLASH_FWD_GRID,
     )(lengths.astype(jnp.int32), qh, kh, vh)
     out = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :].reshape(b, h, tq)
@@ -816,6 +837,12 @@ def _fa_backward_sparse(q, k, v, lengths, out, lse, do, causal, bq, bk,
         in_specs += [pl.BlockSpec((1, bq, 1), sq_idx),
                      pl.BlockSpec((1, bk, 1), sk_idx)]
         operands += [seg3, seg3]
+    dq_shape = [jax.ShapeDtypeStruct((b * h, tq, d), jnp.float32)]
+    dkv_shape = [jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32)] * 2
+    _record_attn_work(K.FLASH_BWD_DQ, b * h, tq, tk, bq, bk, d, causal,
+                      slot, operands, dq_shape)
+    _record_attn_work(K.FLASH_BWD_DKV, b * h, tq, tk, bq, bk, d, causal,
+                      slot, operands, dkv_shape)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_pair_kernel, scale=scale,
                           causal=causal, block_q=bq, block_k=bk,
@@ -827,7 +854,8 @@ def _fa_backward_sparse(q, k, v, lengths, out, lse, do, causal, bq, bk,
             out_specs=[pl.BlockSpec((1, bq, d), q_idx)],
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         ),
-        out_shape=[jax.ShapeDtypeStruct((b * h, tq, d), jnp.float32)],
+        out_shape=dq_shape,
+        name=K.FLASH_BWD_DQ,
         **common,
     )(lengths, lo_q, hi_q, tab_q, *operands)[0]
 
@@ -886,10 +914,8 @@ def _fa_backward_sparse(q, k, v, lengths, out, lse, do, causal, bq, bk,
                 pltpu.VMEM((bk, d), jnp.float32),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32),
-        ],
+        out_shape=dkv_shape,
+        name=K.FLASH_BWD_DKV,
         **common,
     )(lengths, lo_k, hi_k, tab_k, *operands2)
 
@@ -973,6 +999,13 @@ def _fa_backward_pallas(q, k, v, lengths, out, lse, do, causal, bq, bk):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=pallas_interpret(),
     )
+    operands = (qh, kh, vh, doh, lse3, delta)
+    dq_shape = [jax.ShapeDtypeStruct((b * h, tq, d), jnp.float32)]
+    dkv_shape = [jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32)] * 2
+    _record_attn_work(K.FLASH_BWD_DQ_GRID, b * h, tq, tk, bq, bk, d,
+                      causal, 0, operands, dq_shape)
+    _record_attn_work(K.FLASH_BWD_DKV_GRID, b * h, tq, tk, bq, bk, d,
+                      causal, 0, operands, dkv_shape)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, n_kblocks=tk // bk,
@@ -993,9 +1026,10 @@ def _fa_backward_pallas(q, k, v, lengths, out, lse, do, causal, bq, bk):
             ],
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         ),
-        out_shape=[jax.ShapeDtypeStruct((b * h, tq, d), jnp.float32)],
+        out_shape=dq_shape,
+        name=K.FLASH_BWD_DQ_GRID,
         **common,
-    )(lengths, qh, kh, vh, doh, lse3, delta)[0]
+    )(lengths, *operands)[0]
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -1021,12 +1055,10 @@ def _fa_backward_pallas(q, k, v, lengths, out, lse, do, causal, bq, bk):
                 pltpu.VMEM((bk, d), jnp.float32),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32),
-        ],
+        out_shape=dkv_shape,
+        name=K.FLASH_BWD_DKV_GRID,
         **common,
-    )(lengths, qh, kh, vh, doh, lse3, delta)
+    )(lengths, *operands)
 
     unpack_q = lambda a: a.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
     unpack_k = lambda a: a.reshape(b, h, tk, d).transpose(0, 2, 1, 3)
@@ -1295,6 +1327,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=pallas_interpret(),
+        # THE one call without name= (tests/test_kernel_names.py lists
+        # it): the accepted benchmark metric paged_decode_roofline.serve
+        # finds this kernel as ``%_lambda_.N = f32[B·H,1,D] …``, the
+        # instruction name it inherits from serving/model.py's
+        # jax.jit(lambda …); name=K.PAGED_DECODE comes with the
+        # benchmark PR that repoints that metric (PERF.md §7).  Its
+        # work depends on run-time lengths: the serve loop's
+        # serve_decode_step span carries live_tokens / live_pages.
     )(lengths, used, page_indices.astype(jnp.int32), qh, kp, vp)[0]
     return out.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)
 

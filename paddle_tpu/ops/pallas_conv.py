@@ -77,6 +77,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.device import pallas_interpret
+from . import kernels as K
 
 # VMEM budget for the gate: tiles + resident weights must fit under the
 # 16 MB scoped-vmem cap with headroom for double-buffering.
@@ -190,6 +191,12 @@ def _conv3x3(x, w):
         dimension_numbers=dn)
 
 
+def _conv_flops(n, h, w, cin, cout) -> float:
+    """One direction (forward or backward-data) of a 3×3 stride-1 conv
+    over [N, H, W]: what ``kernels.record_kernel_work`` is told."""
+    return 2.0 * n * h * w * 9 * cin * cout
+
+
 # ------------------------------------------------------------- dX kernel
 def _dx_kernel(g_ref, z_ref, co_ref, wt_ref, dx_ref, dz_ref, pad_s, *,
                hh, ww):
@@ -235,6 +242,13 @@ def _dx_call(dy, z, coeffs, w, dx_dtype, dz_dtype):
     # weights (constant-folded outside the step loop by XLA)
     wt = jnp.flip(w, (0, 1)).transpose(0, 1, 3, 2)   # [3, 3, Cout, Cin]
     kernel = _partial(_dx_kernel, hh=h, ww=ww)
+    out_shape = [
+        jax.ShapeDtypeStruct((n, h, ww, cin), dx_dtype),
+        jax.ShapeDtypeStruct((n, h, ww, cout), dz_dtype),
+    ]
+    # the op: one 3×3 backward-data conv
+    K.record_kernel_work(K.CONV_BN_DX, _conv_flops(n, h, ww, cin, cout),
+                         (dy, z, coeffs, wt), out_shape)
     return pl.pallas_call(
         kernel,
         grid=(n,),
@@ -248,16 +262,14 @@ def _dx_call(dy, z, coeffs, w, dx_dtype, dz_dtype):
             pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # dx
             pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),  # dz
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, h, ww, cin), dx_dtype),
-            jax.ShapeDtypeStruct((n, h, ww, cout), dz_dtype),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dz
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.CONV_BN_DX,
     )(dy, z, coeffs, wt)
 
 
@@ -375,6 +387,10 @@ def _fwd_call(z, ci, w, out_dtype, relu):
     n, h, ww, cin = z.shape
     cout = w.shape[3]
     kernel = _partial(_fwd_kernel, hh=h, ww=ww, relu=relu)
+    out_shape = jax.ShapeDtypeStruct((n, h, ww, cout), out_dtype)
+    # the op: one 3×3 forward conv
+    K.record_kernel_work(K.CONV_BN_FWD, _conv_flops(n, h, ww, cin, cout),
+                         (z, ci, w), (out_shape,))
     return pl.pallas_call(
         kernel,
         grid=(n,),
@@ -384,13 +400,14 @@ def _fwd_call(z, ci, w, out_dtype, relu):
             pl.BlockSpec((3, 3, cin, cout), lambda i: (0, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, h, ww, cout), out_dtype),
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((h + 2, ww + 2, cin), jnp.float32),   # padded x
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.CONV_BN_FWD,
     )(z, ci, w)
 
 
@@ -445,6 +462,15 @@ def _fwd_bwd_call(dy, z, ci, w, relu):
     cin = w.shape[2]
     wt = jnp.flip(w, (0, 1)).transpose(0, 1, 3, 2)   # [3, 3, Cout, Cin]
     kernel = _partial(_fwd_bwd_kernel, hh=h, ww=ww, relu=relu)
+    out_shape = [
+        jax.ShapeDtypeStruct((n, h, ww, cin), z.dtype),
+        jax.ShapeDtypeStruct((n, h, ww, cin), z.dtype),
+        jax.ShapeDtypeStruct((8, cin), jnp.float32),
+    ]
+    # the op: one 3×3 backward-data conv (x is recomputed, not work)
+    K.record_kernel_work(K.CONV_BN_FWD_BWD,
+                         _conv_flops(n, h, ww, cin, cout),
+                         (dy, z, ci, wt), out_shape)
     return pl.pallas_call(
         kernel,
         grid=(n,),
@@ -459,17 +485,14 @@ def _fwd_bwd_call(dy, z, ci, w, relu):
             pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # x
             pl.BlockSpec((8, cin), lambda i: (0, 0)),             # dA/dC
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, h, ww, cin), z.dtype),
-            jax.ShapeDtypeStruct((n, h, ww, cin), z.dtype),
-            jax.ShapeDtypeStruct((8, cin), jnp.float32),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dy
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.CONV_BN_FWD_BWD,
     )(dy, z, ci, wt)
 
 
@@ -562,6 +585,16 @@ def _chain_bwd_call(dy, z2, co, z1, ci, w, relu):
     cin = w.shape[2]
     wt = jnp.flip(w, (0, 1)).transpose(0, 1, 3, 2)
     kernel = _partial(_chain_bwd_kernel, hh=h, ww=ww, relu=relu)
+    out_shape = [
+        jax.ShapeDtypeStruct((n, h, ww, cout), z2.dtype),
+        jax.ShapeDtypeStruct((n, h, ww, cin), z1.dtype),
+        jax.ShapeDtypeStruct((n, h, ww, cin), z1.dtype),
+        jax.ShapeDtypeStruct((8, cin), jnp.float32),
+    ]
+    # the op: one 3×3 backward-data conv between two BN affines
+    K.record_kernel_work(K.CONV_BN_CHAIN_BWD,
+                         _conv_flops(n, h, ww, cin, cout),
+                         (dy, z2, co, z1, ci, wt), out_shape)
     return pl.pallas_call(
         kernel,
         grid=(n,),
@@ -579,18 +612,14 @@ def _chain_bwd_call(dy, z2, co, z1, ci, w, relu):
             pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # x1
             pl.BlockSpec((8, cin), lambda i: (0, 0)),             # dA/dC
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, h, ww, cout), z2.dtype),
-            jax.ShapeDtypeStruct((n, h, ww, cin), z1.dtype),
-            jax.ShapeDtypeStruct((n, h, ww, cin), z1.dtype),
-            jax.ShapeDtypeStruct((8, cin), jnp.float32),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dz2
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.CONV_BN_CHAIN_BWD,
     )(dy, z2, co, z1, ci, wt)
 
 

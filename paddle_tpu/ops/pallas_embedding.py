@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.device import pallas_interpret
+from . import kernels as K
 from ..observe import counter
 from ..utils import FLAGS
 from ..utils.logger import get_logger, warn_once
@@ -105,6 +106,7 @@ def _gather_rows_kernel(table: jax.Array, rows: jax.Array) -> jax.Array:
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.EMBEDDING_GATHER,
     )(safe, table.reshape(v // _TILE_ROWS, _TILE_ROWS, d))
     return out.reshape(k_pad, d)[:k]
 

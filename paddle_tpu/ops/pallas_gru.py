@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.device import pallas_interpret
+from . import kernels as K
 from .pallas_lstm import (HBLOCK, _from_gate_blocks, _to_gate_blocks,
                           fused_tier as _lstm_fused_tier)
 
@@ -108,6 +109,7 @@ def _fwd_call(xw, mask, w_gates, w_cand, h0):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.GRU_FWD,
     )(xw, mask, w_gates, w_cand, h0)
 
 
@@ -186,6 +188,7 @@ def _bwd_call(gates, h_prev_seq, mask, w_gates, w_cand, dy):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.GRU_BWD,
     )(gates, h_prev_seq, mask, w_gates, w_cand, dy)
 
 
@@ -328,6 +331,7 @@ def _fwd_call_blocked(xur, xc, mask, w_gates, w_cand, h0, hb=HBLOCK):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
+        name=K.GRU_FWD_BLOCKED,
     )(xur, xc, mask, w_gates, w_cand, h0)
 
 
@@ -442,6 +446,7 @@ def _bwd_call_blocked(ur_seq, c_seq, h_prev_seq, mask, w_gates, w_cand,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
+        name=K.GRU_BWD_BLOCKED,
     )(ur_seq, c_seq, h_prev_seq, mask, w_gates, w_cand, dy)
 
 
@@ -489,6 +494,7 @@ def _dw_call_blocked(h_prev_seq, rh_seq, dg_seq, dcp_seq, hb=HBLOCK):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
+        name=K.GRU_DW_BLOCKED,
     )(h_prev_seq, rh_seq, dg_seq, dcp_seq)
 
 

@@ -52,6 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 from ..core.device import pallas_interpret
+from . import kernels as K
 
 
 # Hidden-block width of the blocked tier.  128 = one lane tile, the
@@ -244,6 +245,7 @@ def _fwd_call(xw, mask, w_hh, checks, h0, c0):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.LSTM_FWD,
     )(xw, mask, w_hh, checks, h0, c0)
 
 
@@ -352,6 +354,7 @@ def _bwd_call(gates, h_prev_seq, c_prev_seq, c_seq, mask, w_hh, checks,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
+        name=K.LSTM_BWD,
     )(gates, h_prev_seq, c_prev_seq, c_seq, mask, w_hh, checks, dy, dyc)
 
 
@@ -516,6 +519,7 @@ def _fwd_call_blocked(xw, mask, w_hh, checks, h0, c0, hb=HBLOCK):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
+        name=K.LSTM_FWD_BLOCKED,
     )(xw, mask, w_hh, checks, h0, c0)
 
 
@@ -628,6 +632,7 @@ def _bwd_call_blocked(gates, c_prev_seq, c_seq, mask, w_hh, checks,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
+        name=K.LSTM_BWD_BLOCKED,
     )(gates, c_prev_seq, c_seq, mask, w_hh, checks, dy, dyc)
 
 
@@ -664,6 +669,7 @@ def _dw_call_blocked(h_prev_seq, dgates, hb=HBLOCK):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
+        name=K.LSTM_DW_BLOCKED,
     )(h_prev_seq, dgates)
 
 

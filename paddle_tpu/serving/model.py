@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..layers.beam_search import eos_frozen_logits
+from ..observe.trace import span as _span
 from ..ops.pallas_attention import (flash_attention_packed, paged_kv_write,
                                     paged_decode_attention,
                                     segments_from_lengths)
@@ -227,25 +228,32 @@ class DecoderModel:
         ``tokens`` [B, T] int32 padded, ``lengths`` [B], ``page_indices``
         [B, max_pages] physical page tables covering each prompt PLUS
         the tokens to be generated."""
-        tokens = jnp.asarray(tokens, jnp.int32)
-        enforce(tokens.ndim == 2 and tokens.shape[1] <= self.cfg.max_context,
-                f"prompt batch {tokens.shape} exceeds max_context "
+        shape = np.shape(tokens)
+        enforce(len(shape) == 2 and shape[1] <= self.cfg.max_context,
+                f"prompt batch {shape} exceeds max_context "
                 f"{self.cfg.max_context}")
-        nxt, logits, k_pool, v_pool = self._prefill(
-            self.params, k_pool, v_pool, tokens,
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(page_indices, jnp.int32))
-        return np.asarray(nxt), np.asarray(logits), k_pool, v_pool
+        with _span("prefill_dispatch"):       # host→device + launch
+            nxt, logits, k_pool, v_pool = self._prefill(
+                self.params, k_pool, v_pool,
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(page_indices, jnp.int32))
+        with _span("prefill_fetch"):          # blocks on the device
+            nxt, logits = np.asarray(nxt), np.asarray(logits)
+        return nxt, logits, k_pool, v_pool
 
     def decode(self, k_pool, v_pool, tokens, page_indices, lengths, active):
         """One continuous-batching decode step over the page pool."""
-        nxt, logits, k_pool, v_pool = self._decode(
-            self.params, k_pool, v_pool,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(page_indices, jnp.int32),
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(active, bool))
-        return np.asarray(nxt), np.asarray(logits), k_pool, v_pool
+        with _span("decode_dispatch"):        # host→device + launch
+            nxt, logits, k_pool, v_pool = self._decode(
+                self.params, k_pool, v_pool,
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(page_indices, jnp.int32),
+                jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(active, bool))
+        with _span("decode_fetch"):           # blocks on the device
+            nxt, logits = np.asarray(nxt), np.asarray(logits)
+        return nxt, logits, k_pool, v_pool
 
     # -------------------------------------------------------- artifacts
     @classmethod

@@ -36,10 +36,33 @@ Telemetry (all optional, live when ``paddle_tpu.observe`` is active):
 ``serve_ttft_seconds`` / ``serve_request_seconds`` reservoir histograms
 (p99 SLO source), ``serve_queue_depth`` / ``serve_batch_size`` gauges,
 ``serve_requests`` / ``serve_tokens_generated`` counters,
-``serve_page_pool_pages`` pool census, and the
-``serve_admit`` / ``serve_prefill`` / ``serve_decode_step`` span
-family.  Threads are ``ptpu-serve-decode`` and ``ptpu-serve-http``
-(the conftest thread-leak guard and ptpu-lint key on the prefix).
+``serve_page_pool_pages`` pool census, and the span family below.
+Threads are ``ptpu-serve-decode`` and ``ptpu-serve-http`` (the conftest
+thread-leak guard and ptpu-lint key on the prefix).
+
+Spans (``observe.trace``; one shared no-op each when tracing is off).
+The decode thread opens ``serve_loop_iter`` once there is work, so that
+nothing it does for a request lies outside a span; under it::
+
+    serve_loop_iter
+    ├─ serve_admit{queued}
+    ├─ serve_prefill{n, t_pad, prompt_tokens, requests}
+    │    serve_step_build · prefill_dispatch · prefill_fetch · serve_step_emit
+    ├─ serve_decode_step{batch, live_tokens, live_pages}
+    │    serve_step_build · decode_dispatch · decode_fetch · serve_step_emit
+    └─ serve_snapshot
+
+``serve_step_build`` is the host's numpy input build, ``*_dispatch`` the
+host→device copy of the inputs and the jitted call's return (the
+launch), ``*_fetch`` the ``np.asarray`` that blocks on the device and
+brings the token ids and logits back (both in ``serving/model.py``),
+``serve_step_emit`` the token bookkeeping, finishes and page releases.
+``live_tokens`` is Σ ``lengths`` of the active rows — the K/V positions
+the step attends over — and ``live_pages`` the pages they occupy.  Each
+request carries a ``trace_id`` (its submitter's trace, else its own):
+at admission ``serve_queue_wait`` (submit → admit) and at the finish
+``serve_request`` (submit → last token; ``prompt``, ``tokens``,
+``ttft_ms``) are recorded under it.
 
 Crash safety: with ``snapshot_path`` set, the allocator state persists
 atomically after every mutation; a restarted server restores it only
@@ -88,19 +111,11 @@ HTTP_THREAD_NAME = "ptpu-serve-http"
 _REQ_IDS = itertools.count()
 
 
-def _span_admit(**attrs):
-    return contextlib.nullcontext() if _trace is None \
-        else _trace.span("serve_admit", **attrs)
-
-
-def _span_prefill(**attrs):
-    return contextlib.nullcontext() if _trace is None \
-        else _trace.span("serve_prefill", **attrs)
-
-
-def _span_decode_step(**attrs):
-    return contextlib.nullcontext() if _trace is None \
-        else _trace.span("serve_decode_step", **attrs)
+def _span(name: str, **attrs):
+    if _trace is None:
+        return contextlib.nullcontext()
+    # ptpu: lint-ok[PT-METRIC] forwarding shim; callers pass literals
+    return _trace.span(name, **attrs)
 
 
 class Request:
@@ -110,7 +125,7 @@ class Request:
 
     __slots__ = ("id", "prompt", "max_new_tokens", "tokens", "state",
                  "error", "done", "length", "next_token",
-                 "t_submit", "t_first", "t_done")
+                 "t_submit", "t_admit", "t_first", "t_done", "trace_id")
 
     def __init__(self, prompt: Sequence[int], max_new_tokens: int):
         self.id = f"req{next(_REQ_IDS)}"
@@ -123,8 +138,14 @@ class Request:
         self.length = 0                  # tokens materialized in pages
         self.next_token = -1             # token to feed the next step
         self.t_submit = time.perf_counter()
+        self.t_admit: Optional[float] = None
         self.t_first: Optional[float] = None
         self.t_done: Optional[float] = None
+        # the submitter's trace if it submits from inside a span (an
+        # RPC handler, a client's own span), else a trace of its own
+        ctx = None if _trace is None else _trace.current_context()
+        self.trace_id: Optional[str] = None if _trace is None \
+            else ctx.trace_id if ctx is not None else _trace.new_trace_id()
 
     @property
     def ttft_s(self) -> Optional[float]:
@@ -133,6 +154,15 @@ class Request:
     @property
     def latency_s(self) -> Optional[float]:
         return None if self.t_done is None else self.t_done - self.t_submit
+
+    def record_span(self, name: str, t_end: float, **attrs) -> None:
+        """``name`` from submit to ``t_end`` in this request's trace
+        (returns at once when tracing is off)."""
+        if _trace is not None:
+            ts, dur = _trace.clock_us(self.t_submit), t_end - self.t_submit
+            # ptpu: lint-ok[PT-METRIC] forwarding shim; callers pass literals
+            _trace.record_span(name, ts, dur * 1e6, self.trace_id,
+                               request=self.id, **attrs)
 
 
 class SwapTicket:
@@ -221,6 +251,13 @@ class InferenceServer:
         self.rollout_state = "serving"     # serving|swapping|rolled_back
         self.last_swap_error: Optional[str] = None
         self._pending_swap: Optional[SwapTicket] = None
+        # bound once: a registry look-up (name, help, lock) per token
+        # or per step is host time inside the decode loop
+        self._m_tokens = None if _counter is None else _counter(
+            "serve_tokens_generated", "tokens generated across requests")
+        self._m_batch = None if _gauge is None else _gauge(
+            "serve_batch_size",
+            "requests in the most recent inference launch")
 
     @staticmethod
     def _make_pool(n_pages: int, page_size: int,
@@ -497,7 +534,6 @@ class InferenceServer:
     # ---------------------------------------------------------- decode loop
     def _loop(self) -> None:
         while True:
-            swapped = False
             with self._cond:
                 while not self._stop and not self._queue \
                         and not self._active \
@@ -505,55 +541,68 @@ class InferenceServer:
                     self._cond.wait(0.05)
                 if self._stop:
                     return
-                reprefill: List[Request] = []
-                pending = self._pending_swap
-                if pending is not None and (pending.inflight == "reprefill"
-                                            or not self._active):
-                    # the atomic pointer flip, at the step boundary.
-                    # drain policy only flips once the actives emptied;
-                    # reprefill flips now and restarts them below
-                    reprefill = self._apply_swap_locked(pending)
-                    pending = None
-                    swapped = True
-                # a pending drain swap pauses admission: new requests
-                # must first-run on the NEW model, and the flip waits
-                # for the actives to finish on the old one
-                admitted = [] if pending is not None \
-                    else self._admit_locked()
-            if swapped:
-                self._publish_serving_info()
-            try:
-                batch = reprefill + admitted
-                changed = bool(batch)
-                if batch:
-                    with _span_prefill(n=len(batch)):
-                        self._prefill(batch)
-                if self._active:
-                    with _span_decode_step(batch=len(self._active)):
-                        self._decode_step()
-                    changed = True
-            except Exception as e:  # noqa: BLE001 - one bad batch must
-                # not kill the serve loop: fail its requests, recycle
-                # their pages, keep serving the queue
-                log.exception("decode loop error; failing %d in-flight "
-                              "request(s)", len(self._active))
-                with self._cond:
-                    failed, self._active = self._active, []
-                for r in failed:
-                    self.pool.release(r.id)
-                    r.state = "failed"
-                    r.error = f"{type(e).__name__}: {e}"
-                    r.done.set()
-                    if _histogram is not None:
-                        # unit events: window_rate = failures/s — the
-                        # canary bake's error-rate signal and the
-                        # --slo rate-objective source
-                        _histogram("serve_request_failures",
-                                   "failed requests as unit events "
-                                   "(windowed rate = failures/sec)"
-                                   ).observe(1.0)
+            # there is work: whatever this thread does for it from here
+            # to the next wait lies under one span
+            with _span("serve_loop_iter"):
+                self._iterate()
+
+    def _iterate(self) -> None:
+        """One turn of the decode loop: apply a parked swap, admit,
+        prefill the admitted, advance the active batch one token."""
+        swapped = False
+        with self._cond:
+            if self._stop:
+                return
+            reprefill: List[Request] = []
+            pending = self._pending_swap
+            if pending is not None and (pending.inflight == "reprefill"
+                                        or not self._active):
+                # the atomic pointer flip, at the step boundary.
+                # drain policy only flips once the actives emptied;
+                # reprefill flips now and restarts them below
+                reprefill = self._apply_swap_locked(pending)
+                pending = None
+                swapped = True
+            # a pending drain swap pauses admission: new requests
+            # must first-run on the NEW model, and the flip waits
+            # for the actives to finish on the old one
+            admitted = [] if pending is not None \
+                else self._admit_locked()
+        if swapped:
+            self._publish_serving_info()
+        for r in admitted:
+            r.record_span("serve_queue_wait", r.t_admit)
+        try:
+            batch = reprefill + admitted
+            changed = bool(batch)
+            if batch:
+                self._prefill(batch)
+            if self._active:
+                self._decode_step()
                 changed = True
-            if changed and self.snapshot_path:
+        except Exception as e:  # noqa: BLE001 - one bad batch must
+            # not kill the serve loop: fail its requests, recycle
+            # their pages, keep serving the queue
+            log.exception("decode loop error; failing %d in-flight "
+                          "request(s)", len(self._active))
+            with self._cond:
+                failed, self._active = self._active, []
+            for r in failed:
+                self.pool.release(r.id)
+                r.state = "failed"
+                r.error = f"{type(e).__name__}: {e}"
+                r.done.set()
+                if _histogram is not None:
+                    # unit events: window_rate = failures/s — the
+                    # canary bake's error-rate signal and the
+                    # --slo rate-objective source
+                    _histogram("serve_request_failures",
+                               "failed requests as unit events "
+                               "(windowed rate = failures/sec)"
+                               ).observe(1.0)
+            changed = True
+        if changed and self.snapshot_path:
+            with _span("serve_snapshot"):
                 self.pool.snapshot(self.snapshot_path)
 
     def _admit_locked(self) -> List[Request]:
@@ -562,7 +611,7 @@ class InferenceServer:
         request only when the batch is empty — single-request serving."""
         cap = self.max_batch if self.continuous else 1
         admitted: List[Request] = []
-        with _span_admit(queued=len(self._queue)):
+        with _span("serve_admit", queued=len(self._queue)):
             while self._queue and len(self._active) + len(admitted) < cap:
                 r = self._queue[0]
                 try:
@@ -571,6 +620,7 @@ class InferenceServer:
                 except PagePoolExhausted:
                     break            # backpressure: retry after retires
                 self._queue.popleft()
+                r.t_admit = time.perf_counter()
                 r.state = "active"
                 self._active.append(r)
                 admitted.append(r)
@@ -590,32 +640,38 @@ class InferenceServer:
         # bucket the pad length: bounded set of compiled prefill shapes
         t_pad = -(-t_pad // 16) * 16
         t_pad = min(t_pad, self.model.cfg.max_context)
-        tokens = np.zeros((b, t_pad), np.int32)
-        lengths = np.zeros((b,), np.int32)
-        tables = np.zeros((b, self.max_pages), np.int32)
-        for i, r in enumerate(admitted):
-            tokens[i, :len(r.prompt)] = r.prompt
-            lengths[i] = len(r.prompt)
-            tables[i] = self._table_row(r)
-        # testing/bench knob: a seeded-slow artifact (manifest
-        # debug_prefill_delay_ms) inflates TTFT here — inside the
-        # TTFT stamp, before the launch — so a canary bake has a
-        # deterministic latency regression to detect.  Swap probes
-        # call model.prefill directly and never pay it.
-        delay = getattr(self.model, "debug_prefill_delay_s", 0.0)
-        if delay:
-            time.sleep(delay)
-        nxt, _, self._k_pool, self._v_pool = self.model.prefill(
-            self._k_pool, self._v_pool, tokens, lengths, tables)
-        now = time.perf_counter()
-        for i, r in enumerate(admitted):
-            r.length = len(r.prompt)
-            r.t_first = now
-            if _histogram is not None:
-                _histogram("serve_ttft_seconds",
-                           "submit-to-first-token latency").observe(
-                    now - r.t_submit)
-            self._emit_token(r, int(nxt[i]))
+        with _span("serve_prefill", n=b, t_pad=t_pad,
+                   prompt_tokens=sum(len(r.prompt) for r in admitted),
+                   requests=",".join(r.id for r in admitted)):
+            with _span("serve_step_build"):
+                tokens = np.zeros((b, t_pad), np.int32)
+                lengths = np.zeros((b,), np.int32)
+                tables = np.zeros((b, self.max_pages), np.int32)
+                for i, r in enumerate(admitted):
+                    tokens[i, :len(r.prompt)] = r.prompt
+                    lengths[i] = len(r.prompt)
+                    tables[i] = self._table_row(r)
+            # testing/bench knob: a seeded-slow artifact (manifest
+            # debug_prefill_delay_ms) inflates TTFT here — inside the
+            # TTFT stamp, before the launch — so a canary bake has a
+            # deterministic latency regression to detect.  Swap probes
+            # call model.prefill directly and never pay it.
+            delay = getattr(self.model, "debug_prefill_delay_s", 0.0)
+            if delay:
+                time.sleep(delay)
+            nxt, _, self._k_pool, self._v_pool = self.model.prefill(
+                self._k_pool, self._v_pool, tokens, lengths, tables)
+            with _span("serve_step_emit"):
+                now = time.perf_counter()
+                self._count_tokens(b)
+                for i, r in enumerate(admitted):
+                    r.length = len(r.prompt)
+                    r.t_first = now
+                    if _histogram is not None:
+                        _histogram("serve_ttft_seconds",
+                                   "submit-to-first-token latency"
+                                   ).observe(now - r.t_submit)
+                    self._emit_token(r, int(nxt[i]))
 
     def _decode_step(self) -> None:
         """Advance every active request one token in a single
@@ -625,34 +681,45 @@ class InferenceServer:
         b = self.max_batch if self.continuous else 1
         enforce(len(slots) <= b,
                 f"active {len(slots)} exceeds batch width {b}")
-        tokens = np.zeros((b,), np.int32)
-        lengths = np.ones((b,), np.int32)
-        active = np.zeros((b,), bool)
-        tables = np.full((b, self.max_pages), SCRATCH_PAGE, np.int32)
-        for i, r in enumerate(slots):
-            tokens[i] = r.next_token
-            lengths[i] = r.length + 1    # feeding one new token
-            active[i] = True
-            tables[i] = self._table_row(r)
-        if _gauge is not None:
-            _gauge("serve_batch_size",
-                   "requests in the most recent inference launch").set(
-                len(slots))
-        nxt, _, self._k_pool, self._v_pool = self.model.decode(
-            self._k_pool, self._v_pool, tokens, tables, lengths, active)
-        for i, r in enumerate(slots):
-            r.length += 1
-            self._emit_token(r, int(nxt[i]))
+        # what the step attends over: each row's length INCLUDING the
+        # token it feeds, and the pages those lengths occupy
+        fed = [r.length + 1 for r in slots]
+        with _span("serve_decode_step", batch=len(slots),
+                   live_tokens=sum(fed),
+                   live_pages=sum(map(self.pool.pages_needed, fed))):
+            with _span("serve_step_build"):
+                tokens = np.zeros((b,), np.int32)
+                lengths = np.ones((b,), np.int32)
+                active = np.zeros((b,), bool)
+                tables = np.full((b, self.max_pages), SCRATCH_PAGE,
+                                 np.int32)
+                for i, r in enumerate(slots):
+                    tokens[i] = r.next_token
+                    lengths[i] = fed[i]
+                    active[i] = True
+                    tables[i] = self._table_row(r)
+            if self._m_batch is not None:
+                self._m_batch.set(len(slots))
+            nxt, _, self._k_pool, self._v_pool = self.model.decode(
+                self._k_pool, self._v_pool, tokens, tables, lengths,
+                active)
+            with _span("serve_step_emit"):
+                self._count_tokens(len(slots))
+                for i, r in enumerate(slots):
+                    r.length += 1
+                    self._emit_token(r, int(nxt[i]))
+
+    def _count_tokens(self, n: int) -> None:
+        """``n`` tokens came back from one launch."""
+        self.generated_tokens += n
+        if self._m_tokens is not None:
+            self._m_tokens.inc(n)
 
     def _emit_token(self, r: Request, token: int) -> None:
         """Record one generated token; finish the request on EOS or the
         token budget, releasing its pages for immediate recycling."""
         r.tokens.append(token)
         r.next_token = token
-        self.generated_tokens += 1
-        if _counter is not None:
-            _counter("serve_tokens_generated",
-                     "tokens generated across requests").inc()
         if token == self.model.cfg.eos_id \
                 or len(r.tokens) >= r.max_new_tokens:
             self._finish(r)
@@ -671,6 +738,9 @@ class InferenceServer:
                        "submit-to-last-token latency").observe(
                 r.latency_s)
             _counter("serve_requests", "requests served").inc()
+        r.record_span("serve_request", r.t_done, prompt=len(r.prompt),
+                      tokens=len(r.tokens),
+                      ttft_ms=round(r.ttft_s * 1e3, 3))
         r.done.set()
 
     def _publish_queue_locked(self) -> None:

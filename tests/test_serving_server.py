@@ -278,6 +278,157 @@ def test_server_telemetry(tiny_model):
                              "").retained_samples() >= 3
 
 
+def _traced_serve(model, prompts, max_new=6):
+    """Serve ``prompts`` with the ring on.  → (server, requests, the
+    ring's events, the ``lengths`` and ``active`` of every decode
+    launch as the model got them)."""
+    from paddle_tpu.observe import trace
+    from paddle_tpu.serving.server import InferenceServer
+
+    fed = []
+    real = model.decode
+
+    def decode(k, v, tokens, tables, lengths, active):
+        fed.append((np.array(lengths), np.array(active)))
+        return real(k, v, tokens, tables, lengths, active)
+
+    trace.enable(fences=False)
+    model.decode = decode
+    try:
+        with InferenceServer(model, max_batch=4, n_pages=33,
+                             page_size=8) as srv:
+            reqs = [srv.submit(p, max_new) for p in prompts]
+            for r in reqs:
+                srv.result(r, timeout=120.0)
+        events = trace.events()
+    finally:
+        del model.decode
+        trace.disable()
+    return srv, reqs, events, fed
+
+
+def _named(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def test_spans_belong_to_a_request(tiny_model):
+    """One ``serve_queue_wait`` and one ``serve_request`` per request,
+    under the request's trace id, with its own clocks."""
+    _, reqs, events, _ = _traced_serve(tiny_model, _prompts(6, seed=21))
+    waits = {e["args"]["request"]: e
+             for e in _named(events, "serve_queue_wait")}
+    whole = {e["args"]["request"]: e
+             for e in _named(events, "serve_request")}
+    assert len(_named(events, "serve_queue_wait")) == len(reqs)
+    assert len(_named(events, "serve_request")) == len(reqs)
+    assert len({r.trace_id for r in reqs}) == len(reqs)
+    for r in reqs:
+        w, d = waits[r.id], whole[r.id]
+        assert w["args"]["trace_id"] == d["args"]["trace_id"] == r.trace_id
+        assert r.t_submit <= r.t_admit <= r.t_first <= r.t_done
+        assert w["ts"] == d["ts"]                       # both from submit
+        assert w["dur"] == pytest.approx(
+            (r.t_admit - r.t_submit) * 1e6, abs=1.0)
+        assert d["dur"] == pytest.approx(r.latency_s * 1e6, abs=1.0)
+        assert d["args"]["prompt"] == len(r.prompt)
+        assert d["args"]["tokens"] == len(r.tokens)
+        assert d["args"]["ttft_ms"] == pytest.approx(r.ttft_s * 1e3,
+                                                     abs=1e-3)
+
+
+def test_a_request_joins_its_submitters_trace(tiny_model):
+    from paddle_tpu.observe import trace
+    from paddle_tpu.serving.server import Request
+
+    trace.enable(fences=False)
+    with trace.span("client_call") as sp:
+        inside = Request([2, 3], 2)
+    assert inside.trace_id == sp.context.trace_id
+    assert Request([2, 3], 2).trace_id != inside.trace_id
+
+
+def test_a_step_is_split_into_host_phases(tiny_model):
+    """Every launch span has its four phases as children, every launch
+    lies under a ``serve_loop_iter``, and the spans' counts add up to
+    the tokens the server generated."""
+    srv, reqs, events, _ = _traced_serve(tiny_model, _prompts(6, seed=22))
+    by_parent = {}
+    for e in events:
+        by_parent.setdefault(e["args"].get("parent_id"), []).append(
+            e["name"])
+    iters = {e["args"]["span_id"] for e in _named(events,
+                                                  "serve_loop_iter")}
+    steps, prefills = (_named(events, "serve_decode_step"),
+                       _named(events, "serve_prefill"))
+    assert steps and prefills
+    for e in steps:
+        assert sorted(by_parent[e["args"]["span_id"]]) == sorted(
+            ["serve_step_build", "decode_dispatch", "decode_fetch",
+             "serve_step_emit"])
+    for e in prefills:
+        assert sorted(by_parent[e["args"]["span_id"]]) == sorted(
+            ["serve_step_build", "prefill_dispatch", "prefill_fetch",
+             "serve_step_emit"])
+        assert e["args"]["t_pad"] % 16 == 0
+    for e in steps + prefills + _named(events, "serve_admit"):
+        assert e["args"]["parent_id"] in iters
+    assert sum(e["args"]["batch"] for e in steps) \
+        + sum(e["args"]["n"] for e in prefills) == srv.generated_tokens
+    assert srv.generated_tokens == sum(len(r.tokens) for r in reqs)
+    assert sum(e["args"]["prompt_tokens"] for e in prefills) \
+        == sum(len(r.prompt) for r in reqs)
+    admitted = [i for e in prefills for i in e["args"]["requests"].split(",")]
+    assert sorted(admitted) == sorted(r.id for r in reqs)
+
+
+def test_decode_span_states_the_kv_it_attends_over(tiny_model):
+    """``live_tokens`` is the sum of the lengths the launch was fed for
+    its active rows, ``live_pages`` the pages those lengths occupy."""
+    srv, _, events, fed = _traced_serve(tiny_model, _prompts(5, seed=23),
+                                        max_new=8)
+    steps = _named(events, "serve_decode_step")
+    assert len(steps) == len(fed)
+    for e, (lengths, active) in zip(steps, fed):
+        assert e["args"]["batch"] == int(active.sum())
+        assert e["args"]["live_tokens"] == int(lengths[active].sum())
+        assert e["args"]["live_pages"] == sum(
+            srv.pool.pages_needed(int(n)) for n in lengths[active])
+
+
+def test_snapshot_has_its_span(tiny_model, tmp_path):
+    from paddle_tpu.observe import trace
+    from paddle_tpu.serving.server import InferenceServer
+
+    trace.enable(fences=False)
+    with InferenceServer(tiny_model, max_batch=2, n_pages=17, page_size=8,
+                         snapshot_path=str(tmp_path / "pool.snap")) as srv:
+        srv.generate([2, 3, 4], 3, timeout=120.0)
+    events = trace.events()
+    iters = {e["args"]["span_id"] for e in _named(events,
+                                                  "serve_loop_iter")}
+    snaps = _named(events, "serve_snapshot")
+    assert snaps and all(e["args"]["parent_id"] in iters for e in snaps)
+
+
+def test_tracing_off_records_nothing_and_counts_every_token(tiny_model):
+    from paddle_tpu import observe
+    from paddle_tpu.observe import trace
+    from paddle_tpu.serving.server import InferenceServer
+
+    assert not trace.enabled()
+    assert trace.span("serve_decode_step", batch=1) is trace._NULL_SPAN
+    with InferenceServer(tiny_model, max_batch=4, n_pages=33,
+                         page_size=8) as srv:
+        reqs = [srv.submit(p, 6) for p in _prompts(5, seed=24)]
+        outs = [srv.result(r, timeout=120.0) for r in reqs]
+    assert trace.events() == [] and not trace.enabled()
+    assert all(r.t_admit is not None and r.trace_id for r in reqs)
+    made = sum(len(t) for t in outs)
+    assert srv.generated_tokens == made
+    assert observe.counter("serve_tokens_generated", "").value() == made
+    assert observe.gauge("serve_batch_size", "").value() >= 1
+
+
 def test_server_thread_names(tiny_model):
     from paddle_tpu.serving.server import (DECODE_THREAD_NAME,
                                            InferenceServer)
