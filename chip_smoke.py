@@ -522,6 +522,48 @@ def _rel_err(got, want):
     return worst
 
 
+_HLO_ARRAY = r"[a-z0-9]+\[([\d,]*)\](?:\{([\d,]*)[^}]*\})?"
+
+
+def decode_step_checks(compiled, pool_elems):
+    """What the benchmark's traced run needs of the server's compiled
+    decode step: an instruction that the accepted metric
+    ``paged_decode_roofline.serve`` finds by its own pattern, and no
+    relayout of a layer's pool (a ``transpose``, a ``reshape`` the
+    compiler could not make a bitcast, a ``copy`` into another
+    dimension order) anywhere in it, so none feeds that instruction.
+    Copies that keep the order (the undonated pool's, the compiler's
+    moves between memory spaces) are counted, not refused."""
+    from jax._src.lib import xla_client as xc
+
+    with open(os.path.join(HERE, "chipbench", "metrics",
+                           "paged_decode_roofline.serve.json")) as f:
+        patterns = json.load(f)["args"]["patterns"]
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    text = "\n".join(m.to_string(opts)
+                     for m in compiled.runtime_executable().hlo_modules())
+    calls, plain, relayouts = 0, 0, []
+    for line in map(str.strip, text.splitlines()):
+        calls += any(re.search(pat, line) for pat in patterns)
+        m = re.match(r"(?:ROOT )?(%[\w.\-]+) = " + _HLO_ARRAY
+                     + r" (copy|transpose|reshape)\(" + _HLO_ARRAY, line)
+        if not m or math.prod(
+                int(n) for n in m.group(2).split(",") if n) < pool_elems:
+            continue
+        if m.group(4) == "copy" and m.group(3) == m.group(6):
+            plain += 1
+        else:
+            relayouts.append(line[:160])
+    check(calls, "no instruction of the compiled decode step matches "
+                 f"{patterns}: the traced run of the benchmark cannot "
+                 "find the paged decode kernel")
+    check(not relayouts, "the compiled decode step relayouts a layer's "
+                         f"pool: {relayouts}")
+    return {"decode_calls": calls, "pool_copies_in_place_order": plain}
+
+
 def phase_kernels(S, ctx):
     """Each kernel family on the path against the reference the CPU
     tests use, on this backend's numerics, within ``REL_TOL``."""
@@ -618,6 +660,23 @@ def phase_kernels(S, ctx):
         jax.jit(pa.paged_decode_attention)(q, kp, vp, pidx, lens),
         jax.jit(pa.paged_decode_reference)(q, kp, vp, pidx, lens))
 
+    # the decode step as the server compiles it, small: the benchmark's
+    # traced run must find the kernel in it, fed by the pool as stored.
+    # A rehearsal has no Mosaic call to look at
+    decode_step = {}
+    if not ctx.args.rehearsal:
+        from paddle_tpu.serving.model import (DecoderConfig, DecoderModel,
+                                              init_decoder_params)
+        cfg = DecoderConfig(vocab=512, dim=nh * d, heads=nh, layers=2,
+                            ffn=2 * nh * d, max_context=max_pages * page)
+        model = DecoderModel(init_decoder_params(cfg, seed=0), cfg)
+        decode_step = decode_step_checks(
+            model._decode.lower(
+                model.params, *model.new_pools(n_pages, page),
+                jnp.zeros((b,), jnp.int32), pidx, lens,
+                jnp.ones((b,), bool)).compile(),
+            n_pages * page * nh * d)
+
     # the trace can name the kernels: a Mosaic call compiles to an
     # instruction named after ops/kernels.py's table (its ``name=``),
     # whatever scope or jitted lambda it sits in — the op events of a
@@ -652,7 +711,7 @@ def phase_kernels(S, ctx):
                    f"(all: {errs})")
     # no Expect: the references tick their own fallback labels by design
     return {"rel_err": errs, "tolerance": REL_TOL,
-            "named_kernels": sorted(lowered)}, None
+            "named_kernels": sorted(lowered), **decode_step}, None
 
 
 # ------------------------------------------------------ --all phases

@@ -35,8 +35,12 @@ Three entry points:
 - :func:`paged_decode_attention` — the serving decode primitive: a
   small-Tq query batch attends a block-paged KV cache through a
   per-row page table + valid lengths (ROADMAP items 1 and 5's shared
-  base; *Ragged Paged Attention*, arxiv 2604.15464).  Inference-only
-  (no VJP).
+  base; *Ragged Paged Attention*, arxiv 2604.15464).  One kernel
+  invocation loops over each row's live pages only — one step per row
+  and page, all heads in it, the page fetched by DMA from the pool
+  left in HBM as the server stores it (``[P, page, H·D]``) — so its
+  time follows the live K/V, not the page table's width.
+  Inference-only (no VJP).
 
 Layout matches :mod:`paddle_tpu.parallel.ring_attention`'s
 ``full_attention``: q, k, v are ``[B, T, H, D]``; output ``[B, T, H, D]``.
@@ -1197,64 +1201,101 @@ def segments_from_lengths(lengths, batch: int, t: int):
 
 
 # --------------------------------------------------- paged-KV decode
-def _decode_kernel(len_ref, used_ref, pidx_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_s, l_s, acc_s, *, scale, page, t_q,
-                   n_heads, n_pages_max):
-    """Grid (B·H, max_pages_per_row): one query tile (small Tq — the
-    decode step's new tokens) attends its row's paged KV cache, one
-    physical page per grid step, via the scalar-prefetched page table.
-    Pages wholly past the row's length are clamped to the last used
-    page (no DMA when the index repeats) and compute-skipped."""
-    i = pl.program_id(0)
-    p = pl.program_id(1)
-    b = i // n_heads
-    kv_len = len_ref[b]
+def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
+                   o_ref, kbuf, vbuf, sem, qe_s, m_s, l_s, acc_s, *,
+                   scale, page, t_q, n_heads, d, n_pages_max):
+    """One invocation, one loop step per row and live page, all heads
+    in that step — so the time follows the K/V that is live, not the
+    table's width.  The pools stay in HBM as they are stored
+    (``[P, page, H·D]``); each live page of K and of V comes by its
+    own DMA into a double buffer, the next page (the next live row's
+    first, at a row's end) in flight while this one is attended.  All
+    heads go through one product: the query is laid out
+    block-diagonally (``[H, H·D]``, head h's D lanes in row h), so
+    ``qe @ k.T`` is every head's scores ``[H, page]`` and the diagonal
+    blocks of ``p @ v`` ``[H, H·D]`` are every head's output;
+    ``m``/``l``/acc stay f32.  Table slots past a row's used pages are
+    never read, let alone dereferenced."""
+    n_rows, _, hd = q_ref.shape
 
-    @pl.when(p == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+    def copies(b, j, slot):
+        pg = pidx_ref[b, j]
+        return (pltpu.make_async_copy(k_hbm.at[pg], kbuf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pg], vbuf.at[slot],
+                                      sem.at[1, slot]))
 
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale         # [tq, D]
-        kb = k_ref[0]                                    # [page, D]
-        vb = v_ref[0]
-        s = q @ kb.astype(jnp.float32).T                 # [tq, page]
-        ki = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (t_q, page), 1)
-        # query r sits at absolute position kv_len - t_q + r: it may
-        # attend every key at or before itself (ragged causal tail)
-        qpos = kv_len - t_q + jax.lax.broadcasted_iota(
-            jnp.int32, (t_q, page), 0)
-        valid = ki <= qpos
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_s[:]
-        l_prev = l_s[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        # fully-masked query rows (0 < length < Tq: the leading rows of
-        # a speculative/chunked tile sit at negative positions) have
-        # m_new = NEG_INF; clamp the exponent base so exp(s − m) under-
-        # flows to 0 instead of exp(−inf − (−inf)) = 1 leaking V mass —
-        # same guard as _fa_pair_kernel, flush's l_safe emits zeros
-        m_base = jnp.maximum(m_new, NEG_INF / 2)
-        pexp = jnp.exp(s - m_base)
-        alpha = jnp.exp(m_prev - m_base)
-        # Pallas VMEM scratch refs are the kernel's mutable-by-design
-        # accumulator API (this kernel is jit-reachable directly, not
-        # through a custom_vjp wrapper, so PT-TRACE sees the writes)
-        m_s[:] = m_new                          # ptpu: lint-ok[PT-TRACE]
-        # ptpu: lint-ok[PT-TRACE]
-        l_s[:] = l_prev * alpha + pexp.sum(axis=-1, keepdims=True)
-        # ptpu: lint-ok[PT-TRACE]
-        acc_s[:] = acc_s[:] * alpha + pexp @ vb.astype(jnp.float32)
+    def fetch(b, j, slot):
+        @pl.when(b < n_rows)              # live_ref says n_rows for "none"
+        def _():
+            for cp in copies(b, j, slot):
+                cp.start()
 
-    pl.when(p * page < kv_len)(_step)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 1)
+    own = (lanes >= rows * d) & (lanes < (rows + 1) * d)     # [H, H·D]
+    ki = jax.lax.broadcasted_iota(jnp.int32, (n_heads, page), 1)
 
-    @pl.when(p == n_pages_max - 1)
-    def _flush():
-        l_safe = jnp.where(l_s[:] == 0.0, 1.0, l_s[:])
-        o_ref[0] = (acc_s[:] / l_safe).astype(o_ref.dtype)
+    def _row(b, n):
+        """``n`` counts the pages attended so far: its parity is the
+        buffer the current page lies in."""
+        kv_len = len_ref[b]
+        used = jnp.minimum((kv_len + page - 1) // page, n_pages_max)
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+        for t in range(t_q):
+            qt = q_ref[b, pl.ds(t, 1), :].astype(jnp.float32) * scale
+            qe_s[t] = jnp.where(own, qt, 0.0)
+
+        def _page(j, n):
+            slot = n % 2
+            last = j + 1 == used
+            fetch(jnp.where(last, live_ref[b + 1], b),
+                  jnp.where(last, 0, j + 1), 1 - slot)
+            for cp in copies(b, j, slot):
+                cp.wait()
+            kb = kbuf[slot].astype(jnp.float32)          # [page, H·D]
+            vb = vbuf[slot].astype(jnp.float32)
+            for t in range(t_q):
+                s = jax.lax.dot_general(
+                    qe_s[t], kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [H, page]
+                # query t sits at absolute position kv_len - t_q + t:
+                # it may attend every key at or before itself (ragged
+                # causal tail), which also masks the page's slots past
+                # the row's length
+                s = jnp.where(j * page + ki <= kv_len - t_q + t, s,
+                              NEG_INF)
+                m_prev = m_s[t]
+                m_new = jnp.maximum(m_prev,
+                                    s.max(axis=-1, keepdims=True))
+                # fully-masked queries (0 < length < Tq: the leading
+                # rows of a speculative/chunked tile sit at negative
+                # positions) have m_new = NEG_INF; clamp the exponent
+                # base so exp(s − m) underflows to 0 instead of
+                # exp(−inf − (−inf)) = 1 leaking V mass — same guard
+                # as _fa_pair_kernel; the flush's l_safe emits zeros
+                m_base = jnp.maximum(m_new, NEG_INF / 2)
+                pexp = jnp.exp(s - m_base)
+                alpha = jnp.exp(m_prev - m_base)
+                m_s[t] = m_new
+                l_s[t] = l_s[t] * alpha + pexp.sum(axis=-1,
+                                                   keepdims=True)
+                acc_s[t] = acc_s[t] * alpha + jnp.dot(
+                    pexp, vb, preferred_element_type=jnp.float32)
+            return n + 1
+
+        n = jax.lax.fori_loop(0, used, _page, n)
+        for t in range(t_q):              # a row of length 0: zeros
+            l_safe = jnp.where(l_s[t] == 0.0, 1.0, l_s[t])
+            o = jnp.where(own, acc_s[t] / l_safe, 0.0)
+            o_ref[b, pl.ds(t, 1), :] = o.sum(
+                axis=0, keepdims=True).astype(o_ref.dtype)
+        return n
+
+    fetch(live_ref[0], 0, 0)
+    jax.lax.fori_loop(0, n_rows, _row, 0)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
@@ -1262,10 +1303,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
 
     - ``q``: ``[B, Tq, H, D]`` — the row's newest ``Tq`` tokens (Tq is
       small: 1 for plain decode, >1 for speculative/chunked steps);
-    - ``k_pages`` / ``v_pages``: ``[P, page_size, H, D]`` physical
-      page pools shared by every row;
+    - ``k_pages`` / ``v_pages``: the physical page pools shared by
+      every row, ``[P, page_size, H·D]`` — one lane-dense row a token,
+      as the server stores them, read where they lie — or
+      ``[P, page_size, H, D]``, which costs the reshape (on a TPU with
+      D < 128 a relayout of the pool);
     - ``page_indices``: int32 ``[B, max_pages]`` per-row page table
-      (entries past the row's used pages are ignored);
+      (entries past the row's used pages are ignored: never read);
     - ``lengths``: int32 ``[B]`` valid cached tokens per row — the
       query tile occupies positions ``length - Tq … length - 1``, so
       the current step's K/V must already be written to the pages.
@@ -1274,9 +1318,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
     the serving decode primitive (ROADMAP item 1) exercised standalone.
     """
     b, t_q, h, d = q.shape
-    n_pages, page, hp, dp = k_pages.shape
-    enforce(hp == h and dp == d,
-            f"page pool heads/dim {hp}/{dp} != query {h}/{d}")
+    page = k_pages.shape[1]
+    hd = h * d
+    enforce(k_pages.shape[2:] in ((h, d), (hd,)),
+            f"page pool {k_pages.shape} is neither [P, page, {h}, {d}] "
+            f"nor [P, page, {hd}] for query heads/dim {h}/{d}")
     enforce(v_pages.shape == k_pages.shape,
             "k_pages and v_pages shapes differ: "
             f"{k_pages.shape} vs {v_pages.shape}")
@@ -1285,58 +1331,58 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
             f"{page_indices.shape}/{lengths.shape} vs B={b}")
     n_pages_max = page_indices.shape[1]
     record_attention_dispatch("decode")
-    scale = 1.0 / np.sqrt(d)
     lengths = lengths.astype(jnp.int32)
-    # pages become rows of one [H·P, page, D] pool so a single index
-    # computed from (head, page table) addresses a (page, D) block
-    kp = k_pages.transpose(2, 0, 1, 3).reshape(h * n_pages, page, d)
-    vp = v_pages.transpose(2, 0, 1, 3).reshape(h * n_pages, page, d)
-    qh = _heads_first(q, b, t_q, h, d)
-    used = jnp.maximum((lengths + page - 1) // page, 1)      # [B]
-    nh = h
+    # live[i]: the first row at or after i that holds K/V, B for none —
+    # where the kernel's prefetch goes at a row's end
+    at = jnp.arange(b, dtype=jnp.int32)
+    live = jnp.append(jax.lax.cummin(jnp.where(lengths > 0, at, b),
+                                     reverse=True), b).astype(jnp.int32)
+    # a DMA cannot cut a row inside a 128-lane tile: rows narrower than
+    # whole tiles (toy sizes) are padded out, a copy that real widths
+    # (H·D a multiple of 128) never make
+    pad = -hd % 128
+    width = hd + pad
 
-    def kv_idx(i, p, ln, us, pi):
-        bb = i // nh
-        slot = jnp.minimum(p, us[bb] - 1)
-        return ((i % nh) * n_pages + pi[bb, slot], 0, 0)
-
+    def rows(a):
+        a = a.reshape(*a.shape[:2], hd)
+        return jnp.pad(a, ((0, 0), (0, 0), (0, pad))) if pad else a
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, page=page,
-                          t_q=t_q, n_heads=h,
+        functools.partial(_decode_kernel, scale=1.0 / np.sqrt(d),
+                          page=page, t_q=t_q, n_heads=h, d=d,
                           n_pages_max=n_pages_max),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b * h, n_pages_max),
-            in_specs=[
-                pl.BlockSpec((1, t_q, d),
-                             lambda i, p, ln, us, pi: (i, 0, 0)),
-                pl.BlockSpec((1, page, d), kv_idx),
-                pl.BlockSpec((1, page, d), kv_idx),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, t_q, d),
-                             lambda i, p, ln, us, pi: (i, 0, 0)),
-            ],
+            grid=(1,),
+            in_specs=[vmem, hbm, hbm],
+            out_specs=[vmem],
             scratch_shapes=[
-                pltpu.VMEM((t_q, 1), jnp.float32),
-                pltpu.VMEM((t_q, 1), jnp.float32),
-                pltpu.VMEM((t_q, d), jnp.float32),
+                pltpu.VMEM((2, page, width), k_pages.dtype),
+                pltpu.VMEM((2, page, width), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((t_q, h, width), jnp.float32),
+                pltpu.VMEM((t_q, h, 1), jnp.float32),
+                pltpu.VMEM((t_q, h, 1), jnp.float32),
+                pltpu.VMEM((t_q, h, width), jnp.float32),
             ],
         ),
-        out_shape=[jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b, t_q, width), q.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
         # THE one call without name= (tests/test_kernel_names.py lists
         # it): the accepted benchmark metric paged_decode_roofline.serve
-        # finds this kernel as ``%_lambda_.N = f32[B·H,1,D] …``, the
-        # instruction name it inherits from serving/model.py's
-        # jax.jit(lambda …); name=K.PAGED_DECODE comes with the
-        # benchmark PR that repoints that metric (PERF.md §7).  Its
-        # work depends on run-time lengths: the serve loop's
-        # serve_decode_step span carries live_tokens / live_pages.
-    )(lengths, used, page_indices.astype(jnp.int32), qh, kp, vp)[0]
-    return out.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)
+        # finds this kernel as ``%_lambda_.N = f32[B,1,H·D] …
+        # custom-call(s32[…``, the instruction name it inherits from
+        # serving/model.py's jax.jit(lambda …); name=K.PAGED_DECODE
+        # comes with the benchmark PR that repoints that metric
+        # (PERF.md §7).  Its work depends on run-time lengths: the
+        # serve loop's serve_decode_step span carries live_tokens /
+        # live_pages.
+    )(lengths, page_indices.astype(jnp.int32), live,
+      rows(q), rows(k_pages), rows(v_pages))[0]
+    return out[..., :hd].reshape(b, t_q, h, d)
 
 
 def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths):
@@ -1372,6 +1418,9 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
     pool-maintenance half of the paged-decode contract ("the current
     step's K/V must already be written to the pages").
 
+    - ``k_pages`` / ``v_pages``: the pools, ``[P, page, H·D]`` (as the
+      server stores them) or ``[P, page, H, D]``; returned in the
+      shape they came in;
     - ``k_new`` / ``v_new``: ``[B, Tn, H, D]`` — each row's newest
       ``Tn`` tokens (``Tn`` = padded prompt length at prefill, 1 per
       decode step);
@@ -1387,7 +1436,7 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
     ``.at[].set`` per pool, out-of-range destinations dropped) so XLA
     aliases the update in place when the caller donates the pools.
     """
-    n_pages, page, h, d = k_pages.shape
+    n_pages, page = k_pages.shape[:2]
     b, t_n = k_new.shape[0], k_new.shape[1]
     enforce(v_new.shape == k_new.shape,
             f"k_new/v_new shapes differ: {k_new.shape} vs {v_new.shape}")
@@ -1406,9 +1455,10 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
              < counts.astype(jnp.int32)[:, None]) & (pos >= 0)
     # invalid tokens aim past the pool; mode="drop" discards them
     dest = jnp.where(valid, dest, n_pages * page).reshape(-1)
-    kf = k_pages.reshape(n_pages * page, h, d).at[dest].set(
-        k_new.reshape(b * t_n, h, d).astype(k_pages.dtype), mode="drop")
-    vf = v_pages.reshape(n_pages * page, h, d).at[dest].set(
-        v_new.reshape(b * t_n, h, d).astype(v_pages.dtype), mode="drop")
-    return (kf.reshape(n_pages, page, h, d),
-            vf.reshape(n_pages, page, h, d))
+    # one lane-dense [H·D] row a token: for a pool stored [P, page, H·D]
+    # the reshapes are free and the scatter writes whole rows in place
+    kf = k_pages.reshape(n_pages * page, -1).at[dest].set(
+        k_new.reshape(b * t_n, -1).astype(k_pages.dtype), mode="drop")
+    vf = v_pages.reshape(n_pages * page, -1).at[dest].set(
+        v_new.reshape(b * t_n, -1).astype(v_pages.dtype), mode="drop")
+    return kf.reshape(k_pages.shape), vf.reshape(v_pages.shape)

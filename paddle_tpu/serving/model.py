@@ -14,8 +14,9 @@ The two entry points mirror the two serving kernels from PR 14/15:
   and length 1, so the kernel touches no memory the slot does not own.
 
 Batch invariance is a load-bearing property, not an accident: every
-per-row computation (matmuls, RMS norms, per-(b,h) attention grid rows,
-``argmax`` sampling) is row-independent and runs in the same
+per-row computation (matmuls, RMS norms, attention — the decode
+kernel walks a row's own pages in the row's own order — ``argmax``
+sampling) is row-independent and runs in the same
 within-row reduction order regardless of batch width, which is what
 lets the ``--serve_continuous`` kill switch promise byte-for-byte
 identical tokens between batched-continuous and sequential
@@ -167,6 +168,8 @@ def _decode_impl(params, k_pool, v_pool, tokens, page_indices, lengths,
                                 page_indices, lengths - 1, counts)
         k_pool = k_pool.at[i].set(kp)
         v_pool = v_pool.at[i].set(vp)
+        # kp/vp are the layer's pool as stored, [P, page, H·D]: the
+        # kernel DMAs the rows' live pages out of it, nothing else
         attn = paged_decode_attention(q, kp, vp, page_indices, klen)
         x = x + attn.reshape(b, 1, cfg.dim) @ params[f"l{i}.wo"]
         x = _ffn(x, params, i)
@@ -217,9 +220,13 @@ class DecoderModel:
     # ----------------------------------------------------------- pools
     def new_pools(self, n_pages: int, page_size: int
                   ) -> Tuple[jax.Array, jax.Array]:
-        """Zeroed per-layer K/V pools, ``[L, P, page, H, Dh]``."""
-        dh = self.cfg.dim // self.cfg.heads
-        shape = (self.cfg.layers, n_pages, page_size, self.cfg.heads, dh)
+        """Zeroed per-layer K/V pools, ``[L, P, page, H·Dh]``: one
+        lane-dense row a token, the layout the decode kernel fetches
+        pages in and ``paged_kv_write`` scatters rows into.  (Stored
+        ``[…, H, Dh]`` with Dh < 128 the TPU lays the page axis along
+        the lanes, and every use of a layer's pool is a relayout copy
+        of it: PERF.md §6, PR 26.)"""
+        shape = (self.cfg.layers, n_pages, page_size, self.cfg.dim)
         return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
 
     # ----------------------------------------------------------- steps

@@ -307,27 +307,136 @@ def test_packed_zero_length_row(rng):
 
 
 # ------------------------------------------------------------- decode
+def _decode_case(case, rng):
+    """(H, D, pools, page table, lengths, the table the reference may
+    gather through) of one named decode case.  Pages no row uses hold
+    NaN where the case plants table entries that must never be
+    dereferenced: a fetched page shows in the output even under p = 0."""
+    H, D, P, page = 2, 16, 10, 16
+    poison = ()
+    if case == "base":
+        # mid-page, page-boundary, and single-page fills
+        pidx = [[2, 0, 4, 7], [5, 1, 3, 8], [9, 6, 2, 0]]
+        lengths = [55, 32, 7]
+    elif case in ("wide_scratch", "wide_garbage"):
+        # the server's table: 128 slots, 3 in use, the rest the
+        # scratch page or whatever was there
+        fill = 0 if case == "wide_scratch" else 10 ** 6
+        pidx = [[4, 2, 7] + [fill] * 125, [5, 1, 3] + [-5] * 125]
+        lengths = [41, 48]
+        if case == "wide_garbage":
+            poison = (0, P - 1)       # where a clamped fetch would land
+    elif case == "page_boundary":
+        pidx = [[3, 5, 1, 0], [2, 4, 0, 0], [6, 0, 0, 0]]
+        lengths = [48, 32, 16]        # every row ends on a page's edge
+    elif case == "inactive_rows":
+        # padded batch slots: length 1 over the scratch page, beside
+        # live rows (serving/model.py's klen)
+        pidx = [[0] * 6, [4, 2, 7, 9, 0, 0], [0] * 6, [5, 1, 0, 0, 0, 0]]
+        lengths = [1, 60, 1, 20]
+    elif case == "hd2048":
+        # the serve cell's row: 32 heads of 64, lane-dense 2048
+        H, D, P = 32, 64, 8
+        pidx = [[6, 2, 5, 0], [1, 3, 0, 0]]
+        lengths = [37, 32]
+    else:
+        raise AssertionError(case)
+    kpg = rng.randn(P, page, H, D).astype(np.float32)
+    vpg = rng.randn(P, page, H, D).astype(np.float32)
+    for pg in poison:
+        kpg[pg] = vpg[pg] = np.nan
+    pidx = np.asarray(pidx, np.int32)
+    used = -(-np.asarray(lengths) // page)
+    live = np.arange(pidx.shape[1])[None, :] < used[:, None]
+    assert not np.isin(pidx[live], poison).any()
+    # the reference gathers every slot of the table before it masks:
+    # give it a live page wherever the kernel must not look
+    safe = np.where(live, pidx, pidx[:, :1])
+    return (H, D, jnp.asarray(kpg), jnp.asarray(vpg), jnp.asarray(pidx),
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(safe))
+
+
 @pytest.mark.parametrize("t_q", [1, 4])
-def test_paged_decode_matches_dense_reference(t_q, rng):
+@pytest.mark.parametrize("case", ["base", "wide_scratch", "wide_garbage",
+                                  "page_boundary", "inactive_rows",
+                                  "hd2048"])
+def test_paged_decode_matches_dense_reference(case, t_q, rng):
     """The decode primitive over a partially-filled paged cache equals
-    the dense one-step reference: per-row lengths (mid-page fills),
-    per-row page tables, small-Tq causal tail."""
-    B, H, D = 3, 2, 16
-    P, page, n_max = 10, 16, 4
-    kpg = jnp.asarray(rng.randn(P, page, H, D).astype(np.float32))
-    vpg = jnp.asarray(rng.randn(P, page, H, D).astype(np.float32))
-    pidx = jnp.asarray([[2, 0, 4, 7], [5, 1, 3, 8], [9, 6, 2, 0]],
-                       jnp.int32)
-    # mid-page, page-boundary, and single-page fills
-    lengths = jnp.asarray([55, 32, 7], jnp.int32)
-    q = jnp.asarray(rng.randn(B, t_q, H, D).astype(np.float32))
+    the dense one-step reference: per-row lengths (mid-page fills,
+    fills that end on a page's edge), per-row page tables far wider
+    than the pages in use whose dead slots are never dereferenced,
+    inactive rows beside live ones, small-Tq causal tail, toy and
+    lane-dense head widths."""
+    H, D, kpg, vpg, pidx, lengths, safe = _decode_case(case, rng)
+    q = jnp.asarray(rng.randn(pidx.shape[0], t_q, H, D).astype(np.float32))
     out = pa.paged_decode_attention(q, kpg, vpg, pidx, lengths)
-    ref = pa.paged_decode_reference(q, kpg, vpg, pidx, lengths)
+    ref = pa.paged_decode_reference(q, kpg, vpg, safe, lengths)
+    assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
     flat = observe.REGISTRY.flat(kinds=("counter",))
     assert flat['attention_dispatch_total{path="decode",reason=""}'] \
         >= 1
+
+
+@pytest.mark.parametrize("t_q", [1, 4])
+def test_paged_decode_lane_dense_pool_is_the_same_pool(t_q, rng):
+    """The server stores a token as one [H·D] row (``new_pools``): the
+    kernel and ``paged_kv_write`` take that pool as they take
+    [P, page, H, D], and give the same numbers."""
+    H, D, kpg, vpg, pidx, lengths, _ = _decode_case("base", rng)
+    B, P, page = pidx.shape[0], kpg.shape[0], kpg.shape[1]
+    q = jnp.asarray(rng.randn(B, t_q, H, D).astype(np.float32))
+    k_new, v_new = (jnp.asarray(rng.randn(B, t_q, H, D)
+                                .astype(np.float32)) for _ in range(2))
+    counts = jnp.full((B,), t_q, jnp.int32)
+    kp4, vp4 = pa.paged_kv_write(kpg, vpg, k_new, v_new, pidx,
+                                 lengths - t_q, counts)
+    kp3, vp3 = pa.paged_kv_write(
+        kpg.reshape(P, page, H * D), vpg.reshape(P, page, H * D),
+        k_new, v_new, pidx, lengths - t_q, counts)
+    assert kp3.shape == (P, page, H * D) and kp4.shape == kpg.shape
+    np.testing.assert_array_equal(np.asarray(kp3).reshape(kp4.shape),
+                                  np.asarray(kp4))
+    np.testing.assert_array_equal(np.asarray(vp3).reshape(vp4.shape),
+                                  np.asarray(vp4))
+    np.testing.assert_array_equal(
+        np.asarray(pa.paged_decode_attention(q, kp3, vp3, pidx, lengths)),
+        np.asarray(pa.paged_decode_attention(q, kp4, vp4, pidx, lengths)))
+
+
+def _pool_sized_eqns(jaxpr, floor, found):
+    """(primitive, shapes) of every equation outside a kernel's body
+    that takes an operand of ``floor`` elements or more."""
+    for eqn in jaxpr.eqns:
+        big = [tuple(v.aval.shape) for v in eqn.invars
+               if hasattr(v.aval, "shape")
+               and int(np.prod(v.aval.shape)) >= floor]
+        if big:
+            found.append((eqn.primitive.name, big))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pool_sized_eqns(sub, floor, found)
+    return found
+
+
+@pytest.mark.parametrize("rank", [3, 4])
+def test_paged_decode_takes_the_pool_as_it_is_stored(rank):
+    """No transpose, copy, pad or gather of a pool-sized operand
+    anywhere in the wrapper: a lane-dense pool goes to the kernel
+    untouched, [P, page, H, D] through one reshape (which the TPU has
+    to pay for as a relayout: why the server stores the first)."""
+    B, t_q, H, D, P, page, slots = 4, 1, 4, 32, 24, 16, 8
+    pool = (P, page, H * D) if rank == 3 else (P, page, H, D)
+    args = (jnp.zeros((B, t_q, H, D)), jnp.zeros(pool), jnp.zeros(pool),
+            jnp.zeros((B, slots), jnp.int32), jnp.ones((B,), jnp.int32))
+    closed = jax.make_jaxpr(pa.paged_decode_attention)(*args)
+    found = _pool_sized_eqns(closed.jaxpr, P * page * H * D, [])
+    names = [name for name, _ in found]
+    assert names.count("pallas_call") == 1, found
+    rest = [n for n in names if n != "pallas_call"]
+    assert rest == ([] if rank == 3 else ["reshape", "reshape"]), found
 
 
 def test_paged_decode_fully_masked_rows_emit_zeros(rng):
