@@ -75,7 +75,11 @@ FULL = {
                 # dense reference scores are [B, H, T, T] f32: B=4 fits
                 "flash": (4, 2048, 8, 64, 512),
                 "packed": (5, 96, 8, 32),
-                "decode": (8, 8, 32, 512, 16, 32)},
+                "decode": (8, 8, 32, 512, 16, 32),
+                # the routed decoder's row: 32 heads over 4 K/V heads of
+                # 128, pages of 64; (tokens, experts, a token, dim, width)
+                "decode_gqa": (8, 32, 4, 128, 128, 64, 8),
+                "moe": (256, 16, 4, 512, 256)},
     "resnet": {"depth": 50, "image": 224, "batch": 128, "classes": 1000},
     "seq2seq": {"B": 128, "S_LEN": 30, "T_LEN": 30, "V": 30000, "E": 512,
                 "H": 512},
@@ -94,7 +98,9 @@ REHEARSAL = {
                "n_pages": 64, "page": 16, "max_new": 6,
                "prompt_lens": (12, 5, 20)},
     "kernels": {"lstm": (8, 6, 128), "flash": (2, 256, 2, 32, 128),
-                "packed": (3, 32, 2, 32), "decode": (4, 2, 32, 32, 16, 4)},
+                "packed": (3, 32, 2, 32), "decode": (4, 2, 32, 32, 16, 4),
+                "decode_gqa": (4, 4, 2, 16, 32, 16, 4),
+                "moe": (16, 4, 2, 32, 32)},
     "resnet": {"depth": 8, "image": 32, "batch": 8, "classes": 10},
     "seq2seq": {"B": 8, "S_LEN": 6, "T_LEN": 6, "V": 200, "E": 128,
                 "H": 128},
@@ -525,10 +531,12 @@ def _rel_err(got, want):
 _HLO_ARRAY = r"[a-z0-9]+\[([\d,]*)\](?:\{([\d,]*)[^}]*\})?"
 
 
-def decode_step_checks(compiled, pool_elems):
+def decode_step_checks(compiled, pool_elems, patterns=None):
     """What the benchmark's traced run needs of the server's compiled
     decode step: an instruction that the accepted metric
-    ``paged_decode_roofline.serve`` finds by its own pattern, and no
+    ``paged_decode_roofline.serve`` finds by its own pattern (or, for a
+    decoder with a layer plan, by the ``patterns`` of the kernel's own
+    name), and no
     relayout of a layer's pool (a ``transpose``, a ``reshape`` the
     compiler could not make a bitcast, a ``copy`` into another
     dimension order) anywhere in it, so none feeds that instruction.
@@ -536,9 +544,10 @@ def decode_step_checks(compiled, pool_elems):
     moves between memory spaces) are counted, not refused."""
     from jax._src.lib import xla_client as xc
 
-    with open(os.path.join(HERE, "chipbench", "metrics",
-                           "paged_decode_roofline.serve.json")) as f:
-        patterns = json.load(f)["args"]["patterns"]
+    if patterns is None:
+        with open(os.path.join(HERE, "chipbench", "metrics",
+                               "paged_decode_roofline.serve.json")) as f:
+            patterns = json.load(f)["args"]["patterns"]
     opts = xc._xla.HloPrintOptions()
     opts.print_operand_shape = True
     opts.print_metadata = False
@@ -660,6 +669,50 @@ def phase_kernels(S, ctx):
         jax.jit(pa.paged_decode_attention)(q, kp, vp, pidx, lens),
         jax.jit(pa.paged_decode_reference)(q, kp, vp, pidx, lens))
 
+    # the same with query heads that share K/V heads under a window of
+    # two pages, the pool stored in bfloat16, one row a token
+    bg, hg, g, dg, pages_g, page_g, slots_g = S["decode_gqa"]
+    qg = randn(bg, 1, hg, dg)
+    kg, vg = (randn(pages_g, page_g, g * dg, dtype=jnp.bfloat16)
+              for _ in range(2))
+    lens_g = jnp.asarray(rng.randint(1, slots_g * page_g, (bg,)), jnp.int32)
+    pidx_g = jnp.asarray(rng.permutation(pages_g - 1)[:bg * slots_g]
+                         .reshape(bg, slots_g) + 1, jnp.int32)
+    errs["paged_decode_gqa_window"] = _rel_err(
+        jax.jit(lambda *a: pa.paged_decode_attention(
+            *a, window=2 * page_g))(qg, kg, vg, pidx_g, lens_g),
+        jax.jit(lambda *a: pa.paged_decode_reference(
+            *a, window=2 * page_g))(
+            qg, kg.reshape(pages_g, page_g, g, dg),
+            vg.reshape(pages_g, page_g, g, dg), pidx_g, lens_g))
+
+    # routed experts: the grouped matmul over rows sorted by expert vs
+    # every expert on every token
+    from paddle_tpu.ops import pallas_moe
+
+    t_moe, e, top_k, dm, f = S["moe"]
+    xm = randn(t_moe, dm)
+    rw, rb = randn(dm, e, scale=dm ** -0.5), randn(e, scale=0.1)
+    wg, wu = (randn(e, dm, f, dtype=jnp.bfloat16, scale=dm ** -0.5)
+              for _ in range(2))
+    wd = randn(e, f, dm, dtype=jnp.bfloat16, scale=f ** -0.5)
+
+    def every_expert(x):
+        ex, wt = pallas_moe.route(x, rw, rb, top_k, 2.826)
+        weight = jnp.zeros((t_moe, e)).at[
+            jnp.arange(t_moe)[:, None], ex].set(wt)
+        mm = lambda a, w, eq: jnp.einsum(
+            eq, a.astype(jnp.bfloat16), w,
+            preferred_element_type=jnp.float32)
+        hid = jax.nn.silu(mm(x, wg, "td,edf->etf")) \
+            * mm(x, wu, "td,edf->etf")
+        return jnp.einsum("etd,te->td", mm(hid, wd, "etf,efd->etd"), weight)
+
+    errs["routed_experts"] = _rel_err(
+        jax.jit(lambda x: pallas_moe.routed_experts(
+            x, rw, rb, wg, wu, wd, top_k=top_k, route_scale=2.826)[0])(xm),
+        jax.jit(every_expert)(xm))
+
     # the decode step as the server compiles it, small: the benchmark's
     # traced run must find the kernel in it, fed by the pool as stored.
     # A rehearsal has no Mosaic call to look at
@@ -676,6 +729,26 @@ def phase_kernels(S, ctx):
                 jnp.zeros((b,), jnp.int32), pidx, lens,
                 jnp.ones((b,), bool)).compile(),
             n_pages * page * nh * d)
+        # and of a decoder with a layer plan: grouped K/V heads, a window
+        # layer and a full one, routed experts, bfloat16 storage; its
+        # kernel is found by the name it is given
+        from paddle_tpu.ops import kernels as K
+
+        cfg = DecoderConfig(
+            vocab=512, dim=dm, heads=hg, layers=2, ffn=2 * dm,
+            max_context=slots_g * page_g, kv_heads=g, head_dim=dg,
+            window=2 * page_g, experts=e, top_k=top_k, expert_ffn=f,
+            route_scale=2.826, pos_embed=False, storage="bfloat16",
+            plan=("window+rope+qknorm+gate+postnorm/swiglu",
+                  "full+qknorm+gate+postnorm/routed+shared"))
+        model = DecoderModel(init_decoder_params(cfg, seed=0), cfg)
+        decode_step["planned"] = decode_step_checks(
+            model._decode.lower(
+                model.params, *model.new_pools(pages_g, page_g),
+                jnp.zeros((bg,), jnp.int32), pidx_g, lens_g,
+                jnp.ones((bg,), bool)).compile(),
+            pages_g * page_g * g * dg,
+            [K.instruction_pattern(K.PAGED_DECODE) + ".*tpu_custom_call"])
 
     # the trace can name the kernels: a Mosaic call compiles to an
     # instruction named after ops/kernels.py's table (its ``name=``),

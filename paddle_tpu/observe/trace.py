@@ -356,6 +356,9 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -407,6 +410,11 @@ class _Span:
         self._annot = annot
         self._t0 = time.perf_counter()
         return self
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the span's work is done (what a
+        step returned); recorded with the rest when the span closes."""
+        self.attrs.update(attrs)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()

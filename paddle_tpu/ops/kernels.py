@@ -47,10 +47,14 @@ FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
 FLASH_BWD_DQ_GRID = "flash_bwd_dq_grid"
 FLASH_BWD_DKV_GRID = "flash_bwd_dkv_grid"
-#: reserved: ``paged_decode_attention`` takes it when the benchmark's
-#: ``paged_decode_roofline.serve`` no longer finds the kernel by the
-#: ``_lambda_`` of ``serving/model.py``'s jit (see the call site)
+#: ``paged_decode_attention`` takes it where its caller asks (the
+#: decoders with a layer plan do); the default plan's call stays unnamed
+#: while the benchmark's ``paged_decode_roofline.serve`` finds that
+#: kernel by the ``_lambda_`` of ``serving/model.py``'s jit (see the
+#: call site)
 PAGED_DECODE = "paged_decode"
+# ops/pallas_moe.py
+MOE_GMM = "moe_gmm"                      # grouped matmul, rows by expert
 # ops/pallas_embedding.py
 EMBEDDING_GATHER = "embedding_gather"
 # ops/pallas_lstm.py, ops/pallas_gru.py (whole-sequence and H-blocked)

@@ -116,12 +116,21 @@ def _causal_block_live(q_off, k_off, block_q):
     return k_off <= q_off + block_q - 1
 
 
+def _window_block_live(q_off, k_off, block_k, window):
+    """Block-level liveness under a sliding window: the k tile holds a
+    key no more than ``window - 1`` positions behind the q tile's
+    first query (python ints at table build)."""
+    return k_off + block_k - 1 > q_off - window
+
+
 def _tile_mask(q_off, k_off, kv_len, causal, block_q, block_k,
-               seg_q=None, seg_k=None):
+               seg_q=None, seg_k=None, window=0):
     """[block_q, block_k] element validity for one tile — THE shared
     masking helper for the forward kernel, both backward kernels and
     the packed variants: key-padding (``kv_len``), causal diagonal,
-    and (packed) segment-id equality with −1 = padding."""
+    (packed) segment-id equality with −1 = padding, and a sliding
+    ``window`` (a query sees the ``window`` newest keys up to itself;
+    causal only)."""
     ki = k_off + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
     valid = ki < kv_len
@@ -129,6 +138,8 @@ def _tile_mask(q_off, k_off, kv_len, causal, block_q, block_k,
         qi = q_off + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         valid = jnp.logical_and(valid, qi >= ki)
+        if window:
+            valid = jnp.logical_and(valid, qi - ki < window)
     if seg_q is not None:
         valid = jnp.logical_and(valid, seg_q[:, None] == seg_k[None, :])
         valid = jnp.logical_and(valid, seg_q[:, None] >= 0)
@@ -138,7 +149,7 @@ def _tile_mask(q_off, k_off, kv_len, causal, block_q, block_k,
 # ----------------------------------------------------------- pair tables
 @functools.lru_cache(maxsize=None)
 def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
-                 slot: int = 0):
+                 slot: int = 0, window: int = 0):
     """Static block-sparse iteration tables.
 
     Returns ``(tab_q, tab_k)`` — int32 ``[4, n_pairs]`` arrays with
@@ -159,6 +170,10 @@ def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
     packed grid has exactly the padded grid's pair count instead of
     the full (B·nq)² cross product.  Only applied when slots are whole
     blocks (slot % bq == slot % bk == 0); 0 disables.
+
+    ``window`` (causal only): blocks wholly behind the sliding window
+    of the q block's first query are dropped like those above the
+    diagonal.
     """
     nq, nk = tq // bq, tk // bk
     if slot and (slot % bq or slot % bk):
@@ -176,6 +191,9 @@ def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
                         j * bq, s * bk, bq):
                     continue
                 if slot and (j * bq) // slot != (s * bk) // slot:
+                    continue
+                if causal and window and not _window_block_live(
+                        j * bq, s * bk, bk, window):
                     continue
                 members.append((j, s))
             for t, (j, s) in enumerate(members):
@@ -249,7 +267,7 @@ def _win_clip(idx, lo, hi, n: int):
 
 # ------------------------------------------------ pair-grid fwd kernel
 def _fa_pair_kernel(*refs, scale, causal, block_q, block_k, n_heads,
-                    packed):
+                    packed, window=0):
     """Grid (B·H, n_pairs) over the q-major pair table: the online
     softmax carries in VMEM scratch across one q block's pairs,
     initialized at its first table entry and flushed at its last.
@@ -282,7 +300,7 @@ def _fa_pair_kernel(*refs, scale, causal, block_q, block_k, n_heads,
         valid = _tile_mask(
             q_off, k_off, kv_len, causal, block_q, block_k,
             None if sq_ref is None else sq_ref[0, :, 0],
-            None if sk_ref is None else sk_ref[0, :, 0])
+            None if sk_ref is None else sk_ref[0, :, 0], window)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_s[:]
         l_prev = l_s[:]
@@ -338,14 +356,18 @@ def packed_tileable(t_total: int, block_q: int, block_k: int) -> bool:
     return _tiling_ok(t_total, t_total, bq, bk)
 
 
-def _mask_scores(s, causal, lengths, segments=None):
+def _mask_scores(s, causal, lengths, segments=None, window=0):
     """Apply causal / key-padding / packed-segment masks to
     [B, H, Tq, Tk] scores — the dense-path twin of :func:`_tile_mask`
     (same semantics at full-matrix granularity)."""
     tq, tk = s.shape[-2], s.shape[-1]
     if causal:
-        s = jnp.where(jnp.arange(tq)[None, None, :, None]
-                      >= jnp.arange(tk)[None, None, None, :], s, NEG_INF)
+        behind = (jnp.arange(tq)[None, None, :, None]
+                  - jnp.arange(tk)[None, None, None, :])
+        seen = behind >= 0
+        if window:
+            seen = jnp.logical_and(seen, behind < window)
+        s = jnp.where(seen, s, NEG_INF)
     if lengths is not None:
         valid = jnp.arange(tk)[None, :] < lengths[:, None]   # [B, Tk]
         s = jnp.where(valid[:, None, None, :], s, NEG_INF)
@@ -356,14 +378,18 @@ def _mask_scores(s, causal, lengths, segments=None):
     return s
 
 
-def _dense_forward(q, k, v, lengths, causal, segments=None):
+def _dense_forward(q, k, v, lengths, causal, segments=None, window=0):
     """Fallback for shapes the kernel can't tile (and the exact
     unfused reference the kill switches restore): plain XLA attention,
-    same (out, lse) contract so the shared backward rule applies."""
+    same (out, lse) contract so the shared backward rule applies.
+    Grouped KV heads are repeated out to the query heads."""
     scale = 1.0 / np.sqrt(q.shape[-1])
+    if k.shape[2] != q.shape[2]:
+        k, v = (jnp.repeat(a, q.shape[2] // a.shape[2], axis=2)
+                for a in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    s = _mask_scores(s, causal, lengths, segments)
+    s = _mask_scores(s, causal, lengths, segments, window)
     m = s.max(axis=-1)
     # fully-masked rows (query past a zero-length sequence): emit 0
     m_safe = jnp.maximum(m, NEG_INF / 2)
@@ -376,12 +402,13 @@ def _dense_forward(q, k, v, lengths, causal, segments=None):
 
 
 def _record_attn_work(kernel, bh, tq, tk, bq, bk, d, causal, slot,
-                      operands, results):
+                      operands, results, window=0):
     """The work account of one flash kernel (``ops/kernels.py``): 4·d
     FLOPs per (query, key) position of the statically live blocks —
     QKᵀ and PV forward; dP and dQ, or dV and dK, backward (the scores
     a backward kernel recomputes are re-done work, not the op's)."""
-    n_pairs = _pair_tables(tq, tk, bq, bk, causal, slot)[0].shape[1]
+    n_pairs = _pair_tables(tq, tk, bq, bk, causal, slot,
+                           window)[0].shape[1]
     K.record_kernel_work(kernel, 4.0 * d * bq * bk * n_pairs * bh,
                          operands, results)
 
@@ -392,30 +419,36 @@ def _heads_first(a, b, t, h, d):
 
 
 def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
-                       segments=None, slot=0):
-    """Pair-table (block-sparse) forward: grid (B·H, n_pairs)."""
+                       segments=None, slot=0, window=0):
+    """Pair-table (block-sparse) forward: grid (B·H, n_pairs).  With
+    grouped KV heads (``k``/``v`` hold G < H heads) a grid row's k/v
+    blocks are its group's: no copy of K or V is made."""
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, g = k.shape[1], k.shape[2]
     scale = 1.0 / np.sqrt(d)
     qh = _heads_first(q, b, tq, h, d)
-    kh = _heads_first(k, b, tk, h, d)
-    vh = _heads_first(v, b, tk, h, d)
+    kh = _heads_first(k, b, tk, g, d)
+    vh = _heads_first(v, b, tk, g, d)
     nq, nk = tq // bq, tk // bk
-    tab = jnp.asarray(_pair_tables(tq, tk, bq, bk, causal, slot)[0])
+    tab = jnp.asarray(_pair_tables(tq, tk, bq, bk, causal, slot,
+                                   window)[0])
     n_pairs = tab.shape[1]
     if segments is None:
         lo, hi = _length_windows(lengths, b, nq, bk)
     else:
         lo, hi = _segment_windows(segments, segments, bq, bk)
     nh = h
+    heads_per_group = h // g
 
     def q_idx(i, p, ln, lo_, hi_, tb):
         return (i, tb[0, p], 0)
 
     def kv_idx(i, p, ln, lo_, hi_, tb):
         j = tb[0, p]
-        return (i, _win_clip(tb[1, p], lo_[i // nh, j],
-                             hi_[i // nh, j], nk), 0)
+        row = i if g == h else \
+            (i // nh) * g + (i % nh) // heads_per_group
+        return (row, _win_clip(tb[1, p], lo_[i // nh, j],
+                               hi_[i // nh, j], nk), 0)
 
     def sq_idx(i, p, ln, lo_, hi_, tb):
         return (i // nh, tb[0, p], 0)
@@ -453,14 +486,15 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
     )
     kernel = functools.partial(
         _fa_pair_kernel, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, n_heads=h, packed=segments is not None)
+        block_k=bk, n_heads=h, packed=segments is not None,
+        window=window)
     out_shape = [
         jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
         jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
     ]
     name = K.FLASH_FWD if segments is None else K.FLASH_FWD_PACKED
     _record_attn_work(name, b * h, tq, tk, bq, bk, d, causal, slot,
-                      operands, out_shape)
+                      operands, out_shape, window)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -592,9 +626,15 @@ def _flash_enabled() -> bool:
 
 
 def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
-                segments=None, slot=0):
+                segments=None, slot=0, window=0):
     b, tq, h, d = q.shape
     tk = k.shape[1]
+    enforce(not window or causal,
+            "a sliding window is a causal mask's: pass causal=True")
+    enforce(k.shape[2] == h or (segments is not None
+                                and h % k.shape[2] == 0),
+            f"K/V heads {k.shape[2]} must equal the {h} query heads "
+            "(the packed entry also takes a divisor: grouped KV heads)")
     if causal:
         # a causal mask is only meaningful on a shared timeline
         enforce(tq == tk,
@@ -610,12 +650,12 @@ def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
     if not _flash_enabled():
         record_attention_dispatch(
             "dense", "kill_switch:flash_kernel")
-        return _dense_forward(q, k, v, lengths, causal, segments)
+        return _dense_forward(q, k, v, lengths, causal, segments, window)
     if not _tiling_ok(tq, tk, bq, bk):
         reason = "untileable shape (lse/kv block constraints)"
         record_attention_dispatch("dense", reason)
         _warn_dense_fallback(reason, tq, tk, bq, bk)
-        return _dense_forward(q, k, v, lengths, causal, segments)
+        return _dense_forward(q, k, v, lengths, causal, segments, window)
     if _block_sparse():
         reason = ""
         if packed and slot and (slot % bq or slot % bk) \
@@ -636,12 +676,12 @@ def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
         record_attention_dispatch("packed" if packed
                                    else "block_sparse", reason)
         return _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
-                                  segments, slot)
+                                  segments, slot, window)
     if packed:
         # the legacy grid has no segment plumbing: exact dense fallback
         record_attention_dispatch(
             "dense", "kill_switch:flash_block_sparse(packed)")
-        return _dense_forward(q, k, v, lengths, causal, segments)
+        return _dense_forward(q, k, v, lengths, causal, segments, window)
     record_attention_dispatch("legacy_grid",
                                "kill_switch:flash_block_sparse")
     return _fa_forward_grid(q, k, v, lengths, causal, bq, bk)
@@ -1146,15 +1186,17 @@ flash_attention.defvjp(_fa_fwd_rule, _fa_bwd_rule)
 
 
 # ------------------------------------------------------ sequence packing
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention_packed(q, k, v, segments, causal: bool = False,
                            block_q: int = 512, block_k: int = 512,
-                           slot: int = 0):
+                           slot: int = 0, window: int = 0):
     """Packed (ragged-batch) attention: tokens attend only within
     their segment.
 
-    q, k, v: ``[B, T_total, H, D]`` — mixed-length sequences share one
-    packed token axis; ``segments``: int32 ``[B, T_total]`` per-token
+    q: ``[B, T_total, H, D]``, k, v: ``[B, T_total, G, D]`` with G = H
+    or a divisor of it (grouped KV heads: query head h attends KV head
+    ``h // (H // G)``) — mixed-length sequences share one packed token
+    axis; ``segments``: int32 ``[B, T_total]`` per-token
     segment ids, **non-decreasing** over valid tokens with ``-1``
     marking padding (the packing contract — the dynamic block windows
     rely on it).  Padding tokens produce zero output and zero grads;
@@ -1164,22 +1206,29 @@ def flash_attention_packed(q, k, v, segments, causal: bool = False,
     per-segment diagonal).  ``slot``: optional static slot width when
     the caller guarantees no segment crosses a slot boundary — pairs
     across slots leave the iteration space entirely (see
-    :func:`_pair_tables`).
+    :func:`_pair_tables`).  ``window`` (causal only): a query sees the
+    ``window`` newest keys of its segment up to itself; blocks wholly
+    behind it leave the iteration space too.  Grouped heads and a
+    window are the serving prefill's: forward only (their backward
+    raises).
     """
     out, _lse = _fa_forward(q, k, v, None, causal, block_q, block_k,
-                            segments=segments, slot=slot)
+                            segments=segments, slot=slot, window=window)
     return out
 
 
 def _fa_packed_fwd_rule(q, k, v, segments, causal, block_q, block_k,
-                        slot):
+                        slot, window):
     out, lse = _fa_forward(q, k, v, None, causal, block_q, block_k,
-                           segments=segments, slot=slot)
+                           segments=segments, slot=slot, window=window)
     return out, (q, k, v, segments, out, lse)
 
 
-def _fa_packed_bwd_rule(causal, block_q, block_k, slot, res, do):
+def _fa_packed_bwd_rule(causal, block_q, block_k, slot, window, res, do):
     q, k, v, segments, out, lse = res
+    enforce(not window and k.shape[2] == q.shape[2],
+            "flash_attention_packed: no backward for a sliding window "
+            "or grouped KV heads yet (serving is forward only)")
     lengths = jnp.full((q.shape[0],), k.shape[1], jnp.int32)
     dq, dk, dv = _fa_backward(q, k, v, lengths, out, lse, do, causal,
                               block_q, block_k, segments=segments,
@@ -1203,7 +1252,8 @@ def segments_from_lengths(lengths, batch: int, t: int):
 # --------------------------------------------------- paged-KV decode
 def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                    o_ref, kbuf, vbuf, sem, qe_s, m_s, l_s, acc_s, *,
-                   scale, page, t_q, n_heads, d, n_pages_max):
+                   scale, page, t_q, n_heads, kv_heads, d, n_pages_max,
+                   window):
     """One invocation, one loop step per row and live page, all heads
     in that step — so the time follows the K/V that is live, not the
     table's width.  The pools stay in HBM as they are stored
@@ -1215,8 +1265,26 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
     ``qe @ k.T`` is every head's scores ``[H, page]`` and the diagonal
     blocks of ``p @ v`` ``[H, H·D]`` are every head's output;
     ``m``/``l``/acc stay f32.  Table slots past a row's used pages are
-    never read, let alone dereferenced."""
-    n_rows, _, hd = q_ref.shape
+    never read, let alone dereferenced.
+
+    Grouped KV heads (``kv_heads`` < ``n_heads``): the pool's rows are
+    ``kv_heads·D`` wide and query head h lies in the D lanes of KV head
+    ``h // (n_heads // kv_heads)``, so the same two products give every
+    head its group's scores and output; the flush picks each group's
+    heads out of its lanes (``o_ref`` is then ``[B, Tq, H, D]``).
+    ``window`` > 0: a query sees only the ``window`` newest positions
+    up to its own, and the page walk starts at the first page that
+    holds one of them, so the pages behind the window are not read."""
+    n_rows = q_ref.shape[0]
+    rep = n_heads // kv_heads
+    hd = kbuf.shape[-1]
+
+    def first_page(b):
+        """The first page row ``b``'s earliest query can see."""
+        if not window:
+            return 0
+        kv_len = len_ref[jnp.minimum(b, n_rows - 1)]
+        return jnp.maximum(kv_len - t_q - window + 1, 0) // page
 
     def copies(b, j, slot):
         pg = pidx_ref[b, j]
@@ -1231,9 +1299,9 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
             for cp in copies(b, j, slot):
                 cp.start()
 
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 0)
+    group = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 0) // rep
     lanes = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 1)
-    own = (lanes >= rows * d) & (lanes < (rows + 1) * d)     # [H, H·D]
+    own = (lanes >= group * d) & (lanes < (group + 1) * d)   # [H, G·D]
     ki = jax.lax.broadcasted_iota(jnp.int32, (n_heads, page), 1)
 
     def _row(b, n):
@@ -1245,14 +1313,21 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
         for t in range(t_q):
-            qt = q_ref[b, pl.ds(t, 1), :].astype(jnp.float32) * scale
-            qe_s[t] = jnp.where(own, qt, 0.0)
+            if rep == 1:
+                qt = q_ref[b, pl.ds(t, 1), :]            # [1, H·D]
+            else:
+                # [H, D] → each head's D lanes under every KV head
+                qt = jnp.concatenate([q_ref[b, t]] * kv_heads, axis=1)
+                if hd > qt.shape[1]:
+                    qt = jnp.pad(qt, ((0, 0), (0, hd - qt.shape[1])))
+            qe_s[t] = jnp.where(own, qt.astype(jnp.float32) * scale, 0.0)
 
         def _page(j, n):
             slot = n % 2
             last = j + 1 == used
-            fetch(jnp.where(last, live_ref[b + 1], b),
-                  jnp.where(last, 0, j + 1), 1 - slot)
+            nxt = live_ref[b + 1]
+            fetch(jnp.where(last, nxt, b),
+                  jnp.where(last, first_page(nxt), j + 1), 1 - slot)
             for cp in copies(b, j, slot):
                 cp.wait()
             kb = kbuf[slot].astype(jnp.float32)          # [page, H·D]
@@ -1265,8 +1340,11 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                 # it may attend every key at or before itself (ragged
                 # causal tail), which also masks the page's slots past
                 # the row's length
-                s = jnp.where(j * page + ki <= kv_len - t_q + t, s,
-                              NEG_INF)
+                at = kv_len - t_q + t
+                seen = j * page + ki <= at
+                if window:
+                    seen = seen & (j * page + ki > at - window)
+                s = jnp.where(seen, s, NEG_INF)
                 m_prev = m_s[t]
                 m_new = jnp.maximum(m_prev,
                                     s.max(axis=-1, keepdims=True))
@@ -1286,43 +1364,61 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                     pexp, vb, preferred_element_type=jnp.float32)
             return n + 1
 
-        n = jax.lax.fori_loop(0, used, _page, n)
+        n = jax.lax.fori_loop(first_page(b), used, _page, n)
         for t in range(t_q):              # a row of length 0: zeros
             l_safe = jnp.where(l_s[t] == 0.0, 1.0, l_s[t])
-            o = jnp.where(own, acc_s[t] / l_safe, 0.0)
-            o_ref[b, pl.ds(t, 1), :] = o.sum(
-                axis=0, keepdims=True).astype(o_ref.dtype)
+            o = acc_s[t] / l_safe
+            if rep == 1:
+                o_ref[b, pl.ds(t, 1), :] = jnp.where(own, o, 0.0).sum(
+                    axis=0, keepdims=True).astype(o_ref.dtype)
+            else:
+                for g in range(kv_heads):
+                    o_ref[b, t, pl.ds(g * rep, rep), :] = o[
+                        g * rep:(g + 1) * rep,
+                        g * d:(g + 1) * d].astype(o_ref.dtype)
         return n
 
-    fetch(live_ref[0], 0, 0)
+    fetch(live_ref[0], first_page(live_ref[0]), 0)
     jax.lax.fori_loop(0, n_rows, _row, 0)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
+def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
+                           window: int = 0, name=None):
     """Decode-step attention over a block-paged KV cache.
 
     - ``q``: ``[B, Tq, H, D]`` — the row's newest ``Tq`` tokens (Tq is
       small: 1 for plain decode, >1 for speculative/chunked steps);
     - ``k_pages`` / ``v_pages``: the physical page pools shared by
-      every row, ``[P, page_size, H·D]`` — one lane-dense row a token,
+      every row, ``[P, page_size, G·D]`` — one lane-dense row a token,
       as the server stores them, read where they lie — or
-      ``[P, page_size, H, D]``, which costs the reshape (on a TPU with
-      D < 128 a relayout of the pool);
+      ``[P, page_size, G, D]``, which costs the reshape (on a TPU with
+      D < 128 a relayout of the pool).  ``G`` KV heads divide the ``H``
+      query heads: head h attends KV head ``h // (H // G)``;
     - ``page_indices``: int32 ``[B, max_pages]`` per-row page table
       (entries past the row's used pages are ignored: never read);
     - ``lengths``: int32 ``[B]`` valid cached tokens per row — the
       query tile occupies positions ``length - Tq … length - 1``, so
-      the current step's K/V must already be written to the pages.
+      the current step's K/V must already be written to the pages;
+    - ``window``: 0, or the number of newest positions (its own
+      included) a query sees; pages wholly behind it are not read;
+    - ``name``: the kernel's name in a device trace
+      (``K.PAGED_DECODE``), or None for the instruction name the call
+      inherits (see the call below).
 
     Returns ``[B, Tq, H, D]``.  Inference-only (no custom VJP): this is
     the serving decode primitive (ROADMAP item 1) exercised standalone.
     """
     b, t_q, h, d = q.shape
     page = k_pages.shape[1]
-    hd = h * d
-    enforce(k_pages.shape[2:] in ((h, d), (hd,)),
-            f"page pool {k_pages.shape} is neither [P, page, {h}, {d}] "
-            f"nor [P, page, {hd}] for query heads/dim {h}/{d}")
+    if k_pages.ndim == 4:
+        g = k_pages.shape[2]
+    else:
+        g = k_pages.shape[2] // d
+    gd = g * d
+    enforce(k_pages.shape[2:] in ((g, d), (gd,)) and g >= 1
+            and h % g == 0,
+            f"page pool {k_pages.shape} is neither [P, page, G, {d}] "
+            f"nor [P, page, G·{d}] with G dividing the {h} query heads")
     enforce(v_pages.shape == k_pages.shape,
             "k_pages and v_pages shapes differ: "
             f"{k_pages.shape} vs {v_pages.shape}")
@@ -1339,19 +1435,21 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
                                      reverse=True), b).astype(jnp.int32)
     # a DMA cannot cut a row inside a 128-lane tile: rows narrower than
     # whole tiles (toy sizes) are padded out, a copy that real widths
-    # (H·D a multiple of 128) never make
-    pad = -hd % 128
-    width = hd + pad
+    # (G·D a multiple of 128) never make
+    pad = -gd % 128
+    width = gd + pad
 
     def rows(a):
-        a = a.reshape(*a.shape[:2], hd)
+        a = a.reshape(*a.shape[:2], a.shape[2] if a.ndim == 3
+                      else a.shape[2] * a.shape[3])
         return jnp.pad(a, ((0, 0), (0, 0), (0, pad))) if pad else a
+    grouped = g != h
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=1.0 / np.sqrt(d),
-                          page=page, t_q=t_q, n_heads=h, d=d,
-                          n_pages_max=n_pages_max),
+                          page=page, t_q=t_q, n_heads=h, kv_heads=g, d=d,
+                          n_pages_max=n_pages_max, window=int(window)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
@@ -1367,42 +1465,52 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths):
                 pltpu.VMEM((t_q, h, width), jnp.float32),
             ],
         ),
-        out_shape=[jax.ShapeDtypeStruct((b, t_q, width), q.dtype)],
+        # one [H·D] row a query where every head has its own K/V (the
+        # lanes the products leave it in); [H, D] where heads share
+        out_shape=[jax.ShapeDtypeStruct(
+            (b, t_q, h, d) if grouped else (b, t_q, width), q.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
-        # THE one call without name= (tests/test_kernel_names.py lists
-        # it): the accepted benchmark metric paged_decode_roofline.serve
-        # finds this kernel as ``%_lambda_.N = f32[B,1,H·D] …
-        # custom-call(s32[…``, the instruction name it inherits from
-        # serving/model.py's jax.jit(lambda …); name=K.PAGED_DECODE
-        # comes with the benchmark PR that repoints that metric
-        # (PERF.md §7).  Its work depends on run-time lengths: the
-        # serve loop's serve_decode_step span carries live_tokens /
-        # live_pages.
+        # The default plan's caller (serving/model.py) passes no name:
+        # the accepted benchmark metric paged_decode_roofline.serve
+        # finds its kernel as ``%_lambda_.N = f32[B,1,H·D] …
+        # custom-call(s32[…``, the instruction name the call inherits
+        # from that module's jax.jit(lambda …), until the benchmark PR
+        # that repoints the metric (PERF.md §7).  A caller that passes
+        # K.PAGED_DECODE reads ``%paged_decode.N`` in a trace.  The
+        # work depends on run-time lengths: the serve loop's
+        # serve_decode_step span carries live_tokens / live_pages /
+        # attended_tokens.
+        name=name,
     )(lengths, page_indices.astype(jnp.int32), live,
-      rows(q), rows(k_pages), rows(v_pages))[0]
-    return out[..., :hd].reshape(b, t_q, h, d)
+      q if grouped else rows(q), rows(k_pages), rows(v_pages))[0]
+    return out if grouped else out[..., :gd].reshape(b, t_q, h, d)
 
 
-def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths):
+def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths,
+                           window: int = 0):
     """Dense one-step reference for :func:`paged_decode_attention`
     (tests; also the numerics contract): gather each row's pages into
-    a contiguous [B, max_pages·page, H, D] cache and run the dense
-    masked attention."""
+    a contiguous [B, max_pages·page, G, D] cache, give every query head
+    its KV head, and run the dense masked attention."""
     b, t_q, h, d = q.shape
     page = k_pages.shape[1]
     n_max = page_indices.shape[1]
     gk = k_pages[page_indices.reshape(-1)].reshape(
-        b, n_max * page, h, d)
+        b, n_max * page, -1, d)
     gv = v_pages[page_indices.reshape(-1)].reshape(
-        b, n_max * page, h, d)
+        b, n_max * page, -1, d)
+    rep = h // gk.shape[2]
+    gk, gv = jnp.repeat(gk, rep, axis=2), jnp.repeat(gv, rep, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    gk.astype(jnp.float32)) / np.sqrt(d)
     ki = jnp.arange(n_max * page, dtype=jnp.int32)
     qpos = (lengths[:, None] - t_q
             + jnp.arange(t_q, dtype=jnp.int32)[None, :])     # [B, Tq]
     valid = ki[None, None, :] <= qpos[:, :, None]            # [B,Tq,K]
+    if window:
+        valid &= ki[None, None, :] > qpos[:, :, None] - window
     s = jnp.where(valid[:, None, :, :], s, NEG_INF)
     m = jnp.maximum(s.max(axis=-1, keepdims=True), NEG_INF / 2)
     p = jnp.exp(s - m)

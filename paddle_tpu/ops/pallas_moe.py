@@ -1,0 +1,228 @@
+"""Routed experts: a router that picks ``top_k`` of E experts a token,
+tokens sorted by expert, one **grouped matmul** over the sorted rows
+that reads only the experts that have tokens, and the weighted combine.
+
+The serving decoder's routed feed-forward (``serving/model.py``) is the
+caller.  At decode a step routes a hundred token-choices over 128
+experts: the layer is bound by the bytes of the experts that were hit,
+so a product of every expert with every token (the plain reference's
+way) would read all of them.  At prefill thousands of tokens reach every
+expert and the layer is bound by the MXU, so the rows of one expert
+have to share one pass over its weights.  Both are one kernel here:
+
+- :func:`grouped_matmul` — ``lhs[rows of group g] @ rhs[g]`` for rows
+  sorted by group.  The grid walks (row tile, group) visits listed in
+  scalar-prefetched tables, as many as there are, not E of them: a
+  group with no rows has no visit, so its weights are never fetched;
+  a tile that two groups share is visited once for each, and each
+  stores its own rows.  The contraction is not tiled (``k`` is a model
+  width), so consecutive tiles of one group re-use the weight block
+  that is already in VMEM.
+- :func:`routed_experts` — the whole layer but its shared expert:
+  scores, selection, sort, three grouped matmuls (gate, up, down) and
+  the combine.  Returns the group sizes beside the result, from which
+  a serving step counts the experts it hit.
+
+Router mathematics (the configuration's, ``chipbench/reference``):
+``s = sigmoid(x·W_r)`` in float32; the ``top_k`` largest of ``s + b``
+are chosen (the bias chooses, it does not weigh); weights
+``scale · s_e / (Σ_chosen s + 1e-20)``.  No token is dropped and there
+is no capacity factor.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..core.device import pallas_interpret
+from ..observe import counter
+from . import kernels as K
+
+#: rows a tile holds at most; whole widths of ``k``; columns a tile
+#: holds at most.  The sweep on the chip (PERF.md §6, PR 27): at the
+#: decode shape (128 rows over 66 experts) every tiling reads 0.40-0.42
+#: ms a product, the bytes' time; at the prefill shape (49,152 rows)
+#: 128 x 1024 is the fastest, 2.05 / 2.39 ms for 2.24 / 2.74 at 256 x 512
+TILE_M, TILE_N = 128, 1024
+#: the kernel's VMEM allowance: two buffers of a [k, TILE_N] bf16 weight
+#: block, of a row tile and of its result, and the f32 accumulator
+VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def record_moe_dispatch(path: str, reason: str = "") -> None:
+    """Count one routed-expert lowering decision (trace-time: once per
+    compiled program per call site — the ``*_dispatch_total``
+    convention; ``reason`` is set only where a call left the grouped
+    kernel)."""
+    counter(
+        "moe_dispatch_total",
+        "routed-expert lowering decisions by path (trace-time; a "
+        "reason marks a fallback from the grouped matmul)",
+    ).inc(path=path, reason=reason)
+
+
+def weight_matmul(a, w):
+    """``a @ w`` with ``a`` rounded to the dtype ``w`` is stored in and
+    float32 accumulation: bfloat16 operands for a bfloat16 model, the
+    plain float32 product for a float32 one."""
+    return jnp.dot(a.astype(w.dtype), w,
+                   preferred_element_type=jnp.float32)
+
+
+def _round_up(x: int, to: int) -> int:
+    return -(-x // to) * to
+
+
+def _visits(group_sizes, m: int, tm: int):
+    """The (row tile, group) visits of rows sorted by group, in row
+    order: ``(offsets [E+1], group_of [V], tile_of [V], n)`` with
+    ``V = m/tm + E - 1`` slots of which the first ``n`` are used.  A
+    group visits every tile that holds one of its rows; an empty group
+    visits none."""
+    e = group_sizes.shape[0]
+    tiles_m = m // tm
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    first = starts // tm
+    n_of = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    slots = tiles_m + e - 1
+    group_of = jnp.repeat(jnp.arange(e, dtype=jnp.int32), n_of,
+                          total_repeat_length=slots)
+    # the visit's rank among its group's visits, added to the group's
+    # first tile
+    before = jnp.cumsum(n_of) - n_of
+    tile_of = first[group_of] + (jnp.arange(slots, dtype=jnp.int32)
+                                 - before[group_of])
+    n = n_of.sum()
+    # slots past the used ones repeat the last visit (never run)
+    tile_of = jnp.clip(tile_of, 0, tiles_m - 1)
+    return (offsets.astype(jnp.int32), group_of,
+            tile_of.astype(jnp.int32), n.astype(jnp.int32))
+
+
+def _gmm_kernel(off_ref, grp_ref, tile_ref, lhs_ref, rhs_ref, out_ref, *,
+                tm):
+    """One visit: the tile's rows times the group's weight block; the
+    rows of the tile that belong to the group are stored, the others
+    keep what an earlier visit of the same tile stored."""
+    v = pl.program_id(1)
+    g = grp_ref[v]
+    acc = jnp.dot(lhs_ref[...], rhs_ref[...],
+                  preferred_element_type=jnp.float32)
+    row = tile_ref[v] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, acc.shape, 0)
+    mine = jnp.logical_and(row >= off_ref[g], row < off_ref[g + 1])
+    out_ref[...] = jnp.where(mine, acc.astype(out_ref.dtype),
+                             out_ref[...])
+
+
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.float32):
+    """``out[r] = lhs[r] @ rhs[g(r)]`` for rows sorted by group.
+
+    - ``lhs``: ``[M, k]``, its first ``Σ group_sizes`` rows in group
+      order; the rows after them are padding and their result is
+      **unspecified** (nothing visits them): mask them where they are
+      used;
+    - ``rhs``: ``[E, k, n]``; ``group_sizes``: int32 ``[E]``.
+
+    Returns ``[M, n]`` in ``out_dtype``.  ``M`` is padded up to whole
+    row tiles here.
+    """
+    m, k = lhs.shape
+    e, _, n = rhs.shape
+    tm = min(TILE_M, _round_up(m, 16))
+    tn = min(TILE_N, n)
+    m_pad = _round_up(m, tm)
+    if m_pad != m:
+        lhs = jnp.pad(lhs, ((0, m_pad - m), (0, 0)))
+    offsets, group_of, tile_of, n_visits = _visits(
+        group_sizes.astype(jnp.int32), m_pad, tm)
+    out_shape = jax.ShapeDtypeStruct((m_pad, n), out_dtype)
+    K.record_kernel_work(K.MOE_GMM, 2.0 * m * k * n,
+                         (lhs, rhs), (out_shape,))
+    out = pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            # columns outermost: within one column block, consecutive
+            # visits of one group find its weight block in place
+            grid=(n // tn, n_visits),
+            in_specs=[
+                pl.BlockSpec((tm, k),
+                             lambda j, v, off, grp, tile: (tile[v], 0)),
+                pl.BlockSpec((None, k, tn),
+                             lambda j, v, off, grp, tile: (grp[v], 0, j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda j, v, off, grp, tile: (tile[v], j)),
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=pallas_interpret(),
+        name=K.MOE_GMM,
+    )(offsets, group_of, tile_of, lhs, rhs)
+    return out[:m]
+
+
+def route(x, router_w, router_bias, top_k: int, route_scale: float):
+    """``(experts [T, top_k] int32, weights [T, top_k] float32)``: the
+    ``top_k`` largest of ``sigmoid(x·W_r) + bias``, weighed by their
+    sigmoid scores alone, normalised and scaled.  float32 at the
+    highest matmul precision: a score that rounds differently picks
+    another expert."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, experts, axis=1)
+    weights = route_scale * picked / (
+        picked.sum(axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights
+
+
+def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, *,
+                   top_k: int, route_scale: float, valid=None):
+    """The routed half of a mixture-of-experts feed-forward.
+
+    - ``x``: ``[T, d]`` float32 (the block's normed input);
+    - ``router_w`` ``[d, E]``, ``router_bias`` ``[E]`` (float32);
+    - ``w_gate``, ``w_up`` ``[E, d, f]``, ``w_down`` ``[E, f, d]`` in
+      their storage dtype: each expert is ``(silu(x·Wg) ⊙ x·Wu)·Wd``;
+    - ``valid``: optional bool ``[T]``; a token that is not valid
+      (padding, an idle batch slot) is routed nowhere: it reads no
+      expert, counts in no group and gets zeros.
+
+    Returns ``(y [T, d] float32, group_sizes [E] int32)``.
+    """
+    t, d = x.shape
+    e = router_w.shape[1]
+    record_moe_dispatch("grouped")
+    experts, weights = route(x, router_w, router_bias, top_k, route_scale)
+    flat = experts.reshape(-1)
+    if valid is not None:
+        # the sentinel group E sorts behind every expert
+        flat = jnp.where(jnp.repeat(valid, top_k), flat, e)
+    order = jnp.argsort(flat, stable=True)                # [T·k]
+    group_sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1, mode="drop")
+    xs = x.astype(w_gate.dtype)[order // top_k]           # [T·k, d]
+    gate = grouped_matmul(xs, w_gate, group_sizes)
+    up = grouped_matmul(xs, w_up, group_sizes)
+    h = (jax.nn.silu(gate) * up).astype(w_down.dtype)
+    ys = grouped_matmul(h, w_down, group_sizes)           # [T·k, d]
+    # back to token order: choice c of the flat list lies at row inv[c]
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    per_choice = ys[inv].reshape(t, top_k, d)
+    if valid is not None:
+        per_choice = jnp.where(valid[:, None, None], per_choice, 0.0)
+    y = jnp.sum(per_choice * weights[:, :, None], axis=1)
+    return y, group_sizes

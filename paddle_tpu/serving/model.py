@@ -1,6 +1,13 @@
 """Decoder-only transformer for the serving stack — the model half of
 the continuous-batching server (``serving/server.py``).
 
+One decoder: what a layer computes is read from the **layer plan** of
+:class:`DecoderConfig` (window or full attention, rotary positions, q/k
+norms, an output gate, four norms a block; a GELU, SwiGLU or
+routed-expert feed-forward), query heads may share K/V heads, and the
+weights and the K/V pool are kept in float32 or bfloat16.  The empty
+plan is the dense block this module started as.
+
 The two entry points mirror the two serving kernels from PR 14/15:
 
 - :meth:`DecoderModel.prefill` runs a batch of mixed-length prompts in
@@ -43,9 +50,11 @@ import numpy as np
 
 from ..layers.beam_search import eos_frozen_logits
 from ..observe.trace import span as _span
+from ..ops import kernels as K
 from ..ops.pallas_attention import (flash_attention_packed, paged_kv_write,
                                     paged_decode_attention,
                                     segments_from_lengths)
+from ..ops.pallas_moe import routed_experts, weight_matmul
 from ..utils import enforce
 from . import export as _export
 from . import loader as _loader
@@ -54,7 +63,32 @@ from . import loader as _loader
 class DecoderConfig(NamedTuple):
     """Shape of the served decoder (all sizes static — one compiled
     prefill per (B, T) bucket, one compiled decode step per batch
-    width)."""
+    width).
+
+    ``plan`` is the **layer plan**, the one selector of what a layer
+    computes: one entry a layer, ``"<attention>/<feed-forward>"``, each
+    side a ``+``-joined set of words.
+
+    - attention: ``full`` or ``window`` (a query sees the ``window``
+      newest positions up to its own), then any of ``rope`` (rotary
+      positions on q and k, half-split rotation at ``rope_theta``),
+      ``qknorm`` (RMS norm of every q and k head over ``head_dim``),
+      ``gate`` (the attention output times ``sigmoid(x·Wg)`` before
+      ``wo``) and ``postnorm`` (each sub-block's output is RMS-normed
+      before it joins the residual stream: four norms a block);
+    - feed-forward: ``gelu`` (``w1``, ``w2``, tanh-GELU), ``swiglu``
+      (``w_gate``, ``w_up``, ``w_down`` of width ``ffn``) or ``routed``
+      (``experts`` SwiGLU experts of width ``expert_ffn``, ``top_k`` a
+      token by sigmoid score, ``ops/pallas_moe.py``), the last with
+      ``shared`` for one more expert that every token passes.
+
+    The empty plan is the default one, ``full/gelu`` in every layer
+    (with ``pos_embed`` the decoder this module started as).  ``heads``
+    query heads share ``kv_heads`` K/V heads of ``head_dim`` (0: one
+    K/V head a query head, ``dim // heads`` wide).  ``storage`` is the
+    dtype of the matrices, the embedding and the K/V pool; matrix
+    products take operands in it and accumulate in float32, and norms,
+    router scores, softmax and the residual stream stay float32."""
     vocab: int
     dim: int
     heads: int
@@ -62,36 +96,144 @@ class DecoderConfig(NamedTuple):
     ffn: int
     max_context: int = 256
     eos_id: int = 1
+    plan: Tuple[str, ...] = ()
+    kv_heads: int = 0
+    head_dim: int = 0
+    window: int = 0
+    experts: int = 0
+    top_k: int = 0
+    expert_ffn: int = 0
+    route_scale: float = 1.0
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    embed_scale: float = 1.0
+    pos_embed: bool = True
+    storage: str = "float32"
+
+
+ATTENTION_WORDS = frozenset(
+    {"full", "window", "rope", "qknorm", "gate", "postnorm"})
+FFN_WORDS = frozenset({"gelu", "swiglu", "routed", "shared"})
+
+
+@functools.lru_cache(maxsize=None)
+def layer_plan(cfg: DecoderConfig
+               ) -> Tuple[Tuple[frozenset, frozenset], ...]:
+    """``cfg.plan`` parsed and checked: one (attention words,
+    feed-forward words) pair a layer."""
+    plan = cfg.plan or ("full/gelu",) * cfg.layers
+    enforce(len(plan) == cfg.layers,
+            f"the layer plan has {len(plan)} entries for {cfg.layers} "
+            "layers")
+    out = []
+    for entry in plan:
+        attn, _, ffn = entry.partition("/")
+        attn, ffn = frozenset(attn.split("+")), frozenset(ffn.split("+"))
+        enforce(attn <= ATTENTION_WORDS and len(attn & {"full", "window"})
+                == 1, f"plan entry {entry!r}: attention is full or "
+                f"window, then any of {sorted(ATTENTION_WORDS)}")
+        enforce(ffn <= FFN_WORDS
+                and len(ffn & {"gelu", "swiglu", "routed"}) == 1
+                and ("shared" not in ffn or "routed" in ffn),
+                f"plan entry {entry!r}: the feed-forward is gelu, swiglu "
+                "or routed[+shared]")
+        enforce("window" not in attn or cfg.window > 0,
+                f"plan entry {entry!r} needs window > 0")
+        enforce("routed" not in ffn
+                or (cfg.experts >= cfg.top_k >= 1 and cfg.expert_ffn > 0),
+                f"plan entry {entry!r} needs experts >= top_k >= 1 and "
+                "expert_ffn > 0")
+        out.append((attn, ffn))
+    if cfg.head_dim == 0:
+        enforce(cfg.dim % cfg.heads == 0,
+                f"dim {cfg.dim} not divisible by heads {cfg.heads}")
+    enforce(cfg.heads % kv_heads(cfg) == 0,
+            f"{cfg.heads} query heads do not divide over "
+            f"{kv_heads(cfg)} K/V heads")
+    enforce(cfg.storage in ("float32", "bfloat16"),
+            f"storage {cfg.storage!r} is neither float32 nor bfloat16")
+    return tuple(out)
+
+
+def kv_heads(cfg: DecoderConfig) -> int:
+    return cfg.kv_heads or cfg.heads
+
+
+def head_dim(cfg: DecoderConfig) -> int:
+    return cfg.head_dim or cfg.dim // cfg.heads
+
+
+def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
+    """Every weight of the decoder by its artifact name → its shape:
+    ``embed``, ``pos_embed`` (if ``cfg.pos_embed``), ``ln_f``,
+    ``lm_head`` and per layer ``l{i}.{ln1,ln2,wq,wk,wv,wo}`` plus what
+    its plan entry adds: ``w1 w2`` (gelu) | ``w_gate w_up w_down``
+    (swiglu) | ``router router_bias e_gate e_up e_down`` (routed) and
+    ``s_gate s_up s_down`` (shared); ``qn kn`` (qknorm), ``wg`` (gate),
+    ``ln1p ln2p`` (postnorm)."""
+    d, h, g, dh = cfg.dim, cfg.heads, kv_heads(cfg), head_dim(cfg)
+    e, f = cfg.experts, cfg.expert_ffn
+    out: Dict[str, Tuple[int, ...]] = {"embed": (cfg.vocab, d)}
+    if cfg.pos_embed:
+        out["pos_embed"] = (cfg.max_context, d)
+    out["ln_f"] = (d,)
+    out["lm_head"] = (d, cfg.vocab)
+    for i, (attn, ffn) in enumerate(layer_plan(cfg)):
+        leaves = {"ln1": (d,), "ln2": (d,), "wq": (d, h * dh),
+                  "wk": (d, g * dh), "wv": (d, g * dh), "wo": (h * dh, d)}
+        if "gelu" in ffn:
+            leaves.update(w1=(d, cfg.ffn), w2=(cfg.ffn, d))
+        if "swiglu" in ffn:
+            leaves.update(w_gate=(d, cfg.ffn), w_up=(d, cfg.ffn),
+                          w_down=(cfg.ffn, d))
+        if "routed" in ffn:
+            leaves.update(router=(d, e), router_bias=(e,),
+                          e_gate=(e, d, f), e_up=(e, d, f),
+                          e_down=(e, f, d))
+        if "shared" in ffn:
+            leaves.update(s_gate=(d, f), s_up=(d, f), s_down=(f, d))
+        if "qknorm" in attn:
+            leaves.update(qn=(dh,), kn=(dh,))
+        if "gate" in attn:
+            leaves["wg"] = (d, h * dh)
+        if "postnorm" in attn:
+            leaves.update(ln1p=(d,), ln2p=(d,))
+        for name, shape in leaves.items():
+            out[f"l{i}.{name}"] = shape
+    return out
+
+
+def _stored_as(name: str, shape, cfg: DecoderConfig) -> str:
+    """The dtype a leaf is kept in on the device: ``cfg.storage`` for
+    the matrices and the embeddings; float32 for gains, the router and
+    its bias (a score that rounds differently picks another expert)."""
+    if len(shape) < 2 or name.endswith(".router"):
+        return "float32"
+    return cfg.storage
 
 
 def init_decoder_params(cfg: DecoderConfig, seed: int = 0
                         ) -> Dict[str, np.ndarray]:
-    """Random fp32 decoder weights (scaled normal init); names are the
-    artifact contract: ``embed``, ``pos_embed``, per layer
-    ``l{i}.{ln1,ln2,wq,wk,wv,wo,w1,w2}``, ``ln_f``, ``lm_head``."""
-    enforce(cfg.dim % cfg.heads == 0,
-            f"dim {cfg.dim} not divisible by heads {cfg.heads}")
+    """Random fp32 decoder weights (scaled normal init) under the
+    artifact's names (:func:`leaf_shapes`): matrices N(0, 1/fan_in),
+    the embedding N(0, 1), positions N(0, 0.02²), gains 1, the router's
+    selection bias N(0, 0.1²)."""
     rng = np.random.default_rng(seed)
-
-    def mat(n_in, n_out):
-        return (rng.standard_normal((n_in, n_out)) /
-                np.sqrt(n_in)).astype(np.float32)
-
-    p: Dict[str, np.ndarray] = {
-        "embed": mat(cfg.vocab, cfg.dim) * np.float32(np.sqrt(cfg.vocab)),
-        "pos_embed": (0.02 * rng.standard_normal(
-            (cfg.max_context, cfg.dim))).astype(np.float32),
-        "ln_f": np.ones(cfg.dim, np.float32),
-        "lm_head": mat(cfg.dim, cfg.vocab),
-    }
-    for i in range(cfg.layers):
-        p[f"l{i}.ln1"] = np.ones(cfg.dim, np.float32)
-        p[f"l{i}.ln2"] = np.ones(cfg.dim, np.float32)
-        for w, (a, b) in {"wq": (cfg.dim, cfg.dim), "wk": (cfg.dim, cfg.dim),
-                          "wv": (cfg.dim, cfg.dim), "wo": (cfg.dim, cfg.dim),
-                          "w1": (cfg.dim, cfg.ffn),
-                          "w2": (cfg.ffn, cfg.dim)}.items():
-            p[f"l{i}.{w}"] = mat(a, b)
+    p: Dict[str, np.ndarray] = {}
+    for name, shape in leaf_shapes(cfg).items():
+        if len(shape) == 1:
+            p[name] = (0.1 * rng.standard_normal(shape)
+                       if name.endswith("router_bias")
+                       else np.ones(shape)).astype(np.float32)
+            continue
+        z = rng.standard_normal(shape)
+        if name == "embed":      # N(0, 1), rounded as it always was
+            p[name] = (z / np.sqrt(cfg.vocab)).astype(np.float32) \
+                * np.float32(np.sqrt(cfg.vocab))
+        elif name == "pos_embed":
+            p[name] = (0.02 * z).astype(np.float32)
+        else:
+            p[name] = (z / np.sqrt(shape[-2])).astype(np.float32)
     return p
 
 
@@ -100,18 +242,100 @@ def _rms(x, g, eps=1e-6):
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)) * g
 
 
-def _ffn(x, p, i):
-    h = jax.nn.gelu(_rms(x, p[f"l{i}.ln2"]) @ p[f"l{i}.w1"])
-    return x + h @ p[f"l{i}.w2"]
+def _rope(x, pos, theta: float):
+    """Rotary positions, half-split: ``x`` [B, T, N, D] at ``pos``
+    [B, T]; lane j < D/2 pairs with lane j + D/2."""
+    half = x.shape[-1] // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    ang = pos.astype(jnp.float32)[:, :, None, None] * inv    # [B,T,1,D/2]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos + turned * sin
 
 
-def _qkv(xn, p, i, heads):
-    b, t, d = xn.shape
-    dh = d // heads
+def _embed(params, tokens, pos, cfg: DecoderConfig):
+    """Token ids [B, T] at positions ``pos`` [B, T] → the float32
+    residual stream [B, T, dim]."""
+    x = params["embed"][tokens].astype(jnp.float32)
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
+    if cfg.pos_embed:
+        x = x + params["pos_embed"][
+            jnp.clip(pos, 0, cfg.max_context - 1)].astype(jnp.float32)
+    return x
 
-    def proj(w):
-        return (xn @ p[f"l{i}.{w}"]).reshape(b, t, heads, dh)
-    return proj("wq"), proj("wk"), proj("wv")
+
+def _qkv(x, pos, params, i, cfg: DecoderConfig, attn):
+    """The layer's attention inputs from the residual stream: q
+    [B, T, H, D], k and v [B, T, G, D], and the output gate [B, T, H·D]
+    or None."""
+    b, t, _ = x.shape
+    h, g, dh = cfg.heads, kv_heads(cfg), head_dim(cfg)
+    xn = _rms(x, params[f"l{i}.ln1"], cfg.norm_eps)
+    q = weight_matmul(xn, params[f"l{i}.wq"]).reshape(b, t, h, dh)
+    k = weight_matmul(xn, params[f"l{i}.wk"]).reshape(b, t, g, dh)
+    v = weight_matmul(xn, params[f"l{i}.wv"]).reshape(b, t, g, dh)
+    if "qknorm" in attn:
+        q = _rms(q, params[f"l{i}.qn"], cfg.norm_eps)
+        k = _rms(k, params[f"l{i}.kn"], cfg.norm_eps)
+    if "rope" in attn:
+        q, k = _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta)
+    gate = jax.nn.sigmoid(weight_matmul(xn, params[f"l{i}.wg"])) \
+        if "gate" in attn else None
+    return q, k, v, gate
+
+
+def _attend_out(x, o, gate, params, i, cfg: DecoderConfig, attn):
+    """The attention result ``o`` [B, T, H·D] back into the stream."""
+    if gate is not None:
+        o = o * gate
+    y = weight_matmul(o, params[f"l{i}.wo"])
+    if "postnorm" in attn:
+        y = _rms(y, params[f"l{i}.ln1p"], cfg.norm_eps)
+    return x + y
+
+
+def _swiglu(m, w_gate, w_up, w_down):
+    return weight_matmul(jax.nn.silu(weight_matmul(m, w_gate))
+                         * weight_matmul(m, w_up), w_down)
+
+
+def _ffn(x, valid, params, i, cfg: DecoderConfig, attn, ffn):
+    """The layer's feed-forward on the stream ``x`` [B, T, dim];
+    ``valid`` [B, T] marks the tokens that are real (a routed layer
+    sends the others nowhere).  → (stream, the routed layer's tokens
+    per expert [E] or None)."""
+    p = lambda leaf: params[f"l{i}.{leaf}"]
+    m = _rms(x, p("ln2"), cfg.norm_eps)
+    sizes = None
+    if "gelu" in ffn:
+        y = weight_matmul(jax.nn.gelu(weight_matmul(m, p("w1"))), p("w2"))
+    elif "swiglu" in ffn:
+        y = _swiglu(m, p("w_gate"), p("w_up"), p("w_down"))
+    else:
+        b, t, d = m.shape
+        y, sizes = routed_experts(
+            m.reshape(b * t, d), p("router"), p("router_bias"),
+            p("e_gate"), p("e_up"), p("e_down"), top_k=cfg.top_k,
+            route_scale=cfg.route_scale, valid=valid.reshape(-1))
+        y = y.reshape(b, t, d)
+        if "shared" in ffn:
+            y = y + _swiglu(m, p("s_gate"), p("s_up"), p("s_down"))
+    if "postnorm" in attn:
+        y = _rms(y, p("ln2p"), cfg.norm_eps)
+    return x + y, sizes
+
+
+def _route_counts(sizes):
+    """What a step's routed layers did, two integers that ride back
+    with its tokens: experts with at least one token, summed over the
+    layers, and the most tokens on one expert of any layer.  No routed
+    layer: nothing."""
+    if not sizes:
+        return jnp.zeros((0,), jnp.int32)
+    per = jnp.stack(sizes)                                   # [Lr, E]
+    return jnp.stack([(per > 0).sum(), per.max()]).astype(jnp.int32)
 
 
 def _prefill_impl(params, k_pool, v_pool, tokens, lengths, page_indices,
@@ -121,30 +345,33 @@ def _prefill_impl(params, k_pool, v_pool, tokens, lengths, page_indices,
     [1, B*T] row; segment ids keep rows from attending across each
     other and mask padding outright."""
     b, t = tokens.shape
-    pos = jnp.arange(t, dtype=jnp.int32)[None, :]
-    x = params["embed"][tokens] + params["pos_embed"][
-        jnp.clip(pos, 0, cfg.max_context - 1)]
+    h, g, dh = cfg.heads, kv_heads(cfg), head_dim(cfg)
+    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
+    x = _embed(params, tokens, pos, cfg)
     segments = segments_from_lengths(lengths, b, t)
+    valid = pos < lengths[:, None]
     zero = jnp.zeros((b,), jnp.int32)
-    for i in range(cfg.layers):
-        q, k, v = _qkv(_rms(x, params[f"l{i}.ln1"]), params, i, cfg.heads)
+    for i, (attn, ffn) in enumerate(layer_plan(cfg)):
+        q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
         # the decode contract: K/V must be in the pages before any
         # later step queries them — write the whole prompt now
         kp, vp = paged_kv_write(k_pool[i], v_pool[i], k, v,
                                 page_indices, zero, lengths)
         k_pool = k_pool.at[i].set(kp)
         v_pool = v_pool.at[i].set(vp)
-        dh = cfg.dim // cfg.heads
-        attn = flash_attention_packed(
-            q.reshape(1, b * t, cfg.heads, dh),
-            k.reshape(1, b * t, cfg.heads, dh),
-            v.reshape(1, b * t, cfg.heads, dh),
-            segments, causal=True, slot=t)
-        x = x + attn.reshape(b, t, cfg.dim) @ params[f"l{i}.wo"]
-        x = _ffn(x, params, i)
+        # the kernel multiplies what the pool stores
+        q, k, v = (a.astype(k_pool.dtype) for a in (q, k, v))
+        o = flash_attention_packed(
+            q.reshape(1, b * t, h, dh), k.reshape(1, b * t, g, dh),
+            v.reshape(1, b * t, g, dh), segments, causal=True, slot=t,
+            window=cfg.window if "window" in attn else 0)
+        x = _attend_out(x, o.reshape(b, t, h * dh).astype(jnp.float32),
+                        gate, params, i, cfg, attn)
+        x, _ = _ffn(x, valid, params, i, cfg, attn, ffn)
     last = jnp.take_along_axis(
         x, jnp.clip(lengths - 1, 0, t - 1)[:, None, None], axis=1)[:, 0]
-    logits = _rms(last, params["ln_f"]) @ params["lm_head"]
+    logits = weight_matmul(_rms(last, params["ln_f"], cfg.norm_eps),
+                           params["lm_head"])
     active = lengths > 0
     nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
     return nxt.astype(jnp.int32), logits, k_pool, v_pool
@@ -156,26 +383,38 @@ def _decode_impl(params, k_pool, v_pool, tokens, page_indices, lengths,
     token being fed (its position is ``lengths - 1``); ``active`` masks
     padded slots — their K/V write count is zero and their kernel
     length clamps to 1 over the scratch page, so padding can neither
-    write nor read real pool state."""
+    write nor read real pool state.  The first result is the [B] next
+    tokens, followed by :func:`_route_counts`' integers where the plan
+    has routed layers."""
     b = tokens.shape[0]
-    pos = jnp.clip(lengths - 1, 0, cfg.max_context - 1)
-    x = (params["embed"][tokens] + params["pos_embed"][pos])[:, None, :]
+    pos = jnp.clip(lengths - 1, 0, cfg.max_context - 1)[:, None]
+    x = _embed(params, tokens[:, None], pos, cfg)
     counts = active.astype(jnp.int32)
     klen = jnp.where(active, lengths, 1).astype(jnp.int32)
-    for i in range(cfg.layers):
-        q, k, v = _qkv(_rms(x, params[f"l{i}.ln1"]), params, i, cfg.heads)
+    # the default plan's kernel keeps the name its call inherits (see
+    # paged_decode_attention); a planned decoder's reads %paged_decode
+    name = K.PAGED_DECODE if cfg.plan else None
+    sizes = []
+    for i, (attn, ffn) in enumerate(layer_plan(cfg)):
+        q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
         kp, vp = paged_kv_write(k_pool[i], v_pool[i], k, v,
                                 page_indices, lengths - 1, counts)
         k_pool = k_pool.at[i].set(kp)
         v_pool = v_pool.at[i].set(vp)
-        # kp/vp are the layer's pool as stored, [P, page, H·D]: the
+        # kp/vp are the layer's pool as stored, [P, page, G·D]: the
         # kernel DMAs the rows' live pages out of it, nothing else
-        attn = paged_decode_attention(q, kp, vp, page_indices, klen)
-        x = x + attn.reshape(b, 1, cfg.dim) @ params[f"l{i}.wo"]
-        x = _ffn(x, params, i)
-    logits = _rms(x[:, 0], params["ln_f"]) @ params["lm_head"]
+        o = paged_decode_attention(
+            q, kp, vp, page_indices, klen,
+            window=cfg.window if "window" in attn else 0, name=name)
+        x = _attend_out(x, o.reshape(b, 1, -1), gate, params, i, cfg, attn)
+        x, routed = _ffn(x, active[:, None], params, i, cfg, attn, ffn)
+        if routed is not None:
+            sizes.append(routed)
+    logits = weight_matmul(_rms(x[:, 0], params["ln_f"], cfg.norm_eps),
+                           params["lm_head"])
     nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
-    return nxt.astype(jnp.int32), logits, k_pool, v_pool
+    return jnp.concatenate([nxt.astype(jnp.int32), _route_counts(sizes)]), \
+        logits, k_pool, v_pool
 
 
 @functools.lru_cache(maxsize=None)
@@ -209,25 +448,63 @@ class DecoderModel:
     serves any number of pools/replicas reentrantly."""
 
     def __init__(self, params: Dict[str, Any], cfg: DecoderConfig):
-        enforce(cfg.dim % cfg.heads == 0,
-                f"dim {cfg.dim} not divisible by heads {cfg.heads}")
         self.cfg = cfg
-        # fp32 on-device once; dequantized int8 artifacts land here too
-        self.params = {k: jax.device_put(np.asarray(v))
-                       for k, v in params.items()}
+        self.plan = layer_plan(cfg)          # checks the config too
+        self.routed_layers = sum("routed" in ffn for _, ffn in self.plan)
+        self._window_layers = sum("window" in attn for attn, _ in self.plan)
+        shapes = leaf_shapes(cfg)
+        enforce(set(params) == set(shapes),
+                "the weights are not the plan's: missing "
+                f"{sorted(set(shapes) - set(params))}, unknown "
+                f"{sorted(set(params) - set(shapes))}")
+        # on the device once, each leaf in the dtype it is kept in
+        # (float32 arrives, dequantized int8 artifacts too, and is
+        # rounded there: the host never holds a second copy)
+        self.params = {}
+        for name, v in params.items():
+            a = jax.device_put(np.asarray(v))
+            enforce(a.shape == shapes[name],
+                    f"weight {name}: shape {a.shape}, the plan's is "
+                    f"{shapes[name]}")
+            self.params[name] = a.astype(_stored_as(name, a.shape, cfg))
         self._prefill, self._decode = _jitted_steps(cfg)
 
     # ----------------------------------------------------------- pools
     def new_pools(self, n_pages: int, page_size: int
                   ) -> Tuple[jax.Array, jax.Array]:
-        """Zeroed per-layer K/V pools, ``[L, P, page, H·Dh]``: one
-        lane-dense row a token, the layout the decode kernel fetches
-        pages in and ``paged_kv_write`` scatters rows into.  (Stored
-        ``[…, H, Dh]`` with Dh < 128 the TPU lays the page axis along
-        the lanes, and every use of a layer's pool is a relayout copy
-        of it: PERF.md §6, PR 26.)"""
-        shape = (self.cfg.layers, n_pages, page_size, self.cfg.dim)
-        return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
+        """Zeroed per-layer K/V pools, ``[L, P, page, G·Dh]`` in the
+        storage dtype: one lane-dense row a token, the layout the
+        decode kernel fetches pages in and ``paged_kv_write`` scatters
+        rows into.  (Stored ``[…, G, Dh]`` with Dh < 128 the TPU lays
+        the page axis along the lanes, and every use of a layer's pool
+        is a relayout copy of it: PERF.md §6, PR 26.)"""
+        shape = (self.cfg.layers, n_pages, page_size,
+                 kv_heads(self.cfg) * head_dim(self.cfg))
+        return (jnp.zeros(shape, self.cfg.storage),
+                jnp.zeros(shape, self.cfg.storage))
+
+    # ------------------------------------------------- what a step reads
+    def attended_tokens(self, lengths) -> int:
+        """K/V positions a decode step over rows of these ``lengths``
+        (the fed token included) must read, summed over the layers:
+        all of a row on a full layer, its ``window`` newest on a window
+        layer."""
+        full = sum(lengths)
+        if not self._window_layers:
+            return full * len(self.plan)
+        near = sum(min(n, self.cfg.window) for n in lengths)
+        return near * self._window_layers \
+            + full * (len(self.plan) - self._window_layers)
+
+    def pages_behind_window(self, lengths, page_size: int) -> int:
+        """Layer-pages (one layer's K and V of one page) that these
+        rows hold wholly behind a window layer's window: allocated,
+        never read again."""
+        if not self._window_layers:
+            return 0
+        behind = sum(max(n - self.cfg.window, 0) // page_size
+                     for n in lengths)
+        return behind * self._window_layers
 
     # ----------------------------------------------------------- steps
     def prefill(self, k_pool, v_pool, tokens, lengths, page_indices):
@@ -250,7 +527,11 @@ class DecoderModel:
         return nxt, logits, k_pool, v_pool
 
     def decode(self, k_pool, v_pool, tokens, page_indices, lengths, active):
-        """One continuous-batching decode step over the page pool."""
+        """One continuous-batching decode step over the page pool.  →
+        (next tokens, logits, the pools, and what the step's routed
+        layers did: ``{"experts_hit", "expert_load_max"}``,
+        :func:`_route_counts`; empty for a plan without routed layers).
+        The counts come back in the fetch that brings the tokens."""
         with _span("decode_dispatch"):        # host→device + launch
             nxt, logits, k_pool, v_pool = self._decode(
                 self.params, k_pool, v_pool,
@@ -260,7 +541,10 @@ class DecoderModel:
                 jnp.asarray(active, bool))
         with _span("decode_fetch"):           # blocks on the device
             nxt, logits = np.asarray(nxt), np.asarray(logits)
-        return nxt, logits, k_pool, v_pool
+        b = logits.shape[0]
+        routed = dict(zip(("experts_hit", "expert_load_max"),
+                          map(int, nxt[b:])))
+        return nxt[:b], logits, k_pool, v_pool, routed
 
     # -------------------------------------------------------- artifacts
     @classmethod
@@ -278,7 +562,12 @@ class DecoderModel:
                 f"{dirname}: not a decoder artifact "
                 f"(kind={manifest.get('kind')!r}); ServedModel.load "
                 "handles module artifacts")
-        cfg = DecoderConfig(**manifest["decoder"])
+        # JSON has no tuple: the plan comes back a list (a config must
+        # hash); an artifact from before the plan has no such key and
+        # loads as the default plan
+        shape = dict(manifest["decoder"])
+        shape["plan"] = tuple(shape.get("plan", ()))
+        cfg = DecoderConfig(**shape)
         wsec = manifest["weights"]
         weights = _loader.load_weight_entries(dirname, wsec)
         params = {e["name"]: w

@@ -46,9 +46,10 @@ nothing it does for a request lies outside a span; under it::
 
     serve_loop_iter
     ├─ serve_admit{queued}
-    ├─ serve_prefill{n, t_pad, prompt_tokens, requests}
+    ├─ serve_prefill{n, t_pad, prompt_tokens, moe_tokens, requests}
     │    serve_step_build · prefill_dispatch · prefill_fetch · serve_step_emit
-    ├─ serve_decode_step{batch, live_tokens, live_pages}
+    ├─ serve_decode_step{batch, live_tokens, live_pages, attended_tokens,
+    │                    experts_hit, expert_load_max}
     │    serve_step_build · decode_dispatch · decode_fetch · serve_step_emit
     └─ serve_snapshot
 
@@ -58,7 +59,14 @@ launch), ``*_fetch`` the ``np.asarray`` that blocks on the device and
 brings the token ids and logits back (both in ``serving/model.py``),
 ``serve_step_emit`` the token bookkeeping, finishes and page releases.
 ``live_tokens`` is Σ ``lengths`` of the active rows — the K/V positions
-the step attends over — and ``live_pages`` the pages they occupy.  Each
+the step attends over — and ``live_pages`` the pages they occupy;
+``attended_tokens`` is what the layers must read of them, summed over
+the layers (a window layer reads a row's newest ``window`` only).  Where
+the plan has routed layers, ``moe_tokens`` is the prompt tokens times
+those layers, and the step's own counts come back with its tokens and
+are set before the span closes: ``experts_hit`` (experts with a token of
+an active row, summed over the routed layers) and ``expert_load_max``
+(the most tokens on one expert).  Each
 request carries a ``trace_id`` (its submitter's trace, else its own):
 at admission ``serve_queue_wait`` (submit → admit) and at the finish
 ``serve_request`` (submit → last token; ``prompt``, ``tokens``,
@@ -111,9 +119,19 @@ HTTP_THREAD_NAME = "ptpu-serve-http"
 _REQ_IDS = itertools.count()
 
 
+class _NoSpan:
+    """What ``with _span(...) as sp`` binds without telemetry."""
+
+    def set(self, **attrs) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
 def _span(name: str, **attrs):
     if _trace is None:
-        return contextlib.nullcontext()
+        return contextlib.nullcontext(_NO_SPAN)
     # ptpu: lint-ok[PT-METRIC] forwarding shim; callers pass literals
     return _trace.span(name, **attrs)
 
@@ -258,6 +276,14 @@ class InferenceServer:
         self._m_batch = None if _gauge is None else _gauge(
             "serve_batch_size",
             "requests in the most recent inference launch")
+        # the evidence for releasing pages by layer type (ROADMAP M7):
+        # one page table serves every layer, so a window layer keeps
+        # what it will never read again
+        self._m_behind = None if _gauge is None else _gauge(
+            "serve_kv_pages_behind_window",
+            "layer-pages (one layer's K and V of one page) that the "
+            "rows of the latest decode step hold wholly behind a "
+            "window layer's window")
 
     @staticmethod
     def _make_pool(n_pages: int, page_size: int,
@@ -640,8 +666,10 @@ class InferenceServer:
         # bucket the pad length: bounded set of compiled prefill shapes
         t_pad = -(-t_pad // 16) * 16
         t_pad = min(t_pad, self.model.cfg.max_context)
+        prompt_tokens = sum(len(r.prompt) for r in admitted)
         with _span("serve_prefill", n=b, t_pad=t_pad,
-                   prompt_tokens=sum(len(r.prompt) for r in admitted),
+                   prompt_tokens=prompt_tokens,
+                   moe_tokens=prompt_tokens * self.model.routed_layers,
                    requests=",".join(r.id for r in admitted)):
             with _span("serve_step_build"):
                 tokens = np.zeros((b, t_pad), np.int32)
@@ -686,7 +714,9 @@ class InferenceServer:
         fed = [r.length + 1 for r in slots]
         with _span("serve_decode_step", batch=len(slots),
                    live_tokens=sum(fed),
-                   live_pages=sum(map(self.pool.pages_needed, fed))):
+                   live_pages=sum(map(self.pool.pages_needed, fed)),
+                   attended_tokens=self.model.attended_tokens(fed)
+                   ) as step:
             with _span("serve_step_build"):
                 tokens = np.zeros((b,), np.int32)
                 lengths = np.ones((b,), np.int32)
@@ -700,9 +730,14 @@ class InferenceServer:
                     tables[i] = self._table_row(r)
             if self._m_batch is not None:
                 self._m_batch.set(len(slots))
-            nxt, _, self._k_pool, self._v_pool = self.model.decode(
-                self._k_pool, self._v_pool, tokens, tables, lengths,
-                active)
+            if self._m_behind is not None and self.model.cfg.window:
+                self._m_behind.set(self.model.pages_behind_window(
+                    fed, self.pool.page_size))
+            nxt, _, self._k_pool, self._v_pool, routed = \
+                self.model.decode(
+                    self._k_pool, self._v_pool, tokens, tables, lengths,
+                    active)
+            step.set(**routed)      # experts_hit, expert_load_max
             with _span("serve_step_emit"):
                 self._count_tokens(len(slots))
                 for i, r in enumerate(slots):

@@ -16,10 +16,13 @@ from paddle_tpu.ops import kernels as K
 
 OPS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "paddle_tpu", "ops")
-#: the one call that keeps the instruction name it inherits: the
-#: accepted benchmark metric ``paged_decode_roofline.serve`` finds it
-#: as ``%_lambda_…`` (see the call site; PERF.md §7 queues the rename)
-UNNAMED = {("pallas_attention.py", "paged_decode_attention")}
+#: the one call whose name is its caller's to give: the default plan's
+#: decoder gives none, so that the accepted benchmark metric
+#: ``paged_decode_roofline.serve`` finds the kernel as ``%_lambda_…``,
+#: the instruction name it inherits (see the call site; PERF.md §7
+#: queues the rename); a decoder with a layer plan gives
+#: ``K.PAGED_DECODE`` (``serving/model.py``)
+CALLERS_NAME = {("pallas_attention.py", "paged_decode_attention")}
 
 
 def _sites():
@@ -66,7 +69,7 @@ def _table_constants(node, fn):
 
 
 def test_the_walk_finds_every_site():
-    assert len(SITES) == 22
+    assert len(SITES) == 23
     assert len(set(K.KERNEL_NAMES.values())) == len(K.KERNEL_NAMES)
     used = set()
     for _, _, call, fn in SITES:
@@ -74,8 +77,11 @@ def test_the_walk_finds_every_site():
             if kw.arg == "name":
                 used |= _table_constants(kw.value, fn)
     # a name nobody passes is a metric that can never read: only the
-    # reserved one may wait
+    # one that the serving decoder hands down is given outside ops/
     assert set(K.KERNEL_NAMES) - used == {"PAGED_DECODE"}
+    with open(os.path.join(os.path.dirname(OPS), "serving",
+                           "model.py")) as f:
+        assert "name = K.PAGED_DECODE if cfg.plan else None" in f.read()
 
 
 @pytest.mark.parametrize(
@@ -83,8 +89,13 @@ def test_the_walk_finds_every_site():
     ids=[f"{f}:{fn}:{c.lineno}" for f, fn, c, _ in SITES])
 def test_every_pallas_call_is_named_from_the_table(fname, func, call, fn):
     names = [kw.value for kw in call.keywords if kw.arg == "name"]
-    if (fname, func) in UNNAMED:
-        assert not names, "the exception is over: drop it from UNNAMED"
+    if (fname, func) in CALLERS_NAME:
+        # name=<the function's own ``name`` parameter>, None by default
+        assert [n.id for n in names] == ["name"]
+        arg = [a.arg for a in fn.args.args].index("name")
+        default = fn.args.defaults[arg - len(fn.args.args)]
+        assert default.value is None, \
+            "the default plan's call must stay unnamed"
         return
     assert len(names) == 1, f"{fname}:{call.lineno} passes no name="
     constants = _table_constants(names[0], fn)

@@ -217,36 +217,105 @@ def test_segment_windows_skip_interleaved_padding():
 
 
 # ------------------------------------------------------------- packed
-@pytest.mark.parametrize("causal", [False, True])
-def test_packed_matches_per_row_dense(causal, rng):
+def _np_attention(q, k, v, causal, window):
+    """One row's attention in numpy, float64: q [T, H, D], k and v
+    [T, G, D] (query head h attends K/V head h // (H/G)); ``window`` > 0
+    hides keys ``window`` or more positions behind the query."""
+    t, h, d = q.shape
+    rep = h // k.shape[1]
+    k, v = np.repeat(k, rep, axis=1), np.repeat(v, rep, axis=1)
+    s = np.einsum("qhd,khd->hqk", q, k, dtype=np.float64) / np.sqrt(d)
+    behind = np.arange(t)[:, None] - np.arange(t)[None, :]
+    seen = np.ones((t, t), bool)
+    if causal:
+        seen &= behind >= 0
+    if window:
+        seen &= behind < window
+    s = np.where(seen[None], s, -np.inf)
+    p = np.exp(s - s.max(axis=-1, keepdims=True))
+    p /= p.sum(axis=-1, keepdims=True)
+    return np.einsum("hqk,khd->qhd", p, v)
+
+
+@pytest.mark.parametrize("causal,kv_heads,window", [
+    (False, 2, 0), (True, 2, 0),
+    # grouped K/V heads (4 query heads over 2, over 1) and a sliding
+    # window that cuts inside a k block (40 = 2.5 blocks of 16) and on
+    # a block's edge (32): the serving prefill's, forward only
+    (False, 1, 0), (True, 1, 0), (True, 2, 40), (True, 1, 40),
+    (True, 4, 32)])
+def test_packed_matches_per_row_dense(causal, kv_heads, window, rng):
     """Packed kernel over one [1, B·T] token axis ≡ per-row dense
     attention on every valid token; padding tokens emit exact zeros
     and receive exact-zero gradients."""
-    H, D = 2, 16
+    D = 16
+    H = 2 if (kv_heads, window) == (2, 0) else 4
     lens = [100, 64, 30]          # boundary (64 = 4·16) + odd + short
     B, T = 3, 128
-    x = [rng.randn(B, T, H, D).astype(np.float32) for _ in range(3)]
-    q, k, v = (jnp.asarray(a.reshape(1, B * T, H, D)) for a in x)
+    x = [rng.randn(B, T, n, D).astype(np.float32)
+         for n in (H, kv_heads, kv_heads)]
+    q, k, v = (jnp.asarray(a.reshape(1, B * T, -1, D)) for a in x)
     seg = pa.segments_from_lengths(jnp.asarray(lens, jnp.int32), B, T)
     out = np.asarray(pa.flash_attention_packed(q, k, v, seg, causal,
-                                               128, 16))
+                                               128, 16, 0, window))
     out = out.reshape(B, T, H, D)
-    ref = np.asarray(pa._dense_forward(
-        jnp.asarray(x[0]), jnp.asarray(x[1]), jnp.asarray(x[2]),
-        jnp.asarray(lens, jnp.int32), causal)[0])
     for i, l in enumerate(lens):
-        np.testing.assert_allclose(out[i, :l], ref[i, :l],
-                                   rtol=2e-4, atol=2e-5)
+        ref = _np_attention(x[0][i, :l], x[1][i, :l], x[2][i, :l],
+                            causal, window)
+        np.testing.assert_allclose(out[i, :l], ref, rtol=2e-4, atol=2e-5)
         assert np.abs(out[i, l:]).max() == 0.0
     cot = jnp.asarray(rng.randn(1, B * T, H, D).astype(np.float32))
-    g = _grads(lambda *a: pa.flash_attention_packed(
-        *a, seg, causal, 128, 16), q, k, v, cot)
+    packed = lambda *a: pa.flash_attention_packed(
+        *a, seg, causal, 128, 16, 0, window)
+    if kv_heads != H or window:
+        with pytest.raises(PaddleTpuError, match="no backward"):
+            _grads(packed, q, k, v, cot)
+        return
+    g = _grads(packed, q, k, v, cot)
     gd = _dense_grads(q, k, v, None, causal, cot, segments=seg)
     segn = np.asarray(seg).reshape(B * T)
     for a, b in zip(g, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-4)
         assert np.abs(np.asarray(a)[0, segn < 0]).max() == 0.0
+
+
+def test_packed_window_at_the_served_shape(rng):
+    """The routed decoder's longest prefill as the server launches it:
+    one row of 6144 tokens in blocks of 512, 2 query heads over 1 K/V
+    head of 128, window 2048, ``slot`` = the row.  Sampled queries —
+    inside the window, at its edge, on block edges and at the row's
+    end — equal the windowed attention over their keys."""
+    T, H, D, W = 6144, 2, 128, 2048
+    q = rng.randn(1, T, H, D).astype(np.float32)
+    k, v = (rng.randn(1, T, 1, D).astype(np.float32) for _ in range(2))
+    seg = pa.segments_from_lengths(jnp.asarray([T], jnp.int32), 1, T)
+    out = np.asarray(pa.flash_attention_packed(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), seg, causal=True,
+        slot=T, window=W))
+    for i in (0, 511, 512, 2047, 2048, 2049, 2560, 4095, 4096, 6143):
+        lo = max(0, i - W + 1)
+        s = np.einsum("hd,kd->hk", q[0, i].astype(np.float64),
+                      k[0, lo:i + 1, 0]) / np.sqrt(D)
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        p /= p.sum(axis=-1, keepdims=True)
+        np.testing.assert_allclose(out[0, i], p @ v[0, lo:i + 1, 0],
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_window_drops_the_pairs_behind_it():
+    """The static pair table of a causal, windowed layer holds only
+    the blocks a query of the q block can see: at 6144 tokens in
+    blocks of 512 under a window of 2048, 12 diagonal blocks and at
+    most 4 behind each (the fifth holds only keys 2048 or more behind
+    the block's first query)."""
+    full = pa._pair_tables(6144, 6144, 512, 512, True)[0].shape[1]
+    near = pa._pair_tables(6144, 6144, 512, 512, True, 0, 2048)[0].shape[1]
+    assert full == 12 * 13 // 2
+    assert near == sum(min(j + 1, 5) for j in range(12))
+    # the dense fallback masks the same pairs
+    s = pa._mask_scores(jnp.zeros((1, 1, 8, 8)), True, None, None, 3)
+    assert (np.asarray(s[0, 0]) == 0).sum() == 3 * 8 - 3
 
 
 def test_packed_layer_kill_switch_both_directions(rng, attn_flags):
@@ -313,8 +382,30 @@ def _decode_case(case, rng):
     NaN where the case plants table entries that must never be
     dereferenced: a fetched page shows in the output even under p = 0."""
     H, D, P, page = 2, 16, 10, 16
+    G = window = 0                    # G: K/V heads (0: one a query head)
     poison = ()
-    if case == "base":
+    if case == "gqa":
+        # 4 query heads over 2 K/V heads: the pool's rows are G·D wide
+        H, G = 4, 2
+        pidx = [[2, 0, 4, 7], [5, 1, 3, 8], [9, 6, 2, 0]]
+        lengths = [55, 32, 7]
+    elif case in ("window", "gqa_window"):
+        # a window of 20 over pages of 16: rows whose first pages lie
+        # wholly behind it (never fetched: they hold NaN), a row the
+        # window covers whole, and one that ends on a page's edge
+        H, G = (4, 1) if case == "gqa_window" else (2, 0)
+        window = 20
+        pidx = [[8, 9, 4, 7], [5, 1, 3, 9], [6, 0, 0, 0], [0, 2, 0, 0]]
+        lengths = [55, 48, 7, 32]
+        poison = (8, 9)
+    elif case == "gqa_serve":
+        # the routed decoder's row: 32 heads over 4 K/V heads of 128,
+        # pages of 64 and a window of 128
+        H, G, D, P, page, window = 32, 4, 128, 8, 64, 128
+        pidx = [[7, 2, 5, 1], [3, 6, 0, 0], [4, 0, 0, 0]]
+        lengths = [250, 128, 3]
+        poison = (7,)
+    elif case == "base":
         # mid-page, page-boundary, and single-page fills
         pidx = [[2, 0, 4, 7], [5, 1, 3, 8], [9, 6, 2, 0]]
         lengths = [55, 32, 7]
@@ -341,36 +432,43 @@ def _decode_case(case, rng):
         lengths = [37, 32]
     else:
         raise AssertionError(case)
-    kpg = rng.randn(P, page, H, D).astype(np.float32)
-    vpg = rng.randn(P, page, H, D).astype(np.float32)
+    kpg = rng.randn(P, page, G or H, D).astype(np.float32)
+    vpg = rng.randn(P, page, G or H, D).astype(np.float32)
     for pg in poison:
         kpg[pg] = vpg[pg] = np.nan
     pidx = np.asarray(pidx, np.int32)
     used = -(-np.asarray(lengths) // page)
-    live = np.arange(pidx.shape[1])[None, :] < used[:, None]
+    # under a window the walk starts at the first page the row's
+    # earliest query (of up to 4) can see
+    first = np.maximum(np.asarray(lengths) - 4 - window + 1, 0) // page \
+        if window else np.zeros_like(used)
+    slot = np.arange(pidx.shape[1])[None, :]
+    live = (slot >= first[:, None]) & (slot < used[:, None])
     assert not np.isin(pidx[live], poison).any()
     # the reference gathers every slot of the table before it masks:
     # give it a live page wherever the kernel must not look
-    safe = np.where(live, pidx, pidx[:, :1])
+    safe = np.where(live, pidx, pidx[np.arange(len(used)), first][:, None])
     return (H, D, jnp.asarray(kpg), jnp.asarray(vpg), jnp.asarray(pidx),
-            jnp.asarray(lengths, jnp.int32), jnp.asarray(safe))
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(safe), window)
 
 
 @pytest.mark.parametrize("t_q", [1, 4])
 @pytest.mark.parametrize("case", ["base", "wide_scratch", "wide_garbage",
                                   "page_boundary", "inactive_rows",
-                                  "hd2048"])
+                                  "hd2048", "gqa", "window",
+                                  "gqa_window", "gqa_serve"])
 def test_paged_decode_matches_dense_reference(case, t_q, rng):
     """The decode primitive over a partially-filled paged cache equals
     the dense one-step reference: per-row lengths (mid-page fills,
     fills that end on a page's edge), per-row page tables far wider
     than the pages in use whose dead slots are never dereferenced,
     inactive rows beside live ones, small-Tq causal tail, toy and
-    lane-dense head widths."""
-    H, D, kpg, vpg, pidx, lengths, safe = _decode_case(case, rng)
+    lane-dense head widths; query heads that share K/V heads, and a
+    sliding window whose pages behind it are never fetched."""
+    H, D, kpg, vpg, pidx, lengths, safe, window = _decode_case(case, rng)
     q = jnp.asarray(rng.randn(pidx.shape[0], t_q, H, D).astype(np.float32))
-    out = pa.paged_decode_attention(q, kpg, vpg, pidx, lengths)
-    ref = pa.paged_decode_reference(q, kpg, vpg, safe, lengths)
+    out = pa.paged_decode_attention(q, kpg, vpg, pidx, lengths, window)
+    ref = pa.paged_decode_reference(q, kpg, vpg, safe, lengths, window)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
@@ -379,23 +477,26 @@ def test_paged_decode_matches_dense_reference(case, t_q, rng):
         >= 1
 
 
+@pytest.mark.parametrize("kind", ["base", "gqa"])
 @pytest.mark.parametrize("t_q", [1, 4])
-def test_paged_decode_lane_dense_pool_is_the_same_pool(t_q, rng):
-    """The server stores a token as one [H·D] row (``new_pools``): the
+def test_paged_decode_lane_dense_pool_is_the_same_pool(t_q, kind, rng):
+    """The server stores a token as one [G·D] row (``new_pools``): the
     kernel and ``paged_kv_write`` take that pool as they take
-    [P, page, H, D], and give the same numbers."""
-    H, D, kpg, vpg, pidx, lengths, _ = _decode_case("base", rng)
+    [P, page, G, D], and give the same numbers — at the K/V heads'
+    width where query heads share them."""
+    H, D, kpg, vpg, pidx, lengths, _, _ = _decode_case(kind, rng)
+    G = kpg.shape[2]
     B, P, page = pidx.shape[0], kpg.shape[0], kpg.shape[1]
     q = jnp.asarray(rng.randn(B, t_q, H, D).astype(np.float32))
-    k_new, v_new = (jnp.asarray(rng.randn(B, t_q, H, D)
+    k_new, v_new = (jnp.asarray(rng.randn(B, t_q, G, D)
                                 .astype(np.float32)) for _ in range(2))
     counts = jnp.full((B,), t_q, jnp.int32)
     kp4, vp4 = pa.paged_kv_write(kpg, vpg, k_new, v_new, pidx,
                                  lengths - t_q, counts)
     kp3, vp3 = pa.paged_kv_write(
-        kpg.reshape(P, page, H * D), vpg.reshape(P, page, H * D),
+        kpg.reshape(P, page, G * D), vpg.reshape(P, page, G * D),
         k_new, v_new, pidx, lengths - t_q, counts)
-    assert kp3.shape == (P, page, H * D) and kp4.shape == kpg.shape
+    assert kp3.shape == (P, page, G * D) and kp4.shape == kpg.shape
     np.testing.assert_array_equal(np.asarray(kp3).reshape(kp4.shape),
                                   np.asarray(kp4))
     np.testing.assert_array_equal(np.asarray(vp3).reshape(vp4.shape),

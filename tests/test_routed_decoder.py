@@ -1,0 +1,347 @@
+"""The decoder with a layer plan (``serving/model.py``): routed experts,
+grouped K/V heads, window and full attention mixed, against the plain
+reference of the configuration that brought them
+(``chipbench/reference/trinity-mini-serve.py``, which imports nothing of
+the program) on seeded weights at the configuration's rehearsal sizes:
+hidden 64, 4 heads over 2 K/V heads of 16, window 8, page 4, 32 experts
+with 8 a token, the configuration's own five-layer plan."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu import observe
+from paddle_tpu.observe import trace as ptrace
+from paddle_tpu.ops import kernels as K
+from paddle_tpu.ops import pallas_moe as moe
+from paddle_tpu.serving.model import (DecoderConfig, DecoderModel,
+                                      export_decoder, init_decoder_params,
+                                      layer_plan, leaf_shapes)
+from paddle_tpu.serving.server import InferenceServer
+from paddle_tpu.utils import PaddleTpuError
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = "trinity-mini-serve"
+
+
+@pytest.fixture(scope="module")
+def bench():
+    """(sizes, reference module, system module, seeded weights) of the
+    configuration's rehearsal."""
+    import sys
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from chipbench import harness as H, weights as W
+
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           CONFIG + ".json")) as f:
+        sizes = json.load(f)["rehearsal"]["sizes"]
+    ref = H.load_module("reference", CONFIG)
+    system = H.load_module("systems", CONFIG)
+    return sizes, ref, system, W.make(ref.param_spec(sizes), 20270001)
+
+
+def _model(bench, storage, drop=()):
+    sizes, _, system, weights = bench
+    cfg = system.decoder_config(sizes)._replace(storage=storage)
+    params = {system.leaf_name(k): v for k, v in weights.items()}
+    for name in drop:                       # a planted fault
+        params = {k: np.zeros_like(v) if k.endswith(name) else v
+                  for k, v in params.items()}
+    return DecoderModel(params, cfg)
+
+
+def _reference_logits(bench, seqs):
+    """The reference's logits for the token after each sequence."""
+    sizes, ref, _, weights = bench
+    tokens = np.zeros((len(seqs), 128), np.int32)
+    for i, s in enumerate(seqs):
+        tokens[i, :len(s)] = s
+    at = np.array([[len(s) - 1] for s in seqs])
+    with jax.default_matmul_precision("highest"):
+        return ref.logits_at(weights, sizes, tokens, at)[:, 0]
+
+
+def _serve(model, prompts, steps, page=4, width=4):
+    """Prefill the prompts as one batch, then ``steps`` decode steps at
+    a fixed width through page tables that are neither contiguous nor
+    in order.  → [(sequences so far, the program's logits for each)]."""
+    b = len(prompts)
+    slots = model.cfg.max_context // page
+    k, v = model.new_pools(1 + b * slots, page)
+    tables = 1 + np.random.default_rng(5).permutation(b * slots) \
+        .reshape(b, slots).astype(np.int32)         # page 0: scratch
+    t_pad = -(-max(map(len, prompts)) // 16) * 16
+    tokens = np.zeros((b, t_pad), np.int32)
+    for i, p in enumerate(prompts):
+        tokens[i, :len(p)] = p
+    nxt, logits, k, v = model.prefill(
+        k, v, tokens, np.array([len(p) for p in prompts], np.int32), tables)
+    seqs = [list(p) for p in prompts]
+    out = [([list(s) for s in seqs], logits)]
+    for _ in range(steps):
+        for i in range(b):
+            seqs[i].append(int(nxt[i]))
+        fed = np.zeros((width,), np.int32)
+        lengths = np.ones((width,), np.int32)
+        active = np.zeros((width,), bool)
+        tab = np.zeros((width, slots), np.int32)
+        fed[:b], active[:b], tab[:b] = nxt[:b], True, tables
+        lengths[:b] = [len(s) for s in seqs]
+        nxt, logits, k, v, counts = model.decode(
+            k, v, fed, tab, lengths, active)
+        out.append(([list(s) for s in seqs], logits[:b]))
+        # 4 routed layers of 32 experts, 8 a token
+        assert 4 * 8 <= counts["experts_hit"] <= 4 * min(32, 8 * b)
+        assert 1 <= counts["expert_load_max"] <= b
+    return out
+
+
+# Tolerances, and why.  In float32 storage the program and the reference
+# compute the same sums in another order (a packed kernel's online
+# softmax, sorted rows): 1e-4 of logits of size 1-4 is some ten times
+# what is seen and a hundredth of what the smallest planted fault moves;
+# every row of every step is held to it.  In bfloat16 storage every
+# matrix product rounds its operands to 8 bits of mantissa and K/V are
+# kept so: at these toy widths (a norm over 64 lanes) a row reads
+# 0.05-0.14 — but a router score rounded across a near-tie of the top 8
+# of 32 sends the token to another expert, and that row then reads
+# 0.2-1.0 (four rows in ten here), as the reference itself does when
+# computed in bfloat16 (PERF.md §6, PR 27).  So only the median row is
+# held, to 0.2 (seen: 0.11); float32 is the strict comparison, and the
+# one the planted faults are judged by.
+TOLERANCE = {"float32": 1e-4, "bfloat16": 0.2}
+
+
+@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+def test_prefill_then_decode_through_pages_is_the_reference(bench, storage):
+    """Rows of mixed length in one batch — shorter than the window of 8,
+    past it, past several pages of 4 and ending on a page's edge — are
+    prefilled, then decoded 6 steps at width 4 (one slot idle); every
+    step's logits are the full forward's of the plain reference."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(2, 256, n).tolist() for n in (21, 5, 32)]
+    model = _model(bench, storage)
+    rows = []                    # the worst logit of each row of each step
+    for seqs, logits in _serve(model, prompts, 6):
+        want = _reference_logits(bench, seqs)
+        rows.extend(np.abs(want - logits).max(axis=-1))
+    rows, limit = np.array(rows), TOLERANCE[storage]
+    if storage == "float32":
+        assert rows.max() < limit, rows
+    else:
+        assert np.median(rows) < limit, rows
+    # what the step must read: rows of 27, 11 and 38 positions under
+    # four window layers of 8 and one full layer
+    assert model.attended_tokens([27, 11, 38]) == 4 * 24 + 76
+    assert model.pages_behind_window([27, 11, 38], 4) == 4 * (4 + 0 + 7)
+
+
+@pytest.mark.parametrize("fault", ["window", "bias"])
+def test_a_planted_fault_is_seen(bench, fault):
+    """A program that attends without the window on sliding layers, or
+    that drops the router's selection bias, is far outside the
+    tolerance (so the comparison holds the program to both)."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(2, 256, n).tolist() for n in (21, 32)]
+    if fault == "window":
+        sizes, _, system, weights = bench
+        cfg = system.decoder_config(sizes)._replace(
+            storage="float32", window=10 ** 6)
+        model = DecoderModel({system.leaf_name(k): v
+                              for k, v in weights.items()}, cfg)
+    else:
+        model = _model(bench, "float32", drop=("router_bias",))
+    worst = 0.0
+    for seqs, logits in _serve(model, prompts, 2):
+        want = _reference_logits(bench, seqs)
+        worst = max(worst, float(np.abs(want - logits).max()))
+    assert worst > 0.4, worst
+
+
+# ------------------------------------------------------- the routed op
+def _per_token(x, router_w, bias, wg, wu, wd, top_k, scale):
+    """The routed layer one token at a time, float64 numpy."""
+    out = np.zeros_like(x, dtype=np.float64)
+    hit = np.zeros(router_w.shape[1], np.int64)
+    for t, m in enumerate(x.astype(np.float64)):
+        s = 1.0 / (1.0 + np.exp(-(m @ router_w)))
+        chosen = np.argsort(-(s + bias), kind="stable")[:top_k]
+        norm = s[chosen].sum() + 1e-20
+        for e in chosen:
+            hid = m @ wg[e]
+            hid = hid / (1.0 + np.exp(-hid)) * (m @ wu[e])
+            out[t] += scale * s[e] / norm * (hid @ wd[e])
+            hit[e] += 1
+    return out, hit
+
+
+@pytest.mark.parametrize("tokens,experts,top_k", [
+    (5, 8, 2),          # fewer rows than a tile: most of it padding
+    (40, 8, 3),         # several experts share a row tile
+    (300, 4, 2),        # an expert's rows span row tiles of 256
+    (24, 16, 1)])       # experts with no token at all
+def test_routed_experts_is_the_per_token_loop(tokens, experts, top_k):
+    """Selection by s + b, weights by s alone, normalised and scaled:
+    the sorted, grouped product equals a loop over tokens and their
+    experts; the group sizes count each expert's tokens; a token that
+    is not valid reaches no expert and gets zeros."""
+    rng = np.random.default_rng(tokens)
+    d, f, scale = 32, 48, 2.826
+    x = rng.standard_normal((tokens, d)).astype(np.float32)
+    router_w = rng.standard_normal((d, experts)).astype(np.float32) * 0.3
+    # a bias of the scores' own size: it changes who is chosen
+    bias = rng.standard_normal(experts).astype(np.float32) * 0.3
+    wg, wu = (rng.standard_normal((experts, d, f)).astype(np.float32)
+              / np.sqrt(d) for _ in range(2))
+    wd = rng.standard_normal((experts, f, d)).astype(np.float32) \
+        / np.sqrt(f)
+    valid = np.ones(tokens, bool)
+    valid[1::4] = False
+    y, sizes = moe.routed_experts(
+        jnp.asarray(x), jnp.asarray(router_w), jnp.asarray(bias),
+        jnp.asarray(wg), jnp.asarray(wu), jnp.asarray(wd),
+        top_k=top_k, route_scale=scale, valid=jnp.asarray(valid))
+    want, hit = _per_token(x[valid], router_w, bias, wg, wu, wd, top_k,
+                           scale)
+    # float32 sums in another order against float64: 2e-5 of values of
+    # size 1 (seen: 3e-6)
+    np.testing.assert_allclose(np.asarray(y)[valid], want, atol=2e-5)
+    assert np.abs(np.asarray(y)[~valid]).max() == 0.0
+    np.testing.assert_array_equal(np.asarray(sizes), hit)
+    # the bias chose: without it other experts are hit
+    _, unbiased = moe.routed_experts(
+        jnp.asarray(x), jnp.asarray(router_w), jnp.zeros(experts),
+        jnp.asarray(wg), jnp.asarray(wu), jnp.asarray(wd),
+        top_k=top_k, route_scale=scale, valid=jnp.asarray(valid))
+    assert (np.asarray(unbiased) != hit).any()
+
+
+def test_grouped_matmul_reads_only_groups_with_rows():
+    """An empty group's weights are never fetched: they hold NaN and
+    the result is finite.  The call is named and counted."""
+    rng = np.random.default_rng(3)
+    sizes = np.array([0, 7, 0, 0, 30, 0, 3, 0], np.int32)
+    m, k, n = int(sizes.sum()), 32, 128
+    lhs = rng.standard_normal((m, k)).astype(np.float32)
+    rhs = rng.standard_normal((8, k, n)).astype(np.float32)
+    rhs[sizes == 0] = np.nan
+    out = np.asarray(moe.grouped_matmul(jnp.asarray(lhs), jnp.asarray(rhs),
+                                        jnp.asarray(sizes)))
+    group = np.repeat(np.arange(8), sizes)
+    want = np.einsum("mk,mkn->mn", lhs, rhs[group])
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    rows = {(s["labels"]["kernel"], s["labels"]["kind"]): s["value"]
+            for s in observe.REGISTRY.find(
+                "pallas_kernel_work_total").samples()}
+    assert rows[(K.MOE_GMM, "calls")] >= 1
+    assert rows[(K.MOE_GMM, "flops")] >= 2.0 * m * k * n
+
+
+# ------------------------------------------------------------ the plan
+def test_the_default_plan_is_the_decoder_it_was():
+    cfg = DecoderConfig(vocab=64, dim=32, heads=4, layers=2, ffn=64)
+    assert layer_plan(cfg) == ((frozenset({"full"}),
+                                frozenset({"gelu"})),) * 2
+    assert sorted(leaf_shapes(cfg)) == sorted(
+        ["embed", "pos_embed", "ln_f", "lm_head"]
+        + [f"l{i}.{w}" for i in range(2)
+           for w in ("ln1", "ln2", "wq", "wk", "wv", "wo", "w1", "w2")])
+    model = DecoderModel(init_decoder_params(cfg, 0), cfg)
+    k, _ = model.new_pools(8, 4)
+    assert k.shape == (2, 8, 4, 32) and k.dtype == jnp.float32
+    assert model.routed_layers == 0
+    assert model.attended_tokens([5, 7]) == 2 * 12
+
+
+@pytest.mark.parametrize("plan,why", [
+    (("full/gelu",), "entries for 2 layers"),
+    (("full/gelu", "slow/gelu"), "attention is full or window"),
+    (("full/gelu", "full+window/gelu"), "attention is full or window"),
+    (("full/gelu", "full/shared"), "feed-forward is gelu"),
+    (("full/gelu", "window/gelu"), "needs window > 0"),
+    (("full/gelu", "full/routed"), "needs experts")])
+def test_a_plan_the_decoder_cannot_run_is_refused(plan, why):
+    cfg = DecoderConfig(vocab=64, dim=32, heads=4, layers=2, ffn=64,
+                        plan=plan)
+    with pytest.raises(PaddleTpuError, match=why):
+        layer_plan(cfg)
+
+
+# ------------------------------------------------------------ artifacts
+def test_an_artifact_round_trips_the_plan(bench, tmp_path):
+    """``export_decoder`` → ``from_artifact`` gives the same config
+    (the plan a tuple again) and the same logits."""
+    model = _model(bench, "float32")
+    host = {k: np.asarray(v, np.float32) for k, v in model.params.items()}
+    art = export_decoder(host, model.cfg, str(tmp_path / "art"),
+                         quantize=None)
+    loaded = DecoderModel.from_artifact(art)
+    assert loaded.cfg == model.cfg and isinstance(loaded.cfg.plan, tuple)
+    prompts = [list(range(2, 20))]
+    a = _serve(model, prompts, 1)
+    b = _serve(loaded, prompts, 1)
+    for (_, la), (_, lb) in zip(a, b):
+        np.testing.assert_array_equal(la, lb)
+
+
+def test_an_artifact_from_before_the_plan_loads_as_the_default(tmp_path):
+    """A manifest whose ``decoder`` holds only the seven keys it had
+    before the plan loads as the default plan."""
+    cfg = DecoderConfig(vocab=64, dim=32, heads=4, layers=2, ffn=64,
+                        max_context=32)
+    art = export_decoder(init_decoder_params(cfg, 1), cfg,
+                         str(tmp_path / "old"), quantize=None)
+    path = os.path.join(art, "manifest.json")
+    with open(path) as f:
+        manifest = json.load(f)
+    manifest["decoder"] = {k: manifest["decoder"][k] for k in (
+        "vocab", "dim", "heads", "layers", "ffn", "max_context", "eos_id")}
+    with open(path, "w") as f:
+        json.dump(manifest, f)
+    loaded = DecoderModel.from_artifact(art, verify=False)
+    assert loaded.cfg == cfg and loaded.cfg.plan == ()
+
+
+# ------------------------------------------------------- through the server
+def test_the_server_reports_what_its_routed_steps_did(bench):
+    """Through ``InferenceServer``: the tokens are the reference's
+    greedy tokens within the tolerance's reach, the decode span carries
+    the routing counts and the attended positions, the prefill span
+    ``moe_tokens``, and the gauge counts the pages behind the window."""
+    model = _model(bench, "float32")
+    server = InferenceServer(model, max_batch=4, n_pages=64, page_size=4,
+                             continuous=True)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(2, 256, n).tolist() for n in (30, 9)]
+    ptrace.enable(fences=False)
+    server.start()
+    try:
+        reqs = [server.submit(p, 8) for p in prompts]
+        for r in reqs:
+            assert r.done.wait(120) and r.state == "done", r.error
+        spans = ptrace.events()
+    finally:
+        server.stop()
+        ptrace.disable()
+    for p, r in zip(prompts, reqs):
+        seq = list(p)
+        for tok in r.tokens:
+            want = _reference_logits(bench, [seq])[0]
+            assert want.max() - want[tok] < 1e-4
+            seq.append(tok)
+    steps = [e["args"] for e in spans if e["name"] == "serve_decode_step"]
+    assert steps and all(
+        {"experts_hit", "expert_load_max", "attended_tokens",
+         "live_tokens"} <= set(a) for a in steps)
+    assert all(a["attended_tokens"] <= 5 * a["live_tokens"] for a in steps)
+    fills = [e["args"] for e in spans if e["name"] == "serve_prefill"]
+    assert sum(a["moe_tokens"] for a in fills) == 4 * (30 + 9)
+    gauge = observe.REGISTRY.find("serve_kv_pages_behind_window")
+    assert gauge is not None and gauge.samples()
+    flat = observe.REGISTRY.flat(kinds=("counter",))
+    assert flat['moe_dispatch_total{path="grouped",reason=""}'] >= 1
