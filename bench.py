@@ -911,7 +911,8 @@ def _decoder_observatory_stamp(r, model, cfg, max_batch, pool_pages,
 
     from paddle_tpu.serving.model import _decode_impl
 
-    k_pool, v_pool = model.new_pools(pool_pages, page)
+    # the arrays themselves: this lane's own jit donates nothing
+    k_pool, v_pool = (p.array for p in model.new_pools(pool_pages, page))
     max_pages = min(pool_pages - 1,
                     (cfg.max_context + page - 1) // page)
     b = max_batch

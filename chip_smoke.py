@@ -528,20 +528,23 @@ def _rel_err(got, want):
     return worst
 
 
-_HLO_ARRAY = r"[a-z0-9]+\[([\d,]*)\](?:\{([\d,]*)[^}]*\})?"
+_HLO_SHAPE = r"\b[a-z]+[0-9]*\[([\d,]*)\]"
+_HLO_MOVE = re.compile(
+    r"(?:ROOT )?%[\w.\-]+ = (.+?) (?:copy|copy-start|transpose|reshape|slice"
+    r"|dynamic-slice|dynamic-update-slice)\((.*)")
 
 
 def decode_step_checks(compiled, pool_elems, patterns=None):
-    """What the benchmark's traced run needs of the server's compiled
-    decode step: an instruction that the accepted metric
-    ``paged_decode_roofline.serve`` finds by its own pattern (or, for a
-    decoder with a layer plan, by the ``patterns`` of the kernel's own
-    name), and no
-    relayout of a layer's pool (a ``transpose``, a ``reshape`` the
-    compiler could not make a bitcast, a ``copy`` into another
-    dimension order) anywhere in it, so none feeds that instruction.
-    Copies that keep the order (the undonated pool's, the compiler's
-    moves between memory spaces) are counted, not refused."""
+    """What the benchmark's traced run needs of a compiled step of the
+    server (its prefill or its decode step, pools donated): where
+    ``patterns`` are given, an instruction that one of them finds (None:
+    the accepted metric ``paged_decode_roofline.serve``'s own pattern;
+    for a decoder with a layer plan the pattern of the kernel's own
+    name); and the pools updated in place: no ``copy``, ``slice``,
+    ``dynamic-slice`` or ``dynamic-update-slice``, and no relayout
+    (``transpose``, a ``reshape`` the compiler could not make a
+    bitcast), whose result or operand holds a layer's pool of elements
+    or more, in the entry computation or inside a fusion."""
     from jax._src.lib import xla_client as xc
 
     if patterns is None:
@@ -553,24 +556,20 @@ def decode_step_checks(compiled, pool_elems, patterns=None):
     opts.print_metadata = False
     text = "\n".join(m.to_string(opts)
                      for m in compiled.runtime_executable().hlo_modules())
-    calls, plain, relayouts = 0, 0, []
+    calls, moves = 0, []
     for line in map(str.strip, text.splitlines()):
         calls += any(re.search(pat, line) for pat in patterns)
-        m = re.match(r"(?:ROOT )?(%[\w.\-]+) = " + _HLO_ARRAY
-                     + r" (copy|transpose|reshape)\(" + _HLO_ARRAY, line)
-        if not m or math.prod(
-                int(n) for n in m.group(2).split(",") if n) < pool_elems:
-            continue
-        if m.group(4) == "copy" and m.group(3) == m.group(6):
-            plain += 1
-        else:
-            relayouts.append(line[:160])
-    check(calls, "no instruction of the compiled decode step matches "
-                 f"{patterns}: the traced run of the benchmark cannot "
-                 "find the paged decode kernel")
-    check(not relayouts, "the compiled decode step relayouts a layer's "
-                         f"pool: {relayouts}")
-    return {"decode_calls": calls, "pool_copies_in_place_order": plain}
+        m = _HLO_MOVE.match(line)
+        if m and any(math.prod(int(n) for n in dims.split(",") if n)
+                     >= pool_elems for dims in re.findall(
+                         _HLO_SHAPE, m.group(1) + m.group(2))):
+            moves.append(line[:160])
+    check(calls or not patterns,
+          f"no instruction of the compiled step matches {patterns}: the "
+          "traced run of the benchmark cannot find the paged decode kernel")
+    check(not moves, "the compiled step copies, slices or relayouts a "
+                     f"layer's pool or more: {moves}")
+    return {"decode_calls": calls, "pool_sized_moves": len(moves)}
 
 
 def phase_kernels(S, ctx):
@@ -713,41 +712,48 @@ def phase_kernels(S, ctx):
             x, rw, rb, wg, wu, wd, top_k=top_k, route_scale=2.826)[0])(xm),
         jax.jit(every_expert)(xm))
 
-    # the decode step as the server compiles it, small: the benchmark's
-    # traced run must find the kernel in it, fed by the pool as stored.
-    # A rehearsal has no Mosaic call to look at
-    decode_step = {}
+    # the steps as the server compiles them, small, pools donated: the
+    # benchmark's traced run must find the kernel in the decode step,
+    # and neither step may move a layer's pool.  A rehearsal has no
+    # Mosaic call to look at
+    server_steps = {}
+    from paddle_tpu.ops import kernels as K
+
     if not ctx.args.rehearsal:
         from paddle_tpu.serving.model import (DecoderConfig, DecoderModel,
                                               init_decoder_params)
-        cfg = DecoderConfig(vocab=512, dim=nh * d, heads=nh, layers=2,
-                            ffn=2 * nh * d, max_context=max_pages * page)
-        model = DecoderModel(init_decoder_params(cfg, seed=0), cfg)
-        decode_step = decode_step_checks(
-            model._decode.lower(
-                model.params, *model.new_pools(n_pages, page),
-                jnp.zeros((b,), jnp.int32), pidx, lens,
-                jnp.ones((b,), bool)).compile(),
-            n_pages * page * nh * d)
+
+        def steps_of(cfg, n_pages, page, pidx, lens, patterns):
+            model = DecoderModel(init_decoder_params(cfg, seed=0), cfg)
+            pools = [p.array for p in model.new_pools(n_pages, page)]
+            rows, t = pidx.shape[0], 128
+            layer = pools[0][0].size
+            return {
+                "decode": decode_step_checks(model._decode.lower(
+                    model.params, *pools, jnp.zeros((rows,), jnp.int32),
+                    pidx, lens, jnp.ones((rows,), bool)).compile(),
+                    layer, patterns),
+                "prefill": decode_step_checks(model._prefill.lower(
+                    model.params, *pools, jnp.zeros((1, t), jnp.int32),
+                    jnp.full((1,), t, jnp.int32), pidx[:1]).compile(),
+                    layer, ())}
+
+        server_steps["default_plan"] = steps_of(
+            DecoderConfig(vocab=512, dim=nh * d, heads=nh, layers=2,
+                          ffn=2 * nh * d, max_context=max_pages * page),
+            n_pages, page, pidx, lens, None)
         # and of a decoder with a layer plan: grouped K/V heads, a window
         # layer and a full one, routed experts, bfloat16 storage; its
         # kernel is found by the name it is given
-        from paddle_tpu.ops import kernels as K
-
-        cfg = DecoderConfig(
-            vocab=512, dim=dm, heads=hg, layers=2, ffn=2 * dm,
-            max_context=slots_g * page_g, kv_heads=g, head_dim=dg,
-            window=2 * page_g, experts=e, top_k=top_k, expert_ffn=f,
-            route_scale=2.826, pos_embed=False, storage="bfloat16",
-            plan=("window+rope+qknorm+gate+postnorm/swiglu",
-                  "full+qknorm+gate+postnorm/routed+shared"))
-        model = DecoderModel(init_decoder_params(cfg, seed=0), cfg)
-        decode_step["planned"] = decode_step_checks(
-            model._decode.lower(
-                model.params, *model.new_pools(pages_g, page_g),
-                jnp.zeros((bg,), jnp.int32), pidx_g, lens_g,
-                jnp.ones((bg,), bool)).compile(),
-            pages_g * page_g * g * dg,
+        server_steps["planned"] = steps_of(
+            DecoderConfig(
+                vocab=512, dim=dm, heads=hg, layers=2, ffn=2 * dm,
+                max_context=slots_g * page_g, kv_heads=g, head_dim=dg,
+                window=2 * page_g, experts=e, top_k=top_k, expert_ffn=f,
+                route_scale=2.826, pos_embed=False, storage="bfloat16",
+                plan=("window+rope+qknorm+gate+postnorm/swiglu",
+                      "full+qknorm+gate+postnorm/routed+shared")),
+            pages_g, page_g, pidx_g, lens_g,
             [K.instruction_pattern(K.PAGED_DECODE) + ".*tpu_custom_call"])
 
     # the trace can name the kernels: a Mosaic call compiles to an
@@ -755,7 +761,6 @@ def phase_kernels(S, ctx):
     # whatever scope or jitted lambda it sits in — the op events of a
     # device trace carry that instruction's line.  A rehearsal has no
     # Mosaic call to look at, only the scope in the lowering's locations
-    from paddle_tpu.ops import kernels as K
     from paddle_tpu.ops import nn_ops
 
     z = randn(2, 8, 8, 64, dtype=jnp.bfloat16)
@@ -784,7 +789,8 @@ def phase_kernels(S, ctx):
                    f"(all: {errs})")
     # no Expect: the references tick their own fallback labels by design
     return {"rel_err": errs, "tolerance": REL_TOL,
-            "named_kernels": sorted(lowered), **decode_step}, None
+            "named_kernels": sorted(lowered),
+            "server_steps": server_steps}, None
 
 
 # ------------------------------------------------------ --all phases

@@ -36,6 +36,14 @@ _installed = False
 _install_lock = named_lock("observe.dump.install")
 
 
+def _write_whole(path: str, text: str) -> None:
+    """A dump file appears under its name only once it is whole: whoever
+    watches the directory for it never reads a file half written."""
+    with open(path + ".part", "w") as f:
+        f.write(text)
+    os.replace(path + ".part", path)
+
+
 def debug_dump(out_dir: Optional[str] = None) -> Tuple[str, str]:
     """Write the dump files now; returns the (metrics, trace) paths.
     Usable directly (tests, a REPL on a live run) — the signal handler
@@ -55,25 +63,23 @@ def debug_dump(out_dir: Optional[str] = None) -> Tuple[str, str]:
             time.strftime("%Y%m%d-%H%M%S"), os.getpid()))
     prom_path = stem + ".metrics.prom"
     trace_path = stem + ".trace.json"
-    with open(prom_path, "w") as f:
-        f.write(prometheus_dump())
-    with open(trace_path, "w") as f:
-        f.write(trace.flight_recorder_json())
+    _write_whole(prom_path, prometheus_dump())
+    _write_whole(trace_path, trace.flight_recorder_json())
     hmod = sys.modules.get("paddle_tpu.observe.health")
     health_report = hmod.latest_report() if hmod is not None else None
     if health_report is not None:
-        with open(stem + ".health.json", "w") as f:
-            json.dump({"report": health_report,
-                       "summary": hmod.status_summary()}, f, indent=1)
+        _write_whole(stem + ".health.json", json.dumps(
+            {"report": health_report, "summary": hmod.status_summary()},
+            indent=1))
     # a process HOSTING the fleet aggregator dumps the cluster view
     # too: the rollup + topology of every registered peer at dump time
     # (resolved through sys.modules like health — the module is always
     # imported with the package, the gate is whether it is hosting)
     fmod = sys.modules.get("paddle_tpu.observe.fleet")
     if fmod is not None and fmod.hosting():
-        with open(stem + ".fleet.json", "w") as f:
-            json.dump({"healthz": fmod.rollup(),
-                       "topology": fmod.topology()}, f, indent=1)
+        _write_whole(stem + ".fleet.json", json.dumps(
+            {"healthz": fmod.rollup(), "topology": fmod.topology()},
+            indent=1))
     return prom_path, trace_path
 
 

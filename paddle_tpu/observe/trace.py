@@ -62,7 +62,10 @@ from typing import Any, Dict, Iterator, List, NamedTuple, Optional
 
 from ..analysis.lockorder import named_lock
 
-DEFAULT_RING_SIZE = 4096
+#: a server at a hundred steps a second records some eight spans a
+#: step: the ring has to outlast the ten seconds a benchmark's traced
+#: stretch reads back (4096 did until the step fell under 20 ms)
+DEFAULT_RING_SIZE = 65536
 
 #: Thread name of the JSONL writer; the conftest thread-leak guard
 #: keys on it (same contract as the pipeline's ``ptpu-io-*`` workers).

@@ -1541,8 +1541,11 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
       ``counts == 0``) are dropped, not written.
 
     Returns the updated ``(k_pages, v_pages)``.  Pure jnp scatter (one
-    ``.at[].set`` per pool, out-of-range destinations dropped) so XLA
-    aliases the update in place when the caller donates the pools.
+    ``.at[].set`` per pool, out-of-range destinations dropped), which
+    XLA does in place on a pool that the jitted caller donates, as the
+    server's steps do (``serving/model.py``).  They pass every layer's
+    pool at once, ``[L·P, page, H·D]``, with layer ``i``'s page table
+    offset by ``i·P``: a dropped token then aims past the last layer.
     """
     n_pages, page = k_pages.shape[:2]
     b, t_n = k_new.shape[0], k_new.shape[1]

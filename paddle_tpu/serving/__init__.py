@@ -42,7 +42,7 @@ from .pagepool import (PagePool, PagePoolExhausted,  # noqa: F401
 # paddle_tpu.serving.loader must never drag in the layer engine
 # (pinned by tests/test_serving.py's fresh-process check).
 _LAZY = {
-    "DecoderConfig": "model", "DecoderModel": "model",
+    "DecoderConfig": "model", "DecoderModel": "model", "KVPool": "model",
     "export_decoder": "model", "init_decoder_params": "model",
     "InferenceServer": "server", "Request": "server",
     "SwapTicket": "server",

@@ -20,6 +20,12 @@ The two entry points mirror the two serving kernels from PR 14/15:
   inactive (padded) slots carry a scratch page table, zero write count,
   and length 1, so the kernel touches no memory the slot does not own.
 
+Both update the K/V pools **in place**: a step donates the two stacked
+pools (:class:`KVPool` holds the one reference to each) and every layer
+writes its rows into, and reads its pages out of, the stack as it lies
+in memory, at the layer's page offset — no copy, slice or write-back of
+a pool or of a layer's share of one (PERF.md §6, PR 28).
+
 Batch invariance is a load-bearing property, not an accident: every
 per-row computation (matmuls, RMS norms, attention — the decode
 kernel walks a row's own pages in the row's own order — ``argmax``
@@ -338,6 +344,15 @@ def _route_counts(sizes):
     return jnp.stack([(per > 0).sum(), per.max()]).astype(jnp.int32)
 
 
+def _layers_end_to_end(pool):
+    """The stacked pool ``[L, P, page, G·D]`` as one pool of ``L·P``
+    pages (a free reshape): layer ``i``'s page ``p`` is page
+    ``i·P + p``, so a page table offset by ``i·P`` addresses the
+    layer's share and ``paged_kv_write``'s "past the pool" for a
+    dropped row lies past the last layer, never in the next one."""
+    return pool.reshape(-1, *pool.shape[2:])
+
+
 def _prefill_impl(params, k_pool, v_pool, tokens, lengths, page_indices,
                   cfg: DecoderConfig):
     """[B, T] padded prompts → ([B] first generated tokens, [B, V]
@@ -351,14 +366,15 @@ def _prefill_impl(params, k_pool, v_pool, tokens, lengths, page_indices,
     segments = segments_from_lengths(lengths, b, t)
     valid = pos < lengths[:, None]
     zero = jnp.zeros((b,), jnp.int32)
+    shape = k_pool.shape
+    k_pool, v_pool = _layers_end_to_end(k_pool), _layers_end_to_end(v_pool)
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
         q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
         # the decode contract: K/V must be in the pages before any
         # later step queries them — write the whole prompt now
-        kp, vp = paged_kv_write(k_pool[i], v_pool[i], k, v,
-                                page_indices, zero, lengths)
-        k_pool = k_pool.at[i].set(kp)
-        v_pool = v_pool.at[i].set(vp)
+        k_pool, v_pool = paged_kv_write(
+            k_pool, v_pool, k, v, page_indices + i * shape[1], zero,
+            lengths)
         # the kernel multiplies what the pool stores
         q, k, v = (a.astype(k_pool.dtype) for a in (q, k, v))
         o = flash_attention_packed(
@@ -374,7 +390,8 @@ def _prefill_impl(params, k_pool, v_pool, tokens, lengths, page_indices,
                            params["lm_head"])
     active = lengths > 0
     nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
-    return nxt.astype(jnp.int32), logits, k_pool, v_pool
+    return nxt.astype(jnp.int32), logits, \
+        k_pool.reshape(shape), v_pool.reshape(shape)
 
 
 def _decode_impl(params, k_pool, v_pool, tokens, page_indices, lengths,
@@ -395,16 +412,18 @@ def _decode_impl(params, k_pool, v_pool, tokens, page_indices, lengths,
     # paged_decode_attention); a planned decoder's reads %paged_decode
     name = K.PAGED_DECODE if cfg.plan else None
     sizes = []
+    shape = k_pool.shape
+    k_pool, v_pool = _layers_end_to_end(k_pool), _layers_end_to_end(v_pool)
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
         q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
-        kp, vp = paged_kv_write(k_pool[i], v_pool[i], k, v,
-                                page_indices, lengths - 1, counts)
-        k_pool = k_pool.at[i].set(kp)
-        v_pool = v_pool.at[i].set(vp)
-        # kp/vp are the layer's pool as stored, [P, page, G·D]: the
-        # kernel DMAs the rows' live pages out of it, nothing else
+        table = page_indices + i * shape[1]
+        k_pool, v_pool = paged_kv_write(k_pool, v_pool, k, v, table,
+                                        lengths - 1, counts)
+        # the whole stack as stored, [L·P, page, G·D], stays in HBM: the
+        # kernel DMAs the rows' live pages of this layer out of it,
+        # nothing else, before the next layer's rows are written
         o = paged_decode_attention(
-            q, kp, vp, page_indices, klen,
+            q, k_pool, v_pool, table, klen,
             window=cfg.window if "window" in attn else 0, name=name)
         x = _attend_out(x, o.reshape(b, 1, -1), gate, params, i, cfg, attn)
         x, routed = _ffn(x, active[:, None], params, i, cfg, attn, ffn)
@@ -414,7 +433,7 @@ def _decode_impl(params, k_pool, v_pool, tokens, page_indices, lengths,
                            params["lm_head"])
     nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
     return jnp.concatenate([nxt.astype(jnp.int32), _route_counts(sizes)]), \
-        logits, k_pool, v_pool
+        logits, k_pool.reshape(shape), v_pool.reshape(shape)
 
 
 @functools.lru_cache(maxsize=None)
@@ -428,24 +447,50 @@ def _jitted_steps(cfg: DecoderConfig):
     compiled instead of stalling the first post-flip requests behind
     a full recompile."""
     # static cfg via closure; jax caches one executable per
-    # (B, T)/(B,) shape bucket.  No buffer donation: CPU (the test
-    # platform) does not alias donations and warns per compile —
-    # on TPU the pools would be donate_argnums=(1, 2)
+    # (B, T)/(B,) shape bucket.  The pools are donated: the step's
+    # scatters write into the buffers that came in, and the arrays the
+    # caller passed are deleted (KVPool keeps the only reference)
     prefill = jax.jit(
         lambda p, kp, vp, tk, ln, pi: _prefill_impl(
-            p, kp, vp, tk, ln, pi, cfg))
+            p, kp, vp, tk, ln, pi, cfg), donate_argnums=(1, 2))
     decode = jax.jit(
         lambda p, kp, vp, tk, pi, ln, ac: _decode_impl(
-            p, kp, vp, tk, pi, ln, ac, cfg))
+            p, kp, vp, tk, pi, ln, ac, cfg), donate_argnums=(1, 2))
     return prefill, decode
+
+
+class KVPool:
+    """One stacked K or V pool on the device, ``[L, P, page, G·Dh]``,
+    and the only reference to it.  A step donates ``array`` and puts
+    its result back here (:meth:`DecoderModel.prefill` / ``decode``),
+    so whoever holds the pool always holds the live buffer and a stale
+    reference to a donated one cannot exist."""
+    __slots__ = ("array",)
+
+    def __init__(self, array: jax.Array):
+        self.array = array
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return self.array.shape
+
+    @property
+    def dtype(self):
+        return self.array.dtype
+
+    def block_until_ready(self) -> "KVPool":
+        self.array.block_until_ready()
+        return self
 
 
 class DecoderModel:
     """A loaded decoder + its jitted prefill/decode steps.
 
     Pools are owned by the caller (the server) and threaded through
-    every call — the model never holds KV state, so one model instance
-    serves any number of pools/replicas reentrantly."""
+    every call as :class:`KVPool` objects, which a step updates in
+    place — the model never holds KV state, so one model instance
+    serves any number of pools/replicas reentrantly.  One pool pair
+    belongs to one thread at a time."""
 
     def __init__(self, params: Dict[str, Any], cfg: DecoderConfig):
         self.cfg = cfg
@@ -471,17 +516,18 @@ class DecoderModel:
 
     # ----------------------------------------------------------- pools
     def new_pools(self, n_pages: int, page_size: int
-                  ) -> Tuple[jax.Array, jax.Array]:
-        """Zeroed per-layer K/V pools, ``[L, P, page, G·Dh]`` in the
-        storage dtype: one lane-dense row a token, the layout the
-        decode kernel fetches pages in and ``paged_kv_write`` scatters
-        rows into.  (Stored ``[…, G, Dh]`` with Dh < 128 the TPU lays
-        the page axis along the lanes, and every use of a layer's pool
-        is a relayout copy of it: PERF.md §6, PR 26.)"""
+                  ) -> Tuple[KVPool, KVPool]:
+        """Zeroed K and V pools, the caller's to keep: each a
+        :class:`KVPool` over ``[L, P, page, G·Dh]`` in the storage
+        dtype, one lane-dense row a token, the layout the decode kernel
+        fetches pages in and ``paged_kv_write`` scatters rows into.
+        (Stored ``[…, G, Dh]`` with Dh < 128 the TPU lays the page axis
+        along the lanes, and every use of a layer's pool is a relayout
+        copy of it: PERF.md §6, PR 26.)"""
         shape = (self.cfg.layers, n_pages, page_size,
                  kv_heads(self.cfg) * head_dim(self.cfg))
-        return (jnp.zeros(shape, self.cfg.storage),
-                jnp.zeros(shape, self.cfg.storage))
+        return (KVPool(jnp.zeros(shape, self.cfg.storage)),
+                KVPool(jnp.zeros(shape, self.cfg.storage)))
 
     # ------------------------------------------------- what a step reads
     def attended_tokens(self, lengths) -> int:
@@ -507,18 +553,20 @@ class DecoderModel:
         return behind * self._window_layers
 
     # ----------------------------------------------------------- steps
-    def prefill(self, k_pool, v_pool, tokens, lengths, page_indices):
-        """Prompts in, first generated token out (plus updated pools).
-        ``tokens`` [B, T] int32 padded, ``lengths`` [B], ``page_indices``
-        [B, max_pages] physical page tables covering each prompt PLUS
-        the tokens to be generated."""
+    def prefill(self, k_pool: KVPool, v_pool: KVPool, tokens, lengths,
+                page_indices):
+        """Prompts in, first generated token out (plus the pools, the
+        same two objects, updated in place).  ``tokens`` [B, T] int32
+        padded, ``lengths`` [B], ``page_indices`` [B, max_pages]
+        physical page tables covering each prompt PLUS the tokens to be
+        generated."""
         shape = np.shape(tokens)
         enforce(len(shape) == 2 and shape[1] <= self.cfg.max_context,
                 f"prompt batch {shape} exceeds max_context "
                 f"{self.cfg.max_context}")
         with _span("prefill_dispatch"):       # host→device + launch
-            nxt, logits, k_pool, v_pool = self._prefill(
-                self.params, k_pool, v_pool,
+            nxt, logits, k_pool.array, v_pool.array = self._prefill(
+                self.params, k_pool.array, v_pool.array,
                 jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(page_indices, jnp.int32))
@@ -526,15 +574,17 @@ class DecoderModel:
             nxt, logits = np.asarray(nxt), np.asarray(logits)
         return nxt, logits, k_pool, v_pool
 
-    def decode(self, k_pool, v_pool, tokens, page_indices, lengths, active):
+    def decode(self, k_pool: KVPool, v_pool: KVPool, tokens, page_indices,
+               lengths, active):
         """One continuous-batching decode step over the page pool.  →
-        (next tokens, logits, the pools, and what the step's routed
+        (next tokens, logits, the pools (the same two objects, updated
+        in place), and what the step's routed
         layers did: ``{"experts_hit", "expert_load_max"}``,
         :func:`_route_counts`; empty for a plan without routed layers).
         The counts come back in the fetch that brings the tokens."""
         with _span("decode_dispatch"):        # host→device + launch
-            nxt, logits, k_pool, v_pool = self._decode(
-                self.params, k_pool, v_pool,
+            nxt, logits, k_pool.array, v_pool.array = self._decode(
+                self.params, k_pool.array, v_pool.array,
                 jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(page_indices, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),

@@ -215,7 +215,7 @@ FLAGS.define("trace_jsonl", "",
              "trace-event JSON — load it directly in Perfetto / "
              "chrome://tracing; empty = no stream, span() is a shared "
              "no-op and the hot path pays <50 us/step")
-FLAGS.define("trace_ring_size", 4096,
+FLAGS.define("trace_ring_size", 65536,
              "flight-recorder capacity: the last N spans of a live run "
              "kept in a bounded in-memory ring, served by the "
              "--metrics_port /trace endpoint and the SIGUSR2 debug "

@@ -16,10 +16,14 @@ nvprof windows via ``hl_profiler_start/end``
 from __future__ import annotations
 
 import contextlib
+import os
+import socket
 import threading
+import time
 from typing import Iterator, Optional
 
 import jax
+from jax._src import profiler as _jax_profiler
 
 from ..analysis.lockorder import named_lock
 from .logger import get_logger, warn_once
@@ -38,6 +42,30 @@ _trace_depth = 0
 def trace_active() -> bool:
     """True while an xprof window opened by :func:`trace` is live."""
     return _trace_depth > 0
+
+
+def _stop_trace() -> None:
+    """``jax.profiler.stop_trace`` less its second export.  The
+    session's XSpace is written where jax writes it,
+    ``<logdir>/plugins/profile/<time>/<host>.xplane.pb`` — what
+    TensorBoard, xprof and ``jax.profiler.ProfileData`` read; the
+    Chrome-trace JSON that jax derives from it besides is not made: at
+    the million device events that ten seconds of a server at 70 steps
+    a second leave, it is a large part of the time the stop holds its
+    caller (PERF.md §6, PR 28)."""
+    state = _jax_profiler._profile_state
+    with state.lock:
+        if state.profile_session is None:
+            raise RuntimeError("No profile started")
+        logdir = str(state.log_dir)
+        xspace = state.profile_session.stop()
+        state.reset()
+    run = os.path.join(logdir, "plugins", "profile",
+                       time.strftime("%Y_%m_%d_%H_%M_%S"))
+    os.makedirs(run, exist_ok=True)
+    with open(os.path.join(run, socket.gethostname() + ".xplane.pb"),
+              "wb") as f:
+        f.write(xspace)
 
 
 @contextlib.contextmanager
@@ -64,7 +92,13 @@ def trace(logdir: str = "/tmp/paddle_tpu_trace") -> Iterator[None]:
             return
         from .. import observe
 
-        jax.profiler.start_trace(logdir)
+        # the device, the runtime's host events and the program's own
+        # spans (TraceAnnotations), not every Python call: behind a
+        # server at 70 steps a second the Python tracer leaves a
+        # million events in ten seconds, nine in ten of the host's
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(logdir, profiler_options=options)
         observe.counter("profiler_trace_windows_total",
                         "xprof/jax.profiler trace windows opened"
                         ).inc()
@@ -72,7 +106,7 @@ def trace(logdir: str = "/tmp/paddle_tpu_trace") -> Iterator[None]:
         try:
             yield
         finally:
-            jax.profiler.stop_trace()
+            _stop_trace()
             log.info("profiler trace written to %s", logdir)
     finally:
         with _depth_lock:

@@ -18,9 +18,12 @@ from paddle_tpu import observe
 from paddle_tpu.observe import trace as ptrace
 from paddle_tpu.ops import kernels as K
 from paddle_tpu.ops import pallas_moe as moe
+from paddle_tpu.ops.pallas_attention import paged_kv_write
+from paddle_tpu.serving import model as decoder_module
 from paddle_tpu.serving.model import (DecoderConfig, DecoderModel,
                                       export_decoder, init_decoder_params,
                                       layer_plan, leaf_shapes)
+from paddle_tpu.serving.pagepool import SCRATCH_PAGE
 from paddle_tpu.serving.server import InferenceServer
 from paddle_tpu.utils import PaddleTpuError
 
@@ -28,9 +31,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIG = "trinity-mini-serve"
 
 
-@pytest.fixture(scope="module")
-def bench():
-    """(sizes, reference module, system module, seeded weights) of the
+def _rehearsal(config):
+    """(sizes, reference module, system module, seeded weights) of a
     configuration's rehearsal."""
     import sys
     if ROOT not in sys.path:
@@ -38,11 +40,23 @@ def bench():
     from chipbench import harness as H, weights as W
 
     with open(os.path.join(ROOT, "chipbench", "configs",
-                           CONFIG + ".json")) as f:
+                           config + ".json")) as f:
         sizes = json.load(f)["rehearsal"]["sizes"]
-    ref = H.load_module("reference", CONFIG)
-    system = H.load_module("systems", CONFIG)
+    ref = H.load_module("reference", config)
+    system = H.load_module("systems", config)
     return sizes, ref, system, W.make(ref.param_spec(sizes), 20270001)
+
+
+@pytest.fixture(scope="module")
+def bench():
+    return _rehearsal(CONFIG)
+
+
+@pytest.fixture(scope="module")
+def dense_bench():
+    """The same of the default plan's configuration: hidden 128, 4
+    heads of 32, two layers, float32."""
+    return _rehearsal("opt-1.3b-serve")
 
 
 def _model(bench, storage, drop=()):
@@ -95,9 +109,9 @@ def _serve(model, prompts, steps, page=4, width=4):
         nxt, logits, k, v, counts = model.decode(
             k, v, fed, tab, lengths, active)
         out.append(([list(s) for s in seqs], logits[:b]))
-        # 4 routed layers of 32 experts, 8 a token
-        assert 4 * 8 <= counts["experts_hit"] <= 4 * min(32, 8 * b)
-        assert 1 <= counts["expert_load_max"] <= b
+        if model.routed_layers:   # 4 of 32 experts, 8 a token
+            assert 4 * 8 <= counts["experts_hit"] <= 4 * min(32, 8 * b)
+            assert 1 <= counts["expert_load_max"] <= b
     return out
 
 
@@ -345,3 +359,187 @@ def test_the_server_reports_what_its_routed_steps_did(bench):
     assert gauge is not None and gauge.samples()
     flat = observe.REGISTRY.flat(kinds=("counter",))
     assert flat['moe_dispatch_total{path="grouped",reason=""}'] >= 1
+
+
+# ------------------------------------------------- the pools, in place
+def _dense_model(dense_bench):
+    sizes, _, system, weights = dense_bench
+    cfg = DecoderConfig(
+        vocab=sizes["vocab_size"], dim=sizes["hidden_size"],
+        heads=sizes["num_attention_heads"],
+        layers=sizes["num_hidden_layers"], ffn=sizes["ffn_dim"],
+        max_context=sizes["max_position_embeddings"])
+    return DecoderModel({system.leaf_name(k): v
+                         for k, v in weights.items()}, cfg)
+
+
+@pytest.fixture(params=["default", "planned"])
+def decoder(request, bench, dense_bench):
+    """Both served decoders: the default plan (float32 pool, a K/V head
+    a query head) and a planned one (bfloat16 pool, grouped K/V heads,
+    window layers, routed experts)."""
+    if request.param == "default":
+        return _dense_model(dense_bench)
+    return _model(bench, "bfloat16")
+
+
+def test_the_default_plan_through_pages_is_its_reference(dense_bench):
+    """The default plan's prefill and six decode steps, through the
+    stacked pools, against the plain reference of its configuration
+    (the planned decoder's: test_prefill_then_decode_through_pages…)."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(2, 512, n).tolist() for n in (21, 5, 32)]
+    for seqs, logits in _serve(_dense_model(dense_bench), prompts, 6):
+        want = _reference_logits(dense_bench, seqs)
+        assert np.abs(want - logits).max() < TOLERANCE["float32"]
+
+
+def _recorded(impl, cfg):
+    """``impl`` (the model's own ``_prefill_impl`` / ``_decode_impl``)
+    jitted as the server jits it, pools donated, giving besides its
+    results every layer's new K/V rows as ``paged_kv_write`` got
+    them."""
+    def run(*args):
+        rows = []
+
+        def write(k_pool, v_pool, k_new, v_new, *rest):
+            rows.append((k_new, v_new))
+            return paged_kv_write(k_pool, v_pool, k_new, v_new, *rest)
+        real, decoder_module.paged_kv_write = \
+            decoder_module.paged_kv_write, write
+        try:
+            return impl(*args, cfg), rows
+        finally:
+            decoder_module.paged_kv_write = real
+    return jax.jit(run, donate_argnums=(1, 2))
+
+
+def _a_layer_at_a_time(k_pool, v_pool, rows, tables, start, counts):
+    """What the steps did before the pools were updated in place:
+    ``paged_kv_write`` on each layer's own pool, put back in the
+    stack."""
+    k_pool, v_pool = np.array(k_pool), np.array(v_pool)
+    for i, (k_new, v_new) in enumerate(rows):
+        k_pool[i], v_pool[i] = paged_kv_write(
+            jnp.asarray(k_pool[i]), jnp.asarray(v_pool[i]), k_new, v_new,
+            jnp.asarray(tables), jnp.asarray(start), jnp.asarray(counts))
+    return k_pool, v_pool
+
+
+def _bits(a):
+    """An array's bytes, so that bfloat16 compares exactly too."""
+    a = np.asarray(a)
+    return a.view(np.uint16 if a.dtype.itemsize == 2 else np.uint32)
+
+
+def test_a_step_leaves_the_pools_as_a_layer_at_a_time_would(decoder):
+    """A prefill of padded prompts and four decode steps with an idle
+    slot: after each, both stacked pools are bit for bit what
+    ``paged_kv_write`` gives on every layer's own pool, whole pool
+    compared, so no dropped row (prompt padding, ``counts == 0``, the
+    last layer's too) landed in another layer or wrapped around; and
+    the served path gives those pools and those tokens."""
+    cfg, page, width = decoder.cfg, 4, 4
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(2, cfg.vocab, n).tolist() for n in (21, 5, 30)]
+    b, slots = len(prompts), cfg.max_context // page
+    n_pages = 1 + width * slots
+    tables = np.zeros((width, slots), np.int32)
+    tables[:b] = 1 + rng.permutation(b * slots).reshape(b, slots)
+    lengths = np.array([len(p) for p in prompts], np.int32)
+    tokens = np.zeros((b, 32), np.int32)          # 32 > every prompt
+    for i, p in enumerate(prompts):
+        tokens[i, :len(p)] = p
+    k, v = decoder.new_pools(n_pages, page)
+    assert k.shape == (cfg.layers, n_pages, page, k.shape[-1])
+    want_k, want_v = np.asarray(k.array), np.asarray(v.array)
+
+    def check(out, rows, start, counts, tab):
+        nonlocal want_k, want_v
+        want_k, want_v = _a_layer_at_a_time(want_k, want_v, rows, tab,
+                                            start, counts)
+        for got, want in ((out[2], want_k), (out[3], want_v),
+                          (k.array, want_k), (v.array, want_v)):
+            np.testing.assert_array_equal(_bits(got), _bits(want))
+
+    prefill = _recorded(decoder_module._prefill_impl, cfg)
+    out, rows = prefill(decoder.params, jnp.asarray(want_k),
+                        jnp.asarray(want_v), tokens, lengths, tables[:b])
+    nxt, _, k, v = decoder.prefill(k, v, tokens, lengths, tables[:b])
+    np.testing.assert_array_equal(nxt, np.asarray(out[0]))
+    check(out, rows, np.zeros((b,), np.int32), lengths, tables[:b])
+    assert np.abs(np.asarray(want_k, np.float32)).sum(axis=(1, 2, 3)).all()
+
+    decode = _recorded(decoder_module._decode_impl, cfg)
+    active = np.arange(width) < b
+    for _ in range(4):
+        fed = np.zeros((width,), np.int32)
+        fed[:b] = nxt[:b]
+        lengths = lengths + 1
+        klen = np.where(active, np.resize(lengths, width), 1).astype(np.int32)
+        out, rows = decode(decoder.params, jnp.asarray(want_k),
+                           jnp.asarray(want_v), fed, tables, klen, active)
+        nxt, _, k, v, _ = decoder.decode(k, v, fed, tables, klen, active)
+        np.testing.assert_array_equal(nxt, np.asarray(out[0])[:width])
+        check(out, rows, klen - 1, active.astype(np.int32), tables)
+    # the pages no row holds, each layer's scratch page among them,
+    # were never written
+    free = np.setdiff1d(np.arange(n_pages), tables[:b])
+    assert SCRATCH_PAGE in free
+    assert not _bits(want_k[:, free]).any() and not _bits(want_v[:, free]).any()
+
+
+def test_a_step_donates_its_pools(decoder):
+    """The array a pool held before a step is deleted after it, the
+    pool holds the step's result, and the caller's two objects are the
+    ones that come back."""
+    k, v = decoder.new_pools(9, 4)
+    held = (k.array, v.array)
+    out = decoder.prefill(k, v, np.full((1, 16), 3, np.int32),
+                          np.array([7], np.int32),
+                          np.arange(1, 9, dtype=np.int32)[None, :])
+    assert out[2] is k and out[3] is v
+    assert all(a.is_deleted() for a in held)
+    held = (k.array, v.array)
+    assert not any(a.is_deleted() for a in held)
+    out = decoder.decode(k, v, np.array([5], np.int32),
+                         np.arange(1, 9, dtype=np.int32)[None, :],
+                         np.array([8], np.int32), np.array([True]))
+    assert out[2] is k and out[3] is v
+    assert all(a.is_deleted() for a in held)
+    with pytest.raises(RuntimeError, match="deleted"):
+        np.asarray(held[0])
+    assert np.abs(np.asarray(k.array, np.float32)[:, 2]).sum() > 0
+
+
+def test_the_benchmarks_warm_up_leaves_the_servers_pages(decoder):
+    """``chipbench/drivers/serve_closed.py::warm`` sends the server's
+    own two pool objects through several prefills and a decode step and
+    drops the results: it runs, compiles the programs the loop then
+    uses, writes nothing but each layer's scratch page, and the server
+    serves as before."""
+    from chipbench.drivers.serve_closed import warm
+
+    server = InferenceServer(decoder, max_batch=4, n_pages=64, page_size=4,
+                             continuous=True)
+    prompt = np.random.default_rng(4).integers(2, 256, 13).tolist()
+    server.start()
+    try:
+        first = server.submit(prompt, 6)
+        assert first.done.wait(120) and first.state == "done", first.error
+        k, v = server._k_pool, server._v_pool
+        before = [np.asarray(p.array) for p in (k, v)]
+        warm(server, {"blocks": [{"prompt_lens": [13, 30]}],
+                      "admit_cap": 2}, decoder.cfg.vocab)
+        assert server._k_pool is k and server._v_pool is v
+        for pool, was in zip((k, v), before):
+            now = np.asarray(pool.array)
+            np.testing.assert_array_equal(
+                _bits(now[:, SCRATCH_PAGE + 1:]),
+                _bits(was[:, SCRATCH_PAGE + 1:]))
+            assert _bits(now[:, SCRATCH_PAGE]).any()
+        again = server.submit(prompt, 6)
+        assert again.done.wait(120) and again.state == "done", again.error
+        assert again.tokens == first.tokens
+    finally:
+        server.stop()

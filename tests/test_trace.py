@@ -485,8 +485,8 @@ def test_profiler_trace_reentrant_and_annotates(monkeypatch, tmp_path):
 
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
-    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda d, **kw: calls.append(("start", d)))
+    monkeypatch.setattr(profiler, "_stop_trace",
                         lambda: calls.append(("stop", None)))
     trace.enable(ring_size=16)
     assert profiler.trace_active() is False
@@ -515,6 +515,39 @@ def test_profiler_trace_real_window(tmp_path):
                 pass
     assert profiler.trace_active() is False
     assert [e["name"] for e in trace.events()] == ["annotated"]
+
+
+def test_profiler_window_writes_the_xplane_alone(tmp_path):
+    """A real window: ``<logdir>/plugins/profile/<time>/<host>.xplane.pb``
+    is written where ``jax.profiler.ProfileData`` (and the benchmark's
+    ``tracelib.find_xplane``) reads it, it holds the annotations opened
+    in the window, nothing else is exported beside it (the Chrome-trace
+    JSON costs as much again to make), and a second window opens."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from paddle_tpu.utils import profiler
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((8, 8))
+    f(x).block_until_ready()
+    for name in ("first", "second"):
+        logdir = str(tmp_path / name)
+        with profiler.trace(logdir):
+            with jax.profiler.TraceAnnotation("in_the_" + name):
+                f(x).block_until_ready()
+        files = [p for p in glob.glob(logdir + "/**/*", recursive=True)
+                 if os.path.isfile(p)]
+        assert len(files) == 1 and files == glob.glob(
+            logdir + "/plugins/profile/*/*.xplane.pb"), files
+        names = {ev.name for plane in ProfileData.from_file(files[0]).planes
+                 for line in plane.lines for ev in line.events}
+        assert "in_the_" + name in names
+    with pytest.raises(RuntimeError, match="No profile started"):
+        profiler._stop_trace()
 
 
 def test_parameter_stats_single_batched_device_get(monkeypatch):
