@@ -1,11 +1,10 @@
 """IMDB LSTM benchmark config (reference ``benchmark/paddle/rnn/rnn.py``).
 
 Baseline rows (reference benchmark/README.md:124-126, bs=128, 1× K40m):
-hidden 256 → 110 ms/batch, 512 → 261 ms, 1280 → 1007 ms.  ``bench.py``
-measures both hidden=512 (fused Pallas LSTM) and hidden=1280 (past the
-kernel's VMEM gate → the lax.scan path, logged by ops/recurrent_ops.py);
-run this config with ``--config_args hidden_size=1280`` for the
-big-hidden row.
+hidden 256 → 110 ms/batch, 512 → 261 ms, 1280 → 1007 ms.  Run this
+config with ``--config_args hidden_size=1280`` for the big-hidden row
+(past the single-block kernel's VMEM gate: the hidden-blocked tier,
+``chip_smoke.py --all``'s ``lstm1280`` phase).
 """
 
 num_class = 2

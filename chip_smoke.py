@@ -837,11 +837,71 @@ def phase_lstm1280(S, ctx):
             Expect([("rnn_dispatch_total", "fused_blocked")]))
 
 
-def phase_seq2seq(S, ctx):
-    """bi-GRU encoder + attention-GRU decoder (``bench.seq2seq_setup``)."""
-    import bench
+def seq2seq_setup(B, S_LEN, T_LEN, V, E, H):
+    """The seq2seq trainer and one feed."""
+    import jax.numpy as jnp
+    import numpy as np
 
-    trainer, feed = bench.seq2seq_setup(**S)
+    from paddle_tpu.config import dsl
+    from paddle_tpu.config.dsl import ParamAttr, StepInput, config_scope
+    from paddle_tpu.core.sequence import SequenceBatch
+    from paddle_tpu.data.feeder import integer_value_sequence
+    from paddle_tpu.utils import FLAGS
+    from paddle_tpu.v2.networks import simple_attention, simple_gru
+
+    FLAGS.set("bf16_activations", True)
+    # the demo/seqToseq training topology
+    with config_scope():
+        src = dsl.data("source", integer_value_sequence(V))
+        trg = dsl.data("target", integer_value_sequence(V))
+        trg_next = dsl.data("target_next", integer_value_sequence(V))
+        src_emb = dsl.embedding(src, size=E, name="src_emb",
+                                param_attr=ParamAttr(name="_src_emb"),
+                                vocab_size=V)
+        fwd = simple_gru(src_emb, size=H, name="enc_fwd")
+        bwd = simple_gru(src_emb, size=H, name="enc_bwd", reverse=True)
+        enc = dsl.concat([fwd, bwd], name="enc_seq")
+        enc_proj = dsl.fc(enc, size=H, act=dsl.LinearActivation(),
+                          bias_attr=False, name="enc_proj")
+        boot = dsl.fc(dsl.last_seq(bwd), size=H,
+                      act=dsl.TanhActivation(), name="dec_boot")
+        trg_emb = dsl.embedding(trg, size=E, name="trg_emb",
+                                param_attr=ParamAttr(name="_trg_emb"),
+                                vocab_size=V)
+
+        def step(e, ep, b, w):
+            mem = dsl.memory(name="dec_gru", size=H, boot_layer=b)
+            context = simple_attention(e, ep, mem.out, name="att")
+            inp = dsl.fc([context, w], size=H * 3,
+                         act=dsl.LinearActivation(), bias_attr=False,
+                         name="dec_inproj")
+            hidden = dsl.gru_step_layer(inp, mem.out, size=H,
+                                        name="dec_gru")
+            return dsl.fc(hidden, size=V, act=dsl.SoftmaxActivation(),
+                          name="dec_prob")
+
+        probs = dsl.recurrent_group(
+            step, [enc, enc_proj, boot, StepInput(trg_emb)],
+            name="decoder")
+        cost = dsl.classification_cost(probs, trg_next)
+        cfg = dsl.topology(cost)
+
+    trainer = _adam_trainer(cfg, lr=5e-4)
+    rng = np.random.RandomState(0)
+
+    def ids(t):
+        return SequenceBatch(
+            jnp.asarray(rng.randint(2, V, (B, t)).astype(np.int32)),
+            jnp.asarray(np.full((B,), t, np.int32)))
+
+    feed = {"source": ids(S_LEN), "target": ids(T_LEN),
+            "target_next": ids(T_LEN)}
+    return trainer, feed
+
+
+def phase_seq2seq(S, ctx):
+    """bi-GRU encoder + attention-GRU decoder."""
+    trainer, feed = seq2seq_setup(**S)
     return (_one_step(trainer, feed, ctx, "seq2seq"),
             Expect([("rnn_dispatch_total", "fused")]))
 

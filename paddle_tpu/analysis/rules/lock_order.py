@@ -27,8 +27,8 @@ This rule derives the acquisition graph statically:
   class are distinct locks under one node name).
 
 :func:`build_lock_graph` exposes the derived graph for the CLI's
-``--lock-graph`` dump — the hierarchy documented in PERF_NOTES and
-asserted at runtime by the chaos/pipeline suites.
+``--lock-graph`` dump — the hierarchy asserted at runtime by the
+chaos/pipeline suites.
 """
 
 from __future__ import annotations

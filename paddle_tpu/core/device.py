@@ -203,7 +203,7 @@ def ensure_compile_cache() -> str:
     """Place JAX's persistent compilation cache and return its path.
     ``JAX_COMPILATION_CACHE_DIR`` wins untouched (JAX reads it itself);
     otherwise ``<checkout>/.jax_cache``.  Called by every entry point
-    that compiles (CLI, bench, server start-up, chip_smoke)."""
+    that compiles (CLI, server start-up, chip_smoke)."""
     external = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if external:
         return external

@@ -89,7 +89,7 @@ def resolve_precision(opt_config=None) -> str:
 
     An explicit ``OptimizationConfig.precision`` wins; empty inherits
     the ``--precision`` flag (default fp32).  This is the ONE resolution
-    point the trainer, the op-level policy, and the bench stamp share.
+    point the trainer and the op-level policy share.
     """
     prec = getattr(opt_config, "precision", "") or FLAGS.precision
     if prec not in ("fp32", "bf16"):
@@ -152,23 +152,3 @@ def record_op_precision(op: str) -> None:
         "per compiled program per shape, labels op + policy compute "
         "dtype)",
     ).inc(op=op, dtype=dtype_name(current_policy().compute_dtype))
-
-
-def dispatch_dtypes(opt_config=None) -> Dict[str, str]:
-    """Resolved per-op-tier dtypes of the active policy — the
-    self-describing precision stamp bench.py attaches to every JSON
-    line (the round-8 ``path``-field pattern, applied to dtype)."""
-    prec = resolve_precision(opt_config)
-    pol = policy_for(prec) if prec == "bf16" else current_policy()
-    cd, od = dtype_name(pol.compute_dtype), dtype_name(pol.output_dtype)
-    return {
-        "policy": prec,
-        "matmul": cd, "conv": cd, "rnn_gates": cd, "attention": cd,
-        # accumulator/carry tiers are pinned fp32 by construction:
-        # BN stats (ops/nn_ops._bn_stats), Pallas RNN VMEM gate math,
-        # flash-attention accumulators — regardless of compute dtype
-        "bn_stats": "float32", "fused_rnn_state": "float32",
-        "attention_accum": "float32",
-        "scan_carry": od, "activations": od,
-        "master_params": "float32", "optimizer_state": "float32",
-    }

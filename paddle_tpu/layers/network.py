@@ -143,8 +143,7 @@ class NeuralNetwork:
         # resolved at build time, per direction and kernel family —
         # ResNet-50 pins 16 Pallas-3×3 + 16 GEMM-1×1 forward pairs (the
         # round-7 resolution; its bwd entries are all evicted into fwd
-        # chains).  The bench artifact reads these back through the
-        # JSONL sink; gauges reflect the most recently built network.
+        # chains).  Gauges reflect the most recently built network.
         from ..observe import gauge
         fwd3 = sum(1 for cv in self._bn_conv_fuse
                    if lmap[cv].attrs.get("filter_size") == 3)
@@ -159,10 +158,9 @@ class NeuralNetwork:
         # build-time precision census: which compute/output dtypes the
         # op policy resolved to when each network was built (the
         # trainer may still override per-step via policy_scope — this
-        # records the flag-resolved default the bench stamp also
-        # reads).  A monotonic per-policy counter, like the fused-pair
-        # census above: a process that builds under two policies (the
-        # bench precision A/B) keeps both series honest.
+        # records the flag-resolved default).  A monotonic per-policy
+        # counter, like the fused-pair census above: a process that
+        # builds under two policies keeps both series honest.
         from ..core.dtypes import current_policy, dtype_name
         from ..observe import counter
         pol = current_policy()

@@ -58,7 +58,7 @@ from .report import (  # noqa: F401
 )
 from .report import start_from_flags as _start_reporter_from_flags
 from .report import stop_global as _stop_reporter_global
-from . import benchgate, dump, fleet, http, memory, shutdown, trace  # noqa: F401
+from . import dump, fleet, http, memory, shutdown, trace  # noqa: F401
 # costmodel and health are NOT imported eagerly: their entry points
 # touch jax (lazily), and keeping them explicit `from
 # paddle_tpu.observe import costmodel` / `... import health` imports
@@ -69,7 +69,7 @@ from . import benchgate, dump, fleet, http, memory, shutdown, trace  # noqa: F40
 
 def start_from_flags():
     """One call a long-running entry point makes (``Trainer.train``,
-    ``bench.main``, the CLI): start every flag-configured observability
+    the CLI): start every flag-configured observability
     surface — the ``--metrics_jsonl`` reporter (with the
     ``--fleet_addr`` push client folded in), ``--trace_jsonl`` span
     sink, the ``--metrics_port`` HTTP endpoint, the ``--fleet_port``
@@ -104,6 +104,5 @@ __all__ = [
     "MetricsRegistry", "REGISTRY", "counter", "gauge", "histogram",
     "format_labels", "MetricsReporter", "active", "attach",
     "prometheus_dump", "start_from_flags", "stop_global",
-    "trace", "http", "dump", "memory", "benchgate", "fleet",
-    "shutdown",
+    "trace", "http", "dump", "memory", "fleet", "shutdown",
 ]

@@ -1,9 +1,8 @@
 """Per-region roofline/MFU attribution over compiled XLA executables.
 
-``bench.py`` has carried a whole-step ``hbm_gb_per_step`` scalar since
-round 7 and a hand-computed MFU per workload since round 1 — one number
-per step, no way to see *which* layer is the bottleneck.  This module is
-the attribution half of the performance observatory:
+A whole-step FLOP or byte count is one number per step, with no way to
+see *which* layer is the bottleneck.  This module is the attribution
+half of the performance observatory:
 
 - :func:`analyze_trainer_step` lowers the trainer's compiled train step,
   parses the **optimized HLO text** (``Compiled.as_text()``) and breaks
@@ -15,9 +14,8 @@ the attribution half of the performance observatory:
 - each region gets a **roofline verdict** — compute- vs memory-bound
   against the detected chip peaks (:func:`detect_peaks`), with
   arithmetic intensity and a peak-bound time estimate;
-- :func:`mfu` / :func:`step_mfu` are the ONE model-level MFU
-  implementation every bench row stamps (replacing the per-workload
-  hand formulas): measured-step FLOPs over ``time x peak x chips``.
+- :func:`mfu` is the model-level MFU the ``--roofline_dump`` report is
+  stamped with: measured-step FLOPs over ``time x peak x chips``.
 
 Counting conventions (deliberately XLA-compatible so the per-region
 costs reconcile against ``Compiled.cost_analysis()``):
@@ -38,9 +36,8 @@ costs reconcile against ``Compiled.cost_analysis()``):
 - ``custom-call`` regions (the Pallas kernels) are **opaque**: XLA
   reports zero FLOPs for them and so does this parser (bytes are still
   charged from the call-site shapes).  A step containing opaque regions
-  reports them, and :func:`step_mfu` falls back to the caller's
-  analytic FLOP count so the MFU stays honest instead of silently
-  reading near-zero.
+  reports them (``opaque_custom_calls``), so a reader knows its FLOP
+  total leaves the kernels out.
 
 jax is imported lazily (function scope) — the parser itself is pure
 text and testable without a backend; the zero-dependency rule of
@@ -592,10 +589,10 @@ _PEAKS_BY_KIND = {
     # peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
     # 819 GB/s HBM)
     "tpu v5 lite": (197e12, 819e9),
-    # host CPU (the test mesh): order-of-magnitude figures so that the
-    # CPU-small lanes' verdicts have a ridge point between elementwise
+    # host CPU (the test mesh): order-of-magnitude figures so that a
+    # CPU run's verdicts have a ridge point between elementwise
     # (<1 flop/byte) and matmul (tens of flops/byte) intensity — never a
-    # device number (ROADMAP S1 removes the row with those lanes)
+    # device number (ROADMAP D7)
     "cpu": (2e11, 4e10),
 }
 
@@ -653,8 +650,7 @@ def mfu(flops_per_step: float, seconds_per_step: float,
         devices: int = 1,
         peaks: Optional[Dict[str, Any]] = None) -> float:
     """Model FLOP utilization: executed FLOPs per step over
-    ``time x peak x chips`` — THE shared implementation every bench row
-    stamps (replaces the per-workload hand arithmetic)."""
+    ``time x peak x chips``."""
     peaks = peaks or detect_peaks()
     denom = max(seconds_per_step, 1e-12) * peaks["flops"] \
         * max(devices, 1)
@@ -689,11 +685,8 @@ def _known_regions(network) -> frozenset:
     return frozenset(names)
 
 
-_ANALYSIS_CACHE: Dict[str, Dict[str, Any]] = {}
-
 #: Version stamped on every report this module emits.  v1 = the PR-10
-#: unversioned dump; v2 adds ``schema`` + optional ``mfu_est`` and is
-#: the first version ``attribution_diff`` treats as its own.  Bump on
+#: unversioned dump; v2 adds ``schema`` + optional ``mfu_est``.  Bump on
 #: any region-row field change so two dumps are comparable by machine.
 SCHEMA_VERSION = 2
 
@@ -709,8 +702,7 @@ def latest_report() -> Optional[Dict[str, Any]]:
 
 
 def analyze_trainer_step(trainer, feed, top: int = 12,
-                         peaks: Optional[Dict[str, Any]] = None,
-                         cache_key: Optional[str] = None
+                         peaks: Optional[Dict[str, Any]] = None
                          ) -> Optional[Dict[str, Any]]:
     """Attributed cost report of ONE compiled train step.
 
@@ -720,13 +712,8 @@ def analyze_trainer_step(trainer, feed, top: int = 12,
     ``cost_analysis()`` totals, and renders the per-region roofline.
     Returns None when anything in the stack declines (missing cost
     analysis, exotic backend) — the report is an artifact field, never
-    a crash.  ``cache_key`` memoizes per workload: the report is a
-    property of the lowering, identical across timing attempts.
+    a crash.
     """
-    global _latest_report
-    if cache_key is not None and cache_key in _ANALYSIS_CACHE:
-        _latest_report = _ANALYSIS_CACHE[cache_key]
-        return _latest_report
     try:
         # build+compile the step only if the trainer has never stepped:
         # at a pass boundary (--roofline_dump) the step exists, and
@@ -737,8 +724,7 @@ def analyze_trainer_step(trainer, feed, top: int = 12,
         compiled = trainer._train_step.lower(
             *_step_args(trainer, feed)).compile()
         return _report_from_compiled(
-            compiled, _known_regions(trainer.network), top, peaks,
-            cache_key)
+            compiled, _known_regions(trainer.network), top, peaks)
     except Exception as e:   # noqa: BLE001 — best-effort artifact field
         from ..utils.logger import get_logger, warn_once
 
@@ -748,44 +734,12 @@ def analyze_trainer_step(trainer, feed, top: int = 12,
         return None
 
 
-def analyze_fn(fn, args: Sequence[Any], known: Iterable[str] = (),
-               top: int = 12, peaks: Optional[Dict[str, Any]] = None,
-               cache_key: Optional[str] = None
-               ) -> Optional[Dict[str, Any]]:
-    """Attributed cost report of an arbitrary jitted callable — the
-    trainer-free sibling of :func:`analyze_trainer_step` (same report
-    dict, same schema), for inference paths like the serving decode
-    step where there is no trainer to lower.  ``fn`` is jitted if it
-    is not already; ``known`` are the ``jax.named_scope`` names to
-    resolve regions against.  Returns None when the stack declines —
-    a report is an artifact field, never a crash."""
-    global _latest_report
-    if cache_key is not None and cache_key in _ANALYSIS_CACHE:
-        _latest_report = _ANALYSIS_CACHE[cache_key]
-        return _latest_report
-    try:
-        import jax
-
-        jfn = fn if hasattr(fn, "lower") else jax.jit(fn)
-        compiled = jfn.lower(*args).compile()
-        return _report_from_compiled(compiled, frozenset(known), top,
-                                     peaks, cache_key)
-    except Exception as e:   # noqa: BLE001 — best-effort artifact field
-        from ..utils.logger import get_logger, warn_once
-
-        warn_once("costmodel_analyze_fn_failed",
-                  "fn cost attribution unavailable (%s: %s)",
-                  type(e).__name__, e, logger=get_logger("observe"))
-        return None
-
-
 def _report_from_compiled(compiled, known: frozenset, top: int,
-                          peaks: Optional[Dict[str, Any]],
-                          cache_key: Optional[str]) -> Dict[str, Any]:
-    """Shared back half of :func:`analyze_trainer_step` /
-    :func:`analyze_fn`: optimized-HLO attribution reconciled against
-    ``cost_analysis()``, rendered as the versioned per-region roofline
-    report."""
+                          peaks: Optional[Dict[str, Any]]
+                          ) -> Dict[str, Any]:
+    """Back half of :func:`analyze_trainer_step`: optimized-HLO
+    attribution reconciled against ``cost_analysis()``, rendered as the
+    versioned per-region roofline report."""
     global _latest_report
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
@@ -837,44 +791,12 @@ def _report_from_compiled(compiled, known: frozenset, top: int,
                   "ridge": round(peaks["ridge"], 2),
                   "source": peaks["source"]},
     }
-    if cache_key is not None:
-        _ANALYSIS_CACHE[cache_key] = out
     _latest_report = out
     return out
 
 
-def step_mfu(trainer, feed, seconds_per_step: float,
-             devices: int = 1, fallback_flops: Optional[float] = None,
-             cache_key: Optional[str] = None) -> Dict[str, Any]:
-    """Shared MFU stamp for a measured step: executed FLOPs from
-    :func:`analyze_trainer_step` (memoized via ``cache_key``) over
-    ``time x peak x chips``.  When the step contains opaque custom
-    calls (Pallas kernels — zero parsed FLOPs), the caller's analytic
-    ``fallback_flops`` takes over if it is larger, and the stamp says
-    which source produced the number."""
-    report = analyze_trainer_step(trainer, feed, cache_key=cache_key)
-    peaks = detect_peaks()
-    flops = report["flops_per_step"] if report else 0.0
-    source = "costmodel"
-    if fallback_flops and (report is None
-                           or (report["opaque_custom_calls"]
-                               and fallback_flops > flops)):
-        flops = float(fallback_flops)
-        source = "analytic-fallback"
-    return {"mfu_est": round(mfu(flops, seconds_per_step, devices,
-                                 peaks), 3),
-            "mfu_source": source,
-            "flops_per_step": round(flops, 1)}
-
-
-def clear_cache() -> None:
-    """Drop memoized per-workload reports (tests; bench lanes that
-    rebuild a workload under different flags)."""
-    _ANALYSIS_CACHE.clear()
-
-
 def render_table(report: Dict[str, Any]) -> str:
-    """Human-readable per-region roofline table (PERF_NOTES material)."""
+    """Human-readable per-region roofline table."""
     lines = [f"{'region':<28} {'GFLOPs':>10} {'MB':>10} {'int.':>8} "
              f"{'bound':>8} {'t_est_ms':>9} {'share':>6} {'bwd%':>5}"]
     for r in report.get("regions", []):
@@ -898,199 +820,3 @@ def dump_report(report: Dict[str, Any], path: str) -> None:
     with open(path, "w") as f:
         json.dump(report, f, indent=1)
         f.write("\n")
-
-
-# ----------------------------------------------------- attribution diff
-def load_report(path: str) -> Dict[str, Any]:
-    """Read a ``--roofline_dump`` artifact; unversioned (pre-v2) dumps
-    are stamped ``schema: 1`` so the diff can say what it compared."""
-    with open(path) as f:
-        report = json.load(f)
-    if not isinstance(report, dict) or "regions" not in report:
-        raise ValueError(
-            f"{path!r} is not a roofline/cost report (no 'regions')")
-    report.setdefault("schema", 1)
-    return report
-
-
-def _region_rows(report: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
-    return {r["region"]: r for r in report.get("regions") or []}
-
-
-def _frac(old: float, new: float) -> Optional[float]:
-    """(new - old) / |old| — None when the base is zero (a fraction of
-    nothing is noise, the absolute delta field still tells the story)."""
-    if not old:
-        return None
-    return round((new - old) / abs(old), 4)
-
-
-def _match_renames(removed: Dict[str, Dict[str, Any]],
-                   added: Dict[str, Dict[str, Any]],
-                   rtol: float = 0.02) -> Dict[str, str]:
-    """``{added name: removed name}`` for region pairs whose FLOPs AND
-    bytes agree within ``rtol`` — a layer rename (or a named_scope
-    re-label) rather than a genuine add+remove.  A pair is claimed
-    only when the match is unique in BOTH directions: an added region
-    with two removal candidates, or a removed region two added regions
-    could stand in for, stays an honest add/remove — a wrong rename
-    claim is worse than no claim."""
-    hits: Dict[str, List[str]] = {}      # added -> matching removed
-    claims: Dict[str, List[str]] = {}    # removed -> claiming added
-    for aname, arow in added.items():
-        for rname, rrow in removed.items():
-            fo, fn = rrow.get("flops", 0.0), arow.get("flops", 0.0)
-            bo, bn = rrow.get("bytes", 0.0), arow.get("bytes", 0.0)
-            if abs(fn - fo) <= rtol * max(abs(fo), 1.0) \
-                    and abs(bn - bo) <= rtol * max(abs(bo), 1.0):
-                hits.setdefault(aname, []).append(rname)
-                claims.setdefault(rname, []).append(aname)
-    return {aname: rnames[0] for aname, rnames in hits.items()
-            if len(rnames) == 1 and len(claims[rnames[0]]) == 1}
-
-
-#: Per-region numeric fields the diff reports (field, fraction-worthy).
-_DIFF_FIELDS = ("flops", "bytes", "intensity", "time_est_s", "share",
-                "bwd_frac")
-
-
-def attribution_diff(old: Dict[str, Any], new: Dict[str, Any],
-                     tolerance: float = 0.05) -> Dict[str, Any]:
-    """Machine-readable per-region delta between two roofline reports
-    — the ``bench.py --attribution_diff OLD NEW`` payload, closing the
-    loop on attribution-driven kernel work: a PR's before/after claim
-    is verified by machine, not prose.
-
-    Region rows carry ``status`` (``common | added | removed |
-    renamed``), per-field ``*_old / *_new / *_delta / *_delta_frac``,
-    and the roofline ``bound`` verdict transition.  ``regressions``
-    lists common/renamed regions whose HBM ``bytes`` or ``time_est_s``
-    grew beyond ``tolerance`` (fractional) plus total
-    flops/bytes-per-step growth; ``ok`` is False iff any exist —
-    ``--check`` gates on it."""
-    o_rows, n_rows = _region_rows(old), _region_rows(new)
-    removed = {k: v for k, v in o_rows.items() if k not in n_rows}
-    added = {k: v for k, v in n_rows.items() if k not in o_rows}
-    renames = _match_renames(removed, added)
-
-    regions: List[Dict[str, Any]] = []
-    regressions: List[Dict[str, Any]] = []
-    improvements: List[Dict[str, Any]] = []
-
-    def diff_row(name: str, orow: Dict[str, Any], nrow: Dict[str, Any],
-                 status: str, renamed_from: Optional[str] = None
-                 ) -> Dict[str, Any]:
-        row: Dict[str, Any] = {"region": name, "status": status}
-        if renamed_from:
-            row["renamed_from"] = renamed_from
-        for f in _DIFF_FIELDS:
-            ov = float(orow.get(f, 0.0) or 0.0)
-            nv = float(nrow.get(f, 0.0) or 0.0)
-            row[f + "_old"] = ov
-            row[f + "_new"] = nv
-            row[f + "_delta"] = round(nv - ov, 6)
-            row[f + "_delta_frac"] = _frac(ov, nv)
-        row["bound_old"] = orow.get("bound")
-        row["bound_new"] = nrow.get("bound")
-        row["bound_changed"] = row["bound_old"] != row["bound_new"]
-        for f in ("bytes", "time_est_s"):
-            frac = row[f + "_delta_frac"]
-            if frac is None:
-                continue
-            entry = {"region": name, "field": f,
-                     "old": row[f + "_old"], "new": row[f + "_new"],
-                     "delta_frac": frac}
-            if frac > tolerance:
-                regressions.append(entry)
-            elif frac < -tolerance:
-                improvements.append(entry)
-        return row
-
-    for name in sorted(set(o_rows) & set(n_rows)):
-        regions.append(diff_row(name, o_rows[name], n_rows[name],
-                                "common"))
-    for aname, rname in sorted(renames.items()):
-        regions.append(diff_row(aname, o_rows[rname], n_rows[aname],
-                                "renamed", renamed_from=rname))
-    zero = {f: 0.0 for f in _DIFF_FIELDS}
-    for name in sorted(added):
-        if name in renames:
-            continue
-        regions.append(diff_row(name, zero, n_rows[name], "added"))
-    for name in sorted(removed):
-        if name in renames.values():
-            continue
-        regions.append(diff_row(name, o_rows[name], zero, "removed"))
-
-    totals: Dict[str, Any] = {}
-    for f in ("flops_per_step", "bytes_per_step"):
-        ov = float(old.get(f, 0.0) or 0.0)
-        nv = float(new.get(f, 0.0) or 0.0)
-        totals[f + "_old"] = ov
-        totals[f + "_new"] = nv
-        totals[f + "_delta_frac"] = _frac(ov, nv)
-        frac = totals[f + "_delta_frac"]
-        if frac is not None and frac > tolerance:
-            regressions.append({"region": "_total", "field": f,
-                                "old": ov, "new": nv,
-                                "delta_frac": frac})
-        elif frac is not None and frac < -tolerance:
-            improvements.append({"region": "_total", "field": f,
-                                 "old": ov, "new": nv,
-                                 "delta_frac": frac})
-    for f in ("mfu_est",):
-        if old.get(f) is not None or new.get(f) is not None:
-            totals[f + "_old"] = old.get(f)
-            totals[f + "_new"] = new.get(f)
-            if old.get(f) and new.get(f):
-                totals[f + "_delta_frac"] = _frac(float(old[f]),
-                                                  float(new[f]))
-
-    return {
-        "kind": "attribution_diff",
-        "schema": {"old": old.get("schema", 1),
-                   "new": new.get("schema", 1),
-                   "diff": SCHEMA_VERSION},
-        "tolerance": tolerance,
-        "regions": regions,
-        "totals": totals,
-        "added": sorted(n for n in added if n not in renames),
-        "removed": sorted(r for r in removed
-                          if r not in renames.values()),
-        "renamed": {a: r for a, r in sorted(renames.items())},
-        "regressions": regressions,
-        "improvements": improvements,
-        "ok": not regressions,
-    }
-
-
-def render_diff_table(diff: Dict[str, Any]) -> str:
-    """Human-readable attribution diff (stderr companion of the JSON
-    payload; PERF_NOTES material)."""
-    lines = [f"{'region':<28} {'status':>8} {'GFLOPs Δ%':>10} "
-             f"{'HBM Δ%':>8} {'t_est Δ%':>9} {'bound':>18}"]
-
-    def pct(v: Optional[float]) -> str:
-        return f"{v * 100:+.1f}%" if v is not None else "n/a"
-
-    for r in diff.get("regions", []):
-        bound = (r.get("bound_old") or "?")
-        if r.get("bound_changed"):
-            bound = f"{bound}->{r.get('bound_new') or '?'}"
-        name = r["region"]
-        if r.get("renamed_from"):
-            name = f"{r['renamed_from']}->{name}"
-        lines.append(
-            f"{name:<28} {r['status']:>8} "
-            f"{pct(r.get('flops_delta_frac')):>10} "
-            f"{pct(r.get('bytes_delta_frac')):>8} "
-            f"{pct(r.get('time_est_s_delta_frac')):>9} {bound:>18}")
-    t = diff.get("totals", {})
-    lines.append(
-        "totals: flops/step "
-        f"{pct(t.get('flops_per_step_delta_frac'))}, bytes/step "
-        f"{pct(t.get('bytes_per_step_delta_frac'))}; "
-        f"{len(diff.get('regressions', []))} regression(s), "
-        f"{len(diff.get('improvements', []))} improvement(s), "
-        f"ok={diff.get('ok')}")
-    return "\n".join(lines)

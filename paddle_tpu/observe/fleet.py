@@ -98,8 +98,8 @@ _OK = "ok"
 
 # ------------------------------------------------------------ identity
 # Role/logical-name a subsystem claims for this process.  Flags give
-# the defaults; the elastic trainer (trainer_id), the serving loader
-# and bench override programmatically.  The pusher reads this at frame
+# the defaults; the elastic trainer (trainer_id) and the serving loader
+# override programmatically.  The pusher reads this at frame
 # build time, so an identity set after the reporter started still
 # lands on the next frame.
 _identity_lock = named_lock("observe.fleet.identity")
@@ -110,7 +110,7 @@ def set_identity(role: Optional[str] = None,
                  name: Optional[str] = None,
                  node: Optional[str] = None) -> None:
     """Claim this process's fleet identity (role ∈ trainer |
-    master-client | serving | bench by convention; free-form).  Unset
+    master-client | serving by convention; free-form).  Unset
     fields keep their flag/derived defaults."""
     with _identity_lock:
         if role:

@@ -1,6 +1,6 @@
 """Training-health observatory: on-device per-layer telemetry + detectors.
 
-The performance observatory (``costmodel``/``memory``/``benchgate``)
+The performance observatory (``costmodel``/``memory``)
 answers "how fast"; this module answers "is the model healthy".  Two
 halves:
 

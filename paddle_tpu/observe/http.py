@@ -186,8 +186,8 @@ class _Handler(BaseHTTPRequestHandler):
                 if report is None:
                     self._send(404, json.dumps(
                         {"error": "no roofline report yet (run a "
-                                  "--roofline_dump pass or a bench "
-                                  "lane first)"}), "application/json")
+                                  "--roofline_dump pass first)"}),
+                        "application/json")
                 else:
                     self._send(200, json.dumps(report),
                                "application/json")

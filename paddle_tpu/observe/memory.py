@@ -22,8 +22,7 @@ signal.  This module samples
 Sampling discipline (the 26 µs/step no-sink contract): nothing here
 runs per step.  The trainer samples at pass boundaries — and only when
 someone is listening (a metrics sink is attached or the ``/metrics``
-endpoint is live); ``bench.py`` stamps every JSON line through
-:func:`sample`.  jax is imported lazily so importing
+endpoint is live).  jax is imported lazily so importing
 :mod:`paddle_tpu.observe` stays backend-free.
 """
 

@@ -513,7 +513,7 @@ class MetricsRegistry:
     def flat(self, kinds: Sequence[str] = ("counter", "gauge")
              ) -> Dict[str, float]:
         """``{'name{k="v"}': value}`` for scalar metric kinds — the
-        compact form bench lines and delta assertions consume."""
+        compact form delta assertions consume."""
         out: Dict[str, float] = {}
         for m in self.metrics():
             if m.kind not in kinds:

@@ -14,7 +14,7 @@ A :class:`MetricsReporter` snapshots them together:
   HTTP handler; no server is bundled — zero-dependency rule).
 
 :func:`start_from_flags` is the one call subsystem entry points make
-(trainer, bench, CLI): idempotent, starts the global background reporter
+(trainer, CLI): idempotent, starts the global background reporter
 iff ``--metrics_jsonl`` is set.  :func:`active` tells instrumentation
 whether a sink is attached — callers use it to gate work that is NOT
 near-zero-cost, e.g. the trainer's ``block_until_ready`` step fencing
@@ -227,8 +227,8 @@ def start_from_flags() -> Optional[MetricsReporter]:
     is configured — no thread starts, no work happens).  ``--slo``
     alone starts the reporter too: the engine needs the interval
     thread to evaluate on even when nothing is exported.  Every
-    long-running entry point calls this once (``Trainer.train``,
-    ``bench.main``, the CLI)."""
+    long-running entry point calls this once (``Trainer.train``, the
+    CLI)."""
     global _global
     from ..utils import FLAGS
 

@@ -1,7 +1,7 @@
 """Fused conv + BatchNorm-affine Pallas TPU kernels (both directions).
 
-The ResNet-class train step is HBM-bound, not MXU-bound (PERF_NOTES:
-27 GB/step, bandwidth util ~0.70 while flops util sits at 0.29).  The
+The ResNet-class train step is HBM-bound, not MXU-bound (PERF.md §5:
+BN's memory-bound passes lead the device time).  The
 largest removable slice of that traffic is the seam between BatchNorm
 and the convs on either side of it: XLA cannot fuse an elementwise
 producer into a convolution operand (convs read their inputs from HBM).
@@ -30,8 +30,7 @@ it — writing dx *and* dz in the same pass so the filter-grad conv that
 still runs under XLA reads a ready-made dz.  Per fused conv→BN pair
 this removes one full read+write of an activation-sized tensor from the
 step (the apply pass's dz store and the backward-data conv's dz load),
-which is exactly the traffic class PERF_NOTES identified as the
-roofline.
+which is exactly the traffic class that bounds the step.
 
 **Forward half (round 7).**  The forward pass pays the same seam tax in
 the other direction: every BN normalize+scale+ReLU apply writes a full

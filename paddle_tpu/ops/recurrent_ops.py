@@ -90,8 +90,7 @@ def _warn_scan_fallback(kind: str, b: int, h: int) -> str:
     warn_once(
         f"fused_{kind}_fallback:{b}x{h}",
         "fused_%s_fallback: scan path taken for batch=%d hidden=%d "
-        "(%s); throughput is the pre-fusion tier — see "
-        "bench.py::bench_lstm_1280 for the measured gap", kind, b, h,
+        "(%s); throughput is the pre-fusion tier", kind, b, h,
         reason, logger=_log)
     return reason
 

@@ -32,5 +32,4 @@ from .sparse import (SelectedRows, unique_rows, row_gather,
                      row_scatter_add, row_scatter_set, touched_row_mask,
                      prefetch_rows, sparse_embedding_lookup,
                      unique_rows_sorted, lookup_rows, exchange_scope,
-                     exchange_entry,
-                     exchange_payload_bytes)  # noqa: F401
+                     exchange_entry)  # noqa: F401

@@ -193,11 +193,3 @@ def exchange_entry(param_name: str):
     if _exchange_scope:
         return _exchange_scope[-1].get(param_name)
     return None
-
-
-def exchange_payload_bytes(capacity: int, dim: int,
-                           value_itemsize: int = 4) -> int:
-    """Exchanged gradient bytes of one (rows, values) pair: K int32
-    row indices + the [K, D] value block — the traffic a dense
-    all-reduce of the [V, D] gradient is replaced by."""
-    return int(capacity) * (4 + int(dim) * int(value_itemsize))

@@ -40,8 +40,7 @@ digest-verified.
 
 Version-1 artifacts keep loading unchanged (``serving/loader.py``
 supports both).  The measurement template is the Gemma-on-TPU study
-(PAPERS.md, arxiv 2605.25645): ~4× smaller weight payload, with the
-latency/accuracy delta reported by ``bench.py --only precision``.
+(PAPERS.md, arxiv 2605.25645): ~4× smaller weight payload.
 
 Reference parity: replaces ``paddle_gradient_machine_create_for_inference
 _with_parameters`` + ``_forward``; multi-threaded serving needs no

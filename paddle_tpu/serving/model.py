@@ -623,7 +623,7 @@ class DecoderModel:
         params = {e["name"]: w
                   for e, w in zip(wsec["entries"], weights)}
         model = cls(params, cfg)
-        # testing/bench knob (export_decoder extra_meta): a seeded-slow
+        # testing knob (export_decoder extra_meta): a seeded-slow
         # artifact carries debug_prefill_delay_ms in its manifest; the
         # server's _prefill sleeps it inside the TTFT stamp so a canary
         # bake has a deterministic latency regression to detect

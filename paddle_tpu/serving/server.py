@@ -679,7 +679,7 @@ class InferenceServer:
                     tokens[i, :len(r.prompt)] = r.prompt
                     lengths[i] = len(r.prompt)
                     tables[i] = self._table_row(r)
-            # testing/bench knob: a seeded-slow artifact (manifest
+            # testing knob: a seeded-slow artifact (manifest
             # debug_prefill_delay_ms) inflates TTFT here — inside the
             # TTFT stamp, before the launch — so a canary bake has a
             # deterministic latency regression to detect.  Swap probes

@@ -424,7 +424,7 @@ class ServeServerProcess:
 def _child_env() -> dict:
     """Environment of every chaos child that imports jax.  They run on
     the CPU by design, not as a fallback: a chip belongs to one process,
-    the parent (pytest, a bench lane) may hold it, and what these
+    the parent (pytest, the smoke) may hold it, and what these
     children prove — recovery after SIGKILL, torn snapshots, lease
     replay — does not depend on the device.  ``setdefault`` lets a
     caller that owns no chip choose otherwise."""

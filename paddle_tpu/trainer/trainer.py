@@ -362,8 +362,8 @@ class Trainer:
         (``--precision=bf16``) then the health accumulator
         (``--health_interval``).  THE one definition of the extra-state
         order — every step variant mirrors it in its trailing outputs,
-        and ``bench._scan_time_ms`` / ``costmodel._step_args`` reuse it
-        instead of re-deriving the tuple."""
+        and ``costmodel._step_args`` reuses it instead of re-deriving
+        the tuple."""
         extras: Tuple = ()
         if self._ls_state is not None:
             extras += (self._ls_state,)
@@ -1040,8 +1040,7 @@ class Trainer:
                 self, self._roofline_feed)
             if report is not None:
                 # stamp MFU when a fenced step time exists (a metrics
-                # sink fenced the steps) — makes two dumps diffable on
-                # MFU by --attribution_diff without an extra bench run
+                # sink fenced the steps)
                 fenced = observe.histogram(
                     "train_device_blocked_seconds",
                     "time blocked on the device per step (fenced; only "

@@ -106,13 +106,11 @@ FLAGS = FlagRegistry()
 # Core knobs (reference: paddle/utils/Flags.cpp).
 FLAGS.define("trainer_count", 1, "data-parallel replicas (mesh 'data' axis size)")
 FLAGS.define("trainer_id", 0, "index of this host in a multi-host job")
-FLAGS.define("num_hosts", 1, "number of hosts in the job")
 FLAGS.define("log_period", 100, "log every N batches")
 FLAGS.define("test_period", 0, "test every N batches (0: per pass)")
 FLAGS.define("show_parameter_stats_period", 0, "dump param stats every N batches")
 FLAGS.define("checkgrad_eps", 1e-2, "finite-difference step for --job=checkgrad")
 FLAGS.define("seed", 1, "global RNG seed (0: nondeterministic)")
-FLAGS.define("dot_period", 1, "print a progress dot every N batches")
 FLAGS.define("saving_period", 1, "checkpoint every N passes")
 FLAGS.define("load_missing_parameter_strategy", "fail", "fail|rand|zero")
 FLAGS.define("init_model_path", "", "checkpoint dir to warm-start from")
@@ -272,8 +270,8 @@ FLAGS.define("fleet_id", "",
              "NEW process and the old entry stays missing)")
 FLAGS.define("fleet_role", "trainer",
              "fleet role this process registers as (trainer | "
-             "master-client | serving | bench by convention); the "
-             "elastic trainer, serving loader and bench override this "
+             "master-client | serving by convention); the elastic "
+             "trainer and the serving loader override this "
              "programmatically")
 FLAGS.define("fleet_stale_factor", 3.0,
              "staleness multiplier for the /fleet/healthz rollup: a "
@@ -380,7 +378,7 @@ FLAGS.define("kv_page_size", 16,
              "kv_page_size")
 FLAGS.define("serve_slo_ms", 0.0,
              "optional p99 TTFT SLO in milliseconds: when > 0 the "
-             "server's /healthz and the bench serving lane report "
+             "server's /healthz reports "
              "ttft_p99_ms and slo_met from the serve_ttft_seconds "
              "WINDOWED reservoir p99 (last ~60s), so a recovered "
              "server stops advertising a stale lifetime p99; 0 "
@@ -517,8 +515,4 @@ FLAGS.define("reader_workers", 2,
              "(clamped to prefetch_depth; reading from the source is "
              "serialized, convert+transfer parallelize)")
 FLAGS.define("parallel_nn", False, "per-layer device placement (sharding annotations)")
-FLAGS.define("enable_timers", True, "collect named wall timers (Stat.h equivalent)")
 FLAGS.define("port", 7164, "data-task coordinator service port")
-FLAGS.define("ports_num", 1, "kept for config compatibility; unused on TPU")
-FLAGS.define("num_gradient_servers", 1, "kept for config compatibility")
-FLAGS.define("rdma_tcp", "tcp", "kept for config compatibility; unused on TPU")

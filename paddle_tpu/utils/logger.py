@@ -4,7 +4,7 @@ Level selection (first match wins):
 
 1. ``set_log_level("debug")`` in code,
 2. ``--log_level`` CLI flag (applied by the entry points after flag
-   parsing — :mod:`paddle_tpu.cli`, ``bench.py``),
+   parsing — :mod:`paddle_tpu.cli`),
 3. ``PADDLE_TPU_LOG_LEVEL`` environment variable at import,
 4. INFO.
 

@@ -73,8 +73,8 @@ def trace(logdir: str = "/tmp/paddle_tpu_trace") -> Iterator[None]:
     """``with profiler.trace(dir): ...`` — xprof window (nvprof-window
     equivalent); view with TensorBoard's profile plugin.
 
-    Re-entrancy-safe: a nested ``trace`` (e.g. bench's ``--profile``
-    around a code path that opens its own window) warns once and rides
+    Re-entrancy-safe: a nested ``trace`` (around a code path that
+    opens its own window) warns once and rides
     the already-open window instead of raising.  Windows are
     tick-counted (``profiler_trace_windows_total``) so a run's artifact
     records how many xprof dumps it produced."""

@@ -168,6 +168,7 @@ def _reset_global_state(_io_thread_leak_guard):
     # judges what's still alive
     yield
     from paddle_tpu import observe
+    from paddle_tpu.core import device
     from paddle_tpu.observe import REGISTRY
     from paddle_tpu.utils.logger import reset_warn_once
     from paddle_tpu.utils.stat import global_stat
@@ -175,6 +176,11 @@ def _reset_global_state(_io_thread_leak_guard):
     global_stat.reset()
     REGISTRY.reset()
     reset_warn_once()
+    # the process-global mesh: a test that sets one (set_mesh, the
+    # multichip dry run) must not hand it to the next test of its
+    # worker — under a 4-device mesh left behind, every later Trainer
+    # built without a mesh of its own splits its batch four ways
+    device.set_mesh(None)
     # tracing + the HTTP endpoint + the fleet plane are process-wide: a
     # test that enabled them must not leak its recorder/server/pusher/
     # reporter (threads) or SIGTERM disposition into the next

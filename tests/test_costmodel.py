@@ -267,9 +267,7 @@ def _feed(feeder, n=4):
 @pytest.fixture
 def tiny():
     tr, feeder = _tiny_trainer()
-    costmodel.clear_cache()
-    yield tr, _feed(feeder)
-    costmodel.clear_cache()
+    return tr, _feed(feeder)
 
 
 def test_analyze_trainer_step_attributes_real_layers(tiny):
@@ -314,30 +312,9 @@ def test_analyze_does_not_train(tiny):
     assert REGISTRY.counter("train_steps").value() == steps
 
 
-def test_analyze_memoizes_by_cache_key(tiny):
-    tr, feed = tiny
-    a = costmodel.analyze_trainer_step(tr, feed, cache_key="k")
-    b = costmodel.analyze_trainer_step(tr, feed, cache_key="k")
-    assert a is b
-    costmodel.clear_cache()
-    c = costmodel.analyze_trainer_step(tr, feed, cache_key="k")
-    assert c is not a
-
-
-def test_step_mfu_stamp_and_analytic_fallback(tiny):
-    tr, feed = tiny
-    stamp = costmodel.step_mfu(tr, feed, 1e-3, cache_key="m")
-    assert stamp["mfu_source"] == "costmodel"
-    assert stamp["flops_per_step"] > 0
-    assert 0 <= stamp["mfu_est"] <= 1.0
-    # no opaque custom calls in this step -> the analytic hint is NOT
-    # taken even when larger
-    stamp2 = costmodel.step_mfu(tr, feed, 1e-3, cache_key="m",
-                                fallback_flops=1e15)
-    assert stamp2["mfu_source"] == "costmodel"
-
-
-def test_step_mfu_falls_back_when_analysis_declines():
+def test_analyze_declines_without_raising():
+    """A report is an artifact field, never a crash: a stack that
+    cannot be analysed reads None, with one warning."""
     class Broken:
         network = None
 
@@ -347,10 +324,7 @@ def test_step_mfu_falls_back_when_analysis_declines():
     from paddle_tpu.utils.logger import reset_warn_once
 
     reset_warn_once()
-    stamp = costmodel.step_mfu(Broken(), {}, 1e-3, fallback_flops=2e9)
-    assert stamp["mfu_source"] == "analytic-fallback"
-    assert stamp["flops_per_step"] == pytest.approx(2e9)
-    assert stamp["mfu_est"] > 0
+    assert costmodel.analyze_trainer_step(Broken(), {}) is None
 
 
 def test_dump_report_roundtrip(tiny, tmp_path):

@@ -405,8 +405,8 @@ def test_train_loop_input_bound_ratio():
 def test_network_fused_pair_census_resnet():
     """The build-time census gauge must equal the peephole tables — and
     on ResNet-50 those resolve 16 Pallas-3×3 + 16 GEMM-1×1 forward
-    pairs (the round-7 resolution and the acceptance pin for the bench
-    artifact; the bwd entries are all evicted into fwd chains)."""
+    pairs (the round-7 resolution; the bwd entries are all evicted
+    into fwd chains)."""
     from paddle_tpu.config import dsl
     from paddle_tpu.config.dsl import config_scope
     from paddle_tpu.data.feeder import dense_vector, integer_value

@@ -27,7 +27,7 @@ import pytest
 from paddle_tpu.config import dsl
 from paddle_tpu.config.dsl import config_scope
 from paddle_tpu.config.model_config import OptimizationConfig
-from paddle_tpu.core.dtypes import dispatch_dtypes, np_dtype
+from paddle_tpu.core.dtypes import np_dtype
 from paddle_tpu.data.feeder import (DataFeeder, dense_vector,
                                     integer_value,
                                     integer_value_sequence)
@@ -219,19 +219,6 @@ def test_precision_dispatch_counter_records_dtype():
     t.train_one_batch(_fc_feed(rng))
     c = observe.counter("precision_dispatch_total")
     assert c.value(op="matmul", dtype="bfloat16") > 0, c.samples()
-
-
-def test_dispatch_dtypes_stamp():
-    FLAGS.set("precision", "bf16")
-    st = dispatch_dtypes()
-    assert st["policy"] == "bf16"
-    assert st["matmul"] == "bfloat16"
-    assert st["master_params"] == "float32"
-    assert st["bn_stats"] == "float32"
-    FLAGS.set("precision", "fp32")
-    FLAGS.set("use_bf16", False)
-    st = dispatch_dtypes()
-    assert st["policy"] == "fp32" and st["matmul"] == "float32"
 
 
 # --------------------------------------------------------- convergence

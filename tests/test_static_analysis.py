@@ -540,7 +540,7 @@ def test_parse_cache_single_parse_property():
 
 
 def test_repo_lock_graph_is_current():
-    """The derived hierarchy PERF_NOTES documents: pipeline source lock
+    """The derived hierarchy: pipeline source lock
     nests the queue condition, reporter flush nests warn-once — and the
     whole graph stays acyclic."""
     project, _ = engine.build_project([PKG_DIR])
