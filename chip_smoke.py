@@ -731,6 +731,11 @@ def phase_kernels(S, ctx):
             return {
                 "decode": decode_step_checks(model._decode.lower(
                     model.params, *pools, jnp.zeros((rows,), jnp.int32),
+                    # the ids of the launch before and which of them
+                    # each row is fed (routed counts ride behind them)
+                    jnp.zeros((rows + (2 if model.routed_layers else 0),),
+                              jnp.int32),
+                    jnp.full((rows,), -1, jnp.int32),
                     pidx, lens, jnp.ones((rows,), bool)).compile(),
                     layer, patterns),
                 "prefill": decode_step_checks(model._prefill.lower(

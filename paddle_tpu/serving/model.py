@@ -26,6 +26,14 @@ writes its rows into, and reads its pages out of, the stack as it lies
 in memory, at the layer's page offset — no copy, slice or write-back of
 a pool or of a layer's share of one (PERF.md §6, PR 28).
 
+Each has a **launch** half (``launch_prefill`` / ``launch_decode``:
+queue the program, return at once) and a **collect** half (wait, bring
+back the token ids and the routed counts, nothing else); ``prefill`` and
+``decode`` are the two in one.  A decode launch can take its rows' ids
+from the decode launch before it while those are still on the device,
+which is what lets the server keep one launch queued ahead of the host
+(PERF.md §6, PR 30).  The logits stay a device array in every result.
+
 Batch invariance is a load-bearing property, not an accident: every
 per-row computation (matmuls, RMS norms, attention — the decode
 kernel walks a row's own pages in the row's own order — ``argmax``
@@ -453,9 +461,13 @@ def _jitted_steps(cfg: DecoderConfig):
     prefill = jax.jit(
         lambda p, kp, vp, tk, ln, pi: _prefill_impl(
             p, kp, vp, tk, ln, pi, cfg), donate_argnums=(1, 2))
+    # a row's fed id is the host's ``tk`` or, where ``src`` >= 0, entry
+    # ``src`` of ``prev``: the ids of the decode launch before this one,
+    # still on the device (the host has not read them yet)
     decode = jax.jit(
-        lambda p, kp, vp, tk, pi, ln, ac: _decode_impl(
-            p, kp, vp, tk, pi, ln, ac, cfg), donate_argnums=(1, 2))
+        lambda p, kp, vp, tk, prev, src, pi, ln, ac: _decode_impl(
+            p, kp, vp, jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tk),
+            pi, ln, ac, cfg), donate_argnums=(1, 2))
     return prefill, decode
 
 
@@ -553,13 +565,26 @@ class DecoderModel:
         return behind * self._window_layers
 
     # ----------------------------------------------------------- steps
+    # Each step has a launch half, which queues the program on the
+    # device and returns at once, and a collect half, which waits for
+    # it and brings back the ids (and the routed counts): 64-72 bytes.
+    # The logits stay on the device, the caller's to fetch.  ``prefill``
+    # and ``decode`` are the two in one, and the launch half is the same
+    # method told not to collect: the jitted call sits in ONE frame,
+    # as deep under ``prefill`` as under ``launch_prefill``.  JAX writes
+    # the Python traceback into every op's location, and one frame more
+    # above the call cost a 24-layer program a second or more of
+    # lowering on the chip's host (PERF.md §6, PR 30).
     def prefill(self, k_pool: KVPool, v_pool: KVPool, tokens, lengths,
-                page_indices):
-        """Prompts in, first generated token out (plus the pools, the
-        same two objects, updated in place).  ``tokens`` [B, T] int32
-        padded, ``lengths`` [B], ``page_indices`` [B, max_pages]
-        physical page tables covering each prompt PLUS the tokens to be
-        generated."""
+                page_indices, collect: bool = True):
+        """Prompts in, first generated token out (plus the logits and
+        the pools, the same two objects, updated in place).  ``tokens``
+        [B, T] int32 padded, ``lengths`` [B], ``page_indices``
+        [B, max_pages] physical page tables covering each prompt PLUS
+        the tokens to be generated.  The two pools hold the launch's
+        result as soon as it is queued; ``collect=False``
+        (:meth:`launch_prefill`) returns there, with the launch for
+        :meth:`collect_prefill`."""
         shape = np.shape(tokens)
         enforce(len(shape) == 2 and shape[1] <= self.cfg.max_context,
                 f"prompt batch {shape} exceeds max_context "
@@ -570,31 +595,68 @@ class DecoderModel:
                 jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(page_indices, jnp.int32))
+        if not collect:
+            return nxt, logits
+        return (*self.collect_prefill((nxt, logits)), k_pool, v_pool)
+
+    launch_prefill = functools.partialmethod(prefill, collect=False)
+
+    @staticmethod
+    def collect_prefill(launch):
+        """→ (each row's first generated token, on the host; the
+        logits, a device array)."""
+        nxt, logits = launch
         with _span("prefill_fetch"):          # blocks on the device
-            nxt, logits = np.asarray(nxt), np.asarray(logits)
-        return nxt, logits, k_pool, v_pool
+            nxt = np.asarray(nxt)
+        return nxt, logits
 
     def decode(self, k_pool: KVPool, v_pool: KVPool, tokens, page_indices,
-               lengths, active):
+               lengths, active, prev=None, src=None, collect: bool = True):
         """One continuous-batching decode step over the page pool.  →
         (next tokens, logits, the pools (the same two objects, updated
-        in place), and what the step's routed
-        layers did: ``{"experts_hit", "expert_load_max"}``,
-        :func:`_route_counts`; empty for a plan without routed layers).
-        The counts come back in the fetch that brings the tokens."""
+        in place), the routed counts of :meth:`collect_decode`).
+        ``prev`` is an earlier decode launch of the same width,
+        collected or not, and ``src`` [B] says per row which of its ids
+        the row is fed (−1: the host's ``tokens``); the same program
+        runs with or without it.  ``collect=False``
+        (:meth:`launch_decode`) returns once the step is queued, with
+        the launch for :meth:`collect_decode`."""
+        b = np.shape(tokens)[0]
+        if prev is None:       # the program's shapes, fed by nobody
+            counts = 2 if self.routed_layers else 0    # _route_counts
+            ids = np.zeros((b + counts,), np.int32)
+            src = np.full((b,), -1, np.int32)
+        else:
+            ids = prev[0]
         with _span("decode_dispatch"):        # host→device + launch
-            nxt, logits, k_pool.array, v_pool.array = self._decode(
+            ids, logits, k_pool.array, v_pool.array = self._decode(
                 self.params, k_pool.array, v_pool.array,
-                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(tokens, jnp.int32), jnp.asarray(ids, jnp.int32),
+                jnp.asarray(src, jnp.int32),
                 jnp.asarray(page_indices, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(active, bool))
+        if not collect:
+            return ids, logits
+        nxt, logits, routed = self.collect_decode((ids, logits))
+        return nxt, logits, k_pool, v_pool, routed
+
+    launch_decode = functools.partialmethod(decode, collect=False)
+
+    @staticmethod
+    def collect_decode(launch):
+        """→ (next tokens, on the host; the logits, a device array;
+        what the step's routed layers did: ``{"experts_hit",
+        "expert_load_max"}``, :func:`_route_counts`, empty for a plan
+        without routed layers).  The counts come back in the fetch that
+        brings the tokens."""
+        ids, logits = launch
         with _span("decode_fetch"):           # blocks on the device
-            nxt, logits = np.asarray(nxt), np.asarray(logits)
+            ids = np.asarray(ids)
         b = logits.shape[0]
         routed = dict(zip(("experts_hit", "expert_load_max"),
-                          map(int, nxt[b:])))
-        return nxt[:b], logits, k_pool, v_pool, routed
+                          map(int, ids[b:])))
+        return ids[:b], logits, routed
 
     # -------------------------------------------------------- artifacts
     @classmethod

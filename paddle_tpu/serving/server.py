@@ -5,10 +5,10 @@ Request lifecycle — admission → prefill → continuous-batch decode loop
 → detokenize (caller-side):
 
 1. **Admission**: :meth:`InferenceServer.submit` enqueues a request;
-   the decode thread admits from the queue *between decode steps*
+   the decode thread admits from the queue each time it plans a launch,
    whenever a batch slot AND enough free KV pages exist — new requests
-   join the in-flight batch immediately instead of waiting for it to
-   drain (the continuous-batching property).  Page tables come from one
+   join the in-flight batch instead of waiting for it to drain (the
+   continuous-batching property).  Page tables come from one
    shared :class:`~paddle_tpu.serving.pagepool.PagePool`; each request
    reserves ``prompt + max_new_tokens`` worth of pages up front, so a
    request that admits can never die of pool exhaustion mid-decode —
@@ -18,55 +18,93 @@ Request lifecycle — admission → prefill → continuous-batch decode loop
    a single [1, B·T] row), which also writes the prompt K/V into the
    request's pages and yields the first generated token — the TTFT
    moment.
-3. **Decode loop**: one ``paged_decode_attention`` step per iteration
-   over a fixed-width batch (``--serve_max_batch``; inactive slots are
+3. **Decode loop**: one ``paged_decode_attention`` launch a step over a
+   fixed-width batch (``--serve_max_batch``; inactive slots are
    padded with scratch-page tables so there is exactly one compiled
-   decode shape).  Finished requests retire at step boundaries, their
-   pages recycle instantly — the kernel's stale-page immunity makes a
-   freed page safe to reissue without scrubbing.
+   decode shape).  Finished requests retire when their last token is
+   collected, their pages recycle instantly — the kernel's stale-page
+   immunity makes a freed page safe to reissue without scrubbing.
+
+**The loop runs one launch ahead of the host** (PERF.md §6, PR 30).  A
+turn of the decode thread builds launch k+1 and queues it on the device
+while launch k still runs, then collects launch k's token ids (nothing
+else comes back: the logits stay on the device), emits them and turns
+round; the device goes from one launch straight into the next, and the
+host's build, upload, dispatch, emit and its waits for the interpreter
+lock lie under the device's work.  Depth is one.  What that means for a
+row:
+
+- a row of the decode launch in flight is fed in the next launch **from
+  the device**: the jitted step picks its id out of the ids of the
+  launch before (``DecoderModel.launch_decode``'s ``prev``/``src``),
+  merged with host-known ids for rows that just joined;
+- a row that launch k ends **by its budget** is known to the host
+  beforehand and is not in k+1; a row that ends **by EOS** is learned
+  one launch late: it rides in k+1 dead, writes one K/V row into pages
+  it still owns, inside its reservation, and its k+1 token is neither
+  emitted nor counted (``serve_rows_discarded_total``).  Its pages are
+  released when the host learns of the EOS; a prefill admitted into
+  them is queued behind k+1 on the device, so order keeps it safe;
+- a prefill's first token joins from the host: the rows of a prefill in
+  flight sit out the decode launch queued behind it and join the one
+  after, fed the id the host has collected by then.  No launch waits
+  for the host but the first after a lone prefill (and after a swap or
+  an empty server): ``serve_launch_total{kind, queued=behind|idle}``;
+- whatever assumed "nothing in flight" waits for it: a parked swap is
+  applied only once everything the old model launched is collected,
+  ``stop()`` and a failing turn collect what is queued first.
 
 The kill switch ``--serve_continuous=false`` degrades the same loop to
 sequential single-request serving (admit one, run to completion, batch
 width 1).  Because every per-request computation in
 ``serving/model.py`` is row-independent, both modes generate
-byte-for-byte identical tokens — pinned in both directions by
-``tests/test_serving_server.py``.
+byte-for-byte identical tokens, those of a plain serial greedy loop —
+pinned in both directions by ``tests/test_serving_server.py``.
 
 Telemetry (all optional, live when ``paddle_tpu.observe`` is active):
 ``serve_ttft_seconds`` / ``serve_request_seconds`` reservoir histograms
 (p99 SLO source), ``serve_queue_depth`` / ``serve_batch_size`` gauges,
-``serve_requests`` / ``serve_tokens_generated`` counters,
-``serve_page_pool_pages`` pool census, and the span family below.
+``serve_requests`` / ``serve_tokens_generated`` counters, the two
+counters above, ``serve_page_pool_pages`` pool census, and the span
+family below.
 Threads are ``ptpu-serve-decode`` and ``ptpu-serve-http`` (the conftest
 thread-leak guard and ptpu-lint key on the prefix).
 
 Spans (``observe.trace``; one shared no-op each when tracing is off).
-The decode thread opens ``serve_loop_iter`` once there is work, so that
-nothing it does for a request lies outside a span; under it::
+**Spans follow launches, not host phases**: a turn of the decode thread
+is one ``serve_loop_iter`` holding one launch span, which bears launch
+k's attributes and runs from the collect of launch k−1 to launch k's own
+(after its emit), so the launch spans tile the thread's time in launch
+order and the device runs launch k inside span k (to within the emit
+before it: a few hundred microseconds).  Under span k::
 
     serve_loop_iter
-    ├─ serve_admit{queued}
-    ├─ serve_prefill{n, t_pad, prompt_tokens, moe_tokens, requests}
-    │    serve_step_build · prefill_dispatch · prefill_fetch · serve_step_emit
-    ├─ serve_decode_step{batch, live_tokens, live_pages, attended_tokens,
-    │                    experts_hit, expert_load_max}
-    │    serve_step_build · decode_dispatch · decode_fetch · serve_step_emit
+    ├─ serve_prefill{n, t_pad, prompt_tokens, moe_tokens, requests, queued}
+    │  or serve_decode_step{batch, live_tokens, live_pages, attended_tokens,
+    │                       queued, experts_hit, expert_load_max[, discarded]}
+    │    (from idle: serve_step_build · *_dispatch of launch k itself)
+    │    serve_admit{queued} · serve_step_build · *_dispatch  of launch k+1
+    │    *_fetch · serve_step_emit                            of launch k
     └─ serve_snapshot
 
 ``serve_step_build`` is the host's numpy input build, ``*_dispatch`` the
 host→device copy of the inputs and the jitted call's return (the
-launch), ``*_fetch`` the ``np.asarray`` that blocks on the device and
-brings the token ids and logits back (both in ``serving/model.py``),
+launch), ``*_fetch`` the ``np.asarray`` that waits for the device and
+brings the token ids back (both in ``serving/model.py``),
 ``serve_step_emit`` the token bookkeeping, finishes and page releases.
-``live_tokens`` is Σ ``lengths`` of the active rows — the K/V positions
+A span's duration is the period from collect to collect: what a step
+costs.  ``queued`` says what the device had when the launch was queued
+(``behind`` a launch not yet collected, or ``idle``).
+``live_tokens`` is Σ ``lengths`` of the launched rows — the K/V positions
 the step attends over — and ``live_pages`` the pages they occupy;
 ``attended_tokens`` is what the layers must read of them, summed over
-the layers (a window layer reads a row's newest ``window`` only).  Where
-the plan has routed layers, ``moe_tokens`` is the prompt tokens times
-those layers, and the step's own counts come back with its tokens and
-are set before the span closes: ``experts_hit`` (experts with a token of
-an active row, summed over the routed layers) and ``expert_load_max``
-(the most tokens on one expert).  Each
+the layers (a window layer reads a row's newest ``window`` only);
+``batch`` the rows whose token was emitted (``discarded`` the others).
+Where the plan has routed layers, ``moe_tokens`` is the prompt tokens
+times those layers, and the step's own counts come back with its tokens
+and are set before the span closes: ``experts_hit`` (experts with a
+token of an active row, summed over the routed layers) and
+``expert_load_max`` (the most tokens on one expert).  Each
 request carries a ``trace_id`` (its submitter's trace, else its own):
 at admission ``serve_queue_wait`` (submit → admit) and at the finish
 ``serve_request`` (submit → last token; ``prompt``, ``tokens``,
@@ -139,10 +177,12 @@ def _span(name: str, **attrs):
 class Request:
     """One generation request and its lifecycle state.  ``tokens`` holds
     the generated ids (prompt excluded); ``length`` counts tokens whose
-    K/V is already written to this request's pages."""
+    K/V the launches queued so far write to this request's pages (the
+    one in flight included); ``table`` is its page-table row once
+    admitted."""
 
     __slots__ = ("id", "prompt", "max_new_tokens", "tokens", "state",
-                 "error", "done", "length", "next_token",
+                 "error", "done", "length", "next_token", "table",
                  "t_submit", "t_admit", "t_first", "t_done", "trace_id")
 
     def __init__(self, prompt: Sequence[int], max_new_tokens: int):
@@ -154,7 +194,8 @@ class Request:
         self.error: Optional[str] = None
         self.done = threading.Event()
         self.length = 0                  # tokens materialized in pages
-        self.next_token = -1             # token to feed the next step
+        self.next_token = -1             # newest token the host has read
+        self.table: Optional[np.ndarray] = None
         self.t_submit = time.perf_counter()
         self.t_admit: Optional[float] = None
         self.t_first: Optional[float] = None
@@ -181,6 +222,25 @@ class Request:
             # ptpu: lint-ok[PT-METRIC] forwarding shim; callers pass literals
             _trace.record_span(name, ts, dur * 1e6, self.trace_id,
                                request=self.id, **attrs)
+
+
+class _Launch:
+    """One program the device is given: a prefill or a decode step.
+    ``rows`` are its requests in batch order, ``src`` (decode) the batch
+    index each row had in the decode launch this one is queued behind,
+    from which it takes its id on the device (−1: from the host),
+    ``attrs`` what its span states, ``handle`` the model's launch once
+    queued."""
+
+    __slots__ = ("kind", "rows", "src", "attrs", "handle")
+
+    def __init__(self, kind: str, rows: List[Request], attrs: Dict,
+                 src: Sequence[int] = ()):
+        self.kind = kind                 # prefill|decode
+        self.rows = rows
+        self.src = src
+        self.attrs = attrs
+        self.handle = None
 
 
 class SwapTicket:
@@ -218,8 +278,8 @@ class InferenceServer:
     With ``--rollout`` (default on) the server also speaks the
     zero-downtime train→serve protocol (``serving/rollout.py``):
     :meth:`request_swap` parks a fully built replacement model as a
-    :class:`SwapTicket`; the decode loop applies it at a step boundary
-    — ``drain`` finishes in-flight requests on the OLD model first
+    :class:`SwapTicket`; the decode loop applies it at a launch
+    boundary with nothing in flight — ``drain`` finishes in-flight requests on the OLD model first
     (admissions pause), ``reprefill`` flips immediately and restarts
     in-flight generation from the prompt on the NEW model — so every
     response's tokens come from exactly one model.  ``--rollout=false``
@@ -246,6 +306,9 @@ class InferenceServer:
                                if continuous is None else continuous)
         enforce(self.max_batch >= 1,
                 f"serve_max_batch must be >= 1, got {self.max_batch}")
+        # the one decode shape: sequential mode (the kill switch) is
+        # batch width 1 of the same loop
+        self._width = self.max_batch if self.continuous else 1
         self.snapshot_path = snapshot_path
         self.pool = self._make_pool(n_pages, page_size, snapshot_path)
         self._k_pool, self._v_pool = model.new_pools(n_pages, page_size)
@@ -256,6 +319,10 @@ class InferenceServer:
         self._cond = named_condition("serve.admission")
         self._queue: collections.deque = collections.deque()
         self._active: List[Request] = []
+        # launches queued on the device and not collected yet, oldest
+        # first: the decode thread's alone, at most two (one being
+        # collected, one ahead of the host)
+        self._inflight: collections.deque = collections.deque()
         self._stop = False
         self._thread: Optional[threading.Thread] = None
         self._httpd = None
@@ -284,6 +351,15 @@ class InferenceServer:
             "layer-pages (one layer's K and V of one page) that the "
             "rows of the latest decode step hold wholly behind a "
             "window layer's window")
+        self._m_launch = None if _counter is None else _counter(
+            "serve_launch_total",
+            "programs queued on the device by kind (decode | prefill) "
+            "and by what the device had when they were queued: behind "
+            "= a launch the host had not collected yet, idle = nothing")
+        self._m_discarded = None if _counter is None else _counter(
+            "serve_rows_discarded_total",
+            "rows a decode launch computed for a request that EOS had "
+            "already ended (the host learns of an EOS one launch late)")
 
     @staticmethod
     def _make_pool(n_pages: int, page_size: int,
@@ -480,8 +556,8 @@ class InferenceServer:
         self._publish_serving_info()
 
     def _apply_swap_locked(self, ticket: SwapTicket) -> List[Request]:
-        """Apply a parked swap at the decode-loop boundary (``_cond``
-        held).  Returns the in-flight requests to re-prefill on the new
+        """Apply a parked swap at a launch boundary with nothing in
+        flight (``_cond`` held).  Returns the in-flight requests to re-prefill on the new
         model (``reprefill`` policy; empty under ``drain``, which only
         gets here with no actives).  Failure to stand up the new pools
         rolls back — the old model/pools were never unhooked."""
@@ -562,33 +638,69 @@ class InferenceServer:
         while True:
             with self._cond:
                 while not self._stop and not self._queue \
-                        and not self._active \
+                        and not self._active and not self._inflight \
                         and self._pending_swap is None:
                     self._cond.wait(0.05)
                 if self._stop:
-                    return
+                    break
             # there is work: whatever this thread does for it from here
             # to the next wait lies under one span
             with _span("serve_loop_iter"):
-                self._iterate()
+                try:
+                    ran = self._turn()
+                except Exception as e:  # noqa: BLE001 - one bad batch
+                    # must not kill the serve loop: fail its requests,
+                    # recycle their pages, keep serving the queue
+                    self._fail_active(e)
+                    ran = True
+                if ran and self.snapshot_path:
+                    with _span("serve_snapshot"):
+                        self.pool.snapshot(self.snapshot_path)
+        self._drain()        # stopped: nothing stays queued on the device
 
-    def _iterate(self) -> None:
-        """One turn of the decode loop: apply a parked swap, admit,
-        prefill the admitted, advance the active batch one token."""
+    def _turn(self) -> bool:
+        """One turn of the loop is one launch's span: from the collect
+        of the launch before it to its own.  Under it the NEXT launch is
+        built and queued behind it on the device, then its own ids are
+        collected and emitted; from idle its own build and dispatch come
+        first.  So the device goes from one launch into the next while
+        the host works a launch ahead, and the spans tile this thread's
+        time in launch order."""
+        idle = not self._inflight
+        cur = self._next_launch() if idle else self._inflight[0]
+        if cur is None:
+            return False
+        with (_span("serve_prefill", **cur.attrs) if cur.kind == "prefill"
+              else _span("serve_decode_step", **cur.attrs)) as step:
+            if idle:
+                self._launch(cur)
+            ahead = self._next_launch()
+            if ahead is not None:
+                self._launch(ahead)
+            self._collect(step)
+        return True
+
+    def _next_launch(self) -> Optional[_Launch]:
+        """What to queue now, behind whatever is in flight: a parked
+        swap is applied first (only with nothing in flight, so no launch
+        straddles the flip), then the admitted are prefilled, else the
+        active rows advance one token."""
+        reprefill: List[Request] = []
         swapped = False
         with self._cond:
             if self._stop:
-                return
-            reprefill: List[Request] = []
+                return None
             pending = self._pending_swap
             if pending is not None and (pending.inflight == "reprefill"
                                         or not self._active):
-                # the atomic pointer flip, at the step boundary.
-                # drain policy only flips once the actives emptied;
-                # reprefill flips now and restarts them below
+                # the atomic pointer flip, at a launch boundary.  drain
+                # policy only flips once the actives emptied; reprefill
+                # flips now and restarts them below.  Either waits for
+                # what the old model still runs to be collected
+                if self._inflight:
+                    return None
                 reprefill = self._apply_swap_locked(pending)
-                pending = None
-                swapped = True
+                pending, swapped = None, True
             # a pending drain swap pauses admission: new requests
             # must first-run on the NEW model, and the flip waits
             # for the actives to finish on the old one
@@ -598,47 +710,18 @@ class InferenceServer:
             self._publish_serving_info()
         for r in admitted:
             r.record_span("serve_queue_wait", r.t_admit)
-        try:
-            batch = reprefill + admitted
-            changed = bool(batch)
-            if batch:
-                self._prefill(batch)
-            if self._active:
-                self._decode_step()
-                changed = True
-        except Exception as e:  # noqa: BLE001 - one bad batch must
-            # not kill the serve loop: fail its requests, recycle
-            # their pages, keep serving the queue
-            log.exception("decode loop error; failing %d in-flight "
-                          "request(s)", len(self._active))
-            with self._cond:
-                failed, self._active = self._active, []
-            for r in failed:
-                self.pool.release(r.id)
-                r.state = "failed"
-                r.error = f"{type(e).__name__}: {e}"
-                r.done.set()
-                if _histogram is not None:
-                    # unit events: window_rate = failures/s — the
-                    # canary bake's error-rate signal and the
-                    # --slo rate-objective source
-                    _histogram("serve_request_failures",
-                               "failed requests as unit events "
-                               "(windowed rate = failures/sec)"
-                               ).observe(1.0)
-            changed = True
-        if changed and self.snapshot_path:
-            with _span("serve_snapshot"):
-                self.pool.snapshot(self.snapshot_path)
+        if reprefill or admitted:
+            return self._plan_prefill(reprefill + admitted)
+        return self._plan_decode()
 
     def _admit_locked(self) -> List[Request]:
         """Move requests queue → active while a batch slot and enough
         free pages exist.  Sequential mode (the kill switch) admits one
         request only when the batch is empty — single-request serving."""
-        cap = self.max_batch if self.continuous else 1
         admitted: List[Request] = []
         with _span("serve_admit", queued=len(self._queue)):
-            while self._queue and len(self._active) + len(admitted) < cap:
+            while self._queue \
+                    and len(self._active) + len(admitted) < self._width:
                 r = self._queue[0]
                 try:
                     self.pool.alloc(
@@ -654,31 +737,75 @@ class InferenceServer:
             self._publish_queue_locked()
         return admitted
 
-    def _table_row(self, r: Request) -> List[int]:
-        t = self.pool.table_of(r.id)
-        return t + [SCRATCH_PAGE] * (self.max_pages - len(t))
+    def _queued(self) -> str:
+        """``behind`` a launch the host has not collected, or on an
+        ``idle`` device (start, after a drain, after a lone prefill)."""
+        return "behind" if self._inflight else "idle"
 
-    def _prefill(self, admitted: List[Request]) -> None:
+    def _plan_prefill(self, admitted: List[Request]) -> _Launch:
         """One packed launch for every request admitted this round;
         produces each request's first generated token (TTFT)."""
-        b = len(admitted)
         t_pad = max(len(r.prompt) for r in admitted)
         # bucket the pad length: bounded set of compiled prefill shapes
         t_pad = -(-t_pad // 16) * 16
         t_pad = min(t_pad, self.model.cfg.max_context)
         prompt_tokens = sum(len(r.prompt) for r in admitted)
-        with _span("serve_prefill", n=b, t_pad=t_pad,
-                   prompt_tokens=prompt_tokens,
-                   moe_tokens=prompt_tokens * self.model.routed_layers,
-                   requests=",".join(r.id for r in admitted)):
+        for r in admitted:
+            t = self.pool.table_of(r.id)
+            r.table = np.array(
+                t + [SCRATCH_PAGE] * (self.max_pages - len(t)), np.int32)
+        return _Launch("prefill", admitted, dict(
+            n=len(admitted), t_pad=t_pad, prompt_tokens=prompt_tokens,
+            moe_tokens=prompt_tokens * self.model.routed_layers,
+            requests=",".join(r.id for r in admitted),
+            queued=self._queued()))
+
+    def _plan_decode(self) -> Optional[_Launch]:
+        """The active rows advance one token in a single fixed-width
+        paged-attention launch.  A row of the decode launch in flight is
+        fed from it on the device, unless that launch ends it by its
+        budget; whether it ended by EOS the host learns one launch late,
+        and such a row rides along dead (:meth:`_collect` drops its
+        token).  A row whose prefill is in flight joins at the launch
+        after this one, from the host."""
+        behind = self._inflight[-1] if self._inflight else None
+        flying = {} if behind is None \
+            else {id(r): i for i, r in enumerate(behind.rows)}
+        rows, src = [], []
+        for r in self._active:
+            i = flying.get(id(r), -1)
+            if i >= 0 and (behind.kind == "prefill"
+                           or len(r.tokens) + 1 >= r.max_new_tokens):
+                continue
+            rows.append(r)
+            src.append(i)
+        if not rows:
+            return None
+        enforce(len(rows) <= self._width,
+                f"active {len(rows)} exceeds batch width {self._width}")
+        # what the step attends over: each row's length INCLUDING the
+        # token it feeds, and the pages those lengths occupy
+        fed = [r.length + 1 for r in rows]
+        return _Launch("decode", rows, dict(
+            batch=len(rows), live_tokens=sum(fed),
+            live_pages=sum(map(self.pool.pages_needed, fed)),
+            attended_tokens=self.model.attended_tokens(fed),
+            queued=self._queued()), src)
+
+    def _launch(self, launch: _Launch) -> None:
+        """Build the launch's inputs and queue it on the device."""
+        rows, n = launch.rows, len(launch.rows)
+        if self._m_launch is not None:
+            self._m_launch.inc(kind=launch.kind,
+                               queued=launch.attrs["queued"])
+        if launch.kind == "prefill":
             with _span("serve_step_build"):
-                tokens = np.zeros((b, t_pad), np.int32)
-                lengths = np.zeros((b,), np.int32)
-                tables = np.zeros((b, self.max_pages), np.int32)
-                for i, r in enumerate(admitted):
+                tokens = np.zeros((n, launch.attrs["t_pad"]), np.int32)
+                for i, r in enumerate(rows):
                     tokens[i, :len(r.prompt)] = r.prompt
-                    lengths[i] = len(r.prompt)
-                    tables[i] = self._table_row(r)
+                    r.length = len(r.prompt)
+                lengths = np.array([r.length for r in rows], np.int32)
+                tables = np.stack([r.table for r in rows])
             # testing knob: a seeded-slow artifact (manifest
             # debug_prefill_delay_ms) inflates TTFT here — inside the
             # TTFT stamp, before the launch — so a canary bake has a
@@ -687,62 +814,103 @@ class InferenceServer:
             delay = getattr(self.model, "debug_prefill_delay_s", 0.0)
             if delay:
                 time.sleep(delay)
-            nxt, _, self._k_pool, self._v_pool = self.model.prefill(
+            launch.handle = self.model.launch_prefill(
                 self._k_pool, self._v_pool, tokens, lengths, tables)
-            with _span("serve_step_emit"):
-                now = time.perf_counter()
-                self._count_tokens(b)
-                for i, r in enumerate(admitted):
-                    r.length = len(r.prompt)
+        else:
+            with _span("serve_step_build"):
+                b = self._width
+                tokens = np.zeros((b,), np.int32)
+                src = np.full((b,), -1, np.int32)
+                lengths = np.ones((b,), np.int32)
+                active = np.zeros((b,), bool)
+                tables = np.full((b, self.max_pages), SCRATCH_PAGE,
+                                 np.int32)
+                for i, r in enumerate(rows):
+                    if launch.src[i] < 0:
+                        tokens[i] = r.next_token
+                    r.length += 1
+                    lengths[i] = r.length
+                    tables[i] = r.table
+                src[:n] = launch.src
+                active[:n] = True
+            if self._m_batch is not None:
+                self._m_batch.set(n)
+            if self._m_behind is not None and self.model.cfg.window:
+                self._m_behind.set(self.model.pages_behind_window(
+                    lengths[:n].tolist(), self.pool.page_size))
+            # rows with ``src`` >= 0 take their ids from the launch this
+            # one is queued behind, on the device
+            prev = self._inflight[-1].handle if max(launch.src) >= 0 \
+                else None
+            launch.handle = self.model.launch_decode(
+                self._k_pool, self._v_pool, tokens, tables, lengths,
+                active, prev, src)
+        self._inflight.append(launch)
+
+    def _collect(self, step) -> None:
+        """Wait for the oldest launch in flight, bring its ids back and
+        emit them; ``step`` is its span.  A row whose request ended
+        while the launch was queued (EOS, learned a launch late) is
+        dropped: not emitted, not counted."""
+        launch = self._inflight.popleft()
+        handle, launch.handle = launch.handle, None
+        if launch.kind == "prefill":
+            ids, _ = self.model.collect_prefill(handle)
+        else:
+            ids, _, routed = self.model.collect_decode(handle)
+            step.set(**routed)      # experts_hit, expert_load_max
+        with _span("serve_step_emit"):
+            now = time.perf_counter()
+            live = [(r, t) for r, t in zip(launch.rows, ids.tolist())
+                    if r.state == "active"]
+            self._count_tokens(len(live))
+            for r, token in live:
+                if launch.kind == "prefill":
                     r.t_first = now
                     if _histogram is not None:
                         _histogram("serve_ttft_seconds",
                                    "submit-to-first-token latency"
                                    ).observe(now - r.t_submit)
-                    self._emit_token(r, int(nxt[i]))
+                self._emit_token(r, token)
+            dead = len(launch.rows) - len(live)
+            if dead:
+                if self._m_discarded is not None:
+                    self._m_discarded.inc(dead)
+                step.set(batch=len(live), discarded=dead)
 
-    def _decode_step(self) -> None:
-        """Advance every active request one token in a single
-        fixed-width paged-attention launch; retire finished requests
-        and recycle their pages at the step boundary."""
-        slots = list(self._active)
-        b = self.max_batch if self.continuous else 1
-        enforce(len(slots) <= b,
-                f"active {len(slots)} exceeds batch width {b}")
-        # what the step attends over: each row's length INCLUDING the
-        # token it feeds, and the pages those lengths occupy
-        fed = [r.length + 1 for r in slots]
-        with _span("serve_decode_step", batch=len(slots),
-                   live_tokens=sum(fed),
-                   live_pages=sum(map(self.pool.pages_needed, fed)),
-                   attended_tokens=self.model.attended_tokens(fed)
-                   ) as step:
-            with _span("serve_step_build"):
-                tokens = np.zeros((b,), np.int32)
-                lengths = np.ones((b,), np.int32)
-                active = np.zeros((b,), bool)
-                tables = np.full((b, self.max_pages), SCRATCH_PAGE,
-                                 np.int32)
-                for i, r in enumerate(slots):
-                    tokens[i] = r.next_token
-                    lengths[i] = fed[i]
-                    active[i] = True
-                    tables[i] = self._table_row(r)
-            if self._m_batch is not None:
-                self._m_batch.set(len(slots))
-            if self._m_behind is not None and self.model.cfg.window:
-                self._m_behind.set(self.model.pages_behind_window(
-                    fed, self.pool.page_size))
-            nxt, _, self._k_pool, self._v_pool, routed = \
-                self.model.decode(
-                    self._k_pool, self._v_pool, tokens, tables, lengths,
-                    active)
-            step.set(**routed)      # experts_hit, expert_load_max
-            with _span("serve_step_emit"):
-                self._count_tokens(len(slots))
-                for i, r in enumerate(slots):
-                    r.length += 1
-                    self._emit_token(r, int(nxt[i]))
+    def _drain(self) -> None:
+        """Collect whatever is still in flight, outside its span: after
+        a stop or a failed turn.  A launch that cannot be collected is
+        dropped."""
+        while self._inflight:
+            try:
+                self._collect(_NO_SPAN)
+            except Exception:  # noqa: BLE001 - the failure is the
+                # caller's to report; the loop must end with no launch
+                log.exception("a launch in flight could not be collected")
+
+    def _fail_active(self, e: Exception) -> None:
+        """A turn raised: what the device still has is collected first
+        (a token it made belongs to its request), then every request
+        still active fails and its pages recycle."""
+        log.exception("decode loop error; failing %d in-flight "
+                      "request(s)", len(self._active))
+        self._drain()
+        with self._cond:
+            failed, self._active = self._active, []
+        for r in failed:
+            self.pool.release(r.id)
+            r.state = "failed"
+            r.error = f"{type(e).__name__}: {e}"
+            r.done.set()
+            if _histogram is not None:
+                # unit events: window_rate = failures/s — the
+                # canary bake's error-rate signal and the
+                # --slo rate-objective source
+                _histogram("serve_request_failures",
+                           "failed requests as unit events "
+                           "(windowed rate = failures/sec)"
+                           ).observe(1.0)
 
     def _count_tokens(self, n: int) -> None:
         """``n`` tokens came back from one launch."""

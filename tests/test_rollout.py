@@ -461,6 +461,61 @@ def test_swap_boundary_exactly_one_model(cfg, policy):
         assert srv.generate(prompt, max_new, timeout=120.0) == ref_new
 
 
+@pytest.mark.parametrize("policy", ["drain", "reprefill"])
+def test_swap_parked_with_a_launch_in_flight(cfg, policy):
+    """The loop keeps a launch queued ahead of the host.  A swap parked
+    while one is in flight (parked from inside the third decode launch,
+    so one surely is): everything the old model launched is collected
+    before the flip, the flip finds nothing in flight, and no launch or
+    collect of the old model follows it."""
+    prompt, max_new = [2, 3, 4, 5], 16
+    ref = _ref_tokens(cfg, 0 if policy == "drain" else 1, prompt, max_new)
+    old, new = _model(cfg, 0), _model(cfg, 1)
+    log, tickets = [], []
+
+    def tell(model, who):
+        for name in ("launch_prefill", "launch_decode", "collect_prefill",
+                     "collect_decode"):
+            def told(*args, _real=getattr(model, name), _name=name, **kw):
+                out = _real(*args, **kw)
+                log.append((_name.split("_")[0], who))
+                if (_name, who) == ("launch_decode", "old") \
+                        and not tickets and log.count(
+                            ("launch", "old")) == 4:   # prefill + 3
+                    tickets.append(srv.request_swap(
+                        new, version="v-new", inflight=policy))
+                return out
+            setattr(model, name, told)
+
+    from paddle_tpu.serving.server import InferenceServer
+
+    srv = InferenceServer(old, max_batch=4, n_pages=33, page_size=8)
+    flip = srv._apply_swap_locked
+
+    def flipped(ticket):
+        log.append(("flip", len(srv._inflight)))
+        return flip(ticket)
+    srv._apply_swap_locked = flipped
+    tell(old, "old")
+    tell(new, "new")
+    with srv:
+        r = srv.submit(prompt, max_new)
+        toks = srv.result(r, timeout=120.0)
+        assert tickets and tickets[0].wait(120.0)["result"] == "ok"
+    assert toks == ref
+    at = log.index(("flip", 0))                 # nothing in flight
+    before, after = log[:at], log[at + 1:]
+    assert {who for _, who in before} == {"old"}
+    assert before.count(("launch", "old")) \
+        == before.count(("collect", "old")) >= 4
+    assert ("launch", "old") not in after and ("collect", "old") not in after
+    if policy == "reprefill":
+        assert after.count(("launch", "new")) \
+            == after.count(("collect", "new")) >= 2
+    else:                 # the old model finished the request first
+        assert after == [] and len(before) == 2 * max_new
+
+
 def test_kill_switch_server_byte_identical(cfg):
     """--rollout=false: stats()/healthz carry NO rollout keys, /v1/swap
     does not exist (404 body byte-identical to the pre-rollout server),

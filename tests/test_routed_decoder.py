@@ -465,8 +465,11 @@ def test_a_step_leaves_the_pools_as_a_layer_at_a_time_would(decoder):
     prefill = _recorded(decoder_module._prefill_impl, cfg)
     out, rows = prefill(decoder.params, jnp.asarray(want_k),
                         jnp.asarray(want_v), tokens, lengths, tables[:b])
-    nxt, _, k, v = decoder.prefill(k, v, tokens, lengths, tables[:b])
+    nxt, logits, k, v = decoder.prefill(k, v, tokens, lengths, tables[:b])
     np.testing.assert_array_equal(nxt, np.asarray(out[0]))
+    # the ids came to the host, the logits stayed on the device
+    assert isinstance(nxt, np.ndarray) and isinstance(logits, jax.Array)
+    np.testing.assert_array_equal(_bits(logits), _bits(out[1]))
     check(out, rows, np.zeros((b,), np.int32), lengths, tables[:b])
     assert np.abs(np.asarray(want_k, np.float32)).sum(axis=(1, 2, 3)).all()
 
@@ -479,14 +482,83 @@ def test_a_step_leaves_the_pools_as_a_layer_at_a_time_would(decoder):
         klen = np.where(active, np.resize(lengths, width), 1).astype(np.int32)
         out, rows = decode(decoder.params, jnp.asarray(want_k),
                            jnp.asarray(want_v), fed, tables, klen, active)
-        nxt, _, k, v, _ = decoder.decode(k, v, fed, tables, klen, active)
+        nxt, logits, k, v, _ = decoder.decode(k, v, fed, tables, klen,
+                                              active)
         np.testing.assert_array_equal(nxt, np.asarray(out[0])[:width])
+        assert isinstance(nxt, np.ndarray) and isinstance(logits, jax.Array)
+        np.testing.assert_array_equal(_bits(logits), _bits(out[1]))
         check(out, rows, klen - 1, active.astype(np.int32), tables)
     # the pages no row holds, each layer's scratch page among them,
     # were never written
     free = np.setdiff1d(np.arange(n_pages), tables[:b])
     assert SCRATCH_PAGE in free
     assert not _bits(want_k[:, free]).any() and not _bits(want_v[:, free]).any()
+
+
+def test_a_launch_fed_from_the_one_before_is_the_host_fed_step(decoder):
+    """Five decode launches queued one behind the other, each row fed
+    on the device from whatever slot it had in the launch before (the
+    rows change slots every step) and no id read in between, give bit
+    for bit the ids, the logits and the pools of five steps whose ids
+    the host read and fed back; and both ways run one program."""
+    cfg, page, width = decoder.cfg, 4, 4
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(2, cfg.vocab, n).tolist() for n in (17, 6, 25)]
+    b, slots = len(prompts), cfg.max_context // page
+    tables = 1 + rng.permutation(b * slots).reshape(b, slots) \
+        .astype(np.int32)
+    tokens = np.zeros((b, 32), np.int32)
+    for i, p in enumerate(prompts):
+        tokens[i, :len(p)] = p
+    lengths = np.array([len(p) for p in prompts], np.int32)
+
+    def start():
+        k, v = decoder.new_pools(1 + width * slots, page)
+        first, _, k, v = decoder.prefill(k, v, tokens, lengths, tables)
+        return k, v, first
+
+    def inputs(step, order):
+        """Row r sits in slot order[r] of this step."""
+        tab = np.zeros((width, slots), np.int32)
+        klen = np.ones((width,), np.int32)
+        active = np.zeros((width,), bool)
+        tab[order], klen[order], active[order] = \
+            tables, lengths + step + 1, True
+        return tab, klen, active
+
+    orders = [rng.permutation(width)[:b] for _ in range(5)]
+    k, v, nxt = start()
+    want = []
+    for step, order in enumerate(orders):
+        tab, klen, active = inputs(step, order)
+        fed = np.zeros((width,), np.int32)
+        fed[order] = nxt
+        ids, logits, k, v, _ = decoder.decode(k, v, fed, tab, klen, active)
+        nxt = ids[order]
+        want.append((nxt, np.asarray(logits)[order]))
+    want_k, want_v = np.asarray(k.array), np.asarray(v.array)
+
+    programs = decoder._decode._cache_size()
+    k, v, first = start()
+    launches, prev = [], None
+    for step, order in enumerate(orders):
+        tab, klen, active = inputs(step, order)
+        fed = np.zeros((width,), np.int32)
+        src = np.full((width,), -1, np.int32)
+        if prev is None:
+            fed[order] = first
+        else:
+            src[order] = orders[step - 1]
+        prev = decoder.launch_decode(k, v, fed, tab, klen, active, prev, src)
+        launches.append(prev)
+    assert decoder._decode._cache_size() == programs
+    for launch, order, (ids, logits) in zip(launches, orders, want):
+        got, got_logits, _ = decoder.collect_decode(launch)
+        np.testing.assert_array_equal(got[order], ids)
+        np.testing.assert_array_equal(
+            _bits(np.asarray(got_logits)[order]), _bits(logits))
+    np.testing.assert_array_equal(_bits(k.array), _bits(want_k))
+    np.testing.assert_array_equal(_bits(v.array), _bits(want_v))
 
 
 def test_a_step_donates_its_pools(decoder):
@@ -538,8 +610,17 @@ def test_the_benchmarks_warm_up_leaves_the_servers_pages(decoder):
                 _bits(now[:, SCRATCH_PAGE + 1:]),
                 _bits(was[:, SCRATCH_PAGE + 1:]))
             assert _bits(now[:, SCRATCH_PAGE]).any()
+        # what the loop launches from here on, warm has compiled: a
+        # prompt length that only warm has run, and decode launches fed
+        # from the device where warm's were fed from the host
+        programs = (decoder._prefill._cache_size(),
+                    decoder._decode._cache_size())
         again = server.submit(prompt, 6)
-        assert again.done.wait(120) and again.state == "done", again.error
+        longer = server.submit(prompt + prompt + [7, 8, 9], 6)
+        for r in (again, longer):
+            assert r.done.wait(120) and r.state == "done", r.error
         assert again.tokens == first.tokens
+        assert (decoder._prefill._cache_size(),
+                decoder._decode._cache_size()) == programs
     finally:
         server.stop()

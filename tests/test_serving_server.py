@@ -242,6 +242,169 @@ def test_kill_switch_flag_driven(tiny_model):
     assert outs[False] == outs[True]
 
 
+def _serial_greedy(model, prompt, max_new, page=8):
+    """The plain loop the server is held to: one request alone, prefill
+    then one width-1 decode step a token through ``model.prefill`` /
+    ``model.decode``, the host reading every id before the next step."""
+    slots = -(-model.cfg.max_context // page)
+    k, v = model.new_pools(1 + slots, page)
+    table = np.arange(1, 1 + slots, dtype=np.int32)[None, :]
+    tokens = np.zeros((1, -(-len(prompt) // 16) * 16), np.int32)
+    tokens[0, :len(prompt)] = prompt
+    nxt, _, k, v = model.prefill(k, v, tokens,
+                                 np.array([len(prompt)], np.int32), table)
+    out, length = [int(nxt[0])], len(prompt)
+    while out[-1] != model.cfg.eos_id and len(out) < max_new:
+        length += 1
+        nxt, _, k, v, _ = model.decode(
+            k, v, np.array([out[-1]], np.int32), table,
+            np.array([length], np.int32), np.array([True]))
+        out.append(int(nxt[0]))
+    return out
+
+
+@pytest.mark.parametrize("continuous", [True, False])
+def test_served_tokens_are_the_serial_greedy_loops(tiny_model, continuous):
+    """The loop runs a launch ahead of the host and feeds rows from the
+    device; request for request it serves what a serial loop serves,
+    under admissions that arrive while others decode."""
+    from paddle_tpu.serving.server import InferenceServer
+
+    prompts = _prompts(9, seed=31)
+    budgets = [1, 2, 9, 5, 12, 3, 7, 12, 4]
+    want = [_serial_greedy(tiny_model, p, n)
+            for p, n in zip(prompts, budgets)]
+    with InferenceServer(tiny_model, max_batch=3, n_pages=33, page_size=8,
+                         continuous=continuous) as srv:
+        reqs = []
+        for p, n in zip(prompts, budgets):
+            reqs.append(srv.submit(p, n))
+            # the next one arrives once this one is decoding (or done)
+            assert _wait(lambda: reqs[-1].tokens or reqs[-1].done.is_set())
+        got = [srv.result(r, timeout=120.0) for r in reqs]
+        assert got == want
+        assert srv.generated_tokens == sum(map(len, want))
+        assert srv.pool.used_pages() == 0 and not srv._inflight
+
+
+def _wait(pred, timeout_s=60.0):
+    import time
+
+    end = time.monotonic() + timeout_s
+    while not pred():
+        if time.monotonic() > end:
+            return False
+        time.sleep(0.001)
+    return True
+
+
+def test_a_row_launched_past_its_eos_is_dropped(tiny_model):
+    """EOS mid-stream: the host learns of it one launch late, so the row
+    rides in the next launch dead.  Its extra token is neither emitted
+    nor counted, ``serve_rows_discarded_total`` counts the row, and a
+    request admitted into the recycled pages generates what it
+    generates alone."""
+    from paddle_tpu import observe
+    from paddle_tpu.serving.model import DecoderModel
+    from paddle_tpu.serving.server import InferenceServer
+
+    prompts = _prompts(4, seed=41)
+    free = _serial_greedy(tiny_model, prompts[0], 12)
+    assert len(free) == 12                  # no EOS of its own
+    stop_at = next(i for i in range(3, 9) if free[i] not in free[:i])
+    # the same weights, with the id of that step as the end of sequence
+    model = DecoderModel(
+        {k: np.asarray(v) for k, v in tiny_model.params.items()},
+        tiny_model.cfg._replace(eos_id=free[stop_at]))
+    want = [_serial_greedy(model, p, 12) for p in prompts]
+    assert want[0] == free[:stop_at + 1]
+    discarded = observe.counter("serve_rows_discarded_total", "")
+    before = discarded.value()
+    # 4 pages of 8: two requests of prompt <= 8 plus 12 hold all of
+    # them, so the third waits for the pages the first gives back
+    with InferenceServer(model, max_batch=4, n_pages=5,
+                         page_size=8) as srv:
+        reqs = [srv.submit(p, 12) for p in prompts]
+        got = [srv.result(r, timeout=120.0) for r in reqs]
+        assert got == want
+        assert srv.generated_tokens == sum(map(len, got))
+        assert srv.pool.used_pages() == 0
+    dead = sum(len(t) < 12 for t in want)
+    assert dead >= 1 and discarded.value() - before == dead
+
+
+def _faulty_launches(model, fail_at=None, on_launch=None):
+    """``model.launch_decode`` wrapped on the instance: the ``fail_at``-th
+    call raises, and ``on_launch(n)`` runs after each launch is queued."""
+    real, calls = model.launch_decode, []
+
+    def launch_decode(*args, **kw):
+        calls.append(1)
+        if len(calls) == fail_at:
+            raise RuntimeError("planted launch failure")
+        out = real(*args, **kw)
+        if on_launch is not None:
+            on_launch(len(calls))
+        return out
+    model.launch_decode = launch_decode
+    return calls
+
+
+def test_a_failing_launch_with_one_in_flight_fails_its_requests(tiny_model):
+    """The third decode launch raises while the second is queued on the
+    device: that one is collected, every active request fails, no page
+    stays held, and the loop serves the next request."""
+    from paddle_tpu.serving.server import InferenceServer
+
+    try:
+        _faulty_launches(tiny_model, fail_at=3)
+        with InferenceServer(tiny_model, max_batch=4, n_pages=33,
+                             page_size=8) as srv:
+            reqs = [srv.submit(p, 10) for p in _prompts(3, seed=43)]
+            assert all(r.done.wait(120.0) for r in reqs)
+            assert {r.state for r in reqs} <= {"done", "failed"}
+            failed = [r for r in reqs if r.state == "failed"]
+            assert failed and all("planted launch failure" in r.error
+                                  for r in failed)
+            # its prefill and both decode launches before the failing
+            # one were collected, the second of them by the failure path
+            assert max(len(r.tokens) for r in failed) == 3
+            assert srv.pool.used_pages() == 0 and not srv._inflight
+            srv.pool.verify()
+            again = srv.generate(_prompts(1, seed=44)[0], 6, timeout=120.0)
+            assert again == _serial_greedy(tiny_model,
+                                           _prompts(1, seed=44)[0], 6)
+    finally:
+        del tiny_model.launch_decode
+
+
+def test_stop_with_a_launch_in_flight_leaves_nothing(tiny_model):
+    """``stop()`` while the loop has a launch queued ahead: the thread
+    ends, nothing stays in flight, no page is held, every request is
+    done or failed."""
+    from paddle_tpu.serving.server import (DECODE_THREAD_NAME,
+                                           InferenceServer)
+
+    queued = threading.Event()
+    try:
+        _faulty_launches(
+            tiny_model, on_launch=lambda n: n >= 3 and queued.set())
+        srv = InferenceServer(tiny_model, max_batch=4, n_pages=33,
+                              page_size=8).start()
+        reqs = [srv.submit(p, 40) for p in _prompts(6, seed=45)]
+        assert queued.wait(60.0)
+        srv.stop()
+    finally:
+        del tiny_model.launch_decode
+    assert DECODE_THREAD_NAME not in [t.name for t in threading.enumerate()]
+    assert not srv._inflight and srv.pool.used_pages() == 0
+    srv.pool.verify()
+    assert all(r.done.is_set() and r.state in ("done", "failed")
+               for r in reqs)
+    assert any(r.state == "failed" and r.error == "server stopped"
+               for r in reqs)
+
+
 def test_submit_validation(tiny_model):
     from paddle_tpu.serving.server import InferenceServer
 
@@ -281,19 +444,19 @@ def test_server_telemetry(tiny_model):
 def _traced_serve(model, prompts, max_new=6):
     """Serve ``prompts`` with the ring on.  → (server, requests, the
     ring's events, the ``lengths`` and ``active`` of every decode
-    launch as the model got them)."""
+    launch as the model got them, in launch order)."""
     from paddle_tpu.observe import trace
     from paddle_tpu.serving.server import InferenceServer
 
     fed = []
-    real = model.decode
+    real = model.launch_decode
 
-    def decode(k, v, tokens, tables, lengths, active):
+    def launch_decode(k, v, tokens, tables, lengths, active, *feed):
         fed.append((np.array(lengths), np.array(active)))
-        return real(k, v, tokens, tables, lengths, active)
+        return real(k, v, tokens, tables, lengths, active, *feed)
 
     trace.enable(fences=False)
-    model.decode = decode
+    model.launch_decode = launch_decode
     try:
         with InferenceServer(model, max_batch=4, n_pages=33,
                              page_size=8) as srv:
@@ -302,7 +465,7 @@ def _traced_serve(model, prompts, max_new=6):
                 srv.result(r, timeout=120.0)
         events = trace.events()
     finally:
-        del model.decode
+        del model.launch_decode
         trace.disable()
     return srv, reqs, events, fed
 
@@ -347,31 +510,55 @@ def test_a_request_joins_its_submitters_trace(tiny_model):
     assert Request([2, 3], 2).trace_id != inside.trace_id
 
 
-def test_a_step_is_split_into_host_phases(tiny_model):
-    """Every launch span has its four phases as children, every launch
-    lies under a ``serve_loop_iter``, and the spans' counts add up to
-    the tokens the server generated."""
-    srv, reqs, events, _ = _traced_serve(tiny_model, _prompts(6, seed=22))
-    by_parent = {}
+def _launch_spans(events):
+    """The launch spans in launch order (they tile the loop thread's
+    time, so by start), and every span's children by name."""
+    children = {}
     for e in events:
-        by_parent.setdefault(e["args"].get("parent_id"), []).append(
-            e["name"])
+        children.setdefault(e["args"].get("parent_id"), []).append(e)
+    launches = sorted(_named(events, "serve_decode_step")
+                      + _named(events, "serve_prefill"),
+                      key=lambda e: e["ts"])
+    return launches, children
+
+
+def _kind(e):
+    return "prefill" if e["name"] == "serve_prefill" else "decode"
+
+
+def test_a_step_is_split_into_host_phases(tiny_model):
+    """The launch-ordered layout: a ``serve_loop_iter`` holds one launch
+    span; under launch k's span lie the fetch and the emit of launch k
+    and the build and dispatch of launch k+1 (queued ``behind`` it), or
+    launch k's own when the device was ``idle``; and the spans' counts
+    add up to the tokens the server generated."""
+    srv, reqs, events, _ = _traced_serve(tiny_model, _prompts(6, seed=22))
+    launches, children = _launch_spans(events)
     iters = {e["args"]["span_id"] for e in _named(events,
                                                   "serve_loop_iter")}
     steps, prefills = (_named(events, "serve_decode_step"),
                        _named(events, "serve_prefill"))
     assert steps and prefills
-    for e in steps:
-        assert sorted(by_parent[e["args"]["span_id"]]) == sorted(
-            ["serve_step_build", "decode_dispatch", "decode_fetch",
-             "serve_step_emit"])
-    for e in prefills:
-        assert sorted(by_parent[e["args"]["span_id"]]) == sorted(
-            ["serve_step_build", "prefill_dispatch", "prefill_fetch",
-             "serve_step_emit"])
-        assert e["args"]["t_pad"] % 16 == 0
-    for e in steps + prefills + _named(events, "serve_admit"):
+    assert len({e["args"]["parent_id"] for e in launches}) == len(launches)
+    for k, e in enumerate(launches):
         assert e["args"]["parent_id"] in iters
+        under = sorted(children[e["args"]["span_id"]],
+                       key=lambda c: c["ts"])
+        names = [c["name"] for c in under]
+        own = ["serve_step_build", _kind(e) + "_dispatch"] \
+            if e["args"]["queued"] == "idle" else []
+        nxt = launches[k + 1] if k + 1 < len(launches) else None
+        ahead = ["serve_step_build", _kind(nxt) + "_dispatch"] \
+            if nxt is not None and nxt["args"]["queued"] == "behind" else []
+        assert [n for n in names if n != "serve_admit"] == \
+            own + ahead + [_kind(e) + "_fetch", "serve_step_emit"]
+        # the admission check of the next launch's planning (the
+        # launch's own, from idle, ran before the span opened)
+        assert names.count("serve_admit") <= 1
+    assert launches[0]["args"]["queued"] == "idle"
+    assert any(e["args"]["queued"] == "behind" for e in steps)
+    for e in prefills:
+        assert e["args"]["t_pad"] % 16 == 0
     assert sum(e["args"]["batch"] for e in steps) \
         + sum(e["args"]["n"] for e in prefills) == srv.generated_tokens
     assert srv.generated_tokens == sum(len(r.tokens) for r in reqs)
@@ -381,15 +568,50 @@ def test_a_step_is_split_into_host_phases(tiny_model):
     assert sorted(admitted) == sorted(r.id for r in reqs)
 
 
+def test_a_launch_span_runs_from_collect_to_collect(tiny_model):
+    """Spans follow launches: the span that bears a launch's attributes
+    closes after that launch's fetch returned (and its emit), and opens
+    no earlier than the fetch of the launch before it returned, so the
+    spans tile the loop thread's time in launch order; and the counter
+    says of each launch what its span says."""
+    from paddle_tpu import observe
+
+    counted = observe.counter("serve_launch_total", "")
+    before = {(k, q): counted.value(kind=k, queued=q)
+              for k in ("decode", "prefill") for q in ("behind", "idle")}
+    _, _, events, _ = _traced_serve(tiny_model, _prompts(6, seed=25),
+                                    max_new=8)
+    launches, children = _launch_spans(events)
+    end = lambda e: e["ts"] + e["dur"]
+    fetched = []
+    for e in launches:
+        fetch, = [c for c in children[e["args"]["span_id"]]
+                  if c["name"] == _kind(e) + "_fetch"]
+        emit, = [c for c in children[e["args"]["span_id"]]
+                 if c["name"] == "serve_step_emit"]
+        assert e["ts"] <= fetch["ts"] and end(fetch) <= emit["ts"]
+        assert end(emit) <= end(e)
+        if fetched:
+            assert e["ts"] >= fetched[-1]
+        fetched.append(end(fetch))
+    for (k, q), was in before.items():
+        assert counted.value(kind=k, queued=q) - was == sum(
+            _kind(e) == k and e["args"]["queued"] == q for e in launches)
+
+
 def test_decode_span_states_the_kv_it_attends_over(tiny_model):
     """``live_tokens`` is the sum of the lengths the launch was fed for
-    its active rows, ``live_pages`` the pages those lengths occupy."""
+    its active rows, ``live_pages`` the pages those lengths occupy;
+    ``batch`` the rows whose token was emitted (a row launched past its
+    EOS is ``discarded``)."""
     srv, _, events, fed = _traced_serve(tiny_model, _prompts(5, seed=23),
                                         max_new=8)
-    steps = _named(events, "serve_decode_step")
+    steps = sorted(_named(events, "serve_decode_step"),
+                   key=lambda e: e["ts"])
     assert len(steps) == len(fed)
     for e, (lengths, active) in zip(steps, fed):
-        assert e["args"]["batch"] == int(active.sum())
+        assert e["args"]["batch"] + e["args"].get("discarded", 0) \
+            == int(active.sum())
         assert e["args"]["live_tokens"] == int(lengths[active].sum())
         assert e["args"]["live_pages"] == sum(
             srv.pool.pages_needed(int(n)) for n in lengths[active])
