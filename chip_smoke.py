@@ -79,6 +79,9 @@ FULL = {
                 # the routed decoder's row: 32 heads over 4 K/V heads of
                 # 128, pages of 64; (tokens, experts, a token, dim, width)
                 "decode_gqa": (8, 32, 4, 128, 128, 64, 8),
+                # the latent decoder's row: 32 heads over rows of 512 +
+                # 64 numbers in 640 lanes, pages of 64, 24 slots a row
+                "decode_latent": (8, 32, 512, 64, 256, 64, 24),
                 "moe": (256, 16, 4, 512, 256)},
     "resnet": {"depth": 50, "image": 224, "batch": 128, "classes": 1000},
     "seq2seq": {"B": 128, "S_LEN": 30, "T_LEN": 30, "V": 30000, "E": 512,
@@ -100,6 +103,7 @@ REHEARSAL = {
     "kernels": {"lstm": (8, 6, 128), "flash": (2, 256, 2, 32, 128),
                 "packed": (3, 32, 2, 32), "decode": (4, 2, 32, 32, 16, 4),
                 "decode_gqa": (4, 4, 2, 16, 32, 16, 4),
+                "decode_latent": (4, 4, 32, 8, 32, 16, 4),
                 "moe": (16, 4, 2, 32, 32)},
     "resnet": {"depth": 8, "image": 32, "batch": 8, "classes": 10},
     "seq2seq": {"B": 8, "S_LEN": 6, "T_LEN": 6, "V": 200, "E": 128,
@@ -685,6 +689,21 @@ def phase_kernels(S, ctx):
             qg, kg.reshape(pages_g, page_g, g, dg),
             vg.reshape(pages_g, page_g, g, dg), pidx_g, lens_g))
 
+    # decode over a latent cache: one row a token is every head's key
+    # and, in its leading lanes, its value; bfloat16, whole lane tiles
+    bl, hl, rank, rope, pages_l, page_l, slots_l = S["decode_latent"]
+    wl = -(-(rank + rope) // 128) * 128
+    ql = randn(bl, hl, wl, dtype=jnp.bfloat16)
+    rows_l = randn(pages_l, page_l, wl, dtype=jnp.bfloat16)
+    lens_l = jnp.asarray(rng.randint(1, slots_l * page_l, (bl,)), jnp.int32)
+    pidx_l = jnp.asarray(rng.permutation(pages_l - 1)[:bl * slots_l]
+                         .reshape(bl, slots_l) + 1, jnp.int32)
+    latent = lambda fn: jax.jit(lambda *a: fn(*a, rank, wl ** -0.5))(
+        ql, rows_l, pidx_l, lens_l)
+    errs["latent_decode"] = _rel_err(
+        latent(pa.latent_decode_attention),
+        latent(pa.latent_decode_reference))
+
     # routed experts: the grouped matmul over rows sorted by expert vs
     # every expert on every token
     from paddle_tpu.ops import pallas_moe
@@ -760,6 +779,18 @@ def phase_kernels(S, ctx):
                       "full+qknorm+gate+postnorm/routed+shared")),
             pages_g, page_g, pidx_g, lens_g,
             [K.instruction_pattern(K.PAGED_DECODE) + ".*tpu_custom_call"])
+        # and of a latent plan: one pool of compressed rows, donated
+        server_steps["latent"] = steps_of(
+            DecoderConfig(
+                vocab=512, dim=dm, heads=hl, layers=2, ffn=2 * dm,
+                max_context=slots_l * page_l, q_rank=3 * rank // 4,
+                kv_rank=rank, nope_dim=rank // 4, rope_dim=rope,
+                v_dim=rank // 4, rope_interleave=True, experts=e,
+                top_k=top_k, expert_ffn=f, route_scale=2.5,
+                pos_embed=False, storage="bfloat16",
+                plan=("latent+rope/swiglu", "latent+rope/routed+shared")),
+            pages_l, page_l, pidx_l, lens_l,
+            [K.instruction_pattern(K.LATENT_DECODE) + ".*tpu_custom_call"])
 
     # the trace can name the kernels: a Mosaic call compiles to an
     # instruction named after ops/kernels.py's table (its ``name=``),

@@ -53,6 +53,9 @@ FLASH_BWD_DKV_GRID = "flash_bwd_dkv_grid"
 #: kernel by the ``_lambda_`` of ``serving/model.py``'s jit (see the
 #: call site)
 PAGED_DECODE = "paged_decode"
+#: decode over a latent cache: one compressed row a token serves every
+#: query head as its key and, in its leading lanes, as its value
+LATENT_DECODE = "latent_decode"
 # ops/pallas_moe.py
 MOE_GMM = "moe_gmm"                      # grouped matmul, rows by expert
 # ops/pallas_embedding.py

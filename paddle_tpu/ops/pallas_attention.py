@@ -402,15 +402,17 @@ def _dense_forward(q, k, v, lengths, causal, segments=None, window=0):
 
 
 def _record_attn_work(kernel, bh, tq, tk, bq, bk, d, causal, slot,
-                      operands, results, window=0):
+                      operands, results, window=0, d_v=None):
     """The work account of one flash kernel (``ops/kernels.py``): 4·d
     FLOPs per (query, key) position of the statically live blocks —
     QKᵀ and PV forward; dP and dQ, or dV and dK, backward (the scores
-    a backward kernel recomputes are re-done work, not the op's)."""
+    a backward kernel recomputes are re-done work, not the op's).
+    Where the values are ``d_v`` wide and not ``d``: 2·(d + d_v)."""
     n_pairs = _pair_tables(tq, tk, bq, bk, causal, slot,
                            window)[0].shape[1]
-    K.record_kernel_work(kernel, 4.0 * d * bq * bk * n_pairs * bh,
-                         operands, results)
+    K.record_kernel_work(
+        kernel, 2.0 * (d + (d if d_v is None else d_v)) * bq * bk
+        * n_pairs * bh, operands, results)
 
 
 def _heads_first(a, b, t, h, d):
@@ -422,13 +424,15 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
                        segments=None, slot=0, window=0):
     """Pair-table (block-sparse) forward: grid (B·H, n_pairs).  With
     grouped KV heads (``k``/``v`` hold G < H heads) a grid row's k/v
-    blocks are its group's: no copy of K or V is made."""
+    blocks are its group's: no copy of K or V is made.  The values
+    may be another width than the queries and keys (``v``'s last
+    axis): the result has the values' width."""
     b, tq, h, d = q.shape
-    tk, g = k.shape[1], k.shape[2]
+    tk, g, d_v = k.shape[1], k.shape[2], v.shape[3]
     scale = 1.0 / np.sqrt(d)
     qh = _heads_first(q, b, tq, h, d)
     kh = _heads_first(k, b, tk, g, d)
-    vh = _heads_first(v, b, tk, g, d)
+    vh = _heads_first(v, b, tk, g, d_v)
     nq, nk = tq // bq, tk // bk
     tab = jnp.asarray(_pair_tables(tq, tk, bq, bk, causal, slot,
                                    window)[0])
@@ -461,7 +465,7 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
     in_specs = [
         pl.BlockSpec((1, bq, d), q_idx),
         pl.BlockSpec((1, bk, d), kv_idx),
-        pl.BlockSpec((1, bk, d), kv_idx),
+        pl.BlockSpec((1, bk, d_v), kv_idx),
     ]
     operands = [qh, kh, vh]
     if segments is not None:
@@ -474,14 +478,14 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
         grid=(b * h, n_pairs),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d), q_idx),
+            pl.BlockSpec((1, bq, d_v), q_idx),
             pl.BlockSpec((1, 8, bq),
                          lambda i, p, ln, lo_, hi_, tb: (i, 0, tb[0, p])),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),       # running max
             pltpu.VMEM((bq, 1), jnp.float32),       # running normalizer
-            pltpu.VMEM((bq, d), jnp.float32),       # output accumulator
+            pltpu.VMEM((bq, d_v), jnp.float32),     # output accumulator
         ],
     )
     kernel = functools.partial(
@@ -489,12 +493,12 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
         block_k=bk, n_heads=h, packed=segments is not None,
         window=window)
     out_shape = [
-        jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+        jax.ShapeDtypeStruct((b * h, tq, d_v), q.dtype),
         jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
     ]
     name = K.FLASH_FWD if segments is None else K.FLASH_FWD_PACKED
     _record_attn_work(name, b * h, tq, tk, bq, bk, d, causal, slot,
-                      operands, out_shape, window)
+                      operands, out_shape, window, d_v)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -504,7 +508,7 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
         interpret=pallas_interpret(),
         name=name,
     )(lengths.astype(jnp.int32), lo, hi, tab, *operands)
-    out = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, tq, d_v).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :].reshape(b, h, tq)
     return out, lse
 
@@ -635,6 +639,9 @@ def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
                                 and h % k.shape[2] == 0),
             f"K/V heads {k.shape[2]} must equal the {h} query heads "
             "(the packed entry also takes a divisor: grouped KV heads)")
+    enforce(k.shape[3] == d and (v.shape[3] == d or segments is not None),
+            f"q/k widths {d}/{k.shape[3]} must agree, and the values' "
+            f"{v.shape[3]} too (the packed entry alone takes another)")
     if causal:
         # a causal mask is only meaningful on a shared timeline
         enforce(tq == tk,
@@ -1208,9 +1215,12 @@ def flash_attention_packed(q, k, v, segments, causal: bool = False,
     across slots leave the iteration space entirely (see
     :func:`_pair_tables`).  ``window`` (causal only): a query sees the
     ``window`` newest keys of its segment up to itself; blocks wholly
-    behind it leave the iteration space too.  Grouped heads and a
-    window are the serving prefill's: forward only (their backward
-    raises).
+    behind it leave the iteration space too.  ``v`` may be
+    ``[B, T_total, G, Dv]`` with ``Dv`` other than ``D`` (latent
+    attention expands keys of 192 and values of 128 lanes a head): the
+    result is ``[B, T_total, H, Dv]``.  Grouped heads, a window and
+    unequal widths are the serving prefill's: forward only (their
+    backward raises).
     """
     out, _lse = _fa_forward(q, k, v, None, causal, block_q, block_k,
                             segments=segments, slot=slot, window=window)
@@ -1226,9 +1236,11 @@ def _fa_packed_fwd_rule(q, k, v, segments, causal, block_q, block_k,
 
 def _fa_packed_bwd_rule(causal, block_q, block_k, slot, window, res, do):
     q, k, v, segments, out, lse = res
-    enforce(not window and k.shape[2] == q.shape[2],
-            "flash_attention_packed: no backward for a sliding window "
-            "or grouped KV heads yet (serving is forward only)")
+    enforce(not window and k.shape[2] == q.shape[2]
+            and v.shape[3] == q.shape[3],
+            "flash_attention_packed: no backward for a sliding window, "
+            "grouped KV heads or values of another width yet (serving "
+            "is forward only)")
     lengths = jnp.full((q.shape[0],), k.shape[1], jnp.int32)
     dq, dk, dv = _fa_backward(q, k, v, lengths, out, lse, do, causal,
                               block_q, block_k, segments=segments,
@@ -1382,6 +1394,16 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
     jax.lax.fori_loop(0, n_rows, _row, 0)
 
 
+def _next_live_row(lengths):
+    """``live[i]``, int32 ``[B + 1]``: the first row at or after ``i``
+    that holds cached tokens, ``B`` for none — where a decode kernel's
+    prefetch goes at a row's end."""
+    b = lengths.shape[0]
+    at = jnp.arange(b, dtype=jnp.int32)
+    return jnp.append(jax.lax.cummin(jnp.where(lengths > 0, at, b),
+                                     reverse=True), b).astype(jnp.int32)
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
                            window: int = 0, name=None):
     """Decode-step attention over a block-paged KV cache.
@@ -1428,11 +1450,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
     n_pages_max = page_indices.shape[1]
     record_attention_dispatch("decode")
     lengths = lengths.astype(jnp.int32)
-    # live[i]: the first row at or after i that holds K/V, B for none —
-    # where the kernel's prefetch goes at a row's end
-    at = jnp.arange(b, dtype=jnp.int32)
-    live = jnp.append(jax.lax.cummin(jnp.where(lengths > 0, at, b),
-                                     reverse=True), b).astype(jnp.int32)
+    live = _next_live_row(lengths)
     # a DMA cannot cut a row inside a 128-lane tile: rows narrower than
     # whole tiles (toy sizes) are padded out, a copy that real widths
     # (G·D a multiple of 128) never make
@@ -1520,18 +1538,17 @@ def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths,
     return out.astype(q.dtype)
 
 
-def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
-                   start_positions, counts):
-    """Scatter new K/V tokens into their rows' physical pages — the
-    pool-maintenance half of the paged-decode contract ("the current
-    step's K/V must already be written to the pages").
+def paged_row_write(pages, new, page_indices, start_positions, counts):
+    """Scatter new tokens' rows into their rows' physical pages of ONE
+    pool — the pool-maintenance half of the paged-decode contract
+    ("the current step's rows must already be written to the pages").
 
-    - ``k_pages`` / ``v_pages``: the pools, ``[P, page, H·D]`` (as the
-      server stores them) or ``[P, page, H, D]``; returned in the
-      shape they came in;
-    - ``k_new`` / ``v_new``: ``[B, Tn, H, D]`` — each row's newest
-      ``Tn`` tokens (``Tn`` = padded prompt length at prefill, 1 per
-      decode step);
+    - ``pages``: the pool, ``[P, page, W]`` (one lane-dense row a
+      token, as the server stores it) or ``[P, page, H, D]``; returned
+      in the shape it came in;
+    - ``new``: ``[B, Tn, …]`` — each row's newest ``Tn`` tokens
+      (``Tn`` = padded prompt length at prefill, 1 per decode step),
+      whatever is behind the token axis flattened to the pool's row;
     - ``page_indices``: int32 ``[B, max_pages]`` per-row page table;
     - ``start_positions``: int32 ``[B]`` absolute position of each
       row's FIRST new token (token ``j`` of row ``b`` lands at
@@ -1540,20 +1557,18 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
       past the count (prompt padding; inactive batch slots via
       ``counts == 0``) are dropped, not written.
 
-    Returns the updated ``(k_pages, v_pages)``.  Pure jnp scatter (one
-    ``.at[].set`` per pool, out-of-range destinations dropped), which
-    XLA does in place on a pool that the jitted caller donates, as the
-    server's steps do (``serving/model.py``).  They pass every layer's
-    pool at once, ``[L·P, page, H·D]``, with layer ``i``'s page table
-    offset by ``i·P``: a dropped token then aims past the last layer.
+    Pure jnp scatter (one ``.at[].set``, out-of-range destinations
+    dropped), which XLA does in place on a pool that the jitted caller
+    donates, as the server's steps do (``serving/model.py``).  They
+    pass every layer's pool at once, ``[L·P, page, W]``, with layer
+    ``i``'s page table offset by ``i·P``: a dropped token then aims
+    past the last layer.
     """
-    n_pages, page = k_pages.shape[:2]
-    b, t_n = k_new.shape[0], k_new.shape[1]
-    enforce(v_new.shape == k_new.shape,
-            f"k_new/v_new shapes differ: {k_new.shape} vs {v_new.shape}")
+    n_pages, page = pages.shape[:2]
+    b, t_n = new.shape[0], new.shape[1]
     enforce(page_indices.shape[0] == b
             and start_positions.shape == (b,) and counts.shape == (b,),
-            f"paged_kv_write batch mismatch: page_indices "
+            f"paged_row_write batch mismatch: page_indices "
             f"{page_indices.shape}, start_positions "
             f"{start_positions.shape}, counts {counts.shape} vs B={b}")
     pos = start_positions.astype(jnp.int32)[:, None] \
@@ -1566,10 +1581,197 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
              < counts.astype(jnp.int32)[:, None]) & (pos >= 0)
     # invalid tokens aim past the pool; mode="drop" discards them
     dest = jnp.where(valid, dest, n_pages * page).reshape(-1)
-    # one lane-dense [H·D] row a token: for a pool stored [P, page, H·D]
-    # the reshapes are free and the scatter writes whole rows in place
-    kf = k_pages.reshape(n_pages * page, -1).at[dest].set(
-        k_new.reshape(b * t_n, -1).astype(k_pages.dtype), mode="drop")
-    vf = v_pages.reshape(n_pages * page, -1).at[dest].set(
-        v_new.reshape(b * t_n, -1).astype(v_pages.dtype), mode="drop")
-    return kf.reshape(k_pages.shape), vf.reshape(v_pages.shape)
+    # one lane-dense row a token: for a pool stored [P, page, W] the
+    # reshapes are free and the scatter writes whole rows in place
+    flat = pages.reshape(n_pages * page, -1).at[dest].set(
+        new.reshape(b * t_n, -1).astype(pages.dtype), mode="drop")
+    return flat.reshape(pages.shape)
+
+
+def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
+                   start_positions, counts):
+    """:func:`paged_row_write` for a K pool and a V pool of one shape:
+    ``k_new`` / ``v_new`` ``[B, Tn, H, D]`` into ``k_pages`` /
+    ``v_pages``.  Returns the updated ``(k_pages, v_pages)``."""
+    enforce(v_new.shape == k_new.shape,
+            f"k_new/v_new shapes differ: {k_new.shape} vs {v_new.shape}")
+    return (paged_row_write(k_pages, k_new, page_indices, start_positions,
+                            counts),
+            paged_row_write(v_pages, v_new, page_indices, start_positions,
+                            counts))
+
+
+# ------------------------------------------------- latent-cache decode
+#: pages of one loop step of the latent kernel: 8 pages of 64 tokens are
+#: one [512, W] operand of both products, and the step's bookkeeping is
+#: paid once for them
+LATENT_PAGES_PER_STEP = 8
+
+
+def _latent_decode_kernel(len_ref, pidx_ref, live_ref, q_ref, pages_hbm,
+                          o_ref, buf, sem, m_s, l_s, acc_s, *, scale,
+                          page, chunk, v_width, n_pages_max):
+    """One invocation; a loop step takes ``chunk`` pages of one row: the
+    live ones come each by its own DMA into one half of a double buffer
+    ``[chunk·page, W]`` (the next chunk — the next live row's first, at
+    a row's end — in flight meanwhile), and that buffer is the step's
+    keys (all ``W`` lanes) and its values (the first ``v_width``): **a
+    page is fetched once**.  Every head's query is a row of ``q``
+    ``[H, W]``, so ``q @ bufᵀ`` is every head's scores ``[H,
+    chunk·page]`` and ``p @ buf[:, :v_width]`` every head's output.
+    Operands in the pool's dtype, ``m``/``l``/acc in float32.  A page
+    slot of a chunk past the row's used pages is not fetched; what an
+    earlier step left there (zeros at first) meets a weight of 0."""
+    n_rows, span = q_ref.shape[0], chunk * page
+
+    def used_pages(b):
+        kv_len = len_ref[jnp.minimum(b, n_rows - 1)]
+        return jnp.minimum((kv_len + page - 1) // page, n_pages_max)
+
+    def each_live_page(b, first, slot, act):
+        """``act`` on the DMA of every page of row ``b``'s chunk that
+        starts at page ``first``; ``b == n_rows`` means no row."""
+        @pl.when(b < n_rows)
+        def _():
+            used = used_pages(b)
+            for i in range(chunk):
+                @pl.when(first + i < used)
+                def _():
+                    act(pltpu.make_async_copy(
+                        pages_hbm.at[pidx_ref[b, first + i]],
+                        buf.at[slot, pl.ds(i * page, page)],
+                        sem.at[slot, i]))
+
+    ki = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[1], span), 1)
+
+    def _row(b, n):
+        """``n`` counts the chunks attended so far: its parity is the
+        half of the buffer the current chunk lies in."""
+        kv_len = len_ref[b]
+        n_chunks = (used_pages(b) + chunk - 1) // chunk
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+        q = q_ref[b]                                     # [H, W]
+
+        def _chunk(j, n):
+            slot = n % 2
+            last = j + 1 == n_chunks
+            each_live_page(jnp.where(last, live_ref[b + 1], b),
+                           jnp.where(last, 0, (j + 1) * chunk), 1 - slot,
+                           lambda cp: cp.start())
+            each_live_page(b, j * chunk, slot, lambda cp: cp.wait())
+            rows = buf[slot]                             # [span, W]
+            s = jax.lax.dot_general(
+                q, rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            # the one query sits at position kv_len - 1 and sees every
+            # key up to itself, which also masks what lies past the
+            # row's length in its last page and in the slots not fetched
+            s = jnp.where(j * span + ki < kv_len, s, NEG_INF)
+            m_prev = m_s[...]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            pexp = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_s[...] = m_new
+            l_s[...] = l_s[...] * alpha + pexp.sum(axis=-1, keepdims=True)
+            acc_s[...] = acc_s[...] * alpha + jnp.dot(
+                pexp.astype(rows.dtype), rows[:, :v_width],
+                preferred_element_type=jnp.float32)
+            return n + 1
+
+        n = jax.lax.fori_loop(0, n_chunks, _chunk, n)
+        l_safe = jnp.where(l_s[...] == 0.0, 1.0, l_s[...])   # length 0
+        o_ref[b] = (acc_s[...] / l_safe).astype(o_ref.dtype)
+        return n
+
+    buf[...] = jnp.zeros_like(buf)
+    each_live_page(live_ref[0], 0, 0, lambda cp: cp.start())
+    jax.lax.fori_loop(0, n_rows, _row, 0)
+
+
+def latent_decode_attention(q, pages, page_indices, lengths, v_width: int,
+                            scale: float):
+    """Decode-step attention over a paged **latent** cache: one
+    compressed row a token serves every query head, as its key and, in
+    its first ``v_width`` lanes, as its value (multi-head latent
+    attention with the key and value up-projections absorbed into the
+    query and the output, ``serving/model.py``).
+
+    - ``q``: ``[B, H, W]`` — each head's absorbed query, laid out as the
+      cache rows are, in the pool's dtype;
+    - ``pages``: the pool ``[P, page_size, W]``, read where it lies;
+    - ``page_indices``: int32 ``[B, max_pages]``; ``lengths``: int32
+      ``[B]`` cached tokens a row (the fed token's row already written;
+      0: the row reads nothing and gets zeros);
+    - ``scale``: the scores' factor (the width the query and key had
+      before absorption, not ``W``).
+
+    Returns ``softmax(scale · q·rowsᵀ) · rows[:, :v_width]``,
+    ``[B, H, v_width]`` float32.  Inference only."""
+    b, h, w = q.shape
+    page = pages.shape[1]
+    enforce(pages.ndim == 3 and pages.shape[2] == w and 0 < v_width <= w,
+            f"latent pool {pages.shape} is not [P, page, {w}] or the "
+            f"value width {v_width} is not within the row")
+    enforce(page_indices.shape[0] == b and lengths.shape == (b,),
+            f"page_indices/lengths batch mismatch: "
+            f"{page_indices.shape}/{lengths.shape} vs B={b}")
+    n_pages_max = page_indices.shape[1]
+    chunk = min(LATENT_PAGES_PER_STEP, n_pages_max)
+    record_attention_dispatch("latent_decode")
+    lengths = lengths.astype(jnp.int32)
+    live = _next_live_row(lengths)
+    out_shape = jax.ShapeDtypeStruct((b, h, v_width), jnp.float32)
+    # the op at the table's capacity (what is live is the caller's to
+    # say: the serve loop's span carries attended_tokens)
+    reach = b * n_pages_max * page
+    K.record_kernel_work(
+        K.LATENT_DECODE, 2.0 * h * (w + v_width) * reach,
+        (q, jax.ShapeDtypeStruct((reach, w), pages.dtype)), (out_shape,))
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, scale=float(scale),
+                          page=page, chunk=chunk, v_width=int(v_width),
+                          n_pages_max=n_pages_max),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[vmem, hbm],
+            out_specs=[vmem],
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk * page, w), pages.dtype),
+                pltpu.SemaphoreType.DMA((2, chunk)),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, v_width), jnp.float32),
+            ],
+        ),
+        out_shape=[out_shape],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pallas_interpret(),
+        name=K.LATENT_DECODE,
+    )(lengths, page_indices.astype(jnp.int32), live, q, pages)[0]
+
+
+def latent_decode_reference(q, pages, page_indices, lengths, v_width: int,
+                            scale: float):
+    """Dense reference for :func:`latent_decode_attention` (tests; the
+    numerics contract): gather each row's pages into one contiguous
+    cache and attend it, float32 throughout."""
+    b, h, w = q.shape
+    page, n_max = pages.shape[1], page_indices.shape[1]
+    rows = pages[page_indices.reshape(-1)].reshape(
+        b, n_max * page, w).astype(jnp.float32)
+    seen = jnp.arange(n_max * page)[None, :] < lengths[:, None]  # [B, K]
+    # what lies past a row's length is never read (whatever it holds)
+    rows = jnp.where(seen[:, :, None], rows, 0.0)
+    s = jnp.einsum("bhw,bkw->bhk", q.astype(jnp.float32), rows) * scale
+    s = jnp.where(seen[:, None, :], s, NEG_INF)
+    m = jnp.maximum(s.max(axis=-1, keepdims=True), NEG_INF / 2)
+    p = jnp.where(seen[:, None, :], jnp.exp(s - m), 0.0)
+    l = p.sum(axis=-1, keepdims=True)
+    p = p / jnp.where(l == 0.0, 1.0, l)
+    return jnp.einsum("bhk,bkw->bhw", p, rows[..., :v_width])

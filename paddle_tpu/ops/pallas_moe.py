@@ -75,6 +75,14 @@ def weight_matmul(a, w):
                    preferred_element_type=jnp.float32)
 
 
+def weight_einsum(spec: str, a, w):
+    """:func:`weight_matmul` for a weight with more axes than two:
+    ``einsum(spec, a, w)`` with ``a`` rounded to ``w``'s dtype and
+    float32 accumulation."""
+    return jnp.einsum(spec, a.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
 def _round_up(x: int, to: int) -> int:
     return -(-x // to) * to
 

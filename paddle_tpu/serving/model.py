@@ -2,11 +2,14 @@
 the continuous-batching server (``serving/server.py``).
 
 One decoder: what a layer computes is read from the **layer plan** of
-:class:`DecoderConfig` (window or full attention, rotary positions, q/k
-norms, an output gate, four norms a block; a GELU, SwiGLU or
-routed-expert feed-forward), query heads may share K/V heads, and the
-weights and the K/V pool are kept in float32 or bfloat16.  The empty
-plan is the dense block this module started as.
+:class:`DecoderConfig` (window, full or latent attention, rotary
+positions, q/k norms, an output gate, four norms a block; a GELU, SwiGLU
+or routed-expert feed-forward), query heads may share K/V heads, and the
+weights and the cache pools are kept in float32 or bfloat16.  The empty
+plan is the dense block this module started as.  A plan of ``latent``
+layers caches ONE compressed row a token and layer in one pool where
+the others cache per-head K and V in two (:meth:`DecoderModel.new_pools`
+says which, and the steps take and donate whatever it gave).
 
 The two entry points mirror the two serving kernels from PR 14/15:
 
@@ -20,7 +23,7 @@ The two entry points mirror the two serving kernels from PR 14/15:
   inactive (padded) slots carry a scratch page table, zero write count,
   and length 1, so the kernel touches no memory the slot does not own.
 
-Both update the K/V pools **in place**: a step donates the two stacked
+Both update the pools **in place**: a step donates the stacked
 pools (:class:`KVPool` holds the one reference to each) and every layer
 writes its rows into, and reads its pages out of, the stack as it lies
 in memory, at the layer's page offset — no copy, slice or write-back of
@@ -65,10 +68,12 @@ import numpy as np
 from ..layers.beam_search import eos_frozen_logits
 from ..observe.trace import span as _span
 from ..ops import kernels as K
-from ..ops.pallas_attention import (flash_attention_packed, paged_kv_write,
-                                    paged_decode_attention,
-                                    segments_from_lengths)
-from ..ops.pallas_moe import routed_experts, weight_matmul
+from ..ops.pallas_attention import (flash_attention_packed,
+                                    latent_decode_attention,
+                                    paged_decode_attention, paged_kv_write,
+                                    paged_row_write, segments_from_lengths)
+from ..ops.pallas_moe import (routed_experts, weight_einsum,
+                              weight_matmul)
 from ..utils import enforce
 from . import export as _export
 from . import loader as _loader
@@ -83,9 +88,11 @@ class DecoderConfig(NamedTuple):
     computes: one entry a layer, ``"<attention>/<feed-forward>"``, each
     side a ``+``-joined set of words.
 
-    - attention: ``full`` or ``window`` (a query sees the ``window``
-      newest positions up to its own), then any of ``rope`` (rotary
-      positions on q and k, half-split rotation at ``rope_theta``),
+    - attention: ``full``, ``window`` (a query sees the ``window``
+      newest positions up to its own) or ``latent`` (below), then any
+      of ``rope`` (rotary positions on q and k at ``rope_theta``: lane
+      j turns with lane j + D/2, or with ``rope_interleave`` lane 2j
+      with lane 2j + 1),
       ``qknorm`` (RMS norm of every q and k head over ``head_dim``),
       ``gate`` (the attention output times ``sigmoid(x·Wg)`` before
       ``wo``) and ``postnorm`` (each sub-block's output is RMS-normed
@@ -95,6 +102,18 @@ class DecoderConfig(NamedTuple):
       (``experts`` SwiGLU experts of width ``expert_ffn``, ``top_k`` a
       token by sigmoid score, ``ops/pallas_moe.py``), the last with
       ``shared`` for one more expert that every token passes.
+
+    ``latent`` is multi-head latent attention, full and causal, in every
+    layer of a plan or in none.  The query comes through a norm at rank
+    ``q_rank``; keys and values come from ONE row a token, ``kv_rank``
+    normed numbers ``c`` and a key part of ``rope_dim`` lanes that all
+    heads share and that alone carries the position (``rope``).  A head
+    has ``nope_dim`` + ``rope_dim`` query/key lanes and ``v_dim`` value
+    lanes; the cache holds the row, never per-head K/V.  Prefill expands
+    K and V per head from the row (``w_ukv``) and runs the packed
+    kernel; decode folds ``w_ukv`` into the query and the output and
+    attends the rows themselves (``ops/pallas_attention.py::
+    latent_decode_attention``): the same function of the same weights.
 
     The empty plan is the default one, ``full/gelu`` in every layer
     (with ``pos_embed`` the decoder this module started as).  ``heads``
@@ -123,10 +142,16 @@ class DecoderConfig(NamedTuple):
     embed_scale: float = 1.0
     pos_embed: bool = True
     storage: str = "float32"
+    rope_interleave: bool = False
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
 
 
-ATTENTION_WORDS = frozenset(
-    {"full", "window", "rope", "qknorm", "gate", "postnorm"})
+KINDS = frozenset({"full", "window", "latent"})
+ATTENTION_WORDS = KINDS | {"rope", "qknorm", "gate", "postnorm"}
 FFN_WORDS = frozenset({"gelu", "swiglu", "routed", "shared"})
 
 
@@ -143,9 +168,10 @@ def layer_plan(cfg: DecoderConfig
     for entry in plan:
         attn, _, ffn = entry.partition("/")
         attn, ffn = frozenset(attn.split("+")), frozenset(ffn.split("+"))
-        enforce(attn <= ATTENTION_WORDS and len(attn & {"full", "window"})
-                == 1, f"plan entry {entry!r}: attention is full or "
-                f"window, then any of {sorted(ATTENTION_WORDS)}")
+        enforce(attn <= ATTENTION_WORDS and len(attn & KINDS) == 1
+                and ("latent" not in attn or attn <= {"latent", "rope"}),
+                f"plan entry {entry!r}: attention is full, window or "
+                f"latent[+rope], then any of {sorted(ATTENTION_WORDS)}")
         enforce(ffn <= FFN_WORDS
                 and len(ffn & {"gelu", "swiglu", "routed"}) == 1
                 and ("shared" not in ffn or "routed" in ffn),
@@ -158,7 +184,15 @@ def layer_plan(cfg: DecoderConfig
                 f"plan entry {entry!r} needs experts >= top_k >= 1 and "
                 "expert_ffn > 0")
         out.append((attn, ffn))
-    if cfg.head_dim == 0:
+    latent = sum("latent" in attn for attn, _ in out)
+    enforce(latent in (0, cfg.layers),
+            "a plan is latent in every layer or in none: one kind of "
+            "cache row a model")
+    enforce(not latent or min(cfg.q_rank, cfg.kv_rank, cfg.nope_dim,
+                              cfg.v_dim) > 0 and cfg.rope_dim % 2 == 0,
+            "a latent plan needs q_rank, kv_rank, nope_dim, v_dim > 0 "
+            "and an even rope_dim")
+    if cfg.head_dim == 0 and not latent:
         enforce(cfg.dim % cfg.heads == 0,
                 f"dim {cfg.dim} not divisible by heads {cfg.heads}")
     enforce(cfg.heads % kv_heads(cfg) == 0,
@@ -184,8 +218,11 @@ def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
     its plan entry adds: ``w1 w2`` (gelu) | ``w_gate w_up w_down``
     (swiglu) | ``router router_bias e_gate e_up e_down`` (routed) and
     ``s_gate s_up s_down`` (shared); ``qn kn`` (qknorm), ``wg`` (gate),
-    ``ln1p ln2p`` (postnorm)."""
-    d, h, g, dh = cfg.dim, cfg.heads, kv_heads(cfg), head_dim(cfg)
+    ``ln1p ln2p`` (postnorm).  A ``latent`` layer has ``w_dq q_ln w_uq
+    w_dkv kv_ln w_ukv`` in the place of ``wq wk wv``, and its ``wo``
+    takes ``heads · v_dim``."""
+    d, h, g = cfg.dim, cfg.heads, kv_heads(cfg)
+    dh = 0 if cfg.kv_rank else head_dim(cfg)
     e, f = cfg.experts, cfg.expert_ffn
     out: Dict[str, Tuple[int, ...]] = {"embed": (cfg.vocab, d)}
     if cfg.pos_embed:
@@ -193,8 +230,18 @@ def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
     out["ln_f"] = (d,)
     out["lm_head"] = (d, cfg.vocab)
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
-        leaves = {"ln1": (d,), "ln2": (d,), "wq": (d, h * dh),
-                  "wk": (d, g * dh), "wv": (d, g * dh), "wo": (h * dh, d)}
+        leaves = {"ln1": (d,), "ln2": (d,)}
+        if "latent" in attn:
+            r, dr = cfg.kv_rank, cfg.rope_dim
+            leaves.update(
+                w_dq=(d, cfg.q_rank), q_ln=(cfg.q_rank,),
+                w_uq=(cfg.q_rank, h * (cfg.nope_dim + dr)),
+                w_dkv=(d, r + dr), kv_ln=(r,),
+                w_ukv=(r, h * (cfg.nope_dim + cfg.v_dim)),
+                wo=(h * cfg.v_dim, d))
+        else:
+            leaves.update(wq=(d, h * dh), wk=(d, g * dh), wv=(d, g * dh),
+                          wo=(h * dh, d))
         if "gelu" in ffn:
             leaves.update(w1=(d, cfg.ffn), w2=(cfg.ffn, d))
         if "swiglu" in ffn:
@@ -256,10 +303,16 @@ def _rms(x, g, eps=1e-6):
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)) * g
 
 
-def _rope(x, pos, theta: float):
-    """Rotary positions, half-split: ``x`` [B, T, N, D] at ``pos``
-    [B, T]; lane j < D/2 pairs with lane j + D/2."""
+def _rope(x, pos, theta: float, interleave: bool = False):
+    """Rotary positions: ``x`` [B, T, N, D] at ``pos`` [B, T]; lane
+    j < D/2 turns with lane j + D/2 by ``pos · theta^(-2j/D)``.
+    ``interleave``: the pairs that turn are lanes 2j and 2j + 1.  They
+    are first brought to j and j + D/2 and stay there: a fixed
+    permutation of the lanes, the same for a query and its key, which
+    no score sees."""
     half = x.shape[-1] // 2
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = pos.astype(jnp.float32)[:, :, None, None] * inv    # [B,T,1,D/2]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
@@ -294,10 +347,99 @@ def _qkv(x, pos, params, i, cfg: DecoderConfig, attn):
         q = _rms(q, params[f"l{i}.qn"], cfg.norm_eps)
         k = _rms(k, params[f"l{i}.kn"], cfg.norm_eps)
     if "rope" in attn:
-        q, k = _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta)
+        q, k = (_rope(a, pos, cfg.rope_theta, cfg.rope_interleave)
+                for a in (q, k))
     gate = jax.nn.sigmoid(weight_matmul(xn, params[f"l{i}.wg"])) \
         if "gate" in attn else None
     return q, k, v, gate
+
+
+def latent_row_width(cfg: DecoderConfig) -> int:
+    """Lanes of a latent cache row: ``kv_rank + rope_dim`` numbers in
+    whole tiles of 128 lanes (576 lie in 640).  The chip lays the array
+    out so whatever width is declared, and a page's DMA cannot cut a row
+    inside a tile; the lanes behind the numbers hold zeros."""
+    return -(-(cfg.kv_rank + cfg.rope_dim) // 128) * 128
+
+
+def _lanes(x, width: int):
+    """``x`` with zeros behind its last axis up to ``width`` lanes."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def _latent_qrow(x, pos, params, i, cfg: DecoderConfig, attn):
+    """A latent layer's attention inputs from the stream: the position-
+    free query part q_n [B, T, H, nope_dim], the rotated part q_r
+    [B, T, H, rope_dim], and the token's **cache row** [B, T, W]: the
+    normed latent ``c``, the one rotated key part every head shares, and
+    zeros up to :func:`latent_row_width`, in the storage dtype."""
+    b, t, _ = x.shape
+    p = lambda leaf: params[f"l{i}.{leaf}"]
+    r, dn = cfg.kv_rank, cfg.nope_dim
+    a = _rms(x, p("ln1"), cfg.norm_eps)
+    cq = _rms(weight_matmul(a, p("w_dq")), p("q_ln"), cfg.norm_eps)
+    q = weight_matmul(cq, p("w_uq")).reshape(b, t, cfg.heads, -1)
+    ckr = weight_matmul(a, p("w_dkv"))
+    c = _rms(ckr[..., :r], p("kv_ln"), cfg.norm_eps)
+    q_r, k_r = q[..., dn:], ckr[:, :, None, r:]
+    if "rope" in attn:
+        q_r, k_r = (_rope(a, pos, cfg.rope_theta, cfg.rope_interleave)
+                    for a in (q_r, k_r))
+    row = _lanes(jnp.concatenate([c, k_r[:, :, 0]], axis=-1),
+                 latent_row_width(cfg))
+    return q[..., :dn], q_r, row.astype(cfg.storage)
+
+
+def _latent_scale(cfg: DecoderConfig) -> float:
+    return 1.0 / float(np.sqrt(cfg.nope_dim + cfg.rope_dim))
+
+
+def _latent_up(params, i, cfg: DecoderConfig):
+    """``w_ukv`` by head: [kv_rank, H, nope_dim + v_dim] (a free
+    reshape); its leading ``nope_dim`` columns a head make keys of the
+    latent, the others values."""
+    return params[f"l{i}.w_ukv"].reshape(cfg.kv_rank, cfg.heads, -1)
+
+
+def _latent_expanded(q_n, q_r, row, params, i, cfg: DecoderConfig,
+                     segments, slot):
+    """Attention of a whole prompt in the **expanded** form: every
+    head's keys [k_n ; k_r] and values are made from the cache rows and
+    go through the packed kernel (keys 192 wide, values 128, at the
+    published sizes).  [B, T, …] → [B, T, H·v_dim] float32."""
+    b, t, h, dn = q_n.shape
+    r = cfg.kv_rank
+    kv = weight_einsum("btr,rhn->bthn", row[..., :r],
+                       _latent_up(params, i, cfg))
+    k_r = jnp.broadcast_to(row[:, :, None, r:r + cfg.rope_dim],
+                           (b, t, h, cfg.rope_dim))
+    # the kernel multiplies what the pool stores
+    q = jnp.concatenate([q_n, q_r], axis=-1).astype(row.dtype)
+    k = jnp.concatenate([kv[..., :dn].astype(row.dtype), k_r], axis=-1)
+    v = kv[..., dn:].astype(row.dtype)
+    o = flash_attention_packed(
+        q.reshape(1, b * t, h, -1), k.reshape(1, b * t, h, -1),
+        v.reshape(1, b * t, h, -1), segments, causal=True, slot=slot)
+    return o.reshape(b, t, h * cfg.v_dim).astype(jnp.float32)
+
+
+def _latent_absorbed(q_n, q_r, pool, table, klen, params, i,
+                     cfg: DecoderConfig):
+    """Attention of one new token a row in the **absorbed** form: the
+    key up-projection goes into the query (q̃_h = q_n,h·W_uk,hᵀ, as wide
+    as the latent), the kernel attends the cache rows themselves, which
+    are keys and, in their leading lanes, values, and the value
+    up-projection goes onto what comes out.  [B, 1, …] → [B, 1,
+    H·v_dim] float32."""
+    b, _, h, dn = q_n.shape
+    r = cfg.kv_rank
+    up = _latent_up(params, i, cfg)
+    q_abs = weight_einsum("bhn,rhn->bhr", q_n[:, 0], up[..., :dn])
+    q = _lanes(jnp.concatenate([q_abs, q_r[:, 0]], axis=-1), pool.shape[-1])
+    o = latent_decode_attention(q.astype(pool.dtype), pool, table, klen, r,
+                                _latent_scale(cfg))
+    o = weight_einsum("bhr,rhv->bhv", o, up[..., dn:])
+    return o.reshape(b, 1, h * cfg.v_dim)
 
 
 def _attend_out(x, o, gate, params, i, cfg: DecoderConfig, attn):
@@ -361,11 +503,11 @@ def _layers_end_to_end(pool):
     return pool.reshape(-1, *pool.shape[2:])
 
 
-def _prefill_impl(params, k_pool, v_pool, tokens, lengths, page_indices,
+def _prefill_impl(params, pools, tokens, lengths, page_indices,
                   cfg: DecoderConfig):
     """[B, T] padded prompts → ([B] first generated tokens, [B, V]
-    logits, updated pools).  Packed causal attention: the batch is ONE
-    [1, B*T] row; segment ids keep rows from attending across each
+    logits, the updated pools).  Packed causal attention: the batch is
+    ONE [1, B*T] row; segment ids keep rows from attending across each
     other and mask padding outright."""
     b, t = tokens.shape
     h, g, dh = cfg.heads, kv_heads(cfg), head_dim(cfg)
@@ -374,23 +516,29 @@ def _prefill_impl(params, k_pool, v_pool, tokens, lengths, page_indices,
     segments = segments_from_lengths(lengths, b, t)
     valid = pos < lengths[:, None]
     zero = jnp.zeros((b,), jnp.int32)
-    shape = k_pool.shape
-    k_pool, v_pool = _layers_end_to_end(k_pool), _layers_end_to_end(v_pool)
+    shapes = [pool.shape for pool in pools]
+    pools = [_layers_end_to_end(pool) for pool in pools]
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
-        q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
-        # the decode contract: K/V must be in the pages before any
-        # later step queries them — write the whole prompt now
-        k_pool, v_pool = paged_kv_write(
-            k_pool, v_pool, k, v, page_indices + i * shape[1], zero,
-            lengths)
-        # the kernel multiplies what the pool stores
-        q, k, v = (a.astype(k_pool.dtype) for a in (q, k, v))
-        o = flash_attention_packed(
-            q.reshape(1, b * t, h, dh), k.reshape(1, b * t, g, dh),
-            v.reshape(1, b * t, g, dh), segments, causal=True, slot=t,
-            window=cfg.window if "window" in attn else 0)
-        x = _attend_out(x, o.reshape(b, t, h * dh).astype(jnp.float32),
-                        gate, params, i, cfg, attn)
+        table = page_indices + i * shapes[0][1]
+        # the decode contract: a token's rows must be in the pages
+        # before any later step queries them — write the whole prompt
+        # now
+        if "latent" in attn:
+            q_n, q_r, row = _latent_qrow(x, pos, params, i, cfg, attn)
+            pools = [paged_row_write(pools[0], row, table, zero, lengths)]
+            o, gate = _latent_expanded(q_n, q_r, row, params, i, cfg,
+                                       segments, t), None
+        else:
+            q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
+            pools = paged_kv_write(*pools, k, v, table, zero, lengths)
+            # the kernel multiplies what the pool stores
+            q, k, v = (a.astype(pools[0].dtype) for a in (q, k, v))
+            o = flash_attention_packed(
+                q.reshape(1, b * t, h, dh), k.reshape(1, b * t, g, dh),
+                v.reshape(1, b * t, g, dh), segments, causal=True, slot=t,
+                window=cfg.window if "window" in attn else 0)
+            o = o.reshape(b, t, h * dh).astype(jnp.float32)
+        x = _attend_out(x, o, gate, params, i, cfg, attn)
         x, _ = _ffn(x, valid, params, i, cfg, attn, ffn)
     last = jnp.take_along_axis(
         x, jnp.clip(lengths - 1, 0, t - 1)[:, None, None], axis=1)[:, 0]
@@ -398,19 +546,19 @@ def _prefill_impl(params, k_pool, v_pool, tokens, lengths, page_indices,
                            params["lm_head"])
     active = lengths > 0
     nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
-    return nxt.astype(jnp.int32), logits, \
-        k_pool.reshape(shape), v_pool.reshape(shape)
+    return (nxt.astype(jnp.int32), logits,
+            *(pool.reshape(shape) for pool, shape in zip(pools, shapes)))
 
 
-def _decode_impl(params, k_pool, v_pool, tokens, page_indices, lengths,
-                 active, cfg: DecoderConfig):
+def _decode_impl(params, pools, tokens, page_indices, lengths, active,
+                 cfg: DecoderConfig):
     """One decode step for a fixed-width batch.  ``lengths`` INCLUDE the
     token being fed (its position is ``lengths - 1``); ``active`` masks
-    padded slots — their K/V write count is zero and their kernel
-    length clamps to 1 over the scratch page, so padding can neither
-    write nor read real pool state.  The first result is the [B] next
-    tokens, followed by :func:`_route_counts`' integers where the plan
-    has routed layers."""
+    padded slots — their write count is zero and their kernel length
+    clamps to 1 over the scratch page, so padding can neither write nor
+    read real pool state.  The first result is the [B] next tokens,
+    followed by :func:`_route_counts`' integers where the plan has
+    routed layers; then the logits and the updated pools."""
     b = tokens.shape[0]
     pos = jnp.clip(lengths - 1, 0, cfg.max_context - 1)[:, None]
     x = _embed(params, tokens[:, None], pos, cfg)
@@ -420,28 +568,42 @@ def _decode_impl(params, k_pool, v_pool, tokens, page_indices, lengths,
     # paged_decode_attention); a planned decoder's reads %paged_decode
     name = K.PAGED_DECODE if cfg.plan else None
     sizes = []
-    shape = k_pool.shape
-    k_pool, v_pool = _layers_end_to_end(k_pool), _layers_end_to_end(v_pool)
+    shapes = [pool.shape for pool in pools]
+    pools = [_layers_end_to_end(pool) for pool in pools]
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
-        q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
-        table = page_indices + i * shape[1]
-        k_pool, v_pool = paged_kv_write(k_pool, v_pool, k, v, table,
-                                        lengths - 1, counts)
-        # the whole stack as stored, [L·P, page, G·D], stays in HBM: the
+        table = page_indices + i * shapes[0][1]
+        # the whole stack as stored, [L·P, page, W], stays in HBM: the
         # kernel DMAs the rows' live pages of this layer out of it,
         # nothing else, before the next layer's rows are written
-        o = paged_decode_attention(
-            q, k_pool, v_pool, table, klen,
-            window=cfg.window if "window" in attn else 0, name=name)
-        x = _attend_out(x, o.reshape(b, 1, -1), gate, params, i, cfg, attn)
+        if "latent" in attn:
+            q_n, q_r, row = _latent_qrow(x, pos, params, i, cfg, attn)
+            pools = [paged_row_write(pools[0], row, table, lengths - 1,
+                                     counts)]
+            o, gate = _latent_absorbed(q_n, q_r, pools[0], table, klen,
+                                       params, i, cfg), None
+        else:
+            q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
+            pools = paged_kv_write(*pools, k, v, table, lengths - 1, counts)
+            o = paged_decode_attention(
+                q, *pools, table, klen,
+                window=cfg.window if "window" in attn else 0,
+                name=name).reshape(b, 1, -1)
+        x = _attend_out(x, o, gate, params, i, cfg, attn)
         x, routed = _ffn(x, active[:, None], params, i, cfg, attn, ffn)
         if routed is not None:
             sizes.append(routed)
     logits = weight_matmul(_rms(x[:, 0], params["ln_f"], cfg.norm_eps),
                            params["lm_head"])
     nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
-    return jnp.concatenate([nxt.astype(jnp.int32), _route_counts(sizes)]), \
-        logits, k_pool.reshape(shape), v_pool.reshape(shape)
+    return (jnp.concatenate([nxt.astype(jnp.int32), _route_counts(sizes)]),
+            logits,
+            *(pool.reshape(shape) for pool, shape in zip(pools, shapes)))
+
+
+def n_pools(cfg: DecoderConfig) -> int:
+    """Cache pools a model of this plan keeps: one of latent rows, or a
+    K and a V pool."""
+    return 1 if "latent" in layer_plan(cfg)[0][0] else 2
 
 
 @functools.lru_cache(maxsize=None)
@@ -455,26 +617,38 @@ def _jitted_steps(cfg: DecoderConfig):
     compiled instead of stalling the first post-flip requests behind
     a full recompile."""
     # static cfg via closure; jax caches one executable per
-    # (B, T)/(B,) shape bucket.  The pools are donated: the step's
-    # scatters write into the buffers that came in, and the arrays the
-    # caller passed are deleted (KVPool keeps the only reference)
+    # (B, T)/(B,) shape bucket.  The arguments are the params, the
+    # plan's pools, then the step's inputs.  The pools are donated: the
+    # step's scatters write into the buffers that came in, and the
+    # arrays the caller passed are deleted (KVPool keeps the only
+    # reference)
+    n = n_pools(cfg)
+    donated = tuple(range(1, 1 + n))
     prefill = jax.jit(
-        lambda p, kp, vp, tk, ln, pi: _prefill_impl(
-            p, kp, vp, tk, ln, pi, cfg), donate_argnums=(1, 2))
+        lambda p, *a: _prefill_impl(p, a[:n], *a[n:], cfg),
+        donate_argnums=donated)
+
     # a row's fed id is the host's ``tk`` or, where ``src`` >= 0, entry
     # ``src`` of ``prev``: the ids of the decode launch before this one,
-    # still on the device (the host has not read them yet)
+    # still on the device (the host has not read them yet).  Both stay
+    # lambdas: the default plan's unnamed kernel is found in a trace by
+    # the ``%_lambda_`` it inherits (see paged_decode_attention)
     decode = jax.jit(
-        lambda p, kp, vp, tk, prev, src, pi, ln, ac: _decode_impl(
-            p, kp, vp, jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tk),
-            pi, ln, ac, cfg), donate_argnums=(1, 2))
+        lambda p, *a: _decode_impl(
+            p, a[:n], _fed_ids(*a[n:n + 3]), *a[n + 3:], cfg),
+        donate_argnums=donated)
     return prefill, decode
 
 
+def _fed_ids(tokens, prev, src):
+    return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
+
+
 class KVPool:
-    """One stacked K or V pool on the device, ``[L, P, page, G·Dh]``,
-    and the only reference to it.  A step donates ``array`` and puts
-    its result back here (:meth:`DecoderModel.prefill` / ``decode``),
+    """One stacked cache pool on the device (K, V or latent rows),
+    ``[L, P, page, W]``, and the only reference to it.  A step donates
+    ``array`` and puts its result back here
+    (:meth:`DecoderModel.prefill` / ``decode``),
     so whoever holds the pool always holds the live buffer and a stale
     reference to a donated one cannot exist."""
     __slots__ = ("array",)
@@ -501,14 +675,15 @@ class DecoderModel:
     Pools are owned by the caller (the server) and threaded through
     every call as :class:`KVPool` objects, which a step updates in
     place — the model never holds KV state, so one model instance
-    serves any number of pools/replicas reentrantly.  One pool pair
-    belongs to one thread at a time."""
+    serves any number of pools/replicas reentrantly.  One set of pools
+    (:meth:`new_pools`) belongs to one thread at a time."""
 
     def __init__(self, params: Dict[str, Any], cfg: DecoderConfig):
         self.cfg = cfg
         self.plan = layer_plan(cfg)          # checks the config too
         self.routed_layers = sum("routed" in ffn for _, ffn in self.plan)
         self._window_layers = sum("window" in attn for attn, _ in self.plan)
+        self.n_pools = n_pools(cfg)
         shapes = leaf_shapes(cfg)
         enforce(set(params) == set(shapes),
                 "the weights are not the plan's: missing "
@@ -528,18 +703,35 @@ class DecoderModel:
 
     # ----------------------------------------------------------- pools
     def new_pools(self, n_pages: int, page_size: int
-                  ) -> Tuple[KVPool, KVPool]:
-        """Zeroed K and V pools, the caller's to keep: each a
-        :class:`KVPool` over ``[L, P, page, G·Dh]`` in the storage
-        dtype, one lane-dense row a token, the layout the decode kernel
-        fetches pages in and ``paged_kv_write`` scatters rows into.
-        (Stored ``[…, G, Dh]`` with Dh < 128 the TPU lays the page axis
-        along the lanes, and every use of a layer's pool is a relayout
-        copy of it: PERF.md §6, PR 26.)"""
-        shape = (self.cfg.layers, n_pages, page_size,
-                 kv_heads(self.cfg) * head_dim(self.cfg))
-        return (KVPool(jnp.zeros(shape, self.cfg.storage)),
-                KVPool(jnp.zeros(shape, self.cfg.storage)))
+                  ) -> Tuple[KVPool, ...]:
+        """The zeroed cache pools this plan needs, the caller's to keep
+        and to hand to every step in this order: a K and a V pool, or
+        the one pool of a latent plan.  Each is a :class:`KVPool` over
+        ``[L, P, page, W]`` in the storage dtype, one lane-dense row a
+        token (``W`` = G·Dh, or :func:`latent_row_width`), the layout
+        the decode kernels fetch pages in and ``paged_row_write``
+        scatters rows into.  (Stored ``[…, G, Dh]`` with Dh < 128 the
+        TPU lays the page axis along the lanes, and every use of a
+        layer's pool is a relayout copy of it: PERF.md §6, PR 26.)"""
+        shape = (self.cfg.layers, n_pages, page_size, self._row_width())
+        return tuple(KVPool(jnp.zeros(shape, self.cfg.storage))
+                     for _ in range(self.n_pools))
+
+    def _row_width(self) -> int:
+        return latent_row_width(self.cfg) if self.n_pools == 1 \
+            else kv_heads(self.cfg) * head_dim(self.cfg)
+
+    def _pools_of(self, args):
+        """A step's arguments, the pools first: → (the plan's pools,
+        the rest).  A caller that names a K and a V pool where the plan
+        has one latent pool names that one twice: its rows are both."""
+        n = next((i for i, a in enumerate(args)
+                  if not isinstance(a, KVPool)), len(args))
+        pools = tuple(dict.fromkeys(args[:n]))
+        enforce(len(pools) == self.n_pools,
+                f"{len(pools)} pools handed to a step of a plan with "
+                f"{self.n_pools} (see new_pools)")
+        return pools, args[n:]
 
     # ------------------------------------------------- what a step reads
     def attended_tokens(self, lengths) -> int:
@@ -550,9 +742,32 @@ class DecoderModel:
         full = sum(lengths)
         if not self._window_layers:
             return full * len(self.plan)
-        near = sum(min(n, self.cfg.window) for n in lengths)
+        return self._over_layers(
+            full, sum(min(n, self.cfg.window) for n in lengths))
+
+    def _over_layers(self, full: int, near: int) -> int:
+        """A count that is ``near`` on a window layer, ``full`` on the
+        others, summed over the plan."""
         return near * self._window_layers \
             + full * (len(self.plan) - self._window_layers)
+
+    def attn_pairs(self, prompts) -> int:
+        """Visible (query, key) pairs of a prefill over prompts of these
+        lengths, summed over the layers: the causal triangle, of which a
+        window layer counts what its window leaves."""
+        full = sum(n * (n + 1) // 2 for n in prompts)
+        if not self._window_layers:
+            return full * len(self.plan)
+        w = self.cfg.window
+        return self._over_layers(full, sum(
+            min(n, w) * (min(n, w) + 1) // 2 + max(n - w, 0) * w
+            for n in prompts))
+
+    def cache_bytes_per_token(self) -> int:
+        """What one position holds in the pools over all layers, as
+        stored (a latent row in whole lane tiles)."""
+        return self.n_pools * self.cfg.layers * self._row_width() \
+            * jnp.dtype(self.cfg.storage).itemsize
 
     def pages_behind_window(self, lengths, page_size: int) -> int:
         """Layer-pages (one layer's K and V of one page) that these
@@ -575,29 +790,32 @@ class DecoderModel:
     # the Python traceback into every op's location, and one frame more
     # above the call cost a 24-layer program a second or more of
     # lowering on the chip's host (PERF.md §6, PR 30).
-    def prefill(self, k_pool: KVPool, v_pool: KVPool, tokens, lengths,
-                page_indices, collect: bool = True):
-        """Prompts in, first generated token out (plus the logits and
-        the pools, the same two objects, updated in place).  ``tokens``
-        [B, T] int32 padded, ``lengths`` [B], ``page_indices``
-        [B, max_pages] physical page tables covering each prompt PLUS
-        the tokens to be generated.  The two pools hold the launch's
-        result as soon as it is queued; ``collect=False``
-        (:meth:`launch_prefill`) returns there, with the launch for
-        :meth:`collect_prefill`."""
+    def prefill(self, *args, collect: bool = True):
+        """``prefill(*pools, tokens, lengths, page_indices)``: prompts
+        in, first generated token out (plus the logits and the pools,
+        the same objects, updated in place).  ``pools`` are
+        :meth:`new_pools`' in their order; ``tokens`` [B, T] int32
+        padded, ``lengths`` [B], ``page_indices`` [B, max_pages]
+        physical page tables covering each prompt PLUS the tokens to be
+        generated.  The pools hold the launch's result as soon as it is
+        queued; ``collect=False`` (:meth:`launch_prefill`) returns
+        there, with the launch for :meth:`collect_prefill`."""
+        pools, (tokens, lengths, page_indices) = self._pools_of(args)
         shape = np.shape(tokens)
         enforce(len(shape) == 2 and shape[1] <= self.cfg.max_context,
                 f"prompt batch {shape} exceeds max_context "
                 f"{self.cfg.max_context}")
         with _span("prefill_dispatch"):       # host→device + launch
-            nxt, logits, k_pool.array, v_pool.array = self._prefill(
-                self.params, k_pool.array, v_pool.array,
+            nxt, logits, *arrays = self._prefill(
+                self.params, *(p.array for p in pools),
                 jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(page_indices, jnp.int32))
+            for pool, array in zip(pools, arrays):
+                pool.array = array
         if not collect:
             return nxt, logits
-        return (*self.collect_prefill((nxt, logits)), k_pool, v_pool)
+        return (*self.collect_prefill((nxt, logits)), *pools)
 
     launch_prefill = functools.partialmethod(prefill, collect=False)
 
@@ -610,17 +828,20 @@ class DecoderModel:
             nxt = np.asarray(nxt)
         return nxt, logits
 
-    def decode(self, k_pool: KVPool, v_pool: KVPool, tokens, page_indices,
-               lengths, active, prev=None, src=None, collect: bool = True):
-        """One continuous-batching decode step over the page pool.  →
-        (next tokens, logits, the pools (the same two objects, updated
-        in place), the routed counts of :meth:`collect_decode`).
-        ``prev`` is an earlier decode launch of the same width,
-        collected or not, and ``src`` [B] says per row which of its ids
-        the row is fed (−1: the host's ``tokens``); the same program
-        runs with or without it.  ``collect=False``
-        (:meth:`launch_decode`) returns once the step is queued, with
-        the launch for :meth:`collect_decode`."""
+    def decode(self, *args, collect: bool = True):
+        """``decode(*pools, tokens, page_indices, lengths, active[,
+        prev, src])``: one continuous-batching decode step over the
+        page pool.  → (next tokens, logits, the pools (the same
+        objects, updated in place), the routed counts of
+        :meth:`collect_decode`).  ``prev`` is an earlier decode launch
+        of the same width, collected or not, and ``src`` [B] says per
+        row which of its ids the row is fed (−1: the host's
+        ``tokens``); the same program runs with or without it.
+        ``collect=False`` (:meth:`launch_decode`) returns once the step
+        is queued, with the launch for :meth:`collect_decode`."""
+        pools, (tokens, page_indices, lengths, active, *feed) = \
+            self._pools_of(args)
+        prev, src = feed or (None, None)
         b = np.shape(tokens)[0]
         if prev is None:       # the program's shapes, fed by nobody
             counts = 2 if self.routed_layers else 0    # _route_counts
@@ -629,17 +850,19 @@ class DecoderModel:
         else:
             ids = prev[0]
         with _span("decode_dispatch"):        # host→device + launch
-            ids, logits, k_pool.array, v_pool.array = self._decode(
-                self.params, k_pool.array, v_pool.array,
+            ids, logits, *arrays = self._decode(
+                self.params, *(p.array for p in pools),
                 jnp.asarray(tokens, jnp.int32), jnp.asarray(ids, jnp.int32),
                 jnp.asarray(src, jnp.int32),
                 jnp.asarray(page_indices, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(active, bool))
+            for pool, array in zip(pools, arrays):
+                pool.array = array
         if not collect:
             return ids, logits
         nxt, logits, routed = self.collect_decode((ids, logits))
-        return nxt, logits, k_pool, v_pool, routed
+        return (nxt, logits, *pools, routed)
 
     launch_decode = functools.partialmethod(decode, collect=False)
 

@@ -328,9 +328,8 @@ def _probe_model(model: DecoderModel) -> None:
     pointer flip is requested."""
     import numpy as np
 
-    k_pool, v_pool = model.new_pools(2, 8)
-    nxt, logits, _, _ = model.prefill(
-        k_pool, v_pool, [[0]], [1], [[1]])
+    nxt, logits, *_ = model.prefill(
+        *model.new_pools(2, 8), [[0]], [1], [[1]])
     if not np.all(np.isfinite(np.asarray(logits))):
         raise FloatingPointError("probe inference produced non-finite "
                                  "logits")

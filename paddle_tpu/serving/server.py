@@ -311,7 +311,9 @@ class InferenceServer:
         self._width = self.max_batch if self.continuous else 1
         self.snapshot_path = snapshot_path
         self.pool = self._make_pool(n_pages, page_size, snapshot_path)
-        self._k_pool, self._v_pool = model.new_pools(n_pages, page_size)
+        # the cache pools the model's plan needs, as new_pools gave
+        # them: every launch hands them on and donates their arrays
+        self._pools = model.new_pools(n_pages, page_size)
         # one page-table width for every request: enough pages to cover
         # a max_context-long sequence (or the whole pool if smaller)
         self.max_pages = min(self.pool.capacity,
@@ -351,6 +353,12 @@ class InferenceServer:
             "layer-pages (one layer's K and V of one page) that the "
             "rows of the latest decode step hold wholly behind a "
             "window layer's window")
+        self._m_cache_row = None if _gauge is None else _gauge(
+            "serve_cache_bytes_per_token",
+            "what one position holds in the cache pools over all "
+            "layers, as stored")
+        if self._m_cache_row is not None:
+            self._m_cache_row.set(model.cache_bytes_per_token())
         self._m_launch = None if _counter is None else _counter(
             "serve_launch_total",
             "programs queued on the device by kind (decode | prefill) "
@@ -360,6 +368,12 @@ class InferenceServer:
             "serve_rows_discarded_total",
             "rows a decode launch computed for a request that EOS had "
             "already ended (the host learns of an EOS one launch late)")
+
+    # what a caller that knows a K and a V pool reaches for (the
+    # benchmark's warm-up): the first and the last of the model's pools,
+    # which for a latent plan are the one pool whose rows are both
+    _k_pool = property(lambda self: self._pools[0])
+    _v_pool = property(lambda self: self._pools[-1])
 
     @staticmethod
     def _make_pool(n_pages: int, page_size: int,
@@ -564,8 +578,8 @@ class InferenceServer:
         t0 = time.perf_counter()
         old_version = self.model_version
         try:
-            k_pool, v_pool = ticket.model.new_pools(self.pool.n_pages,
-                                                    self.pool.page_size)
+            pools = ticket.model.new_pools(self.pool.n_pages,
+                                           self.pool.page_size)
         except Exception as e:  # noqa: BLE001 - rollback, keep serving
             self._pending_swap = None
             self.rollout_state = "rolled_back"
@@ -594,7 +608,9 @@ class InferenceServer:
             reprefill = list(self._active)
             ticket.report["reprefilled"] = [r.id for r in reprefill]
         self.model = ticket.model
-        self._k_pool, self._v_pool = k_pool, v_pool
+        self._pools = pools
+        if self._m_cache_row is not None:
+            self._m_cache_row.set(ticket.model.cache_bytes_per_token())
         self.model_version = ticket.version
         self.model_exported_at = ticket.exported_at
         self.rollout_state = "serving"
@@ -757,6 +773,8 @@ class InferenceServer:
         return _Launch("prefill", admitted, dict(
             n=len(admitted), t_pad=t_pad, prompt_tokens=prompt_tokens,
             moe_tokens=prompt_tokens * self.model.routed_layers,
+            attn_pairs=self.model.attn_pairs(
+                [len(r.prompt) for r in admitted]),
             requests=",".join(r.id for r in admitted),
             queued=self._queued()))
 
@@ -815,7 +833,7 @@ class InferenceServer:
             if delay:
                 time.sleep(delay)
             launch.handle = self.model.launch_prefill(
-                self._k_pool, self._v_pool, tokens, lengths, tables)
+                *self._pools, tokens, lengths, tables)
         else:
             with _span("serve_step_build"):
                 b = self._width
@@ -843,8 +861,7 @@ class InferenceServer:
             prev = self._inflight[-1].handle if max(launch.src) >= 0 \
                 else None
             launch.handle = self.model.launch_decode(
-                self._k_pool, self._v_pool, tokens, tables, lengths,
-                active, prev, src)
+                *self._pools, tokens, tables, lengths, active, prev, src)
         self._inflight.append(launch)
 
     def _collect(self, step) -> None:

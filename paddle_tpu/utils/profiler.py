@@ -16,6 +16,7 @@ nvprof windows via ``hl_profiler_start/end``
 from __future__ import annotations
 
 import contextlib
+import ctypes
 import os
 import socket
 import threading
@@ -68,6 +69,33 @@ def _stop_trace() -> None:
         f.write(xspace)
 
 
+# glibc's mallopt parameters and, per phase, what they are set to.  The
+# stop of a window builds the session's XSpace, copies it and serializes
+# it: hundreds of megabytes in buffers that grow by doubling, each of
+# which glibc's own thresholds would give an mmap of its own.  Measured,
+# behind a server at 210 launches a second: with large blocks kept on
+# the heap and the heap not trimmed, the stop of a ten-second window
+# takes 8-10 s where it took 20-25, whatever ProfileOptions said
+# (PERF.md §6, PR 31).  The settings hold from a window's start to its
+# stop; glibc's own come back behind it, as fixed values (a threshold
+# once set is no longer adjusted by glibc as the process allocates).
+_M_TRIM_THRESHOLD, _M_TOP_PAD, _M_MMAP_THRESHOLD = -1, -2, -3
+_HEAP_WHILE_COLLECTING = ((_M_TRIM_THRESHOLD, 1 << 30),
+                          (_M_MMAP_THRESHOLD, 1 << 30),
+                          (_M_TOP_PAD, 1 << 28))
+_HEAP_AS_IT_COMES = ((_M_TRIM_THRESHOLD, 128 << 10),         # glibc's own
+                     (_M_MMAP_THRESHOLD, 128 << 10), (_M_TOP_PAD, 128 << 10))
+
+
+def _tune_heap(settings) -> None:
+    """``mallopt`` each (parameter, value) where the process's C
+    library has it (glibc); elsewhere the allocator is left alone."""
+    mallopt = getattr(ctypes.CDLL(None), "mallopt", None)
+    if mallopt is not None:
+        for parameter, value in settings:
+            mallopt(parameter, value)
+
+
 @contextlib.contextmanager
 def trace(logdir: str = "/tmp/paddle_tpu_trace") -> Iterator[None]:
     """``with profiler.trace(dir): ...`` — xprof window (nvprof-window
@@ -98,16 +126,20 @@ def trace(logdir: str = "/tmp/paddle_tpu_trace") -> Iterator[None]:
         # million events in ten seconds, nine in ten of the host's
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
-        jax.profiler.start_trace(logdir, profiler_options=options)
-        observe.counter("profiler_trace_windows_total",
-                        "xprof/jax.profiler trace windows opened"
-                        ).inc()
-        log.info("profiler trace started → %s", logdir)
+        _tune_heap(_HEAP_WHILE_COLLECTING)
         try:
-            yield
+            jax.profiler.start_trace(logdir, profiler_options=options)
+            observe.counter("profiler_trace_windows_total",
+                            "xprof/jax.profiler trace windows opened"
+                            ).inc()
+            log.info("profiler trace started → %s", logdir)
+            try:
+                yield
+            finally:
+                _stop_trace()
+                log.info("profiler trace written to %s", logdir)
         finally:
-            _stop_trace()
-            log.info("profiler trace written to %s", logdir)
+            _tune_heap(_HEAP_AS_IT_COMES)
     finally:
         with _depth_lock:
             _trace_depth -= 1

@@ -274,8 +274,8 @@ def test_the_default_plan_is_the_decoder_it_was():
 
 @pytest.mark.parametrize("plan,why", [
     (("full/gelu",), "entries for 2 layers"),
-    (("full/gelu", "slow/gelu"), "attention is full or window"),
-    (("full/gelu", "full+window/gelu"), "attention is full or window"),
+    (("full/gelu", "slow/gelu"), "attention is full, window or latent"),
+    (("full/gelu", "full+window/gelu"), "attention is full, window or latent"),
     (("full/gelu", "full/shared"), "feed-forward is gelu"),
     (("full/gelu", "window/gelu"), "needs window > 0"),
     (("full/gelu", "full/routed"), "needs experts")])
@@ -399,7 +399,7 @@ def _recorded(impl, cfg):
     jitted as the server jits it, pools donated, giving besides its
     results every layer's new K/V rows as ``paged_kv_write`` got
     them."""
-    def run(*args):
+    def run(params, k_pool, v_pool, *inputs):
         rows = []
 
         def write(k_pool, v_pool, k_new, v_new, *rest):
@@ -408,7 +408,7 @@ def _recorded(impl, cfg):
         real, decoder_module.paged_kv_write = \
             decoder_module.paged_kv_write, write
         try:
-            return impl(*args, cfg), rows
+            return impl(params, (k_pool, v_pool), *inputs, cfg), rows
         finally:
             decoder_module.paged_kv_write = real
     return jax.jit(run, donate_argnums=(1, 2))

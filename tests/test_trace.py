@@ -502,6 +502,32 @@ def test_profiler_trace_reentrant_and_annotates(monkeypatch, tmp_path):
     assert [e["name"] for e in trace.events()] == ["annotated"]
 
 
+def test_profiler_window_keeps_its_collection_on_the_heap(monkeypatch,
+                                                         tmp_path):
+    """The allocator is tuned from the outermost window's start to its
+    stop and set back behind it, also when the body raises; a nested
+    window touches nothing.  (Why: profiler.py's comment, PERF.md §7.)"""
+    import jax
+
+    from paddle_tpu.utils import profiler
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: calls.append("start"))
+    monkeypatch.setattr(profiler, "_stop_trace",
+                        lambda: calls.append("stop"))
+    monkeypatch.setattr(profiler, "_tune_heap", calls.append)
+    with pytest.raises(KeyError):
+        with profiler.trace(str(tmp_path / "prof")):
+            with profiler.trace(str(tmp_path / "inner")):
+                raise KeyError("the traced code fails")
+    assert calls == [profiler._HEAP_WHILE_COLLECTING, "start", "stop",
+                     profiler._HEAP_AS_IT_COMES]
+    assert profiler.trace_active() is False
+    monkeypatch.undo()
+    profiler._tune_heap(profiler._HEAP_AS_IT_COMES)   # the real call runs
+
+
 @pytest.mark.slow
 def test_profiler_trace_real_window(tmp_path):
     """Full-lane integration: a REAL nested jax.profiler window opens,
