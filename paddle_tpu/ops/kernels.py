@@ -16,7 +16,8 @@ this name; renaming one is a change to every metric that reads it.
 
 **Work.**  :func:`record_kernel_work` ticks
 ``pallas_kernel_work_total{kernel, kind}`` (``kind`` ∈ ``calls``,
-``flops``, ``bytes``) once per traced call — trace-time, like the
+``flops``, ``bytes``; the flash forward adds ``pairs`` and
+``pairs_interior``) once per traced call — trace-time, like the
 ``*_dispatch_total`` counters: once per compiled program per call site.
 It counts what the *op* is, from the shapes at the call (a 3×3 conv's
 ``2·N·H·W·9·Cin·Cout``; each operand and result once), never what the
@@ -91,10 +92,12 @@ def _nbytes(a) -> int:
 
 
 def record_kernel_work(kernel: str, flops: float, operands: Iterable,
-                       results: Iterable) -> None:
+                       results: Iterable, **more: float) -> None:
     """Tick one traced call of ``kernel``: its logical ``flops`` and the
     bytes of ``operands`` and ``results`` (arrays or
-    ``ShapeDtypeStruct``s), each counted once."""
+    ``ShapeDtypeStruct``s), each counted once; ``more`` are further
+    kinds a kernel counts of itself (the flash forward's ``pairs`` and
+    ``pairs_interior``)."""
     work = counter(
         "pallas_kernel_work_total",
         "logical work of the Pallas kernels traced into compiled "
@@ -104,3 +107,5 @@ def record_kernel_work(kernel: str, flops: float, operands: Iterable,
     work.inc(float(flops), kernel=kernel, kind="flops")
     work.inc(float(sum(_nbytes(a) for a in (*operands, *results))),
              kernel=kernel, kind="bytes")
+    for kind, value in more.items():
+        work.inc(float(value), kernel=kernel, kind=kind)

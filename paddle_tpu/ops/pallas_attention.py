@@ -123,6 +123,18 @@ def _window_block_live(q_off, k_off, block_k, window):
     return k_off + block_k - 1 > q_off - window
 
 
+def _block_interior(q_off, k_off, block_q, block_k, causal, window):
+    """Block-level: EVERY (query, key) of the tile lies on or under the
+    diagonal and, where there is a window, inside it, so neither
+    compare of :func:`_tile_mask` can hide an element (python ints at
+    table build).  What only the data shows (key padding, segments) the
+    kernel adds from scalars: see ``_fa_pair_kernel``."""
+    if not causal:
+        return True
+    under = k_off + block_k - 1 <= q_off
+    return under and (not window or q_off + block_q - 1 - k_off < window)
+
+
 def _tile_mask(q_off, k_off, kv_len, causal, block_q, block_k,
                seg_q=None, seg_k=None, window=0):
     """[block_q, block_k] element validity for one tile — THE shared
@@ -130,7 +142,8 @@ def _tile_mask(q_off, k_off, kv_len, causal, block_q, block_k,
     the packed variants: key-padding (``kv_len``), causal diagonal,
     (packed) segment-id equality with −1 = padding, and a sliding
     ``window`` (a query sees the ``window`` newest keys up to itself;
-    causal only)."""
+    causal only).  ``seg_q`` is a column ``[block_q, 1]``, ``seg_k`` a
+    row ``[1, block_k]``."""
     ki = k_off + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
     valid = ki < kv_len
@@ -141,8 +154,8 @@ def _tile_mask(q_off, k_off, kv_len, causal, block_q, block_k,
         if window:
             valid = jnp.logical_and(valid, qi - ki < window)
     if seg_q is not None:
-        valid = jnp.logical_and(valid, seg_q[:, None] == seg_k[None, :])
-        valid = jnp.logical_and(valid, seg_q[:, None] >= 0)
+        valid = jnp.logical_and(valid, seg_q == seg_k)
+        valid = jnp.logical_and(valid, seg_q >= 0)
     return valid
 
 
@@ -152,8 +165,9 @@ def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
                  slot: int = 0, window: int = 0):
     """Static block-sparse iteration tables.
 
-    Returns ``(tab_q, tab_k)`` — int32 ``[4, n_pairs]`` arrays with
-    rows ``(q_block, k_block, is_first, is_last)`` — enumerating every
+    Returns ``(tab_q, tab_k)`` — int32 ``[5, n_pairs]`` arrays with
+    rows ``(q_block, k_block, is_first, is_last, is_interior)`` —
+    enumerating every
     causally-live (q-block, k-block) pair in q-major order (forward /
     dq kernels: the online-softmax / dq accumulators carry across one
     q block's pairs) and k-major order (dk/dv kernel: the dk/dv
@@ -174,13 +188,18 @@ def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
     ``window`` (causal only): blocks wholly behind the sliding window
     of the q block's first query are dropped like those above the
     diagonal.
+
+    ``is_interior``: the diagonal and the window hide no element of
+    the pair (:func:`_block_interior`); the forward kernel runs such a
+    pair without a mask when its scalars show no padding and one
+    segment.  At 7,168 tokens in blocks of 512 that is 91 of 105.
     """
     nq, nk = tq // bq, tk // bk
     if slot and (slot % bq or slot % bk):
         slot = 0                  # blocks straddle slots: hint unusable
 
     def build(q_major: bool):
-        rows = [[], [], [], []]
+        rows = [[], [], [], [], []]
         outer = range(nq) if q_major else range(nk)
         inner = range(nk) if q_major else range(nq)
         for a in outer:
@@ -201,6 +220,9 @@ def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
                 rows[1].append(s)
                 rows[2].append(1 if t == 0 else 0)
                 rows[3].append(1 if t == len(members) - 1 else 0)
+                rows[4].append(1 if _block_interior(
+                    j * bq, s * bk, bq, bk, causal, window) else 0)
+        # ptpu: lint-ok[PT-TRACE] python ints: the static table itself
         return np.asarray(rows, np.int32)
 
     return build(True), build(False)
@@ -266,25 +288,96 @@ def _win_clip(idx, lo, hi, n: int):
 
 
 # ------------------------------------------------ pair-grid fwd kernel
+#: lanes of a vector register: the forward kernel keeps a row's running
+#: maximum and normalizer once in every lane, ``[block_q, 128]``.  As a
+#: ``[block_q, 1]`` column each is as many registers with one lane in
+#: 128 at work, and the dozen updates a step makes of them then cost
+#: more than the 512 × 512 tile's own softmax (PERF.md §6, PR 32)
+_LANES = 128
+
+
+def _lanes(x, width: int):
+    """``x`` [rows, 128], every lane of a row the same → [rows, width]."""
+    reps, rem = divmod(width, _LANES)
+    parts = [x if reps == 1 else pltpu.repeat(x, reps, axis=1)] \
+        if reps else []
+    if rem:
+        parts.append(x[:, :rem])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _segment_uniform(segments, block: int):
+    """int32 [B, n_blocks]: the segment id every token of the block
+    bears, −1 where a block holds padding or more than one segment
+    (minimum and maximum agree on a valid id, or the block is mixed)."""
+    seg = segments.reshape(segments.shape[0], -1, block)
+    lo, hi = seg.min(axis=2), seg.max(axis=2)
+    return jnp.where(lo == hi, lo, -1).astype(jnp.int32)
+
+
+#: what one forward step may hold in VMEM by :func:`_heads_per_step`'s
+#: count, of the 16 MB Mosaic allows a kernel unasked
+_FWD_VMEM_BUDGET = 12 << 20
+
+
+def _heads_per_step(h: int, per_group: int, bq: int, bk: int, d: int,
+                    d_v: int, itemsize: int) -> int:
+    """Heads one grid step of the forward kernel takes.  A step costs
+    the scalar core its index maps, liveness tests and DMA set-up
+    whatever it computes: about a quarter of a 512 × 512 tile's time,
+    shared by the heads of the step (PERF.md §6, PR 32).  The heads of
+    a step lie in one batch row and, under grouped K/V heads, in one
+    group (they then share the step's one K and V block); the count is
+    the largest of 8, 4, 2 that divides so and fits the budget:
+    operands and results double-buffered, the statistics and the
+    accumulator, three tiles of intermediates."""
+    for n in (8, 4, 2):
+        if h % n or (per_group > 1 and per_group % n):
+            continue
+        kv = 1 if per_group > 1 else n
+        vmem = (2 * itemsize * (n * bq + kv * bk) * (d + d_v)
+                + 4 * n * bq * (2 * _LANES + d_v + 2 * 8)
+                + 3 * 4 * bq * bk)
+        if vmem <= _FWD_VMEM_BUDGET:
+            return n
+    return 1
+
+
 def _fa_pair_kernel(*refs, scale, causal, block_q, block_k, n_heads,
                     packed, window=0):
-    """Grid (B·H, n_pairs) over the q-major pair table: the online
-    softmax carries in VMEM scratch across one q block's pairs,
-    initialized at its first table entry and flushed at its last.
-    Dead pairs (no valid key in the window) skip the compute; their
-    DMA was already skipped by the clamped index maps."""
+    """Grid (B·H / heads a step, n_pairs) over the q-major pair table:
+    the online softmax carries in VMEM scratch across one q block's
+    pairs, initialized at its first table entry and flushed at its
+    last.  Dead pairs (no valid key in the window) skip the compute;
+    their DMA was already skipped by the clamped index maps.  The
+    leading axis of the q, o and scratch blocks is the step's heads
+    (:func:`_heads_per_step`); k and v hold as many, or the one head
+    the group shares.
+
+    The step does for an element only what its block needs.  The two
+    products take q, k and v **in the dtype they arrive in** (bfloat16
+    from a bfloat16 pool, float32 from a float32 one) and accumulate in
+    float32, as ``m``, ``l`` and the accumulator stay; the scale sits
+    in the exponent (``m`` is the running maximum of the unscaled
+    scores, ``scale`` > 0), so the query is multiplied as stored.  A
+    pair that the table calls interior, whose keys all lie under the
+    length and whose two blocks bear one valid segment, holds no
+    hidden element: it runs without :func:`_tile_mask`."""
+    (len_ref, lo_ref, hi_ref, tab_ref, uq_ref, uk_ref,
+     q_ref, k_ref, v_ref, *refs) = refs
     if packed:
-        (len_ref, lo_ref, hi_ref, tab_ref, q_ref, k_ref, v_ref,
-         sq_ref, sk_ref, o_ref, lse_ref, m_s, l_s, acc_s) = refs
+        sq_ref, sk_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
     else:
-        (len_ref, lo_ref, hi_ref, tab_ref, q_ref, k_ref, v_ref,
-         o_ref, lse_ref, m_s, l_s, acc_s) = refs
-        sq_ref = sk_ref = None
+        o_ref, lse_ref, m_s, l_s, acc_s = refs
+    heads, d_v = acc_s.shape[0], acc_s.shape[2]
     p = pl.program_id(1)
-    b = pl.program_id(0) // n_heads
+    b = pl.program_id(0) * heads // n_heads
     kv_len = len_ref[b]
-    q_off = tab_ref[0, p] * block_q
-    k_off = tab_ref[1, p] * block_k
+    j = tab_ref[0, p]
+    s_blk = tab_ref[1, p]
+    q_off = j * block_q
+    k_off = s_blk * block_k
+    operand = jnp.promote_types(q_ref.dtype, k_ref.dtype)
 
     @pl.when(tab_ref[2, p] == 1)
     def _init():
@@ -292,47 +385,78 @@ def _fa_pair_kernel(*refs, scale, causal, block_q, block_k, n_heads,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale        # [bq, D]
-        kb = k_ref[0]                                   # [bk, D]
-        vb = v_ref[0]
-        s = q @ kb.astype(jnp.float32).T                # [bq, bk]
+    def _heads(body):
+        # a loop, not an unrolled one: the step's ops are traced and
+        # lowered once, whatever the heads (set-up time, PERF.md §6)
+        jax.lax.fori_loop(0, heads, lambda hh, _: body(hh), None)
+
+    def _step(masked: bool):
         valid = _tile_mask(
             q_off, k_off, kv_len, causal, block_q, block_k,
-            None if sq_ref is None else sq_ref[0, :, 0],
-            None if sk_ref is None else sk_ref[0, :, 0], window)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_s[:]
-        l_prev = l_s[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        # a fully-masked ROW inside a live block (packed: padding
-        # queries sharing a block with valid ones) has m_new = NEG_INF;
-        # exp(s − m_new) would be exp(0) = 1 and leak mass — clamp the
-        # exponent base so those rows underflow to 0 instead (the
-        # flush's l_safe then emits exact zeros)
-        m_base = jnp.maximum(m_new, NEG_INF / 2)
-        pexp = jnp.exp(s - m_base)
-        alpha = jnp.exp(m_prev - m_base)
-        m_s[:] = m_new
-        l_s[:] = l_prev * alpha + pexp.sum(axis=-1, keepdims=True)
-        acc_s[:] = acc_s[:] * alpha + pexp @ vb.astype(jnp.float32)
+            sq_ref[0] if packed else None,
+            sk_ref[0, 0] if packed else None, window) if masked else None
 
-    pl.when(_pair_live(tab_ref, lo_ref, hi_ref, len_ref, p, b,
-                       block_k))(_step)
+        def one_head(hh):
+            kh = hh if k_ref.shape[0] > 1 else 0
+            vb = v_ref[kh]                              # [bk, Dv]
+            s = jax.lax.dot_general(                    # q · kᵀ [bq, bk]
+                q_ref[hh].astype(operand), k_ref[kh].astype(operand),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if masked:
+                s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_s[hh]                            # [bq, 128]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            # a fully-masked ROW inside a live block (packed: padding
+            # queries sharing a block with valid ones) has m_new =
+            # NEG_INF; exp(s − m_new) would be exp(0) = 1 and leak mass
+            # — clamp the exponent base so those rows underflow to 0
+            # instead (the flush then emits exact zeros)
+            m_base = jnp.maximum(m_new, NEG_INF / 2)
+            pexp = jnp.exp((s - _lanes(m_base, block_k)) * scale)
+            alpha = jnp.exp((m_prev - m_base) * scale)
+            m_s[hh] = m_new  # ptpu: lint-ok[PT-TRACE] a Pallas ref store
+            # ptpu: lint-ok[PT-TRACE] a Pallas ref store
+            l_s[hh] = l_s[hh] * alpha + pexp.sum(axis=-1, keepdims=True)
+            # ptpu: lint-ok[PT-TRACE] a Pallas ref store
+            acc_s[hh] = acc_s[hh] * _lanes(alpha, d_v) \
+                + jax.lax.dot_general(
+                    pexp.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+        _heads(one_head)
+
+    live = _pair_live(tab_ref, lo_ref, hi_ref, len_ref, p, b, block_k)
+    seg = uq_ref[b, j]
+    interior = jnp.logical_and(
+        jnp.logical_and(tab_ref[4, p] == 1, k_off + block_k <= kv_len),
+        jnp.logical_and(seg >= 0, seg == uk_ref[b, s_blk]))
+    pl.when(jnp.logical_and(live, interior))(lambda: _step(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
+        lambda: _step(True))
 
     @pl.when(tab_ref[3, p] == 1)
     def _flush():
-        # guard fully-masked rows (query past a zero-length sequence /
-        # padding segment): l = 0 → emit 0 not NaN, and clamp m away
-        # from NEG_INF so the backward's p = exp(s − lse) underflows to
-        # 0 instead of exp(NEG_INF − NEG_INF) = 1 leaking gradients
-        l_safe = jnp.where(l_s[:] == 0.0, 1.0, l_s[:])
-        m_safe = jnp.maximum(m_s[:], NEG_INF / 2)
-        o_ref[0] = (acc_s[:] / l_safe).astype(o_ref.dtype)
-        # lse block is (1, 8, bq) purely for TPU tiling (last two dims
-        # must be (8k, 128k) or match the array); row 0 carries the data
-        lse_ref[0] = jnp.broadcast_to(
-            (m_safe + jnp.log(l_safe))[:, 0][None, :], (8, block_q))
+        def one_head(hh):
+            # guard fully-masked rows (query past a zero-length
+            # sequence / padding segment): l = 0 → emit 0 not NaN, and
+            # an lse well away from NEG_INF so the backward's
+            # p = exp(s − lse) underflows to 0 instead of
+            # exp(NEG_INF − NEG_INF) = 1 leaking gradients
+            dead = l_s[hh] == 0.0
+            l_safe = jnp.where(dead, 1.0, l_s[hh])
+            o_ref[hh] = (acc_s[hh] / _lanes(l_safe, d_v)
+                         ).astype(o_ref.dtype)
+            # the backward kernels read lse in the scaled scores' units
+            lse = jnp.where(dead, NEG_INF / 2,
+                            m_s[hh] * scale + jnp.log(l_safe))
+            # lse block is (·, 8, bq) purely for TPU tiling (last two
+            # dims must be (8k, 128k) or match the array); row 0
+            # carries the data
+            lse_ref[hh] = jnp.broadcast_to(lse[:, 0][None, :],
+                                           (8, block_q))
+
+        _heads(one_head)
 
 
 def _tiling_ok(tq: int, tk: int, bq: int, bk: int) -> bool:
@@ -407,12 +531,18 @@ def _record_attn_work(kernel, bh, tq, tk, bq, bk, d, causal, slot,
     FLOPs per (query, key) position of the statically live blocks —
     QKᵀ and PV forward; dP and dQ, or dV and dK, backward (the scores
     a backward kernel recomputes are re-done work, not the op's).
-    Where the values are ``d_v`` wide and not ``d``: 2·(d + d_v)."""
-    n_pairs = _pair_tables(tq, tk, bq, bk, causal, slot,
-                           window)[0].shape[1]
+    Where the values are ``d_v`` wide and not ``d``: 2·(d + d_v).  The
+    forward pair kernels also tick ``kind=pairs`` and
+    ``kind=pairs_interior``: the block pairs of the table, and those
+    the diagonal and the window leave whole."""
+    tab = _pair_tables(tq, tk, bq, bk, causal, slot, window)[0]
+    n_pairs = tab.shape[1]
+    # how often the forward pair kernel's unmasked body can engage
+    more = dict(pairs=n_pairs, pairs_interior=int(tab[4].sum())) \
+        if kernel in (K.FLASH_FWD, K.FLASH_FWD_PACKED) else {}
     K.record_kernel_work(
         kernel, 2.0 * (d + (d if d_v is None else d_v)) * bq * bk
-        * n_pairs * bh, operands, results)
+        * n_pairs * bh, operands, results, **more)
 
 
 def _heads_first(a, b, t, h, d):
@@ -422,11 +552,37 @@ def _heads_first(a, b, t, h, d):
 
 def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
                        segments=None, slot=0, window=0):
-    """Pair-table (block-sparse) forward: grid (B·H, n_pairs).  With
-    grouped KV heads (``k``/``v`` hold G < H heads) a grid row's k/v
-    blocks are its group's: no copy of K or V is made.  The values
-    may be another width than the queries and keys (``v``'s last
-    axis): the result has the values' width."""
+    """Pair-table (block-sparse) forward: the call's work account, then
+    :func:`_fa_sparse_call`.  With grouped KV heads (``k``/``v`` hold
+    G < H heads) a grid row's k/v blocks are its group's: no copy of K
+    or V is made.  The values may be another width than the queries
+    and keys (``v``'s last axis): the result has the values' width."""
+    b, tq, h, d = q.shape
+    tk, g, d_v = k.shape[1], k.shape[2], v.shape[3]
+    arr = jax.ShapeDtypeStruct
+    operands = [arr((b * h, tq, d), q.dtype), arr((b * g, tk, d), k.dtype),
+                arr((b * g, tk, d_v), v.dtype)]
+    if segments is not None:
+        operands += [arr((b, tq), jnp.int32)] * 2       # q's and k's ids
+    _record_attn_work(
+        K.FLASH_FWD if segments is None else K.FLASH_FWD_PACKED, b * h,
+        tq, tk, bq, bk, d, causal, slot, operands,
+        [arr((b * h, tq, d_v), q.dtype), arr((b * h, 8, tq), jnp.float32)],
+        window, d_v)
+    return _fa_sparse_call(q, k, v, lengths, segments, causal=causal,
+                           bq=bq, bk=bk, slot=slot, window=window)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "slot",
+                                             "window"))
+def _fa_sparse_call(q, k, v, lengths, segments, *, causal, bq, bk, slot,
+                    window):
+    """The forward pair kernel's call: grid (B·H / n, n_pairs), n heads
+    a step (:func:`_heads_per_step`).  Jitted on its own: the layers of
+    a decoder call it with one set of shapes, and the kernel is traced
+    and lowered once for all of them, not once a layer (a serve cell's
+    four prefill programs hold 20 to 96 such calls, traced anew in
+    every process: PERF.md §6, PR 32)."""
     b, tq, h, d = q.shape
     tk, g, d_v = k.shape[1], k.shape[2], v.shape[3]
     scale = 1.0 / np.sqrt(d)
@@ -439,53 +595,64 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
     n_pairs = tab.shape[1]
     if segments is None:
         lo, hi = _length_windows(lengths, b, nq, bk)
+        # one segment, no padding query: every block is uniform
+        useg_q = jnp.zeros((b, nq), jnp.int32)
+        useg_k = jnp.zeros((b, nk), jnp.int32)
     else:
         lo, hi = _segment_windows(segments, segments, bq, bk)
-    nh = h
-    heads_per_group = h // g
+        useg_q = _segment_uniform(segments, bq)
+        useg_k = _segment_uniform(segments, bk)
+    per_group = h // g
+    n = _heads_per_step(h, per_group, bq, bk, d, d_v, q.dtype.itemsize)
+    n_kv = 1 if per_group > 1 else n
 
-    def q_idx(i, p, ln, lo_, hi_, tb):
+    # grid row i holds heads i·n … i·n + n − 1 of batch row i·n // h
+    def q_idx(i, p, ln, lo_, hi_, tb, *_):
         return (i, tb[0, p], 0)
 
-    def kv_idx(i, p, ln, lo_, hi_, tb):
-        j = tb[0, p]
+    def kv_idx(i, p, ln, lo_, hi_, tb, *_):
+        j, first = tb[0, p], i * n
         row = i if g == h else \
-            (i // nh) * g + (i % nh) // heads_per_group
-        return (row, _win_clip(tb[1, p], lo_[i // nh, j],
-                               hi_[i // nh, j], nk), 0)
+            (first // h) * g + (first % h) // per_group
+        return (row, _win_clip(tb[1, p], lo_[first // h, j],
+                               hi_[first // h, j], nk), 0)
 
-    def sq_idx(i, p, ln, lo_, hi_, tb):
-        return (i // nh, tb[0, p], 0)
+    def sq_idx(i, p, ln, lo_, hi_, tb, *_):
+        return (i * n // h, tb[0, p], 0)
 
-    def sk_idx(i, p, ln, lo_, hi_, tb):
-        j = tb[0, p]
-        return (i // nh, _win_clip(tb[1, p], lo_[i // nh, j],
-                                   hi_[i // nh, j], nk), 0)
+    def sk_idx(i, p, ln, lo_, hi_, tb, *_):
+        j, row = tb[0, p], i * n // h
+        return (row, _win_clip(tb[1, p], lo_[row, j], hi_[row, j], nk),
+                0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, bq, d), q_idx),
-        pl.BlockSpec((1, bk, d), kv_idx),
-        pl.BlockSpec((1, bk, d_v), kv_idx),
+        pl.BlockSpec((n, bq, d), q_idx),
+        pl.BlockSpec((n_kv, bk, d), kv_idx),
+        pl.BlockSpec((n_kv, bk, d_v), kv_idx),
     ]
     operands = [qh, kh, vh]
     if segments is not None:
-        seg3 = segments.astype(jnp.int32).reshape(b, tq, 1)
+        # the queries' ids as a column, the keys' along the lanes: the
+        # mask compares them as they lie, and a step's key ids come in
+        # one contiguous copy (a block of its own array dims: any bk)
+        seg = segments.astype(jnp.int32)
         in_specs += [pl.BlockSpec((1, bq, 1), sq_idx),
-                     pl.BlockSpec((1, bk, 1), sk_idx)]
-        operands += [seg3, seg3]
+                     pl.BlockSpec((1, 1, 1, bk), sk_idx)]
+        operands += [seg.reshape(b, tq, 1), seg.reshape(b, nk, 1, bk)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b * h, n_pairs),
+        num_scalar_prefetch=6,
+        grid=(b * h // n, n_pairs),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d_v), q_idx),
-            pl.BlockSpec((1, 8, bq),
-                         lambda i, p, ln, lo_, hi_, tb: (i, 0, tb[0, p])),
+            pl.BlockSpec((n, bq, d_v), q_idx),
+            pl.BlockSpec((n, 8, bq),
+                         lambda i, p, ln, lo_, hi_, tb, *_:
+                         (i, 0, tb[0, p])),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),       # running max
-            pltpu.VMEM((bq, 1), jnp.float32),       # running normalizer
-            pltpu.VMEM((bq, d_v), jnp.float32),     # output accumulator
+            pltpu.VMEM((n, bq, _LANES), jnp.float32),   # running max
+            pltpu.VMEM((n, bq, _LANES), jnp.float32),   # normalizer
+            pltpu.VMEM((n, bq, d_v), jnp.float32),      # accumulator
         ],
     )
     kernel = functools.partial(
@@ -496,9 +663,6 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
         jax.ShapeDtypeStruct((b * h, tq, d_v), q.dtype),
         jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
     ]
-    name = K.FLASH_FWD if segments is None else K.FLASH_FWD_PACKED
-    _record_attn_work(name, b * h, tq, tk, bq, bk, d, causal, slot,
-                      operands, out_shape, window, d_v)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -506,8 +670,8 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=pallas_interpret(),
-        name=name,
-    )(lengths.astype(jnp.int32), lo, hi, tab, *operands)
+        name=K.FLASH_FWD if segments is None else K.FLASH_FWD_PACKED,
+    )(lengths.astype(jnp.int32), lo, hi, tab, useg_q, useg_k, *operands)
     out = out.reshape(b, h, tq, d_v).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :].reshape(b, h, tq)
     return out, lse
@@ -752,8 +916,8 @@ def _bwd_dq_pair_kernel(*refs, scale, causal, block_q, block_k,
         _p, ds, _q, kb, _do = _recompute_block(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_off,
             k_off, kv_len, scale, causal, block_q, block_k,
-            None if sq_ref is None else sq_ref[0, :, 0],
-            None if sk_ref is None else sk_ref[0, :, 0])
+            None if sq_ref is None else sq_ref[0],
+            None if sk_ref is None else sk_ref[0, :, 0][None, :])
         acc_s[:] = acc_s[:] + ds @ kb * scale
 
     pl.when(_pair_live(tab_ref, lo_ref, hi_ref, len_ref, p, b,
@@ -795,8 +959,8 @@ def _bwd_dkv_pair_kernel(*refs, scale, causal, block_q, block_k,
         pw, ds, q, _kb, do = _recompute_block(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_off,
             k_off, kv_len, scale, causal, block_q, block_k,
-            None if sq_ref is None else sq_ref[0, :, 0],
-            None if sk_ref is None else sk_ref[0, :, 0])
+            None if sq_ref is None else sq_ref[0],
+            None if sk_ref is None else sk_ref[0, :, 0][None, :])
         dv_s[:] = dv_s[:] + pw.T @ do
         dk_s[:] = dk_s[:] + ds.T @ q * scale
 

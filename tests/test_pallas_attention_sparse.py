@@ -188,7 +188,7 @@ def test_pair_tables_causal_skip_fraction():
     (k-major) tables contain the SAME pair set, so forward and
     backward sparsity cannot diverge."""
     tab_q, tab_k = pa._pair_tables(2048, 2048, 512, 512, True)
-    assert tab_q.shape == (4, 10) and tab_k.shape == (4, 10)
+    assert tab_q.shape == (5, 10) and tab_k.shape == (5, 10)
     pairs_q = set(zip(tab_q[0].tolist(), tab_q[1].tolist()))
     pairs_k = set(zip(tab_k[0].tolist(), tab_k[1].tolist()))
     assert pairs_q == pairs_k
@@ -200,7 +200,7 @@ def test_pair_tables_causal_skip_fraction():
     assert tab_k[3].sum() == 4 and tab_k[2].sum() == 4
     # non-causal: full grid, no pairs dropped
     full_q, _ = pa._pair_tables(2048, 2048, 512, 512, False)
-    assert full_q.shape == (4, 16)
+    assert full_q.shape == (5, 16) and full_q[4].all()
 
 
 def test_segment_windows_skip_interleaved_padding():
@@ -680,3 +680,195 @@ def test_paged_decode_ignores_stale_pages(rng):
         q, jnp.asarray(kpg2), jnp.asarray(vpg2), pidx, lengths)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-6, atol=1e-7)
+
+
+# ------------------------------------- operands as stored, interior blocks
+def _p_rounded_reference(q, k, v, seg, window, p_dtype):
+    """The dense forward with the kernel's one rounding made explicit:
+    the unnormalised probabilities go through ``p_dtype`` before P·V,
+    the normalizer sums them unrounded, everything else is float32."""
+    h, g = q.shape[2], k.shape[2]
+    kf, vf = (jnp.repeat(a.astype(jnp.float32), h // g, axis=2)
+              for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kf) \
+        / np.sqrt(q.shape[-1])
+    s = pa._mask_scores(s, True, None, seg, window)
+    p = jnp.exp(s - jnp.maximum(s.max(axis=-1, keepdims=True),
+                                pa.NEG_INF / 2))
+    l = p.sum(axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd",
+                   p.astype(p_dtype).astype(jnp.float32), vf)
+    l = jnp.where(l == 0.0, 1.0, l).transpose(0, 2, 1)[..., None]
+    return (o / l).astype(q.dtype)
+
+
+def _served_case(shape, dtype, rng):
+    """The two served prefill shapes cut small, one row of 512 tokens
+    in blocks of 128 (10 block pairs, 6 of them interior without a
+    window): ``latent`` has keys of 192 lanes and values of 128 with a
+    K/V head a query head, ``routed`` 32 query heads over 4 K/V heads of
+    128 under a window that ends inside a block."""
+    t = 512
+    h, g, d, d_v, window = ((4, 4, 192, 128, 0) if shape == "latent"
+                            else (32, 4, 128, 128, 200))
+    q = jnp.asarray(rng.randn(1, t, h, d), dtype)
+    k = jnp.asarray(rng.randn(1, t, g, d), dtype)
+    v = jnp.asarray(rng.randn(1, t, g, d_v), dtype)
+    seg = pa.segments_from_lengths(jnp.asarray([t], jnp.int32), 1, t)
+    return q, k, v, seg, window
+
+
+def _mean_gap(a, b):
+    a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+    return float(np.abs(a - b).mean() / np.abs(b).mean())
+
+
+@pytest.mark.parametrize("shape", ["latent", "routed"])
+def test_packed_bf16_operands_at_the_served_shapes(shape, rng):
+    """bfloat16 q, k and v go into both products as they are stored:
+    against the dense forward on the same bfloat16 inputs the kernel
+    reads under a tolerance that a path with float32 probabilities
+    passes ten times under and one with fp8 probabilities fails — the
+    one new rounding is the probabilities' to bfloat16."""
+    q, k, v, seg, window = _served_case(shape, jnp.bfloat16, rng)
+    want, _ = pa._dense_forward(q, k, v, None, True, seg, window)
+    got = pa.flash_attention_packed(q, k, v, seg, True, 128, 128, 512,
+                                    window)
+    assert got.dtype == jnp.bfloat16 and got.shape == want.shape
+    tol = 4e-3      # the kernel reads 1.3e-3, fp8 probabilities 2.1e-2
+    assert _mean_gap(got, want) < tol
+    f32_p = _p_rounded_reference(q, k, v, seg, window, jnp.float32)
+    fp8_p = _p_rounded_reference(q, k, v, seg, window, jnp.float8_e4m3fn)
+    assert _mean_gap(f32_p, want) < tol / 10
+    assert _mean_gap(fp8_p, want) > tol
+
+
+@pytest.mark.parametrize("shape", ["latent", "routed"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_packed_lse_is_in_the_scaled_scores_units(shape, dtype, rng):
+    """The scale sits in the exponent, over the running maximum of the
+    unscaled scores; the logsumexp that leaves the kernel is still the
+    scaled scores' (what the backward kernels subtract), and float32
+    inputs give what they gave, to the file's tolerance.  1/√192 is no
+    power of two: a wrong unit shows."""
+    q, k, v, seg, window = _served_case(shape, jnp.dtype(dtype), rng)
+    out, lse = pa._fa_forward(q, k, v, None, True, 128, 128, seg, 512,
+                              window)
+    want, want_lse = pa._dense_forward(q, k, v, None, True, seg, window)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-4, atol=2e-4)
+    if dtype == "float32":
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def _block_case(case, rng):
+    """A block pair wholly under the diagonal that still hides an
+    element, in blocks of 128: (entry, q, k, v, lengths, segments,
+    window)."""
+    t, h, d = 512, 2, 16
+    q, k, v = _qkv(rng, 1, t, h, d)
+    seg = np.zeros((1, t), np.int32)
+    lengths, window = None, 0
+    if case == "segment_boundary":
+        seg[0, 200:] = 1              # inside k block 1, under q block 2
+    elif case == "padding_in_the_middle":
+        seg[0, 150:170] = -1          # k block 1 holds 20 padding tokens
+    elif case == "segment_change_on_a_block_edge":
+        seg[0, 256:] = 1              # uniform blocks of two segments
+    elif case == "key_past_kv_len":
+        lengths = jnp.asarray([200], jnp.int32)    # ends inside k block 1
+    elif case == "window_ends_inside":
+        window = 200                  # q 511 no longer sees keys 256..311
+    else:
+        raise AssertionError(case)
+    return q, k, v, lengths, None if lengths is not None \
+        else jnp.asarray(seg), window
+
+
+@pytest.mark.parametrize("case", [
+    "segment_boundary", "padding_in_the_middle",
+    "segment_change_on_a_block_edge", "key_past_kv_len",
+    "window_ends_inside"])
+def test_a_block_under_the_diagonal_that_hides_an_element_is_masked(
+        case, rng):
+    """Only a pair that the table calls interior AND whose scalars show
+    one valid segment and no key past the length runs without the mask:
+    each of these lies wholly under the diagonal, holds an element that
+    must not be seen, and equals the dense forward."""
+    q, k, v, lengths, seg, window = _block_case(case, rng)
+    if seg is None:
+        got = pa.flash_attention(q, k, v, lengths, True, 128, 128)
+    else:
+        got = pa.flash_attention_packed(q, k, v, seg, True, 128, 128, 0,
+                                        window)
+    want, _ = pa._dense_forward(q, k, v, lengths, True, seg, window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+    if seg is not None:
+        # which blocks the scalars call uniform: none that holds a
+        # boundary or padding
+        uniform = np.asarray(pa._segment_uniform(seg, 128))[0].tolist()
+        assert uniform == {
+            "segment_boundary": [0, -1, 1, 1],
+            "padding_in_the_middle": [0, -1, 0, 0],
+            "segment_change_on_a_block_edge": [0, 0, 1, 1],
+            "window_ends_inside": [0, 0, 0, 0]}[case]
+
+
+@pytest.mark.parametrize("t,window,pairs,interior", [
+    (7168, 0, 105, 91), (6144, 0, 78, 66), (4096, 0, 36, 28),
+    (2048, 0, 10, 6), (6144, 2048, 50, 30)])
+def test_pair_table_counts_the_interior_blocks(t, window, pairs, interior):
+    """The table's fifth row at the served lengths in blocks of 512:
+    every pair off the diagonal is interior without a window; under
+    Trinity's window of 2,048 a full row of 5 pairs keeps 3 (the
+    diagonal block and the one the window ends in stay masked)."""
+    tab = pa._pair_tables(t, t, 512, 512, True, t, window)[0]
+    assert tab.shape == (5, pairs) and int(tab[4].sum()) == interior
+    if window:
+        last_row = tab[:, tab[0] == t // 512 - 1]
+        assert last_row.shape[1] == 5 and int(last_row[4].sum()) == 3
+    # no table entry on the diagonal is interior
+    assert not tab[4][tab[0] == tab[1]].any()
+
+
+def test_forward_work_counter_says_how_often_the_unmasked_body_engages(rng):
+    """``pallas_kernel_work_total`` gains ``pairs`` and
+    ``pairs_interior`` for the forward pair kernels, trace-time."""
+    from paddle_tpu.ops import kernels as K
+
+    def work(kernel):
+        rows = observe.REGISTRY.find("pallas_kernel_work_total")
+        return {s["labels"]["kind"]: s["value"]
+                for s in (rows.samples() if rows is not None else ())
+                if s["labels"]["kernel"] == kernel}
+
+    q, k, v, seg, _ = _served_case("latent", jnp.bfloat16, rng)
+    before = work(K.FLASH_FWD_PACKED)
+    pa.flash_attention_packed(q, k, v, seg, True, 128, 128, 512)
+    after = work(K.FLASH_FWD_PACKED)
+    assert after["pairs"] - before.get("pairs", 0.0) == 10
+    assert after["pairs_interior"] - before.get("pairs_interior", 0.0) == 6
+    assert "pairs" not in work(K.FLASH_BWD_DQ)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_attention_bf16_gradients_match_dense(causal, rng):
+    """The trainer's pairing: a forward that multiplies bfloat16
+    operands and emits the scaled logsumexp, a backward that recomputes
+    the same scores from float32 casts of the same bfloat16 numbers."""
+    B, T = 2, 256
+    q, k, v = (a.astype(jnp.bfloat16) for a in _qkv(rng, B, T))
+    lengths = jnp.asarray([256, 93], jnp.int32)
+    cot = jnp.asarray(rng.randn(*q.shape), jnp.bfloat16)
+    fn = lambda *a: pa.flash_attention(*a, lengths, causal, 128, 16)
+    out = fn(q, k, v)
+    ref, _ = pa._dense_forward(q, k, v, lengths, causal)
+    assert out.dtype == jnp.bfloat16
+    assert _mean_gap(out, ref) < 4e-3
+    g = _grads(fn, q, k, v, cot)
+    gd = _dense_grads(q, k, v, lengths, causal, cot)
+    for a, b in zip(g, gd):
+        assert a.dtype == jnp.bfloat16
+        assert _mean_gap(a, b) < 3e-3
