@@ -42,13 +42,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.device import pallas_interpret
 from ..observe import counter
+from ..utils import enforce
 from . import kernels as K
 
 #: rows a tile holds at most; whole widths of ``k``; columns a tile
 #: holds at most.  The sweep on the chip (PERF.md §6, PR 27): at the
 #: decode shape (128 rows over 66 experts) every tiling reads 0.40-0.42
 #: ms a product, the bytes' time; at the prefill shape (49,152 rows)
-#: 128 x 1024 is the fastest, 2.05 / 2.39 ms for 2.24 / 2.74 at 256 x 512
+#: 128 x 1024 is the fastest, 2.05 / 2.39 ms for 2.24 / 2.74 at 256 x 512.
+#: The column tile divides ``n`` (:func:`_column_tile`): 768 at an
+#: expert width of 1536
 TILE_M, TILE_N = 128, 1024
 #: the kernel's VMEM allowance: two buffers of a [k, TILE_N] bf16 weight
 #: block, of a row tile and of its result, and the f32 accumulator
@@ -85,6 +88,20 @@ def weight_einsum(spec: str, a, w):
 
 def _round_up(x: int, to: int) -> int:
     return -(-x // to) * to
+
+
+def _column_tile(n: int) -> int:
+    """Columns a tile of :func:`grouped_matmul` holds: all ``n`` where
+    they fit a tile, else the largest multiple of 128 lanes up to
+    ``TILE_N`` that divides ``n``.  The grid walks ``n // tile`` column
+    blocks, so a tile that left a remainder would leave the columns
+    behind the last whole block unwritten."""
+    if n <= TILE_N:
+        return n
+    tn = next((t for t in range(TILE_N, 0, -128) if n % t == 0), 0)
+    enforce(tn > 0, f"grouped_matmul: no column tile of whole 128-lane "
+                    f"tiles up to {TILE_N} divides n = {n}")
+    return tn
 
 
 def _visits(group_sizes, m: int, tm: int):
@@ -146,7 +163,7 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.float32):
     m, k = lhs.shape
     e, _, n = rhs.shape
     tm = min(TILE_M, _round_up(m, 16))
-    tn = min(TILE_N, n)
+    tn = _column_tile(n)
     m_pad = _round_up(m, tm)
     if m_pad != m:
         lhs = jnp.pad(lhs, ((0, m_pad - m), (0, 0)))

@@ -2,14 +2,19 @@
 the continuous-batching server (``serving/server.py``).
 
 One decoder: what a layer computes is read from the **layer plan** of
-:class:`DecoderConfig` (window, full or latent attention, rotary
-positions, q/k norms, an output gate, four norms a block; a GELU, SwiGLU
-or routed-expert feed-forward), query heads may share K/V heads, and the
-weights and the cache pools are kept in float32 or bfloat16.  The empty
-plan is the dense block this module started as.  A plan of ``latent``
-layers caches ONE compressed row a token and layer in one pool where
-the others cache per-head K and V in two (:meth:`DecoderModel.new_pools`
-says which, and the steps take and donate whatever it gave).
+:class:`DecoderConfig` (window, full or latent attention or a gated
+short convolution, rotary positions, q/k norms, an output gate, four
+norms a block; a GELU, SwiGLU or routed-expert feed-forward), query
+heads may share K/V heads, and the weights and the caches are kept in
+float32 or bfloat16.  The empty plan is the dense block this module
+started as.  A plan of ``latent`` layers caches ONE compressed row a
+token and layer in one pool where the others cache per-head K and V in
+two; the pools hold the layers that attend and no others.  A ``conv``
+layer caches nothing a token: it keeps ``conv_taps - 1`` rows of
+``dim`` numbers a **sequence**, whatever its length, in one more cache
+beside the pools, at the number of the sequence's first page
+(:meth:`DecoderModel.new_pools` says what the plan needs, and the steps
+take and donate whatever it gave).
 
 The two entry points mirror the two serving kernels from PR 14/15:
 
@@ -89,10 +94,10 @@ class DecoderConfig(NamedTuple):
     side a ``+``-joined set of words.
 
     - attention: ``full``, ``window`` (a query sees the ``window``
-      newest positions up to its own) or ``latent`` (below), then any
-      of ``rope`` (rotary positions on q and k at ``rope_theta``: lane
-      j turns with lane j + D/2, or with ``rope_interleave`` lane 2j
-      with lane 2j + 1),
+      newest positions up to its own), ``latent`` or ``conv`` (both
+      below), then any of ``rope`` (rotary positions on q and k at
+      ``rope_theta``: lane j turns with lane j + D/2, or with
+      ``rope_interleave`` lane 2j with lane 2j + 1),
       ``qknorm`` (RMS norm of every q and k head over ``head_dim``),
       ``gate`` (the attention output times ``sigmoid(x·Wg)`` before
       ``wo``) and ``postnorm`` (each sub-block's output is RMS-normed
@@ -114,6 +119,16 @@ class DecoderConfig(NamedTuple):
     kernel; decode folds ``w_ukv`` into the query and the output and
     attends the rows themselves (``ops/pallas_attention.py::
     latent_decode_attention``): the same function of the same weights.
+
+    ``conv`` is a gated short convolution in the place of attention,
+    alone on its side of the entry and mixed freely with ``full`` and
+    ``window`` layers.  ``in_proj`` gives a token three vectors B, C, u
+    of ``dim`` lanes (in this order); z = B ⊙ u; c_t = Σ_j taps[:, j] ⊙
+    z_{t - (conv_taps - 1) + j}, causal and per lane, with zeros before
+    a sequence's own start; the layer adds (C ⊙ c)·``out_proj``.  What
+    it keeps of a sequence is the newest ``conv_taps - 1`` z, in the
+    storage dtype (prefill writes them, a decode step rolls them), and
+    the sum is float32 over z as stored.
 
     The empty plan is the default one, ``full/gelu`` in every layer
     (with ``pos_embed`` the decoder this module started as).  ``heads``
@@ -148,9 +163,10 @@ class DecoderConfig(NamedTuple):
     nope_dim: int = 0
     rope_dim: int = 0
     v_dim: int = 0
+    conv_taps: int = 3
 
 
-KINDS = frozenset({"full", "window", "latent"})
+KINDS = frozenset({"full", "window", "latent", "conv"})
 ATTENTION_WORDS = KINDS | {"rope", "qknorm", "gate", "postnorm"}
 FFN_WORDS = frozenset({"gelu", "swiglu", "routed", "shared"})
 
@@ -169,9 +185,11 @@ def layer_plan(cfg: DecoderConfig
         attn, _, ffn = entry.partition("/")
         attn, ffn = frozenset(attn.split("+")), frozenset(ffn.split("+"))
         enforce(attn <= ATTENTION_WORDS and len(attn & KINDS) == 1
-                and ("latent" not in attn or attn <= {"latent", "rope"}),
+                and ("latent" not in attn or attn <= {"latent", "rope"})
+                and ("conv" not in attn or attn == {"conv"}),
                 f"plan entry {entry!r}: attention is full, window or "
-                f"latent[+rope], then any of {sorted(ATTENTION_WORDS)}")
+                f"latent[+rope], then any of {sorted(ATTENTION_WORDS)}; "
+                "or conv alone")
         enforce(ffn <= FFN_WORDS
                 and len(ffn & {"gelu", "swiglu", "routed"}) == 1
                 and ("shared" not in ffn or "routed" in ffn),
@@ -184,6 +202,9 @@ def layer_plan(cfg: DecoderConfig
                 f"plan entry {entry!r} needs experts >= top_k >= 1 and "
                 "expert_ffn > 0")
         out.append((attn, ffn))
+    enforce(all("conv" not in attn for attn, _ in out)
+            or cfg.conv_taps >= 2,
+            "a conv layer needs conv_taps >= 2")
     latent = sum("latent" in attn for attn, _ in out)
     enforce(latent in (0, cfg.layers),
             "a plan is latent in every layer or in none: one kind of "
@@ -220,7 +241,8 @@ def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
     ``s_gate s_up s_down`` (shared); ``qn kn`` (qknorm), ``wg`` (gate),
     ``ln1p ln2p`` (postnorm).  A ``latent`` layer has ``w_dq q_ln w_uq
     w_dkv kv_ln w_ukv`` in the place of ``wq wk wv``, and its ``wo``
-    takes ``heads · v_dim``."""
+    takes ``heads · v_dim``; a ``conv`` layer has ``in_proj conv
+    out_proj`` in the place of all four."""
     d, h, g = cfg.dim, cfg.heads, kv_heads(cfg)
     dh = 0 if cfg.kv_rank else head_dim(cfg)
     e, f = cfg.experts, cfg.expert_ffn
@@ -239,6 +261,9 @@ def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
                 w_dkv=(d, r + dr), kv_ln=(r,),
                 w_ukv=(r, h * (cfg.nope_dim + cfg.v_dim)),
                 wo=(h * cfg.v_dim, d))
+        elif "conv" in attn:
+            leaves.update(in_proj=(d, 3 * d), conv=(d, cfg.conv_taps),
+                          out_proj=(d, d))
         else:
             leaves.update(wq=(d, h * dh), wk=(d, g * dh), wv=(d, g * dh),
                           wo=(h * dh, d))
@@ -267,8 +292,9 @@ def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
 def _stored_as(name: str, shape, cfg: DecoderConfig) -> str:
     """The dtype a leaf is kept in on the device: ``cfg.storage`` for
     the matrices and the embeddings; float32 for gains, the router and
-    its bias (a score that rounds differently picks another expert)."""
-    if len(shape) < 2 or name.endswith(".router"):
+    its bias (a score that rounds differently picks another expert) and
+    a conv layer's taps (its sum is float32)."""
+    if len(shape) < 2 or name.endswith((".router", ".conv")):
         return "float32"
     return cfg.storage
 
@@ -278,7 +304,7 @@ def init_decoder_params(cfg: DecoderConfig, seed: int = 0
     """Random fp32 decoder weights (scaled normal init) under the
     artifact's names (:func:`leaf_shapes`): matrices N(0, 1/fan_in),
     the embedding N(0, 1), positions N(0, 0.02²), gains 1, the router's
-    selection bias N(0, 0.1²)."""
+    selection bias N(0, 0.1²), a conv layer's taps N(0, 1/taps)."""
     rng = np.random.default_rng(seed)
     p: Dict[str, np.ndarray] = {}
     for name, shape in leaf_shapes(cfg).items():
@@ -293,6 +319,8 @@ def init_decoder_params(cfg: DecoderConfig, seed: int = 0
                 * np.float32(np.sqrt(cfg.vocab))
         elif name == "pos_embed":
             p[name] = (0.02 * z).astype(np.float32)
+        elif name.endswith(".conv"):
+            p[name] = (z / np.sqrt(shape[-1])).astype(np.float32)
         else:
             p[name] = (z / np.sqrt(shape[-2])).astype(np.float32)
     return p
@@ -442,6 +470,59 @@ def _latent_absorbed(q_n, q_r, pool, table, klen, params, i,
     return o.reshape(b, 1, h * cfg.v_dim)
 
 
+def _conv_gates(x, params, i, cfg: DecoderConfig):
+    """A conv layer's inputs from the stream: z = B ⊙ u as the state
+    stores it (the storage dtype, which prefill's sum and a later
+    decode step's then both read) and the gate C, float32; B, C, u are
+    the thirds of ``in_proj``'s result in this order."""
+    xn = _rms(x, params[f"l{i}.ln1"], cfg.norm_eps)
+    b, c, u = jnp.split(weight_matmul(xn, params[f"l{i}.in_proj"]), 3,
+                        axis=-1)
+    return (b * u).astype(cfg.storage), c
+
+
+def _conv_window(window, taps):
+    """Σ_j taps[:, j] ⊙ window[j] in float32: ``window`` holds the
+    ``conv_taps`` z that end at the token, oldest first."""
+    return sum(taps[:, j] * z.astype(jnp.float32)
+               for j, z in enumerate(window))
+
+
+def _conv_out(x, gate, c, params, i):
+    return x + weight_matmul(gate * c, params[f"l{i}.out_proj"])
+
+
+def _conv_prefill(x, held, place, lengths, params, i, cfg: DecoderConfig):
+    """A conv layer over whole prompts ``x`` [B, T, dim]: every
+    position's sum in one pass over the row, zeros before its start
+    (a row is one sequence, so no sum reaches into another).  What a
+    sequence keeps is its newest ``conv_taps - 1`` z (zeros where the
+    prompt is shorter), written whole into its ``place`` [B] of the
+    state ``held``: a place needs no clearing.  → (stream, state)."""
+    taps, t = cfg.conv_taps, x.shape[1]
+    z, gate = _conv_gates(x, params, i, cfg)
+    zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    conv = _conv_window([zp[:, j:j + t] for j in range(taps)],
+                        params[f"l{i}.conv"])
+    newest = lengths[:, None] + jnp.arange(taps - 1)[None, :]
+    held = held.at[jnp.where(lengths > 0, place, held.shape[0])].set(
+        jnp.take_along_axis(zp, newest[:, :, None], axis=1), mode="drop")
+    return _conv_out(x, gate, conv, params, i), held
+
+
+def _conv_decode(x, held, place, active, params, i, cfg: DecoderConfig):
+    """A conv layer over one new token a row, ``x`` [B, 1, dim]: the
+    row's state is read at its ``place``, rolled by the token's z and
+    written back where it lay; an idle slot reads the scratch place and
+    writes nothing.  → (stream, state)."""
+    z, gate = _conv_gates(x[:, 0], params, i, cfg)
+    window = jnp.concatenate([held[place], z[:, None]], axis=1)
+    held = held.at[jnp.where(active, place, held.shape[0])].set(
+        window[:, 1:], mode="drop")
+    conv = _conv_window(jnp.moveaxis(window, 1, 0), params[f"l{i}.conv"])
+    return _conv_out(x, gate[:, None], conv[:, None], params, i), held
+
+
 def _attend_out(x, o, gate, params, i, cfg: DecoderConfig, attn):
     """The attention result ``o`` [B, T, H·D] back into the stream."""
     if gate is not None:
@@ -499,8 +580,26 @@ def _layers_end_to_end(pool):
     pages (a free reshape): layer ``i``'s page ``p`` is page
     ``i·P + p``, so a page table offset by ``i·P`` addresses the
     layer's share and ``paged_kv_write``'s "past the pool" for a
-    dropped row lies past the last layer, never in the next one."""
-    return pool.reshape(-1, *pool.shape[2:])
+    dropped row lies past the last layer, never in the next one.  The
+    conv state ``[L, places, taps - 1, dim]`` is viewed the same way."""
+    return pool.reshape(pool.shape[0] * pool.shape[1], *pool.shape[2:])
+
+
+def _kv_and_state(pools, cfg: DecoderConfig):
+    """A step's caches as :meth:`DecoderModel.new_pools` orders them →
+    (their shapes, the K/V or latent pools layers end to end, the conv
+    state layers end to end or None)."""
+    shapes = [pool.shape for pool in pools]
+    flat = [_layers_end_to_end(pool) for pool in pools]
+    n = n_kv_pools(cfg)
+    return shapes, flat[:n], (flat[n] if len(flat) > n else None)
+
+
+def _as_stored(shapes, pools, held):
+    """The step's caches back in the shapes and the order they came."""
+    if held is not None:
+        pools = [*pools, held]
+    return tuple(pool.reshape(shape) for pool, shape in zip(pools, shapes))
 
 
 def _prefill_impl(params, pools, tokens, lengths, page_indices,
@@ -516,29 +615,39 @@ def _prefill_impl(params, pools, tokens, lengths, page_indices,
     segments = segments_from_lengths(lengths, b, t)
     valid = pos < lengths[:, None]
     zero = jnp.zeros((b,), jnp.int32)
-    shapes = [pool.shape for pool in pools]
-    pools = [_layers_end_to_end(pool) for pool in pools]
+    shapes, pools, held = _kv_and_state(pools, cfg)
+    n_places = shapes[0][1]
+    a = c = 0            # the layer's index among its kind: its share
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
-        table = page_indices + i * shapes[0][1]
-        # the decode contract: a token's rows must be in the pages
-        # before any later step queries them — write the whole prompt
-        # now
-        if "latent" in attn:
-            q_n, q_r, row = _latent_qrow(x, pos, params, i, cfg, attn)
-            pools = [paged_row_write(pools[0], row, table, zero, lengths)]
-            o, gate = _latent_expanded(q_n, q_r, row, params, i, cfg,
-                                       segments, t), None
+        if "conv" in attn:
+            # a sequence's state lies at the number of its first page
+            x, held = _conv_prefill(
+                x, held, page_indices[:, 0] + c * n_places, lengths,
+                params, i, cfg)
+            c += 1
         else:
-            q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
-            pools = paged_kv_write(*pools, k, v, table, zero, lengths)
-            # the kernel multiplies what the pool stores
-            q, k, v = (a.astype(pools[0].dtype) for a in (q, k, v))
-            o = flash_attention_packed(
-                q.reshape(1, b * t, h, dh), k.reshape(1, b * t, g, dh),
-                v.reshape(1, b * t, g, dh), segments, causal=True, slot=t,
-                window=cfg.window if "window" in attn else 0)
-            o = o.reshape(b, t, h * dh).astype(jnp.float32)
-        x = _attend_out(x, o, gate, params, i, cfg, attn)
+            table = page_indices + a * n_places
+            a += 1
+            # the decode contract: a token's rows must be in the pages
+            # before any later step queries them — write the whole
+            # prompt now
+            if "latent" in attn:
+                q_n, q_r, row = _latent_qrow(x, pos, params, i, cfg, attn)
+                pools = [paged_row_write(pools[0], row, table, zero,
+                                         lengths)]
+                o, gate = _latent_expanded(q_n, q_r, row, params, i, cfg,
+                                           segments, t), None
+            else:
+                q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
+                pools = paged_kv_write(*pools, k, v, table, zero, lengths)
+                # the kernel multiplies what the pool stores
+                q, k, v = (m.astype(pools[0].dtype) for m in (q, k, v))
+                o = flash_attention_packed(
+                    q.reshape(1, b * t, h, dh), k.reshape(1, b * t, g, dh),
+                    v.reshape(1, b * t, g, dh), segments, causal=True,
+                    slot=t, window=cfg.window if "window" in attn else 0)
+                o = o.reshape(b, t, h * dh).astype(jnp.float32)
+            x = _attend_out(x, o, gate, params, i, cfg, attn)
         x, _ = _ffn(x, valid, params, i, cfg, attn, ffn)
     last = jnp.take_along_axis(
         x, jnp.clip(lengths - 1, 0, t - 1)[:, None, None], axis=1)[:, 0]
@@ -547,7 +656,7 @@ def _prefill_impl(params, pools, tokens, lengths, page_indices,
     active = lengths > 0
     nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
     return (nxt.astype(jnp.int32), logits,
-            *(pool.reshape(shape) for pool, shape in zip(pools, shapes)))
+            *_as_stored(shapes, pools, held))
 
 
 def _decode_impl(params, pools, tokens, page_indices, lengths, active,
@@ -568,27 +677,38 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
     # paged_decode_attention); a planned decoder's reads %paged_decode
     name = K.PAGED_DECODE if cfg.plan else None
     sizes = []
-    shapes = [pool.shape for pool in pools]
-    pools = [_layers_end_to_end(pool) for pool in pools]
+    shapes, pools, held = _kv_and_state(pools, cfg)
+    n_places = shapes[0][1]
+    a = c = 0
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
-        table = page_indices + i * shapes[0][1]
-        # the whole stack as stored, [L·P, page, W], stays in HBM: the
-        # kernel DMAs the rows' live pages of this layer out of it,
-        # nothing else, before the next layer's rows are written
-        if "latent" in attn:
-            q_n, q_r, row = _latent_qrow(x, pos, params, i, cfg, attn)
-            pools = [paged_row_write(pools[0], row, table, lengths - 1,
-                                     counts)]
-            o, gate = _latent_absorbed(q_n, q_r, pools[0], table, klen,
-                                       params, i, cfg), None
+        if "conv" in attn:
+            # the row's state lies at its first page's number, an idle
+            # slot's at the scratch page's
+            x, held = _conv_decode(
+                x, held, page_indices[:, 0] + c * n_places, active,
+                params, i, cfg)
+            c += 1
         else:
-            q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
-            pools = paged_kv_write(*pools, k, v, table, lengths - 1, counts)
-            o = paged_decode_attention(
-                q, *pools, table, klen,
-                window=cfg.window if "window" in attn else 0,
-                name=name).reshape(b, 1, -1)
-        x = _attend_out(x, o, gate, params, i, cfg, attn)
+            table = page_indices + a * n_places
+            a += 1
+            # the whole stack as stored, [L·P, page, W], stays in HBM:
+            # the kernel DMAs the rows' live pages of this layer out of
+            # it, nothing else, before the next layer's rows are written
+            if "latent" in attn:
+                q_n, q_r, row = _latent_qrow(x, pos, params, i, cfg, attn)
+                pools = [paged_row_write(pools[0], row, table, lengths - 1,
+                                         counts)]
+                o, gate = _latent_absorbed(q_n, q_r, pools[0], table, klen,
+                                           params, i, cfg), None
+            else:
+                q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
+                pools = paged_kv_write(*pools, k, v, table, lengths - 1,
+                                       counts)
+                o = paged_decode_attention(
+                    q, *pools, table, klen,
+                    window=cfg.window if "window" in attn else 0,
+                    name=name).reshape(b, 1, -1)
+            x = _attend_out(x, o, gate, params, i, cfg, attn)
         x, routed = _ffn(x, active[:, None], params, i, cfg, attn, ffn)
         if routed is not None:
             sizes.append(routed)
@@ -596,14 +716,24 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
                            params["lm_head"])
     nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
     return (jnp.concatenate([nxt.astype(jnp.int32), _route_counts(sizes)]),
-            logits,
-            *(pool.reshape(shape) for pool, shape in zip(pools, shapes)))
+            logits, *_as_stored(shapes, pools, held))
+
+
+def n_kv_pools(cfg: DecoderConfig) -> int:
+    """Pools of cache rows a model of this plan keeps for the layers
+    that attend: one of latent rows, or a K and a V pool."""
+    return 1 if "latent" in layer_plan(cfg)[0][0] else 2
+
+
+def conv_layers(cfg: DecoderConfig) -> int:
+    return sum("conv" in attn for attn, _ in layer_plan(cfg))
 
 
 def n_pools(cfg: DecoderConfig) -> int:
-    """Cache pools a model of this plan keeps: one of latent rows, or a
-    K and a V pool."""
-    return 1 if "latent" in layer_plan(cfg)[0][0] else 2
+    """Caches a step of this plan takes and donates: the pools of the
+    layers that attend and, where the plan has conv layers, their
+    state."""
+    return n_kv_pools(cfg) + (1 if conv_layers(cfg) else 0)
 
 
 @functools.lru_cache(maxsize=None)
@@ -645,8 +775,9 @@ def _fed_ids(tokens, prev, src):
 
 
 class KVPool:
-    """One stacked cache pool on the device (K, V or latent rows),
-    ``[L, P, page, W]``, and the only reference to it.  A step donates
+    """One stacked cache on the device (K, V or latent rows, ``[L, P,
+    page, W]``, or the conv layers' state, ``[L, places, taps - 1,
+    dim]``), and the only reference to it.  A step donates
     ``array`` and puts its result back here
     (:meth:`DecoderModel.prefill` / ``decode``),
     so whoever holds the pool always holds the live buffer and a stale
@@ -683,6 +814,10 @@ class DecoderModel:
         self.plan = layer_plan(cfg)          # checks the config too
         self.routed_layers = sum("routed" in ffn for _, ffn in self.plan)
         self._window_layers = sum("window" in attn for attn, _ in self.plan)
+        self.conv_layers = conv_layers(cfg)
+        # the layers that attend: the pools hold these and no others
+        self._cached_layers = cfg.layers - self.conv_layers
+        self.n_kv_pools = n_kv_pools(cfg)
         self.n_pools = n_pools(cfg)
         shapes = leaf_shapes(cfg)
         enforce(set(params) == set(shapes),
@@ -704,30 +839,51 @@ class DecoderModel:
     # ----------------------------------------------------------- pools
     def new_pools(self, n_pages: int, page_size: int
                   ) -> Tuple[KVPool, ...]:
-        """The zeroed cache pools this plan needs, the caller's to keep
-        and to hand to every step in this order: a K and a V pool, or
-        the one pool of a latent plan.  Each is a :class:`KVPool` over
-        ``[L, P, page, W]`` in the storage dtype, one lane-dense row a
-        token (``W`` = G·Dh, or :func:`latent_row_width`), the layout
-        the decode kernels fetch pages in and ``paged_row_write``
-        scatters rows into.  (Stored ``[…, G, Dh]`` with Dh < 128 the
-        TPU lays the page axis along the lanes, and every use of a
-        layer's pool is a relayout copy of it: PERF.md §6, PR 26.)"""
-        shape = (self.cfg.layers, n_pages, page_size, self._row_width())
-        return tuple(KVPool(jnp.zeros(shape, self.cfg.storage))
-                     for _ in range(self.n_pools))
+        """The zeroed caches this plan needs, the caller's to keep and
+        to hand to every step in this order: a K and a V pool, or the
+        one pool of a latent plan, then the conv layers' state where
+        the plan has such layers.  A pool is a :class:`KVPool` over
+        ``[L, P, page, W]`` in the storage dtype, ``L`` the layers that
+        attend (a conv layer has no share), one lane-dense row a token
+        (``W`` = G·Dh, or :func:`latent_row_width`), the layout the
+        decode kernels fetch pages in and ``paged_row_write`` scatters
+        rows into.  (Stored ``[…, G, Dh]`` with Dh < 128 the TPU lays
+        the page axis along the lanes, and every use of a layer's pool
+        is a relayout copy of it: PERF.md §6, PR 26.)  The state is one
+        more :class:`KVPool`, ``[conv layers, P, conv_taps - 1, dim]``:
+        a sequence's rows lie at the number of its first page, so a
+        state follows its request's page table wherever the row moves
+        in the batch, the scratch page gives idle slots a place, and no
+        second allocator exists."""
+        shape = (self._cached_layers, n_pages, page_size, self._row_width())
+        pools = tuple(KVPool(jnp.zeros(shape, self.cfg.storage))
+                      for _ in range(self.n_kv_pools))
+        return pools + self._new_state(n_pages)
+
+    def _new_state(self, n_places: int) -> Tuple[KVPool, ...]:
+        if not self.conv_layers:
+            return ()
+        shape = (self.conv_layers, n_places, self.cfg.conv_taps - 1,
+                 self.cfg.dim)
+        return (KVPool(jnp.zeros(shape, self.cfg.storage)),)
 
     def _row_width(self) -> int:
-        return latent_row_width(self.cfg) if self.n_pools == 1 \
+        return latent_row_width(self.cfg) if self.n_kv_pools == 1 \
             else kv_heads(self.cfg) * head_dim(self.cfg)
 
     def _pools_of(self, args):
         """A step's arguments, the pools first: → (the plan's pools,
         the rest).  A caller that names a K and a V pool where the plan
-        has one latent pool names that one twice: its rows are both."""
+        has one latent pool names that one twice: its rows are both;
+        one that names no conv state where the plan keeps one gets a
+        zeroed state of the pools' places for the call, the same
+        program at the same shapes (the benchmark's warm-up is such a
+        caller: PERF.md §7)."""
         n = next((i for i, a in enumerate(args)
                   if not isinstance(a, KVPool)), len(args))
         pools = tuple(dict.fromkeys(args[:n]))
+        if len(pools) == self.n_kv_pools:
+            pools += self._new_state(pools[0].shape[1])
         enforce(len(pools) == self.n_pools,
                 f"{len(pools)} pools handed to a step of a plan with "
                 f"{self.n_pools} (see new_pools)")
@@ -736,37 +892,44 @@ class DecoderModel:
     # ------------------------------------------------- what a step reads
     def attended_tokens(self, lengths) -> int:
         """K/V positions a decode step over rows of these ``lengths``
-        (the fed token included) must read, summed over the layers:
-        all of a row on a full layer, its ``window`` newest on a window
-        layer."""
+        (the fed token included) must read, summed over the layers that
+        attend: all of a row on a full layer, its ``window`` newest on
+        a window layer, none on a conv layer."""
         full = sum(lengths)
         if not self._window_layers:
-            return full * len(self.plan)
+            return full * self._cached_layers
         return self._over_layers(
             full, sum(min(n, self.cfg.window) for n in lengths))
 
     def _over_layers(self, full: int, near: int) -> int:
         """A count that is ``near`` on a window layer, ``full`` on the
-        others, summed over the plan."""
+        others that attend, summed over the plan."""
         return near * self._window_layers \
-            + full * (len(self.plan) - self._window_layers)
+            + full * (self._cached_layers - self._window_layers)
 
     def attn_pairs(self, prompts) -> int:
         """Visible (query, key) pairs of a prefill over prompts of these
-        lengths, summed over the layers: the causal triangle, of which a
-        window layer counts what its window leaves."""
+        lengths, summed over the layers that attend: the causal
+        triangle, of which a window layer counts what its window
+        leaves."""
         full = sum(n * (n + 1) // 2 for n in prompts)
         if not self._window_layers:
-            return full * len(self.plan)
+            return full * self._cached_layers
         w = self.cfg.window
         return self._over_layers(full, sum(
             min(n, w) * (min(n, w) + 1) // 2 + max(n - w, 0) * w
             for n in prompts))
 
     def cache_bytes_per_token(self) -> int:
-        """What one position holds in the pools over all layers, as
-        stored (a latent row in whole lane tiles)."""
-        return self.n_pools * self.cfg.layers * self._row_width() \
+        """What one position holds in the pools over the layers that
+        attend, as stored (a latent row in whole lane tiles)."""
+        return self.n_kv_pools * self._cached_layers * self._row_width() \
+            * jnp.dtype(self.cfg.storage).itemsize
+
+    def state_bytes_per_sequence(self) -> int:
+        """What a sequence holds in the conv layers' state, whatever
+        its length."""
+        return self.conv_layers * (self.cfg.conv_taps - 1) * self.cfg.dim \
             * jnp.dtype(self.cfg.storage).itemsize
 
     def pages_behind_window(self, lengths, page_size: int) -> int:

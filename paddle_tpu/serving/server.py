@@ -79,9 +79,11 @@ order and the device runs launch k inside span k (to within the emit
 before it: a few hundred microseconds).  Under span k::
 
     serve_loop_iter
-    ├─ serve_prefill{n, t_pad, prompt_tokens, moe_tokens, requests, queued}
+    ├─ serve_prefill{n, t_pad, prompt_tokens, moe_tokens, conv_tokens,
+    │                attn_pairs, requests, queued}
     │  or serve_decode_step{batch, live_tokens, live_pages, attended_tokens,
-    │                       queued, experts_hit, expert_load_max[, discarded]}
+    │                       state_rows, queued, experts_hit, expert_load_max
+    │                       [, discarded]}
     │    (from idle: serve_step_build · *_dispatch of launch k itself)
     │    serve_admit{queued} · serve_step_build · *_dispatch  of launch k+1
     │    *_fetch · serve_step_emit                            of launch k
@@ -98,8 +100,17 @@ costs.  ``queued`` says what the device had when the launch was queued
 ``live_tokens`` is Σ ``lengths`` of the launched rows — the K/V positions
 the step attends over — and ``live_pages`` the pages they occupy;
 ``attended_tokens`` is what the layers must read of them, summed over
-the layers (a window layer reads a row's newest ``window`` only);
+the layers that attend (a window layer reads a row's newest ``window``
+only, a conv layer none);
 ``batch`` the rows whose token was emitted (``discarded`` the others).
+Where the plan has conv layers, ``conv_tokens`` is the prompt tokens
+times those layers and ``state_rows`` the launched rows times them: the
+sequences whose fixed-size state the step reads and writes back.  That
+state lies at the number of the request's first page, so it follows the
+request's page table from admission to release whatever batch row the
+request has in a launch; an idle slot's lies at the scratch page, a row
+riding one launch past its EOS still owns its pages and so its place,
+and a freed place needs no clearing because a prefill writes all of it.
 Where the plan has routed layers, ``moe_tokens`` is the prompt tokens
 times those layers, and the step's own counts come back with its tokens
 and are set before the span closes: ``experts_hit`` (experts with a
@@ -357,8 +368,11 @@ class InferenceServer:
             "serve_cache_bytes_per_token",
             "what one position holds in the cache pools over all "
             "layers, as stored")
-        if self._m_cache_row is not None:
-            self._m_cache_row.set(model.cache_bytes_per_token())
+        self._m_state = None if _gauge is None else _gauge(
+            "serve_state_bytes_per_sequence",
+            "what one sequence holds in the conv layers' state, "
+            "whatever its length")
+        self._publish_cache_sizes(model)
         self._m_launch = None if _counter is None else _counter(
             "serve_launch_total",
             "programs queued on the device by kind (decode | prefill) "
@@ -370,10 +384,18 @@ class InferenceServer:
             "already ended (the host learns of an EOS one launch late)")
 
     # what a caller that knows a K and a V pool reaches for (the
-    # benchmark's warm-up): the first and the last of the model's pools,
-    # which for a latent plan are the one pool whose rows are both
+    # benchmark's warm-up): the first and the last of the pools of the
+    # layers that attend, which for a latent plan are the one pool whose
+    # rows are both.  The conv state it does not know of the steps
+    # supply (DecoderModel._pools_of)
     _k_pool = property(lambda self: self._pools[0])
-    _v_pool = property(lambda self: self._pools[-1])
+    _v_pool = property(
+        lambda self: self._pools[self.model.n_kv_pools - 1])
+
+    def _publish_cache_sizes(self, model: DecoderModel) -> None:
+        if self._m_cache_row is not None:
+            self._m_cache_row.set(model.cache_bytes_per_token())
+            self._m_state.set(model.state_bytes_per_sequence())
 
     @staticmethod
     def _make_pool(n_pages: int, page_size: int,
@@ -609,8 +631,7 @@ class InferenceServer:
             ticket.report["reprefilled"] = [r.id for r in reprefill]
         self.model = ticket.model
         self._pools = pools
-        if self._m_cache_row is not None:
-            self._m_cache_row.set(ticket.model.cache_bytes_per_token())
+        self._publish_cache_sizes(ticket.model)
         self.model_version = ticket.version
         self.model_exported_at = ticket.exported_at
         self.rollout_state = "serving"
@@ -773,6 +794,7 @@ class InferenceServer:
         return _Launch("prefill", admitted, dict(
             n=len(admitted), t_pad=t_pad, prompt_tokens=prompt_tokens,
             moe_tokens=prompt_tokens * self.model.routed_layers,
+            conv_tokens=prompt_tokens * self.model.conv_layers,
             attn_pairs=self.model.attn_pairs(
                 [len(r.prompt) for r in admitted]),
             requests=",".join(r.id for r in admitted),
@@ -808,6 +830,7 @@ class InferenceServer:
             batch=len(rows), live_tokens=sum(fed),
             live_pages=sum(map(self.pool.pages_needed, fed)),
             attended_tokens=self.model.attended_tokens(fed),
+            state_rows=len(rows) * self.model.conv_layers,
             queued=self._queued()), src)
 
     def _launch(self, launch: _Launch) -> None:

@@ -617,6 +617,47 @@ def test_decode_span_states_the_kv_it_attends_over(tiny_model):
             srv.pool.pages_needed(int(n)) for n in lengths[active])
 
 
+def test_spans_state_what_the_conv_layers_hold(tiny_model):
+    """``conv_tokens`` of a prefill (prompt tokens x conv layers) and
+    ``state_rows`` of a decode step (launched rows x conv layers): zero
+    for a plan in which every layer attends, and the state's gauge reads
+    0 bytes a sequence beside the pools' bytes a token."""
+    from paddle_tpu import observe
+    from paddle_tpu.observe import trace
+    from paddle_tpu.serving.model import (DecoderConfig, DecoderModel,
+                                          init_decoder_params)
+
+    _, _, events, _ = _traced_serve(tiny_model, _prompts(3, seed=29))
+    assert all(e["args"]["conv_tokens"] == 0
+               for e in _named(events, "serve_prefill"))
+    assert all(e["args"]["state_rows"] == 0
+               for e in _named(events, "serve_decode_step"))
+    gauge = lambda name: [s["value"] for s in
+                          observe.REGISTRY.find(name).samples()]
+    assert gauge("serve_state_bytes_per_sequence") == [0]
+    assert gauge("serve_cache_bytes_per_token") == [2 * 1 * 32 * 4]
+    cfg = DecoderConfig(vocab=64, dim=32, heads=2, layers=3, ffn=64,
+                        max_context=64, pos_embed=False,
+                        plan=("conv/gelu", "full+rope/gelu", "conv/gelu"))
+    prompts = _prompts(3, seed=29)
+    model = DecoderModel(init_decoder_params(cfg, seed=0), cfg)
+    trace.enable(fences=False)
+    try:
+        _serve_all(model, prompts)
+        events = trace.events()
+    finally:
+        trace.disable()
+    fills = _named(events, "serve_prefill")
+    assert sum(e["args"]["conv_tokens"] for e in fills) \
+        == 2 * sum(map(len, prompts))
+    steps = [e["args"] for e in _named(events, "serve_decode_step")]
+    assert steps and all(
+        a["state_rows"] == 2 * (a["batch"] + a.get("discarded", 0))
+        for a in steps)
+    assert gauge("serve_state_bytes_per_sequence") == [2 * 2 * 32 * 4]
+    assert gauge("serve_cache_bytes_per_token") == [2 * 1 * 32 * 4]
+
+
 def test_snapshot_has_its_span(tiny_model, tmp_path):
     from paddle_tpu.observe import trace
     from paddle_tpu.serving.server import InferenceServer
