@@ -8,6 +8,7 @@ with 8 a token, the configuration's own five-layer plan."""
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -235,6 +236,13 @@ def test_routed_experts_is_the_per_token_loop(tokens, experts, top_k):
     assert (np.asarray(unbiased) != hit).any()
 
 
+def _kernel_work():
+    """``pallas_kernel_work_total``: {(kernel, kind): value}."""
+    return {(s["labels"]["kernel"], s["labels"]["kind"]): s["value"]
+            for s in observe.REGISTRY.find(
+                "pallas_kernel_work_total").samples()}
+
+
 def test_grouped_matmul_reads_only_groups_with_rows():
     """An empty group's weights are never fetched: they hold NaN and
     the result is finite.  The call is named and counted."""
@@ -249,11 +257,132 @@ def test_grouped_matmul_reads_only_groups_with_rows():
     group = np.repeat(np.arange(8), sizes)
     want = np.einsum("mk,mkn->mn", lhs, rhs[group])
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
-    rows = {(s["labels"]["kernel"], s["labels"]["kind"]): s["value"]
-            for s in observe.REGISTRY.find(
-                "pallas_kernel_work_total").samples()}
+    rows = _kernel_work()
     assert rows[(K.MOE_GMM, "calls")] >= 1
     assert rows[(K.MOE_GMM, "flops")] >= 2.0 * m * k * n
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("sizes,m,n", [
+    ([70, 100, 30], 200, 128),      # row tile 0 holds groups 0 and 1,
+                                    # tile 1 groups 1 and 2
+    ([0, 37, 0, 90, 0], 127, 128),  # experts with no row: never read
+    ([60, 90], 300, 128),           # 150 rows of padding, a whole row
+                                    # tile of them, behind the last group
+    ([9, 0, 30], 39, 768),          # the three expert widths: one
+    ([9, 0, 30], 39, 1024),         # column block, one, and two of 768
+    ([9, 0, 30], 39, 1536)],        # (every column written)
+    ids=["shared-tile", "empty-expert", "padding", "n768", "n1024",
+         "n1536"])
+def test_grouped_glu_is_the_two_products_and_the_activation(sizes, m, n,
+                                                            dtype):
+    """The fused gate-up call equals ``silu(grouped_matmul(gate)) *
+    grouped_matmul(up)`` cast to the storage dtype, to the dtype's last
+    place (the activation is float32 on both sides; a transcendental may
+    differ in its last bit before the cast)."""
+    rng, k = np.random.default_rng(n + m), 64
+    sizes = np.array(sizes, np.int32)
+    lhs = jnp.asarray(rng.standard_normal((m, k)), dtype)
+    gate, up = (rng.standard_normal((len(sizes), k, n)).astype(np.float32)
+                / np.sqrt(k) for _ in range(2))
+    gate[sizes == 0] = up[sizes == 0] = np.nan      # never read
+    gate, up, gs = (jnp.asarray(gate, dtype), jnp.asarray(up, dtype),
+                    jnp.asarray(sizes))
+    got = moe.grouped_glu(lhs, gate, up, gs, lhs.dtype)
+    want = (jax.nn.silu(moe.grouped_matmul(lhs, gate, gs))
+            * moe.grouped_matmul(lhs, up, gs)).astype(lhs.dtype)
+    assert got.shape == (m, n) and got.dtype == lhs.dtype
+    rows = int(sizes.sum())
+    got, want = (np.asarray(a[:rows], np.float32) for a in (got, want))
+    assert np.isfinite(want).all()
+    ulp = {"float32": 2.0 ** -23, "bfloat16": 2.0 ** -7}[dtype]
+    np.testing.assert_allclose(got, want, rtol=ulp, atol=1e-30)
+    # no column block left as it was allocated
+    assert (np.abs(got).max(axis=0) > 0).all()
+
+
+def test_grouped_glu_refuses_weights_that_differ():
+    lhs = jnp.zeros((16, 32))
+    with pytest.raises(PaddleTpuError, match="gate .* and up .* differ"):
+        moe.grouped_glu(lhs, jnp.zeros((2, 32, 128)),
+                        jnp.zeros((2, 32, 256)), jnp.array([8, 8]),
+                        jnp.float32)
+
+
+@pytest.mark.parametrize("invalid", ["none", "some", "all"])
+def test_group_sizes_count_the_choices_of_valid_tokens(invalid):
+    """The choice-major flat list counts what the token-major one did:
+    ``group_sizes[e]`` is the number of (valid token, choice) pairs the
+    router sent to ``e``, without ``valid`` too; a token's result does
+    not depend on where among its group's rows it lies (the tokens in
+    another order give the same rows in that order)."""
+    rng = np.random.default_rng(9)
+    t, d, f, e, top_k = 48, 32, 128, 8, 3
+    x = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
+    router_w = jnp.asarray(rng.standard_normal((d, e)) * 0.3, jnp.float32)
+    bias = jnp.asarray(rng.standard_normal(e) * 0.3, jnp.float32)
+    wg, wu = (jnp.asarray(rng.standard_normal((e, d, f)) / np.sqrt(d),
+                          jnp.float32) for _ in range(2))
+    wd = jnp.asarray(rng.standard_normal((e, f, d)) / np.sqrt(f),
+                     jnp.float32)
+    valid = {"none": None, "all": np.zeros(t, bool),
+             "some": rng.random(t) < 0.6}[invalid]
+    run = lambda x, valid: moe.routed_experts(
+        x, router_w, bias, wg, wu, wd, top_k=top_k, route_scale=1.5,
+        valid=None if valid is None else jnp.asarray(valid))
+    y, sizes = run(x, valid)
+    chosen = np.asarray(moe.route(x, router_w, bias, top_k, 1.5)[0])
+    keep = np.ones(t, bool) if valid is None else valid
+    np.testing.assert_array_equal(
+        np.asarray(sizes), np.bincount(chosen[keep].reshape(-1),
+                                       minlength=e))
+    assert sizes.dtype == jnp.int32 and int(sizes.sum()) == keep.sum() * top_k
+    assert np.abs(np.asarray(y)[~keep]).sum() == 0.0
+    perm = rng.permutation(t)
+    y_perm, sizes_perm = run(x[perm], None if valid is None
+                             else valid[perm])
+    np.testing.assert_array_equal(np.asarray(sizes_perm), np.asarray(sizes))
+    np.testing.assert_allclose(np.asarray(y_perm), np.asarray(y)[perm],
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_a_routed_layer_is_two_named_kernel_calls():
+    """One traced layer ticks ``moe_gmm`` twice: the fused gate-up call
+    at ``2·m·d·(2f)`` and ``down`` at ``2·m·f·d`` over the ``m`` =
+    T·top_k sorted rows, each operand and result once; both calls carry
+    the name the benchmark's ``moe_time_share.serve`` and
+    ``moe_decode_roofline.serve`` find their op events by."""
+    t, d, f, e, top_k = 24, 32, 128, 8, 2
+    m = t * top_k
+    z = lambda *shape: jnp.zeros(shape, jnp.bfloat16)
+    layer = lambda x: moe.routed_experts(
+        x, jnp.zeros((d, e)), jnp.zeros(e), z(e, d, f), z(e, d, f),
+        z(e, f, d), top_k=top_k, route_scale=1.0)
+    jaxpr = jax.make_jaxpr(layer)(jnp.zeros((t, d)))       # trace only
+    rows = _kernel_work()
+    assert rows[(K.MOE_GMM, "calls")] == 2
+    assert rows[(K.MOE_GMM, "flops")] == \
+        2.0 * m * d * (2 * f) + 2.0 * m * f * d
+    # rows, gate, up in and h out; h, down in and ys (float32) out
+    assert rows[(K.MOE_GMM, "bytes")] == \
+        2 * (m * d + 2 * e * d * f + m * f) \
+        + 2 * (m * f + e * f * d) + 4 * m * d
+    dispatch = {(s["labels"]["path"], s["labels"]["reason"]): s["value"]
+                for s in observe.REGISTRY.find(
+                    "moe_dispatch_total").samples()}
+    assert dispatch == {("grouped", ""): 1}
+
+    def kernel_names(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from kernel_names(sub)
+    names = list(kernel_names(jaxpr.jaxpr))
+    assert len(names) == 2
+    for name in names:
+        assert re.search(K.instruction_pattern(K.MOE_GMM),
+                         f"%{name}.7 = bf16[48,128] custom-call(")
 
 
 # ------------------------------------------------------------ the plan
