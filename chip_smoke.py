@@ -574,16 +574,21 @@ def decode_step_checks(compiled, pool_elems, patterns=None):
     opts.print_metadata = False
     text = "\n".join(m.to_string(opts)
                      for m in compiled.runtime_executable().hlo_modules())
-    calls, moves = 0, []
+    calls, moves, found = 0, [], set()
     for line in map(str.strip, text.splitlines()):
-        calls += any(re.search(pat, line) for pat in patterns)
+        hit = {pat for pat in patterns if re.search(pat, line)}
+        calls += bool(hit)
+        found |= hit
         m = _HLO_MOVE.match(line)
         if m and any(math.prod(int(n) for n in dims.split(",") if n)
                      >= pool_elems for dims in _moved_shapes(*m.groups())):
             moves.append(line[:160])
-    check(calls or not patterns,
-          f"no instruction of the compiled step matches {patterns}: the "
-          "traced run of the benchmark cannot find the paged decode kernel")
+    # under whatever scopes the step enters (ops/scopes.py): the default
+    # plan's call keeps the name it inherits only under none
+    check(found == set(patterns),
+          "no instruction of the compiled step matches "
+          f"{sorted(set(patterns) - found)}: the traced run of the "
+          "benchmark cannot find that kernel")
     check(not moves, "the compiled step copies, slices or relayouts a "
                      f"layer's pool or more: {moves}")
     return {"decode_calls": calls, "pool_sized_moves": len(moves)}
@@ -792,7 +797,8 @@ def phase_kernels(S, ctx):
                 plan=("window+rope+qknorm+gate+postnorm/swiglu",
                       "full+qknorm+gate+postnorm/routed+shared")),
             pages_g, page_g, pidx_g, lens_g,
-            [K.instruction_pattern(K.PAGED_DECODE) + ".*tpu_custom_call"])
+            [K.instruction_pattern(name) + ".*tpu_custom_call"
+             for name in (K.PAGED_DECODE, K.MOE_GMM)])
         # and of a latent plan: one pool of compressed rows, donated
         server_steps["latent"] = steps_of(
             DecoderConfig(

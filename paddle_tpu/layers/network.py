@@ -319,9 +319,12 @@ class NeuralNetwork:
             # groups appear in order via their output layers.
             # jax.named_scope threads the layer name into XLA's op_name
             # metadata so the compiled executable's fused regions key
-            # back to THIS layer (observe/costmodel.py attribution);
-            # scope cost is trace-time only, nothing per step.
-            with layer_stack.guard(name), jax.named_scope(name):
+            # back to THIS layer (observe/costmodel.py attribution),
+            # and outside it the layer's type, by which a device trace
+            # is summed (ops/scopes.py); scope cost is trace-time only,
+            # nothing per step.
+            with layer_stack.guard(name), \
+                    jax.named_scope(layer.conf.type), jax.named_scope(name):
                 if name in defer:
                     # forward conv+BN fusion: publish (z, a, c) — the
                     # consuming conv applies the affine in its input

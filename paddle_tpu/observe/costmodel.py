@@ -346,8 +346,11 @@ def _region_of(op_name: str, known: frozenset) -> Tuple[str, bool]:
     """(region, is_backward) for an ``op_name`` metadata path: the
     innermost path component whose unwrapped token (``transpose(jvp(x))``
     → ``x``) is a known region name; backward iff an autodiff
-    ``transpose(...)`` wrapper encloses it."""
-    region, bwd = "_unattributed", False
+    ``transpose(...)`` wrapper encloses it.  JAX closes a wrapper
+    behind the first name under it, and a layer's name follows its type
+    (``transpose(jvp(fc))/out/dot_general``, ``layers/network.py``), so
+    a wrapper encloses the components behind its own too."""
+    region, bwd, transposed = "_unattributed", False, False
     for comp in op_name.split("/"):
         tokens = []
         cur = comp
@@ -362,9 +365,9 @@ def _region_of(op_name: str, known: frozenset) -> Tuple[str, bool]:
         for t in tokens:
             if t in known:
                 hit = t
+        transposed = transposed or "transpose" in tokens[:-1]
         if hit is not None:
-            region = hit
-            bwd = "transpose" in tokens[:-1]
+            region, bwd = hit, transposed
     return region, bwd
 
 
@@ -793,25 +796,6 @@ def _report_from_compiled(compiled, known: frozenset, top: int,
     }
     _latest_report = out
     return out
-
-
-def render_table(report: Dict[str, Any]) -> str:
-    """Human-readable per-region roofline table."""
-    lines = [f"{'region':<28} {'GFLOPs':>10} {'MB':>10} {'int.':>8} "
-             f"{'bound':>8} {'t_est_ms':>9} {'share':>6} {'bwd%':>5}"]
-    for r in report.get("regions", []):
-        lines.append(
-            f"{r['region']:<28} {r['flops'] / 1e9:>10.3f} "
-            f"{r['bytes'] / 1e6:>10.2f} {r['intensity']:>8.2f} "
-            f"{r['bound']:>8} {r['time_est_s'] * 1e3:>9.3f} "
-            f"{r['share']:>6.1%} {r['bwd_frac']:>5.0%}")
-    p = report.get("peaks", {})
-    lines.append(
-        f"peaks: {p.get('flops', 0) / 1e12:.1f} TFLOP/s, "
-        f"{p.get('bw', 0) / 1e9:.0f} GB/s (ridge "
-        f"{p.get('ridge', 0):.1f} flop/B, source {p.get('source')}); "
-        f"flop agreement vs XLA: {report.get('flop_agreement')}")
-    return "\n".join(lines)
 
 
 def dump_report(report: Dict[str, Any], path: str) -> None:

@@ -50,6 +50,7 @@ from ..core.device import pallas_interpret
 from ..observe import counter
 from ..utils import enforce
 from . import kernels as K
+from . import scopes as S
 
 #: rows a tile holds at most; whole widths of ``k``; columns a tile
 #: holds at most: one rule for both kernels, a function of the shapes
@@ -282,28 +283,34 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, *,
     t, d = x.shape
     e = router_w.shape[1]
     record_moe_dispatch("grouped")
-    experts, weights = route(x, router_w, router_bias, top_k, route_scale)
-    # the flat list of choices is choice-major, token t's choice c at
-    # c·T + t: the results back in that order are ``top_k`` slabs of
-    # [T, d] that the weighted sum adds (as [T, top_k, d] a top_k of 4
-    # is second-minor, and the view a relayout copy of every row)
-    flat = experts.T.reshape(-1)
-    if valid is not None:
-        # the sentinel group E sorts behind every expert
-        flat = jnp.where(jnp.tile(valid, top_k), flat, e)
-    order = jnp.argsort(flat, stable=True)                # [k·T]
-    # a compare and a sum, not a scatter of k·T ones (serial on the
-    # chip); the sentinel equals no expert
-    group_sizes = (flat[:, None] == jnp.arange(e, dtype=flat.dtype)).sum(
-        axis=0, dtype=jnp.int32)
-    xs = x.astype(w_gate.dtype)[order % t]                # [k·T, d]
-    h = grouped_glu(xs, w_gate, w_up, group_sizes, w_down.dtype)
-    ys = grouped_matmul(h, w_down, group_sizes)           # [k·T, d]
-    # back to the flat list's order: choice c lies at row inv[c]
-    inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    per_choice = ys[inv].reshape(top_k, t, d)
-    if valid is not None:
-        per_choice = jnp.where(valid[None, :, None], per_choice, 0.0)
-    y = jnp.sum(per_choice * weights.T[:, :, None], axis=0)
+    with jax.named_scope(S.ROUTE):
+        experts, weights = route(x, router_w, router_bias, top_k,
+                                 route_scale)
+    with jax.named_scope(S.SORT):
+        # the flat list of choices is choice-major, token t's choice c
+        # at c·T + t: the results back in that order are ``top_k`` slabs
+        # of [T, d] that the weighted sum adds (as [T, top_k, d] a top_k
+        # of 4 is second-minor, and the view a relayout copy of every
+        # row)
+        flat = experts.T.reshape(-1)
+        if valid is not None:
+            # the sentinel group E sorts behind every expert
+            flat = jnp.where(jnp.tile(valid, top_k), flat, e)
+        order = jnp.argsort(flat, stable=True)                # [k·T]
+        # a compare and a sum, not a scatter of k·T ones (serial on the
+        # chip); the sentinel equals no expert
+        group_sizes = (flat[:, None] == jnp.arange(e, dtype=flat.dtype)
+                       ).sum(axis=0, dtype=jnp.int32)
+        xs = x.astype(w_gate.dtype)[order % t]                # [k·T, d]
+    with jax.named_scope(S.EXPERTS):
+        h = grouped_glu(xs, w_gate, w_up, group_sizes, w_down.dtype)
+        ys = grouped_matmul(h, w_down, group_sizes)           # [k·T, d]
+    with jax.named_scope(S.COMBINE):
+        # back to the flat list's order: choice c lies at row inv[c]
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        per_choice = ys[inv].reshape(top_k, t, d)
+        if valid is not None:
+            per_choice = jnp.where(valid[None, :, None], per_choice, 0.0)
+        y = jnp.sum(per_choice * weights.T[:, :, None], axis=0)
     return y, group_sizes

@@ -61,6 +61,7 @@ loads it through the shared ``loader.read_manifest`` /
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import os
@@ -73,6 +74,7 @@ import numpy as np
 from ..layers.beam_search import eos_frozen_logits
 from ..observe.trace import span as _span
 from ..ops import kernels as K
+from ..ops import scopes as S
 from ..ops.pallas_attention import (flash_attention_packed,
                                     latent_decode_attention,
                                     paged_decode_attention, paged_kv_write,
@@ -367,18 +369,20 @@ def _qkv(x, pos, params, i, cfg: DecoderConfig, attn):
     or None."""
     b, t, _ = x.shape
     h, g, dh = cfg.heads, kv_heads(cfg), head_dim(cfg)
-    xn = _rms(x, params[f"l{i}.ln1"], cfg.norm_eps)
-    q = weight_matmul(xn, params[f"l{i}.wq"]).reshape(b, t, h, dh)
-    k = weight_matmul(xn, params[f"l{i}.wk"]).reshape(b, t, g, dh)
-    v = weight_matmul(xn, params[f"l{i}.wv"]).reshape(b, t, g, dh)
-    if "qknorm" in attn:
-        q = _rms(q, params[f"l{i}.qn"], cfg.norm_eps)
-        k = _rms(k, params[f"l{i}.kn"], cfg.norm_eps)
-    if "rope" in attn:
-        q, k = (_rope(a, pos, cfg.rope_theta, cfg.rope_interleave)
-                for a in (q, k))
-    gate = jax.nn.sigmoid(weight_matmul(xn, params[f"l{i}.wg"])) \
-        if "gate" in attn else None
+    with jax.named_scope(S.MIXER_NORM.format(i)):
+        xn = _rms(x, params[f"l{i}.ln1"], cfg.norm_eps)
+    with jax.named_scope(S.QKV.format(i)):
+        q = weight_matmul(xn, params[f"l{i}.wq"]).reshape(b, t, h, dh)
+        k = weight_matmul(xn, params[f"l{i}.wk"]).reshape(b, t, g, dh)
+        v = weight_matmul(xn, params[f"l{i}.wv"]).reshape(b, t, g, dh)
+        if "qknorm" in attn:
+            q = _rms(q, params[f"l{i}.qn"], cfg.norm_eps)
+            k = _rms(k, params[f"l{i}.kn"], cfg.norm_eps)
+        if "rope" in attn:
+            q, k = (_rope(a, pos, cfg.rope_theta, cfg.rope_interleave)
+                    for a in (q, k))
+        gate = jax.nn.sigmoid(weight_matmul(xn, params[f"l{i}.wg"])) \
+            if "gate" in attn else None
     return q, k, v, gate
 
 
@@ -404,18 +408,20 @@ def _latent_qrow(x, pos, params, i, cfg: DecoderConfig, attn):
     b, t, _ = x.shape
     p = lambda leaf: params[f"l{i}.{leaf}"]
     r, dn = cfg.kv_rank, cfg.nope_dim
-    a = _rms(x, p("ln1"), cfg.norm_eps)
-    cq = _rms(weight_matmul(a, p("w_dq")), p("q_ln"), cfg.norm_eps)
-    q = weight_matmul(cq, p("w_uq")).reshape(b, t, cfg.heads, -1)
-    ckr = weight_matmul(a, p("w_dkv"))
-    c = _rms(ckr[..., :r], p("kv_ln"), cfg.norm_eps)
-    q_r, k_r = q[..., dn:], ckr[:, :, None, r:]
-    if "rope" in attn:
-        q_r, k_r = (_rope(a, pos, cfg.rope_theta, cfg.rope_interleave)
-                    for a in (q_r, k_r))
-    row = _lanes(jnp.concatenate([c, k_r[:, :, 0]], axis=-1),
-                 latent_row_width(cfg))
-    return q[..., :dn], q_r, row.astype(cfg.storage)
+    with jax.named_scope(S.MIXER_NORM.format(i)):
+        a = _rms(x, p("ln1"), cfg.norm_eps)
+    with jax.named_scope(S.QKV.format(i)):
+        cq = _rms(weight_matmul(a, p("w_dq")), p("q_ln"), cfg.norm_eps)
+        q = weight_matmul(cq, p("w_uq")).reshape(b, t, cfg.heads, -1)
+        ckr = weight_matmul(a, p("w_dkv"))
+        c = _rms(ckr[..., :r], p("kv_ln"), cfg.norm_eps)
+        q_r, k_r = q[..., dn:], ckr[:, :, None, r:]
+        if "rope" in attn:
+            q_r, k_r = (_rope(a, pos, cfg.rope_theta, cfg.rope_interleave)
+                        for a in (q_r, k_r))
+        row = _lanes(jnp.concatenate([c, k_r[:, :, 0]], axis=-1),
+                     latent_row_width(cfg))
+        return q[..., :dn], q_r, row.astype(cfg.storage)
 
 
 def _latent_scale(cfg: DecoderConfig) -> float:
@@ -544,24 +550,31 @@ def _ffn(x, valid, params, i, cfg: DecoderConfig, attn, ffn):
     sends the others nowhere).  → (stream, the routed layer's tokens
     per expert [E] or None)."""
     p = lambda leaf: params[f"l{i}.{leaf}"]
-    m = _rms(x, p("ln2"), cfg.norm_eps)
     sizes = None
-    if "gelu" in ffn:
-        y = weight_matmul(jax.nn.gelu(weight_matmul(m, p("w1"))), p("w2"))
-    elif "swiglu" in ffn:
-        y = _swiglu(m, p("w_gate"), p("w_up"), p("w_down"))
-    else:
-        b, t, d = m.shape
-        y, sizes = routed_experts(
-            m.reshape(b * t, d), p("router"), p("router_bias"),
-            p("e_gate"), p("e_up"), p("e_down"), top_k=cfg.top_k,
-            route_scale=cfg.route_scale, valid=valid.reshape(-1))
-        y = y.reshape(b, t, d)
-        if "shared" in ffn:
-            y = y + _swiglu(m, p("s_gate"), p("s_up"), p("s_down"))
-    if "postnorm" in attn:
-        y = _rms(y, p("ln2p"), cfg.norm_eps)
-    return x + y, sizes
+    with jax.named_scope(S.FFN.format(i)):
+        with jax.named_scope(S.FFN_NORM):
+            m = _rms(x, p("ln2"), cfg.norm_eps)
+        if "gelu" in ffn:
+            with jax.named_scope(S.DENSE):
+                y = weight_matmul(jax.nn.gelu(weight_matmul(m, p("w1"))),
+                                  p("w2"))
+        elif "swiglu" in ffn:
+            with jax.named_scope(S.DENSE):
+                y = _swiglu(m, p("w_gate"), p("w_up"), p("w_down"))
+        else:
+            b, t, d = m.shape
+            y, sizes = routed_experts(
+                m.reshape(b * t, d), p("router"), p("router_bias"),
+                p("e_gate"), p("e_up"), p("e_down"), top_k=cfg.top_k,
+                route_scale=cfg.route_scale, valid=valid.reshape(-1))
+            y = y.reshape(b, t, d)
+            if "shared" in ffn:
+                with jax.named_scope(S.SHARED):
+                    y = y + _swiglu(m, p("s_gate"), p("s_up"), p("s_down"))
+        if "postnorm" in attn:
+            with jax.named_scope(S.FFN_NORM):
+                y = _rms(y, p("ln2p"), cfg.norm_eps)
+        return x + y, sizes
 
 
 def _route_counts(sizes):
@@ -610,20 +623,24 @@ def _prefill_impl(params, pools, tokens, lengths, page_indices,
     other and mask padding outright."""
     b, t = tokens.shape
     h, g, dh = cfg.heads, kv_heads(cfg), head_dim(cfg)
-    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
-    x = _embed(params, tokens, pos, cfg)
-    segments = segments_from_lengths(lengths, b, t)
-    valid = pos < lengths[:, None]
-    zero = jnp.zeros((b,), jnp.int32)
-    shapes, pools, held = _kv_and_state(pools, cfg)
+    with jax.named_scope(S.EMBED):
+        pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :],
+                               (b, t))
+        x = _embed(params, tokens, pos, cfg)
+        segments = segments_from_lengths(lengths, b, t)
+        valid = pos < lengths[:, None]
+        zero = jnp.zeros((b,), jnp.int32)
+    with jax.named_scope(S.CACHE_LAYOUT):
+        shapes, pools, held = _kv_and_state(pools, cfg)
     n_places = shapes[0][1]
     a = c = 0            # the layer's index among its kind: its share
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
         if "conv" in attn:
             # a sequence's state lies at the number of its first page
-            x, held = _conv_prefill(
-                x, held, page_indices[:, 0] + c * n_places, lengths,
-                params, i, cfg)
+            with jax.named_scope(S.CONV.format(i)):
+                x, held = _conv_prefill(
+                    x, held, page_indices[:, 0] + c * n_places, lengths,
+                    params, i, cfg)
             c += 1
         else:
             table = page_indices + a * n_places
@@ -633,30 +650,40 @@ def _prefill_impl(params, pools, tokens, lengths, page_indices,
             # prompt now
             if "latent" in attn:
                 q_n, q_r, row = _latent_qrow(x, pos, params, i, cfg, attn)
-                pools = [paged_row_write(pools[0], row, table, zero,
-                                         lengths)]
-                o, gate = _latent_expanded(q_n, q_r, row, params, i, cfg,
-                                           segments, t), None
+                with jax.named_scope(S.CACHE_WRITE.format(i)):
+                    pools = [paged_row_write(pools[0], row, table, zero,
+                                             lengths)]
+                with jax.named_scope(S.ATTEND.format(i)):
+                    o, gate = _latent_expanded(q_n, q_r, row, params, i,
+                                               cfg, segments, t), None
             else:
                 q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
-                pools = paged_kv_write(*pools, k, v, table, zero, lengths)
-                # the kernel multiplies what the pool stores
-                q, k, v = (m.astype(pools[0].dtype) for m in (q, k, v))
-                o = flash_attention_packed(
-                    q.reshape(1, b * t, h, dh), k.reshape(1, b * t, g, dh),
-                    v.reshape(1, b * t, g, dh), segments, causal=True,
-                    slot=t, window=cfg.window if "window" in attn else 0)
-                o = o.reshape(b, t, h * dh).astype(jnp.float32)
-            x = _attend_out(x, o, gate, params, i, cfg, attn)
+                with jax.named_scope(S.CACHE_WRITE.format(i)):
+                    pools = paged_kv_write(*pools, k, v, table, zero,
+                                           lengths)
+                with jax.named_scope(S.ATTEND.format(i)):
+                    # the kernel multiplies what the pool stores
+                    q, k, v = (m.astype(pools[0].dtype) for m in (q, k, v))
+                    o = flash_attention_packed(
+                        q.reshape(1, b * t, h, dh),
+                        k.reshape(1, b * t, g, dh),
+                        v.reshape(1, b * t, g, dh), segments, causal=True,
+                        slot=t,
+                        window=cfg.window if "window" in attn else 0)
+                    o = o.reshape(b, t, h * dh).astype(jnp.float32)
+            with jax.named_scope(S.MIXER_OUT.format(i)):
+                x = _attend_out(x, o, gate, params, i, cfg, attn)
         x, _ = _ffn(x, valid, params, i, cfg, attn, ffn)
-    last = jnp.take_along_axis(
-        x, jnp.clip(lengths - 1, 0, t - 1)[:, None, None], axis=1)[:, 0]
-    logits = weight_matmul(_rms(last, params["ln_f"], cfg.norm_eps),
-                           params["lm_head"])
-    active = lengths > 0
-    nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
-    return (nxt.astype(jnp.int32), logits,
-            *_as_stored(shapes, pools, held))
+    with jax.named_scope(S.HEAD):
+        last = jnp.take_along_axis(
+            x, jnp.clip(lengths - 1, 0, t - 1)[:, None, None], axis=1)[:, 0]
+        logits = weight_matmul(_rms(last, params["ln_f"], cfg.norm_eps),
+                               params["lm_head"])
+        active = lengths > 0
+        nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
+        nxt = nxt.astype(jnp.int32)
+    with jax.named_scope(S.CACHE_LAYOUT):
+        return nxt, logits, *_as_stored(shapes, pools, held)
 
 
 def _decode_impl(params, pools, tokens, page_indices, lengths, active,
@@ -669,24 +696,30 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
     followed by :func:`_route_counts`' integers where the plan has
     routed layers; then the logits and the updated pools."""
     b = tokens.shape[0]
-    pos = jnp.clip(lengths - 1, 0, cfg.max_context - 1)[:, None]
-    x = _embed(params, tokens[:, None], pos, cfg)
-    counts = active.astype(jnp.int32)
-    klen = jnp.where(active, lengths, 1).astype(jnp.int32)
+    with jax.named_scope(S.EMBED):
+        pos = jnp.clip(lengths - 1, 0, cfg.max_context - 1)[:, None]
+        x = _embed(params, tokens[:, None], pos, cfg)
+        counts = active.astype(jnp.int32)
+        klen = jnp.where(active, lengths, 1).astype(jnp.int32)
     # the default plan's kernel keeps the name its call inherits (see
-    # paged_decode_attention); a planned decoder's reads %paged_decode
+    # paged_decode_attention): the ``%_lambda_`` of the step's jit, which
+    # it has only under no scope at all, so there ``attend`` is empty and
+    # the call stands bare; a planned decoder's reads %paged_decode
+    # under any scope
     name = K.PAGED_DECODE if cfg.plan else None
     sizes = []
-    shapes, pools, held = _kv_and_state(pools, cfg)
+    with jax.named_scope(S.CACHE_LAYOUT):
+        shapes, pools, held = _kv_and_state(pools, cfg)
     n_places = shapes[0][1]
     a = c = 0
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
         if "conv" in attn:
             # the row's state lies at its first page's number, an idle
             # slot's at the scratch page's
-            x, held = _conv_decode(
-                x, held, page_indices[:, 0] + c * n_places, active,
-                params, i, cfg)
+            with jax.named_scope(S.CONV.format(i)):
+                x, held = _conv_decode(
+                    x, held, page_indices[:, 0] + c * n_places, active,
+                    params, i, cfg)
             c += 1
         else:
             table = page_indices + a * n_places
@@ -696,27 +729,35 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
             # it, nothing else, before the next layer's rows are written
             if "latent" in attn:
                 q_n, q_r, row = _latent_qrow(x, pos, params, i, cfg, attn)
-                pools = [paged_row_write(pools[0], row, table, lengths - 1,
-                                         counts)]
-                o, gate = _latent_absorbed(q_n, q_r, pools[0], table, klen,
-                                           params, i, cfg), None
+                with jax.named_scope(S.CACHE_WRITE.format(i)):
+                    pools = [paged_row_write(pools[0], row, table,
+                                             lengths - 1, counts)]
+                with jax.named_scope(S.ATTEND.format(i)):
+                    o, gate = _latent_absorbed(q_n, q_r, pools[0], table,
+                                               klen, params, i, cfg), None
             else:
                 q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
-                pools = paged_kv_write(*pools, k, v, table, lengths - 1,
-                                       counts)
-                o = paged_decode_attention(
-                    q, *pools, table, klen,
-                    window=cfg.window if "window" in attn else 0,
-                    name=name).reshape(b, 1, -1)
-            x = _attend_out(x, o, gate, params, i, cfg, attn)
+                with jax.named_scope(S.CACHE_WRITE.format(i)):
+                    pools = paged_kv_write(*pools, k, v, table, lengths - 1,
+                                           counts)
+                with jax.named_scope(S.ATTEND.format(i)) if cfg.plan \
+                        else contextlib.nullcontext():
+                    o = paged_decode_attention(
+                        q, *pools, table, klen,
+                        window=cfg.window if "window" in attn else 0,
+                        name=name).reshape(b, 1, -1)
+            with jax.named_scope(S.MIXER_OUT.format(i)):
+                x = _attend_out(x, o, gate, params, i, cfg, attn)
         x, routed = _ffn(x, active[:, None], params, i, cfg, attn, ffn)
         if routed is not None:
             sizes.append(routed)
-    logits = weight_matmul(_rms(x[:, 0], params["ln_f"], cfg.norm_eps),
-                           params["lm_head"])
-    nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
-    return (jnp.concatenate([nxt.astype(jnp.int32), _route_counts(sizes)]),
-            logits, *_as_stored(shapes, pools, held))
+    with jax.named_scope(S.HEAD):
+        logits = weight_matmul(_rms(x[:, 0], params["ln_f"], cfg.norm_eps),
+                               params["lm_head"])
+        nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
+        ids = jnp.concatenate([nxt.astype(jnp.int32), _route_counts(sizes)])
+    with jax.named_scope(S.CACHE_LAYOUT):
+        return ids, logits, *_as_stored(shapes, pools, held)
 
 
 def n_kv_pools(cfg: DecoderConfig) -> int:
@@ -771,7 +812,8 @@ def _jitted_steps(cfg: DecoderConfig):
 
 
 def _fed_ids(tokens, prev, src):
-    return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
+    with jax.named_scope(S.EMBED):
+        return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
 
 
 class KVPool:

@@ -133,6 +133,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import itertools
 import json
 import threading
@@ -159,6 +160,11 @@ except ImportError:  # pragma: no cover - standalone copy
     make_threading_server = resolve_bind_host = None
 
 log = get_logger("serving")
+
+#: a launch period (one turn of the decode loop: PERF.md §3) longer than
+#: this many seconds is a host stall: a decode turn takes 5-10 ms, a
+#: prefill turn of the longest prompts under 100
+STALL_S = 0.25
 
 #: Decode-loop thread name (thread-leak guard + ptpu-lint contract).
 DECODE_THREAD_NAME = "ptpu-serve-decode"
@@ -382,6 +388,15 @@ class InferenceServer:
             "serve_rows_discarded_total",
             "rows a decode launch computed for a request that EOS had "
             "already ended (the host learns of an EOS one launch late)")
+        self._m_stall = None if _counter is None else _counter(
+            "serve_stall_total",
+            f"launch periods longer than {STALL_S} s, by the phase of "
+            "the turn that took most of it (admit | build | dispatch | "
+            "fetch | emit)")
+        # the turn's phases as they start, (phase, host clock), and the
+        # collector's count at the turn before: see _note_period
+        self._marks: List = []
+        self._collections = 0
 
     # what a caller that knows a K and a V pool reaches for (the
     # benchmark's warm-up): the first and the last of the pools of the
@@ -704,6 +719,8 @@ class InferenceServer:
         the host works a launch ahead, and the spans tile this thread's
         time in launch order."""
         idle = not self._inflight
+        self._marks = []
+        cpu = time.thread_time()
         cur = self._next_launch() if idle else self._inflight[0]
         if cur is None:
             return False
@@ -715,7 +732,34 @@ class InferenceServer:
             if ahead is not None:
                 self._launch(ahead)
             self._collect(step)
+        self._note_period(cur.kind, time.thread_time() - cpu)
         return True
+
+    def _note_period(self, kind: str, cpu_s: float) -> None:
+        """The turn that just ended, if it took longer than
+        :data:`STALL_S`: one tick of ``serve_stall_total`` under the
+        phase that took most of it and one line with what tells a
+        sleeping thread from a running one (its CPU seconds) and a
+        collection from neither.  One serve run in five meets such a
+        period of 2-4.5 s with tracing off, where no span says in which
+        phase (PERF.md §6)."""
+        marks = self._marks + [("", time.perf_counter())]
+        collections = sum(g["collections"] for g in gc.get_stats())
+        wall = marks[-1][1] - marks[0][1]
+        if wall > STALL_S:
+            took: Dict[str, float] = {}
+            for (phase, t0), (_, t1) in zip(marks, marks[1:]):
+                took[phase] = took.get(phase, 0.0) + (t1 - t0)
+            phase = max(took, key=took.get)
+            if self._m_stall is not None:
+                self._m_stall.inc(phase=phase)
+            log.warning(
+                "serve stall: the launch period of a %s took %.3f s, "
+                "%.3f s of it in %s (this thread ran for %.3f s of it; "
+                "%d gc collections since the period before)", kind, wall,
+                took[phase], phase, cpu_s,
+                collections - self._collections)
+        self._collections = collections
 
     def _next_launch(self) -> Optional[_Launch]:
         """What to queue now, behind whatever is in flight: a parked
@@ -724,6 +768,7 @@ class InferenceServer:
         active rows advance one token."""
         reprefill: List[Request] = []
         swapped = False
+        self._marks.append(("admit", time.perf_counter()))
         with self._cond:
             if self._stop:
                 return None
@@ -836,6 +881,7 @@ class InferenceServer:
     def _launch(self, launch: _Launch) -> None:
         """Build the launch's inputs and queue it on the device."""
         rows, n = launch.rows, len(launch.rows)
+        self._marks.append(("build", time.perf_counter()))
         if self._m_launch is not None:
             self._m_launch.inc(kind=launch.kind,
                                queued=launch.attrs["queued"])
@@ -855,6 +901,7 @@ class InferenceServer:
             delay = getattr(self.model, "debug_prefill_delay_s", 0.0)
             if delay:
                 time.sleep(delay)
+            self._marks.append(("dispatch", time.perf_counter()))
             launch.handle = self.model.launch_prefill(
                 *self._pools, tokens, lengths, tables)
         else:
@@ -883,6 +930,7 @@ class InferenceServer:
             # one is queued behind, on the device
             prev = self._inflight[-1].handle if max(launch.src) >= 0 \
                 else None
+            self._marks.append(("dispatch", time.perf_counter()))
             launch.handle = self.model.launch_decode(
                 *self._pools, tokens, tables, lengths, active, prev, src)
         self._inflight.append(launch)
@@ -894,6 +942,7 @@ class InferenceServer:
         dropped: not emitted, not counted."""
         launch = self._inflight.popleft()
         handle, launch.handle = launch.handle, None
+        self._marks.append(("fetch", time.perf_counter()))
         if launch.kind == "prefill":
             ids, _ = self.model.collect_prefill(handle)
         else:
@@ -901,6 +950,7 @@ class InferenceServer:
             step.set(**routed)      # experts_hit, expert_load_max
         with _span("serve_step_emit"):
             now = time.perf_counter()
+            self._marks.append(("emit", now))
             live = [(r, t) for r, t in zip(launch.rows, ids.tolist())
                     if r.state == "active"]
             self._count_tokens(len(live))

@@ -35,6 +35,7 @@ from ..optimizer import Optimizer, create_optimizer, make_schedule
 from ..optimizer import loss_scale as ls
 from .. import observe
 from ..observe import trace
+from ..ops import scopes as S
 from ..utils import FLAGS, PaddleTpuError, enforce, get_logger, global_stat
 from . import events as ev
 from .checkpoint import (
@@ -595,7 +596,7 @@ class Trainer:
             # named_scope: the update lands in its own "optimizer"
             # region in the compiled-step cost attribution
             # (observe/costmodel.py) instead of polluting layer regions
-            with jax.named_scope("optimizer"):
+            with jax.named_scope(S.OPTIMIZER):
                 if ex_plan:
                     count, slots = opt_state
                     dense_slots = [s for n, s in zip(leaf_names, slots)
@@ -618,7 +619,7 @@ class Trainer:
             if hs_stats is not None:
                 # the health aux scopes as its own attribution region,
                 # like the optimizer — it must not pollute layer costs
-                with jax.named_scope("health"):
+                with jax.named_scope(S.HEALTH):
                     new_health = _health.accumulate(
                         health_state[0],
                         hs_stats(grads, params, new_params),
@@ -755,7 +756,7 @@ class Trainer:
                 masks = {n: (touched_row_mask(g) if n in sparse_names
                              else None)
                          for n, g in grads.items()}
-            with jax.named_scope("optimizer"):
+            with jax.named_scope(S.OPTIMIZER):
                 if ex_plan:
                     count, slots = opt_state
                     dense_slots = [s for n, s in zip(leaf_names, slots)
@@ -783,7 +784,7 @@ class Trainer:
                 # post-select new_params: a skipped step reports a zero
                 # update norm (nothing was applied), and its non-finite
                 # counts land in the benign bucket (applied=finite)
-                with jax.named_scope("health"):
+                with jax.named_scope(S.HEALTH):
                     new_health = _health.accumulate(
                         health_state[0],
                         hs_stats(grads, params, new_params, nf_counts),

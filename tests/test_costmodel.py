@@ -218,21 +218,6 @@ def test_detect_peaks_has_ridge_and_flag_override():
         FLAGS.set("roofline_peak_gbps", saved_b)
 
 
-def test_render_table_lists_every_region():
-    rep = costmodel.attribute(SYNTH_HLO, {"__mm_1__", "__ew_1__"})
-    rows = []
-    for name, r in rep["regions"].items():
-        work = r["flops"] + r["trans"]
-        rows.append({"region": name, "flops": work, "bytes": r["bytes"],
-                     "bwd_frac": 0.0,
-                     **costmodel.roofline(work, r["bytes"], PEAKS),
-                     "share": 0.5})
-    txt = costmodel.render_table({"regions": rows, "peaks": PEAKS,
-                                  "flop_agreement": 1.0})
-    assert "__mm_1__" in txt and "__ew_1__" in txt
-    assert "compute" in txt and "memory" in txt
-
-
 # ------------------------------------------------------ real compiled step
 def _tiny_trainer(seed=0):
     from paddle_tpu.config import dsl

@@ -18,6 +18,7 @@ Three layers under test:
 import json
 import os
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -376,6 +377,50 @@ def test_a_failing_launch_with_one_in_flight_fails_its_requests(tiny_model):
                                            _prompts(1, seed=44)[0], 6)
     finally:
         del tiny_model.launch_decode
+
+
+def test_a_stalled_turn_is_counted_under_its_phase(tiny_model, monkeypatch):
+    """A decode fetch that holds the loop's thread for longer than
+    ``STALL_S`` (asleep: no CPU) ticks ``serve_stall_total{phase=fetch}``
+    and leaves a line that says so.  Tracing is off: no span is there
+    to say it."""
+    import logging
+
+    from paddle_tpu import observe
+    from paddle_tpu.serving import server as srv_mod
+    from paddle_tpu.serving.model import DecoderModel
+
+    model = DecoderModel(
+        {k: np.asarray(v) for k, v in tiny_model.params.items()},
+        tiny_model.cfg)
+    _serve_all(model, _prompts(2, seed=5), max_new=4)    # compiled
+    real, calls = model.collect_decode, []
+
+    def collect_decode(handle):
+        calls.append(1)
+        if len(calls) == 3:
+            time.sleep(2 * srv_mod.STALL_S)
+        return real(handle)
+    monkeypatch.setattr(model, "collect_decode", collect_decode)
+    stalls = observe.counter("serve_stall_total", "")
+    before = stalls.value(phase="fetch")
+    records, handler = [], logging.Handler()
+    handler.emit = records.append
+    srv_mod.log.addHandler(handler)
+    try:
+        got = _serve_all(model, _prompts(2, seed=5), max_new=8)
+    finally:
+        srv_mod.log.removeHandler(handler)
+    assert len(calls) >= 4 and all(len(t) == 8 for t in got)
+    # (a loaded machine may stall a turn of its own: held to nothing)
+    assert stalls.value(phase="fetch") - before >= 1
+    planted = [r.getMessage() for r in records
+               if "serve stall" in r.getMessage()
+               and " in fetch " in r.getMessage()]
+    assert planted and "launch period of a decode took 0." in planted[0]
+    # asleep, not running: the thread's CPU seconds stay far below
+    ran = float(planted[0].split("ran for ")[1].split(" s")[0])
+    assert ran < srv_mod.STALL_S
 
 
 def test_stop_with_a_launch_in_flight_leaves_nothing(tiny_model):
