@@ -8,7 +8,10 @@ seed, every file written under ``--out`` (default
     python chip_smoke.py              # CLI LSTM job, T=2048 transformer
                                       # steps, HTTP server, kernel checks
     python chip_smoke.py --all        # + one train step each of ResNet-50,
-                                      # seq2seq, LSTM hidden 1280, sparse CTR
+                                      # seq2seq, LSTM hidden 1280, sparse CTR;
+                                      # the paged decode kernel timed at the
+                                      # serve cells' rows (decode_walk)
+    python chip_smoke.py --only decode_walk   # just the named phases
     python chip_smoke.py --fsdp --hlo # transformer under FSDP; dump the
                                       # compiled steps and print what each
                                       # Mosaic call sees per chip
@@ -75,14 +78,32 @@ FULL = {
                 # dense reference scores are [B, H, T, T] f32: B=4 fits
                 "flash": (4, 2048, 8, 64, 512),
                 "packed": (5, 96, 8, 32),
-                "decode": (8, 8, 32, 512, 16, 32),
+                # tables wider than the pages one loop step of the
+                # kernel takes (32 of 16, 8 of 64): rows can end beyond
+                # a chunk's edge
+                "decode": (8, 8, 32, 512, 16, 48),
                 # the routed decoder's row: 32 heads over 4 K/V heads of
                 # 128, pages of 64; (tokens, experts, a token, dim, width)
-                "decode_gqa": (8, 32, 4, 128, 128, 64, 8),
+                "decode_gqa": (8, 32, 4, 128, 128, 64, 12),
                 # the latent decoder's row: 32 heads over rows of 512 +
                 # 64 numbers in 640 lanes, pages of 64, 24 slots a row
                 "decode_latent": (8, 32, 512, 64, 256, 64, 24),
                 "moe": (256, 16, 4, 512, 256)},
+    # the paged decode kernel at the serve cells' own rows (decode_walk):
+    # (query heads, K/V heads, head lanes, page, pool dtype, window,
+    # prompts a row is drawn from, the most tokens generated behind one)
+    "decode_walk": {
+        "batch": 16, "live_rows": 11, "slots": 128, "pool_pages": 4096,
+        "layers": 24, "reps": 20, "chunks": (1, 2, 4, 8, 16), "seed": 36,
+        "shapes": {
+            "dense": (32, 32, 64, 16, "float32", 0,
+                      (128, 128, 128, 256, 256, 384, 512), 192),
+            "trinity_window": (32, 4, 128, 64, "bfloat16", 2048,
+                               (1024, 1024, 2048, 2048, 4096, 6144), 256),
+            "trinity_full": (32, 4, 128, 64, "bfloat16", 0,
+                             (1024, 1024, 2048, 2048, 4096, 6144), 256),
+            "lfm2": (32, 8, 64, 64, "bfloat16", 0,
+                     (1024, 2048, 2048, 3072, 4096), 192)}},
     "resnet": {"depth": 50, "image": 224, "batch": 128, "classes": 1000},
     "seq2seq": {"B": 128, "S_LEN": 30, "T_LEN": 30, "V": 30000, "E": 512,
                 "H": 512},
@@ -105,6 +126,11 @@ REHEARSAL = {
                 "decode_gqa": (4, 4, 2, 16, 32, 16, 4),
                 "decode_latent": (4, 4, 32, 8, 32, 16, 4),
                 "moe": (16, 4, 2, 32, 32)},
+    "decode_walk": {
+        "batch": 4, "live_rows": 3, "slots": 8, "pool_pages": 40,
+        "layers": 2, "reps": 1, "chunks": (1,), "seed": 36,
+        "shapes": {
+            "trinity_window": (4, 2, 32, 4, "bfloat16", 8, (8, 12, 20), 8)}},
     "resnet": {"depth": 8, "image": 32, "batch": 8, "classes": 10},
     "seq2seq": {"B": 8, "S_LEN": 6, "T_LEN": 6, "V": 200, "E": 128,
                 "H": 128},
@@ -679,13 +705,24 @@ def phase_kernels(S, ctx):
         want = packed()
     errs["packed_prefill"] = _rel_err(got, want)
 
+    def decode_lengths(rows, slots, page, *call):
+        """Seeded lengths whose first three lie at, one under and one
+        over the edge of the chunk the kernel takes a loop step at
+        ``call``'s shapes (its gauge says which)."""
+        jax.eval_shape(pa.paged_decode_attention, *call,
+                       jnp.ones((rows,), jnp.int32))
+        edge = page * pages_a_step(call[1])
+        lens = rng.randint(1, slots * page, (rows,))
+        lens[:3] = np.minimum((edge, edge - 1, edge + 1), slots * page)
+        return jnp.asarray(lens, jnp.int32)
+
     # paged decode vs the dense gather reference
     b, nh, d, n_pages, page, max_pages = S["decode"]
     q = randn(b, 1, nh, d)
     kp, vp = randn(n_pages, page, nh, d), randn(n_pages, page, nh, d)
-    lens = jnp.asarray(rng.randint(1, max_pages * page, (b,)), jnp.int32)
     pidx = jnp.asarray(rng.permutation(n_pages - 1)[:b * max_pages]
                        .reshape(b, max_pages) + 1, jnp.int32)
+    lens = decode_lengths(b, max_pages, page, q, kp, vp, pidx)
     errs["paged_decode"] = _rel_err(
         jax.jit(pa.paged_decode_attention)(q, kp, vp, pidx, lens),
         jax.jit(pa.paged_decode_reference)(q, kp, vp, pidx, lens))
@@ -696,9 +733,9 @@ def phase_kernels(S, ctx):
     qg = randn(bg, 1, hg, dg)
     kg, vg = (randn(pages_g, page_g, g * dg, dtype=jnp.bfloat16)
               for _ in range(2))
-    lens_g = jnp.asarray(rng.randint(1, slots_g * page_g, (bg,)), jnp.int32)
     pidx_g = jnp.asarray(rng.permutation(pages_g - 1)[:bg * slots_g]
                          .reshape(bg, slots_g) + 1, jnp.int32)
+    lens_g = decode_lengths(bg, slots_g, page_g, qg, kg, vg, pidx_g)
     errs["paged_decode_gqa_window"] = _rel_err(
         jax.jit(lambda *a: pa.paged_decode_attention(
             *a, window=2 * page_g))(qg, kg, vg, pidx_g, lens_g),
@@ -866,6 +903,117 @@ def phase_kernels(S, ctx):
     return {"rel_err": errs, "tolerance": REL_TOL,
             "named_kernels": sorted(lowered),
             "server_steps": server_steps}, None
+
+
+def pages_a_step(k_pages):
+    """Pages a loop step the paged decode kernel takes over pool
+    ``k_pages``, as the last call traced over it told its gauge."""
+    from paddle_tpu import observe
+
+    width = math.prod(k_pages.shape[2:])
+    return int(observe.REGISTRY.find("paged_decode_pages_per_step").value(
+        page=str(k_pages.shape[1]), width=str(width + -width % 128),
+        dtype=k_pages.dtype.name))
+
+
+def decode_walk_inputs(S):
+    """The serve cells' decode rows, seeded: for each shape of
+    ``S["shapes"]`` its name, window, a query ``[B, 1, H, D]``, one
+    layer's K and V pool as stored, a page table and two sets of
+    lengths: ``cell`` (a step's live rows, each a prompt of the cell's
+    plus part of its budget, beside idle slots of length 1, as the
+    server pads them) and ``one_page_a_row`` (next to nothing live)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.RandomState(S["seed"])
+    b, slots, n_pages = S["batch"], S["slots"], S["pool_pages"]
+    for shape, (h, g, d, page, dtype, window, prompts, budget) \
+            in S["shapes"].items():
+        q = jnp.asarray(rng.randn(b, 1, h, d), jnp.float32)
+        kp, vp = (jnp.asarray(rng.randn(n_pages, page, g * d), dtype)
+                  for _ in range(2))
+        pidx = jnp.asarray(rng.permutation(n_pages - 1)[:b * slots]
+                           .reshape(b, slots) + 1, jnp.int32)
+        drawn = np.ones(b, np.int64)
+        drawn[:S["live_rows"]] = np.minimum(
+            rng.choice(prompts, S["live_rows"])
+            + rng.randint(0, budget + 1, S["live_rows"]), slots * page)
+        yield shape, window, q, kp, vp, pidx, {
+            "cell": drawn, "one_page_a_row": np.minimum(drawn, page)}
+
+
+def per_call_us(call, layers, reps, q, *rest):
+    """Microseconds one ``call(q, *rest)`` takes as one of ``layers`` in
+    a row inside one program, each fed the result before it, as a decode
+    step's layers are."""
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda q, *rest: jax.lax.fori_loop(
+        0, layers, lambda _, o: call(q + o * 1e-9, *rest),
+        jnp.zeros_like(q)))
+    jax.block_until_ready(f(q, *rest))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = f(q, *rest)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps / layers * 1e6
+
+
+def phase_decode_walk(S, ctx):
+    """Microseconds a call of the paged decode kernel by shape, live
+    pages and pages a loop step (ISSUE 36's step 0, kept: the decode
+    kernels' ``[H, 1]`` statistics, ROADMAP S6, start from these
+    numbers): :func:`decode_walk_inputs`' rows at the chunk
+    ``_pages_per_step`` gives and at every chunk of ``S["chunks"]``,
+    each against the dense reference and the bandwidth's floor for the
+    live pages' K and V."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.observe import costmodel
+    from paddle_tpu.ops import kernels as K
+    from paddle_tpu.ops import pallas_attention as pa
+
+    peak_bytes_s = costmodel.detect_peaks()["bw"]
+    table = []
+    for shape, window, q, kp, vp, pidx, fills in decode_walk_inputs(S):
+        n_pages, page, width = kp.shape
+        g = width // q.shape[3]
+        # the rule's choice, as the call's gauge says it
+        jax.eval_shape(lambda *a: pa.paged_decode_attention(
+            *a, window=window), q, kp, vp, pidx, jnp.ones(q.shape[:1], int))
+        chosen = pages_a_step(kp)
+        as_heads = lambda a: a.reshape(n_pages, page, g, -1)
+        for fill, lens in fills.items():
+            first = np.maximum(lens - window, 0) // page if window else 0
+            live = int(np.sum(-(-lens // page) - first))
+            row = {"shape": shape, "fill": fill, "live_pages": live,
+                   # K and V of the live pages once at the bandwidth
+                   "bandwidth_floor_us": round(
+                       live * page * 2 * width * kp.dtype.itemsize
+                       / peak_bytes_s * 1e6, 2),
+                   "rule_chunk": chosen, "us_a_call": {}, "rel_err": {}}
+            lens = jnp.asarray(lens, jnp.int32)
+            want = jax.jit(lambda *a: pa.paged_decode_reference(
+                *a, window=window))(q, as_heads(kp), as_heads(vp), pidx,
+                                    lens)
+            for chunk in sorted({chosen, *S["chunks"]}):
+                call = lambda *a: pa._paged_decode(
+                    *a, window, chunk, K.PAGED_DECODE)
+                row["rel_err"][chunk] = round(_rel_err(
+                    jax.jit(call)(q, kp, vp, pidx, lens), want), 5)
+                row["us_a_call"][chunk] = round(per_call_us(
+                    call, S["layers"], S["reps"], q, kp, vp, pidx, lens), 2)
+            table.append(row)
+            print(json.dumps(row), flush=True)
+            bad = {c: e for c, e in row["rel_err"].items()
+                   if not e <= REL_TOL}
+            check(not bad, f"{shape}/{fill}: kernel != reference beyond "
+                           f"{REL_TOL} at chunks {bad}")
+    return {"decode_walk": table}, None
 
 
 # ------------------------------------------------------ --all phases
@@ -1061,7 +1209,8 @@ DEFAULT_PHASES = (("lstm_cli", phase_lstm_cli),
                   ("server", phase_server),
                   ("kernels", phase_kernels))
 ALL_PHASES = (("resnet", phase_resnet), ("seq2seq", phase_seq2seq),
-              ("lstm1280", phase_lstm1280), ("sparse", phase_sparse))
+              ("lstm1280", phase_lstm1280), ("sparse", phase_sparse),
+              ("decode_walk", phase_decode_walk))
 
 
 class Ctx:
@@ -1099,7 +1248,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--all", action="store_true",
                     help="also one train step each of ResNet-50, seq2seq, "
-                         "LSTM hidden 1280 and a sparse CTR table")
+                         "LSTM hidden 1280 and a sparse CTR table, and the "
+                         "paged decode kernel's microseconds a call")
     ap.add_argument("--fsdp", action="store_true",
                     help="transformer phase under FSDP with "
                          "zoo_fsdp_rules('transformer')")
@@ -1110,6 +1260,10 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearsal", action="store_true",
                     help="CPU sandbox: every size shrunk, kernels in "
                          "interpret mode; can never print the pass line")
+    ap.add_argument("--only", default="",
+                    help="comma-separated phase names: run just these "
+                         "(of the --all set too); the pass line still "
+                         "needs them to pass")
     ap.add_argument("--out", default=os.path.join(
         HERE, "chiprun_out", "chip_smoke"))
     args = ap.parse_args(argv)
@@ -1160,6 +1314,13 @@ def main(argv=None) -> int:
 
     sizes = REHEARSAL if args.rehearsal else FULL
     phases = list(DEFAULT_PHASES) + (list(ALL_PHASES) if args.all else [])
+    if args.only:
+        only = args.only.split(",")
+        phases = [(n, f) for n, f in DEFAULT_PHASES + ALL_PHASES
+                  if n in only]
+        if len(phases) != len(only):
+            ap.error(f"--only {args.only}: phases are "
+                     f"{[n for n, _ in DEFAULT_PHASES + ALL_PHASES]}")
     ctx = Ctx(args, device, args.out, Meter())
     t0 = time.perf_counter()
     records = [run_phase(name, fn, sizes[name], ctx)

@@ -36,10 +36,11 @@ Three entry points:
   small-Tq query batch attends a block-paged KV cache through a
   per-row page table + valid lengths (ROADMAP items 1 and 5's shared
   base; *Ragged Paged Attention*, arxiv 2604.15464).  One kernel
-  invocation loops over each row's live pages only — one step per row
-  and page, all heads in it, the page fetched by DMA from the pool
-  left in HBM as the server stores it (``[P, page, H·D]``) — so its
-  time follows the live K/V, not the page table's width.
+  invocation loops over each row's live pages only — several pages of
+  one row a loop step (:func:`_pages_per_step`), all heads in it, each
+  page fetched by its own DMA from the pool left in HBM as the server
+  stores it (``[P, page, G·D]``), operands in the pool's dtype — so
+  its time follows the live K/V, not the page table's width.
   Inference-only (no VJP).
 
 Layout matches :mod:`paddle_tpu.parallel.ring_attention`'s
@@ -59,6 +60,7 @@ test mesh exercises the exact same code path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +71,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.device import pallas_interpret
 from . import kernels as K
-from ..observe import counter
+from ..observe import counter, gauge
 from ..utils import enforce
 from ..utils.logger import get_logger, warn_once
 
@@ -1426,22 +1428,60 @@ def segments_from_lengths(lengths, batch: int, t: int):
 
 
 # --------------------------------------------------- paged-KV decode
+#: positions one loop step of a decode kernel attends at most: 8 pages of
+#: 64 tokens are one [512, W] operand of both products
+DECODE_STEP_TOKENS = 512
+#: VMEM a decode kernel may hold (its double buffers, query tile,
+#: accumulator and one step's scores): half of what Mosaic gives a kernel
+#: on a v5e unasked (16 MiB)
+DECODE_VMEM_BUDGET = 8 << 20
+
+
+def _pages_per_step(page: int, token_bytes: int, rows: int,
+                    fixed_bytes: int, n_pages_max: int) -> int:
+    """Pages a decode kernel takes a loop step, from what the call can
+    see: the largest power of two ``c`` with ``c·page`` ≤
+    :data:`DECODE_STEP_TOKENS`, at most the page table's width, that
+    fits :data:`DECODE_VMEM_BUDGET`: the double buffer
+    (``2·c·page·token_bytes``, ``token_bytes`` what a cached token holds
+    in the pools the kernel reads), one step's float32 scores, mask and
+    weights for ``rows`` queries-times-heads, and ``fixed_bytes`` (the
+    query tile and the accumulator).  The step's bookkeeping (one mask,
+    one max / exp / sum, one accumulator update) is paid once for the
+    ``c`` pages.  8 pages of 64 for the routed and the latent pools (bf16
+    rows of 512 and 640 lanes), 8 of 16 for the dense pool's f32 rows of
+    2,048 (16 would hold 9.0 MB)."""
+    def fits(c):
+        span = c * page
+        return (c <= n_pages_max and span <= DECODE_STEP_TOKENS
+                and 2 * span * token_bytes + 3 * 4 * rows * span
+                + fixed_bytes <= DECODE_VMEM_BUDGET)
+
+    chunk = 1
+    while fits(2 * chunk):
+        chunk *= 2
+    return chunk
+
+
 def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                    o_ref, kbuf, vbuf, sem, qe_s, m_s, l_s, acc_s, *,
-                   scale, page, t_q, n_heads, kv_heads, d, n_pages_max,
-                   window):
-    """One invocation, one loop step per row and live page, all heads
-    in that step — so the time follows the K/V that is live, not the
+                   scale, page, chunk, t_q, n_heads, kv_heads, d,
+                   n_pages_max, window):
+    """One invocation; a loop step takes ``chunk`` pages of one row, all
+    heads in it, so the time follows the K/V that is live, not the
     table's width.  The pools stay in HBM as they are stored
-    (``[P, page, H·D]``); each live page of K and of V comes by its
-    own DMA into a double buffer, the next page (the next live row's
-    first, at a row's end) in flight while this one is attended.  All
-    heads go through one product: the query is laid out
-    block-diagonally (``[H, H·D]``, head h's D lanes in row h), so
-    ``qe @ k.T`` is every head's scores ``[H, page]`` and the diagonal
-    blocks of ``p @ v`` ``[H, H·D]`` are every head's output;
-    ``m``/``l``/acc stay f32.  Table slots past a row's used pages are
-    never read, let alone dereferenced.
+    (``[P, page, G·D]``); each live page of the chunk comes by its own
+    DMA, K and V, into one half of a double buffer ``[chunk·page, G·D]``
+    (the next chunk, the next live row's first at a row's end, in flight
+    meanwhile).  All heads go through one product: the queries are laid
+    out block-diagonally (``[Tq·H, G·D]``, head h's D lanes in its row),
+    so ``qe @ kbufᵀ`` is every head's scores ``[Tq·H, chunk·page]`` and
+    the diagonal blocks of ``p @ vbuf`` every head's output.  Operands
+    in the pool's dtype, the scale on the float32 scores,
+    ``m``/``l``/acc in float32.  A page slot of a chunk past the row's
+    used pages is not fetched (table slots there are never read, let
+    alone dereferenced); what an earlier step left in the buffer (zeros
+    at first) meets a weight of 0.
 
     Grouped KV heads (``kv_heads`` < ``n_heads``): the pool's rows are
     ``kv_heads·D`` wide and query head h lies in the D lanes of KV head
@@ -1449,11 +1489,15 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
     head its group's scores and output; the flush picks each group's
     heads out of its lanes (``o_ref`` is then ``[B, Tq, H, D]``).
     ``window`` > 0: a query sees only the ``window`` newest positions
-    up to its own, and the page walk starts at the first page that
-    holds one of them, so the pages behind the window are not read."""
-    n_rows = q_ref.shape[0]
+    up to its own, and a row's walk starts at the first page that holds
+    one of them, so the pages behind the window are not read."""
+    n_rows, span = q_ref.shape[0], chunk * page
     rep = n_heads // kv_heads
     hd = kbuf.shape[-1]
+
+    def used_pages(b):
+        kv_len = len_ref[jnp.minimum(b, n_rows - 1)]
+        return jnp.minimum((kv_len + page - 1) // page, n_pages_max)
 
     def first_page(b):
         """The first page row ``b``'s earliest query can see."""
@@ -1462,29 +1506,41 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
         kv_len = len_ref[jnp.minimum(b, n_rows - 1)]
         return jnp.maximum(kv_len - t_q - window + 1, 0) // page
 
-    def copies(b, j, slot):
-        pg = pidx_ref[b, j]
-        return (pltpu.make_async_copy(k_hbm.at[pg], kbuf.at[slot],
-                                      sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[pg], vbuf.at[slot],
-                                      sem.at[1, slot]))
-
-    def fetch(b, j, slot):
-        @pl.when(b < n_rows)              # live_ref says n_rows for "none"
+    def each_live_page(b, first, slot, act):
+        """``act`` on the K and the V DMA of every page of row ``b``'s
+        chunk that starts at page ``first``; ``b == n_rows`` means no
+        row (``live_ref`` says so)."""
+        @pl.when(b < n_rows)
         def _():
-            for cp in copies(b, j, slot):
-                cp.start()
+            def _page(i, _):
+                pg = pidx_ref[b, first + i]
+                at = pl.ds(pl.multiple_of(i * page, page), page)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[pg], kbuf.at[slot, at], sem.at[0, slot, i]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[pg], vbuf.at[slot, at], sem.at[1, slot, i]))
+                return 0
 
+            # a loop, not ``chunk`` unrolled predicates: the decode step
+            # traces this body once a layer (set-up time)
+            jax.lax.fori_loop(
+                0, jnp.clip(used_pages(b) - first, 0, chunk), _page, 0)
+
+    rows = t_q * n_heads
     group = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 0) // rep
     lanes = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 1)
     own = (lanes >= group * d) & (lanes < (group + 1) * d)   # [H, G·D]
-    ki = jax.lax.broadcasted_iota(jnp.int32, (n_heads, page), 1)
+    ki = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+    # query t of the tile sits t_q - 1 - t positions before the newest
+    back = 0 if t_q == 1 else t_q - 1 - jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0) // n_heads
 
     def _row(b, n):
-        """``n`` counts the pages attended so far: its parity is the
-        buffer the current page lies in."""
+        """``n`` counts the chunks attended so far: its parity is the
+        half of the buffers the current chunk lies in."""
         kv_len = len_ref[b]
-        used = jnp.minimum((kv_len + page - 1) // page, n_pages_max)
+        used, first = used_pages(b), first_page(b)
+        n_chunks = (jnp.maximum(used - first, 0) + chunk - 1) // chunk
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
@@ -1496,54 +1552,54 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                 qt = jnp.concatenate([q_ref[b, t]] * kv_heads, axis=1)
                 if hd > qt.shape[1]:
                     qt = jnp.pad(qt, ((0, 0), (0, hd - qt.shape[1])))
-            qe_s[t] = jnp.where(own, qt.astype(jnp.float32) * scale, 0.0)
+            qe_s[pl.ds(t * n_heads, n_heads), :] = jnp.where(
+                own, qt.astype(qe_s.dtype), 0)
+        # a query attends every key at or before itself (the ragged
+        # causal tail), which also masks the last page's slots past the
+        # row's length and the chunk's slots that were not fetched
+        newest = jnp.minimum(kv_len, used * page) - 1
 
-        def _page(j, n):
+        def _chunk(j, n):
             slot = n % 2
-            last = j + 1 == used
+            last = j + 1 == n_chunks
             nxt = live_ref[b + 1]
-            fetch(jnp.where(last, nxt, b),
-                  jnp.where(last, first_page(nxt), j + 1), 1 - slot)
-            for cp in copies(b, j, slot):
-                cp.wait()
-            kb = kbuf[slot].astype(jnp.float32)          # [page, H·D]
-            vb = vbuf[slot].astype(jnp.float32)
-            for t in range(t_q):
-                s = jax.lax.dot_general(
-                    qe_s[t], kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)  # [H, page]
-                # query t sits at absolute position kv_len - t_q + t:
-                # it may attend every key at or before itself (ragged
-                # causal tail), which also masks the page's slots past
-                # the row's length
-                at = kv_len - t_q + t
-                seen = j * page + ki <= at
-                if window:
-                    seen = seen & (j * page + ki > at - window)
-                s = jnp.where(seen, s, NEG_INF)
-                m_prev = m_s[t]
-                m_new = jnp.maximum(m_prev,
-                                    s.max(axis=-1, keepdims=True))
-                # fully-masked queries (0 < length < Tq: the leading
-                # rows of a speculative/chunked tile sit at negative
-                # positions) have m_new = NEG_INF; clamp the exponent
-                # base so exp(s − m) underflows to 0 instead of
-                # exp(−inf − (−inf)) = 1 leaking V mass — same guard
-                # as _fa_pair_kernel; the flush's l_safe emits zeros
-                m_base = jnp.maximum(m_new, NEG_INF / 2)
-                pexp = jnp.exp(s - m_base)
-                alpha = jnp.exp(m_prev - m_base)
-                m_s[t] = m_new
-                l_s[t] = l_s[t] * alpha + pexp.sum(axis=-1,
-                                                   keepdims=True)
-                acc_s[t] = acc_s[t] * alpha + jnp.dot(
-                    pexp, vb, preferred_element_type=jnp.float32)
+            each_live_page(jnp.where(last, nxt, b),
+                           jnp.where(last, first_page(nxt),
+                                     first + (j + 1) * chunk),
+                           1 - slot, lambda cp: cp.start())
+            each_live_page(b, first + j * chunk, slot,
+                           lambda cp: cp.wait())
+            s = jax.lax.dot_general(
+                qe_s[...], kbuf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [Tq·H, span]
+            at = newest - back - (first + j * chunk) * page
+            seen = ki <= at             # ki: positions within the chunk
+            if window:
+                seen = seen & (ki > at + (kv_len - 1 - newest) - window)
+            s = jnp.where(seen, s, NEG_INF)
+            m_prev = m_s[...]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            # fully-masked queries (0 < length < Tq: the leading rows
+            # of a speculative/chunked tile sit at negative positions)
+            # have m_new = NEG_INF; clamp the exponent base so
+            # exp(s − m) underflows to 0 instead of
+            # exp(−inf − (−inf)) = 1 leaking V mass — same guard as
+            # _fa_pair_kernel; the flush's l_safe emits zeros
+            m_base = jnp.maximum(m_new, NEG_INF / 2)
+            pexp = jnp.exp(s - m_base)
+            alpha = jnp.exp(m_prev - m_base)
+            m_s[...] = m_new
+            l_s[...] = l_s[...] * alpha + pexp.sum(axis=-1, keepdims=True)
+            acc_s[...] = acc_s[...] * alpha + jnp.dot(
+                pexp.astype(vbuf.dtype), vbuf[slot],
+                preferred_element_type=jnp.float32)
             return n + 1
 
-        n = jax.lax.fori_loop(first_page(b), used, _page, n)
+        n = jax.lax.fori_loop(0, n_chunks, _chunk, n)
+        l_all = l_s[...]
+        o_all = acc_s[...] / jnp.where(l_all == 0.0, 1.0, l_all)
         for t in range(t_q):              # a row of length 0: zeros
-            l_safe = jnp.where(l_s[t] == 0.0, 1.0, l_s[t])
-            o = acc_s[t] / l_safe
+            o = o_all[t * n_heads:(t + 1) * n_heads]
             if rep == 1:
                 o_ref[b, pl.ds(t, 1), :] = jnp.where(own, o, 0.0).sum(
                     axis=0, keepdims=True).astype(o_ref.dtype)
@@ -1554,7 +1610,11 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                         g * d:(g + 1) * d].astype(o_ref.dtype)
         return n
 
-    fetch(live_ref[0], first_page(live_ref[0]), 0)
+    # a slot never fetched holds what was there: a NaN times 0 is NaN in
+    # p @ vbuf (in the scores the mask replaces it)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    each_live_page(live_ref[0], first_page(live_ref[0]), 0,
+                   lambda cp: cp.start())
     jax.lax.fori_loop(0, n_rows, _row, 0)
 
 
@@ -1596,11 +1656,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
     """
     b, t_q, h, d = q.shape
     page = k_pages.shape[1]
-    if k_pages.ndim == 4:
-        g = k_pages.shape[2]
-    else:
-        g = k_pages.shape[2] // d
-    gd = g * d
+    gd = math.prod(k_pages.shape[2:])
+    g = gd // d
     enforce(k_pages.shape[2:] in ((g, d), (gd,)) and g >= 1
             and h % g == 0,
             f"page pool {k_pages.shape} is neither [P, page, G, {d}] "
@@ -1613,8 +1670,46 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
             f"{page_indices.shape}/{lengths.shape} vs B={b}")
     n_pages_max = page_indices.shape[1]
     record_attention_dispatch("decode")
+    width, isz = gd + -gd % 128, k_pages.dtype.itemsize
+    chunk = _pages_per_step(page, 2 * width * isz, t_q * h,
+                            t_q * h * width * (isz + 4), n_pages_max)
+    gauge("paged_decode_pages_per_step",
+          "pages one loop step of the paged decode kernel takes, as "
+          "_pages_per_step chose from the call's shapes (trace-time)"
+          ).set(chunk, page=str(page), width=str(width),
+                dtype=k_pages.dtype.name)
+    # the op at the table's capacity (what is live is the caller's to
+    # say: the serve loop's span carries live_pages / attended_tokens)
+    reach = b * n_pages_max * page
+    kv = jax.ShapeDtypeStruct((reach, gd), k_pages.dtype)
+    K.record_kernel_work(K.PAGED_DECODE, 4.0 * t_q * h * d * reach,
+                         (q, kv, kv), (q,))
+    return _paged_decode(q, k_pages, v_pages, page_indices, lengths,
+                         int(window), chunk, name)
+
+
+def _paged_decode(q, k_pages, v_pages, page_indices, lengths, window,
+                  chunk, name=None):
+    """:func:`paged_decode_attention`'s call at ``chunk`` pages a loop
+    step (its rule's; ``chip_smoke.py`` times the others)."""
     lengths = lengths.astype(jnp.int32)
-    live = _next_live_row(lengths)
+    # a named call is traced where it stands, under its layer's scope
+    # (ops/scopes.py); the unnamed one has no scope to keep
+    call = _decode_call if name is None else _decode_pallas
+    return call(
+        lengths, page_indices.astype(jnp.int32), _next_live_row(lengths),
+        q, k_pages, v_pages, window=window, chunk=chunk, name=name)
+
+
+def _decode_pallas(lengths, page_indices, live, q, k_pages, v_pages, *,
+                   window, chunk, name=None):
+    """The ``pallas_call`` of :func:`_decode_kernel`: int32 ``lengths``,
+    page table and next-live-row list first (scalar-prefetched), the
+    pools left in HBM."""
+    b, t_q, h, d = q.shape
+    page, n_pages_max = k_pages.shape[1], page_indices.shape[1]
+    gd = math.prod(k_pages.shape[2:])
+    g = gd // d
     # a DMA cannot cut a row inside a 128-lane tile: rows narrower than
     # whole tiles (toy sizes) are padded out, a copy that real widths
     # (G·D a multiple of 128) never make
@@ -1622,29 +1717,29 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
     width = gd + pad
 
     def rows(a):
-        a = a.reshape(*a.shape[:2], a.shape[2] if a.ndim == 3
-                      else a.shape[2] * a.shape[3])
+        a = a.reshape(*a.shape[:2], -1)
         return jnp.pad(a, ((0, 0), (0, 0), (0, pad))) if pad else a
     grouped = g != h
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=1.0 / np.sqrt(d),
-                          page=page, t_q=t_q, n_heads=h, kv_heads=g, d=d,
-                          n_pages_max=n_pages_max, window=int(window)),
+                          page=page, chunk=chunk, t_q=t_q, n_heads=h,
+                          kv_heads=g, d=d, n_pages_max=n_pages_max,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
             in_specs=[vmem, hbm, hbm],
             out_specs=[vmem],
             scratch_shapes=[
-                pltpu.VMEM((2, page, width), k_pages.dtype),
-                pltpu.VMEM((2, page, width), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((t_q, h, width), jnp.float32),
-                pltpu.VMEM((t_q, h, 1), jnp.float32),
-                pltpu.VMEM((t_q, h, 1), jnp.float32),
-                pltpu.VMEM((t_q, h, width), jnp.float32),
+                pltpu.VMEM((2, chunk * page, width), k_pages.dtype),
+                pltpu.VMEM((2, chunk * page, width), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2, chunk)),
+                pltpu.VMEM((t_q * h, width), k_pages.dtype),
+                pltpu.VMEM((t_q * h, 1), jnp.float32),
+                pltpu.VMEM((t_q * h, 1), jnp.float32),
+                pltpu.VMEM((t_q * h, width), jnp.float32),
             ],
         ),
         # one [H·D] row a query where every head has its own K/V (the
@@ -1658,16 +1753,29 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
         # the accepted benchmark metric paged_decode_roofline.serve
         # finds its kernel as ``%_lambda_.N = f32[B,1,H·D] …
         # custom-call(s32[…``, the instruction name the call inherits
-        # from that module's jax.jit(lambda …), until the benchmark PR
+        # from the jax.jit(lambda …) around it, until the benchmark PR
         # that repoints the metric (PERF.md §7).  A caller that passes
         # K.PAGED_DECODE reads ``%paged_decode.N`` in a trace.  The
         # work depends on run-time lengths: the serve loop's
         # serve_decode_step span carries live_tokens / live_pages /
         # attended_tokens.
         name=name,
-    )(lengths, page_indices.astype(jnp.int32), live,
+    )(lengths, page_indices, live,
       q if grouped else rows(q), rows(k_pages), rows(v_pages))[0]
     return out if grouped else out[..., :gd].reshape(b, t_q, h, d)
+
+
+#: The unnamed kernel's call, jitted on its own: the layers of a decode
+#: step call it with one set of shapes, and the kernel is traced and
+#: lowered once for all of them, not once a layer (24 times in the dense
+#: serve cell's step, in every process: set-up time, as
+#: ``_fa_sparse_call``).  **A lambda**, because an unnamed call takes its
+#: instruction name from the innermost jit around it, and the accepted
+#: metric finds the default plan's kernel as ``%_lambda_``
+#: (``chip_smoke.py::decode_step_checks`` holds it).
+_decode_call = jax.jit(
+    lambda *a, **kw: _decode_pallas(*a, **kw),
+    static_argnames=("window", "chunk", "name"))
 
 
 def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths,
@@ -1766,12 +1874,6 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
 
 
 # ------------------------------------------------- latent-cache decode
-#: pages of one loop step of the latent kernel: 8 pages of 64 tokens are
-#: one [512, W] operand of both products, and the step's bookkeeping is
-#: paid once for them
-LATENT_PAGES_PER_STEP = 8
-
-
 def _latent_decode_kernel(len_ref, pidx_ref, live_ref, q_ref, pages_hbm,
                           o_ref, buf, sem, m_s, l_s, acc_s, *, scale,
                           page, chunk, v_width, n_pages_max):
@@ -1882,7 +1984,9 @@ def latent_decode_attention(q, pages, page_indices, lengths, v_width: int,
             f"page_indices/lengths batch mismatch: "
             f"{page_indices.shape}/{lengths.shape} vs B={b}")
     n_pages_max = page_indices.shape[1]
-    chunk = min(LATENT_PAGES_PER_STEP, n_pages_max)
+    isz = pages.dtype.itemsize
+    chunk = _pages_per_step(page, w * isz, h, h * (w * isz + v_width * 4),
+                            n_pages_max)
     record_attention_dispatch("latent_decode")
     lengths = lengths.astype(jnp.int32)
     live = _next_live_row(lengths)

@@ -702,10 +702,10 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
         counts = active.astype(jnp.int32)
         klen = jnp.where(active, lengths, 1).astype(jnp.int32)
     # the default plan's kernel keeps the name its call inherits (see
-    # paged_decode_attention): the ``%_lambda_`` of the step's jit, which
-    # it has only under no scope at all, so there ``attend`` is empty and
-    # the call stands bare; a planned decoder's reads %paged_decode
-    # under any scope
+    # ops/pallas_attention.py::_decode_call): the ``%_lambda_`` of the
+    # jits around it, held only under no scope at all, so there
+    # ``attend`` is empty and the call stands bare; a planned decoder's
+    # reads %paged_decode under any scope
     name = K.PAGED_DECODE if cfg.plan else None
     sizes = []
     with jax.named_scope(S.CACHE_LAYOUT):
@@ -803,7 +803,7 @@ def _jitted_steps(cfg: DecoderConfig):
     # ``src`` of ``prev``: the ids of the decode launch before this one,
     # still on the device (the host has not read them yet).  Both stay
     # lambdas: the default plan's unnamed kernel is found in a trace by
-    # the ``%_lambda_`` it inherits (see paged_decode_attention)
+    # the ``%_lambda_`` it inherits (ops/pallas_attention.py::_decode_call)
     decode = jax.jit(
         lambda p, *a: _decode_impl(
             p, a[:n], _fed_ids(*a[n:n + 3]), *a[n + 3:], cfg),

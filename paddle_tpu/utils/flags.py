@@ -374,8 +374,9 @@ FLAGS.define("kv_pool_pages", 128,
              "and returns them on completion for recycling")
 FLAGS.define("kv_page_size", 16,
              "tokens per KV page (the paged_decode_attention page "
-             "axis); pool capacity in tokens is kv_pool_pages x "
-             "kv_page_size")
+             "axis; the kernel takes up to 512 tokens' worth of pages a "
+             "loop step, so a small page costs DMAs, not steps); pool "
+             "capacity in tokens is kv_pool_pages x kv_page_size")
 FLAGS.define("serve_slo_ms", 0.0,
              "optional p99 TTFT SLO in milliseconds: when > 0 the "
              "server's /healthz reports "

@@ -59,6 +59,29 @@ def test_rehearsal_runs_every_phase_and_cannot_pass(tmp_path):
                for k in phases["transformer"]["pallas_calls"])
 
 
+def test_only_runs_the_named_phase_the_decode_walks_table(tmp_path):
+    """``--only decode_walk``: the paged decode kernel by shape, fill
+    and pages a loop step, each beside the reference; at toy sizes here,
+    where the times say nothing."""
+    r = _run_smoke(["--rehearsal", "--only", "decode_walk"], tmp_path)
+    assert r.returncode == 3, (r.stdout[-3000:], r.stderr[-3000:])
+    lines = [json.loads(ln) for ln in r.stdout.splitlines()
+             if ln.startswith("{")]
+    (phase,) = [ln for ln in lines if "phase" in ln]
+    assert phase["phase"] == "decode_walk" and phase["ok"], phase
+    rows = phase["decode_walk"]
+    assert [(r["shape"], r["fill"]) for r in rows] == [
+        ("trinity_window", "cell"), ("trinity_window", "one_page_a_row")]
+    for row in rows:
+        # the rule's chunk (the table's 8 slots bound it) and the one asked
+        assert row["rule_chunk"] == 8
+        assert set(row["us_a_call"]) == set(row["rel_err"]) == {"1", "8"}
+        assert max(row["rel_err"].values()) < 3e-2
+    assert rows[0]["live_pages"] > rows[1]["live_pages"] == 4
+    r = _run_smoke(["--rehearsal", "--only", "no_such_phase"], tmp_path)
+    assert r.returncode == 2 and "phases are" in r.stderr
+
+
 def test_compile_cache_is_placed_from_outside(monkeypatch):
     from paddle_tpu.core import device
 

@@ -22,7 +22,7 @@ OPS = os.path.join(os.path.dirname(os.path.dirname(
 #: the instruction name it inherits (see the call site; PERF.md §7
 #: queues the rename); a decoder with a layer plan gives
 #: ``K.PAGED_DECODE`` (``serving/model.py``)
-CALLERS_NAME = {("pallas_attention.py", "paged_decode_attention")}
+CALLERS_NAME = {("pallas_attention.py", "_decode_pallas")}
 
 
 def _sites():
@@ -92,10 +92,16 @@ def test_every_pallas_call_is_named_from_the_table(fname, func, call, fn):
     if (fname, func) in CALLERS_NAME:
         # name=<the function's own ``name`` parameter>, None by default
         assert [n.id for n in names] == ["name"]
-        arg = [a.arg for a in fn.args.args].index("name")
-        default = fn.args.defaults[arg - len(fn.args.args)]
+        arg = [a.arg for a in fn.args.kwonlyargs].index("name")
+        default = fn.args.kw_defaults[arg]
         assert default.value is None, \
             "the default plan's call must stay unnamed"
+        # and so is the public wrapper's, which hands it down
+        import inspect
+
+        from paddle_tpu.ops import pallas_attention as pa
+        assert inspect.signature(pa.paged_decode_attention) \
+            .parameters["name"].default is None
         return
     assert len(names) == 1, f"{fname}:{call.lineno} passes no name="
     constants = _table_constants(names[0], fn)

@@ -208,18 +208,22 @@ def test_a_latent_plan_the_decoder_cannot_run_is_refused(plan, why):
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
                                        ("bfloat16", 3e-2)])
-@pytest.mark.parametrize("pages_per_step", [1, 3, 8])
-def test_latent_decode_is_the_dense_reference(dtype, tol, pages_per_step,
-                                              monkeypatch):
+@pytest.mark.parametrize("page,slots,pages_per_step", [
+    (512, 3, 1), (4, 3, 2), (4, 12, 8)])
+def test_latent_decode_is_the_dense_reference(dtype, tol, page, slots,
+                                              pages_per_step):
     """Rows of 0, 1, one page, several chunks and a ragged last page of
     cached tokens, through page tables in no order whose idle slots
-    point at pages never to be read."""
-    monkeypatch.setattr(pa, "LATENT_PAGES_PER_STEP", pages_per_step)
+    point at pages never to be read; at 1, 2 and 8 pages a loop step,
+    as the rule gives them for the page size and the table's width."""
     rng = np.random.default_rng(0)
-    b, h, w, v_width, page, n_pages, slots = 6, 4, 128, 32, 4, 80, 12
+    b, h, w, v_width, n_pages = 6, 4, 128, 32, 6 * slots + 8
+    assert pa._pages_per_step(
+        page, w * jnp.dtype(dtype).itemsize, h, 0, slots) == pages_per_step
     q = jnp.asarray(rng.standard_normal((b, h, w)), dtype)
     pages = jnp.asarray(rng.standard_normal((n_pages, page, w)), dtype)
-    lengths = np.array([0, 1, 4, 17, 48, 33], np.int32)
+    lengths = np.array([0, 1, page, slots // 3 * page + 1, slots * page,
+                        2 * slots // 3 * page + 1], np.int32)
     tables = rng.permutation(n_pages - 1)[:b * slots].reshape(b, slots) \
         .astype(np.int32)
     for i, n in enumerate(lengths):                # idle slots: the page

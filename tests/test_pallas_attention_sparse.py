@@ -477,6 +477,187 @@ def test_paged_decode_matches_dense_reference(case, t_q, rng):
         >= 1
 
 
+def _pages_a_step(k_pages, d):
+    """What the call's gauge says ``_pages_per_step`` chose."""
+    width = int(np.prod(k_pages.shape[2:]))
+    return observe.REGISTRY.find("paged_decode_pages_per_step").value(
+        page=str(k_pages.shape[1]), width=str(width + -width % 128),
+        dtype=k_pages.dtype.name)
+
+
+#: name → (query heads, K/V heads, head lanes, page, table slots, pool
+#: dtype, window, the chunk the rule must give).  The chunk is forced
+#: through what the rule sees: the page size (512 tokens a step at
+#: most) and the table's width
+CHUNK_CASES = {
+    "page512": (2, 2, 16, 512, 4, "float32", 0, 1),
+    "page256": (2, 2, 16, 256, 8, "float32", 0, 2),
+    "table3": (2, 2, 16, 16, 3, "float32", 0, 2),
+    "page16": (2, 2, 16, 16, 12, "float32", 0, 8),
+    # the walk starts at the window's first page, wherever in a chunk
+    # of a walk from page 0 that falls
+    "window": (4, 2, 16, 16, 12, "float32", 40, 8),
+    # the served rows: the dense pool's, the routed decoders'
+    "dense": (32, 32, 64, 16, 24, "float32", 0, 8),
+    "trinity": (32, 4, 128, 64, 12, "bfloat16", 0, 8),
+    "lfm2": (32, 8, 64, 64, 12, "bfloat16", 0, 8),
+}
+
+
+@pytest.mark.parametrize("t_q", [1, 4])
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+def test_paged_decode_takes_several_pages_a_step(case, t_q, rng):
+    """Rows whose lengths lie at, one under and one over a chunk's edge,
+    a row shorter than a chunk beside a row of several, and rows of
+    length 0 and 1 between them (the prefetch crosses rows), at 1, 2
+    and 8 pages a loop step.  Every page no row may read holds NaN and
+    every table slot past a row's used pages an index outside the pool:
+    a fetched page shows in the output even under a weight of 0."""
+    H, G, D, page, slots, dtype, window, chunk = CHUNK_CASES[case]
+    edge = chunk * page
+    lengths = np.minimum([edge, 0, edge - 1, 1, edge + 1, 0, 3 * edge - 5,
+                          min(7, page)], slots * page)
+    B = len(lengths)
+    used = -(-lengths // page)
+    first = np.maximum(lengths - 4 - window + 1, 0) // page if window \
+        else np.zeros_like(used)
+    slot = np.arange(slots)[None, :]
+    live = (slot >= first[:, None]) & (slot < used[:, None])
+    P = int(live.sum()) + 2
+    pidx = np.full((B, slots), 10 ** 6, np.int32)
+    pidx[:, 1::2] = -5
+    pidx[live] = rng.permutation(P - 2) + 1     # pages 0 and P-1: no row's
+    kpg, vpg = (rng.randn(P, page, G, D).astype(np.float32)
+                for _ in range(2))
+    kpg[[0, P - 1]] = vpg[[0, P - 1]] = np.nan
+    # the reference gathers every slot before it masks: give it a live
+    # page (any finite one for a row with none) where the kernel must
+    # not look
+    safe = np.where(live, pidx, np.where(
+        used > first, pidx[np.arange(B), np.minimum(first, slots - 1)],
+        1)[:, None])
+    kpg, vpg = jnp.asarray(kpg, dtype), jnp.asarray(vpg, dtype)
+    q = jnp.asarray(rng.randn(B, t_q, H, D).astype(np.float32))
+    lengths = jnp.asarray(lengths, jnp.int32)
+    out = pa.paged_decode_attention(q, kpg, vpg, jnp.asarray(pidx), lengths,
+                                    window)
+    assert _pages_a_step(kpg, D) == chunk
+    ref = pa.paged_decode_reference(q, kpg, vpg, jnp.asarray(safe), lengths,
+                                    window)
+    assert np.isfinite(np.asarray(out)).all()
+    assert not np.asarray(out[1]).any()         # length 0: zeros
+    # bfloat16 pools: the query and the weights are rounded to the
+    # pool's dtype before their products, as every configuration states
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == "float32" \
+        else dict(rtol=0, atol=3e-2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **tol)
+
+
+def test_paged_decode_bf16_makes_no_float32_copy_of_a_page():
+    """Operands as the pool stores them: nowhere in the kernel's body is
+    a page's worth of bfloat16 (or more) converted to float32; the
+    products take the buffers as they are and accumulate in float32."""
+    B, H, G, D, P, page, slots = 4, 32, 4, 128, 16, 64, 8
+    args = (jnp.zeros((B, 1, H, D)), jnp.zeros((P, page, G * D), "bfloat16"),
+            jnp.zeros((P, page, G * D), "bfloat16"),
+            jnp.zeros((B, slots), jnp.int32), jnp.ones((B,), jnp.int32))
+    closed = jax.make_jaxpr(pa.paged_decode_attention)(*args)
+
+    def walk(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "convert_element_type" \
+                    and eqn.params["new_dtype"] == jnp.float32 \
+                    and eqn.invars[0].aval.dtype == jnp.bfloat16:
+                found.append(tuple(eqn.invars[0].aval.shape))
+            if eqn.primitive.name == "dot_general":
+                found.append(tuple(str(v.aval.dtype) for v in eqn.invars))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, found)
+        return found
+
+    found = walk(closed.jaxpr, [])
+    casts = [f for f in found if isinstance(f[0], int)]
+    assert not [c for c in casts if int(np.prod(c)) >= page * G * D], casts
+    assert [f for f in found if f == ("bfloat16", "bfloat16")] \
+        == [("bfloat16", "bfloat16")] * 2, found
+
+
+#: the serve cells' decode calls: (B, Tq, H, D), K/V heads, page, dtype,
+#: table slots, window — and the latent cell's, whose kernel asks the
+#: same rule
+CELL_CALLS = {
+    "dense": ((16, 1, 32, 64), 32, 16, "float32", 128, 0),
+    "trinity": ((16, 1, 32, 128), 4, 64, "bfloat16", 128, 2048),
+    "lfm2": ((16, 1, 32, 64), 8, 64, "bfloat16", 128, 0),
+    "latent": ((16, 32, 640), 0, 64, "bfloat16", 128, 0),
+}
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """A described (not attached) v5e chip to compile for."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever says "no libtpu here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_CALLS))
+def test_the_rule_gives_8_pages_at_the_cells_shapes_and_mosaic_takes_them(
+        cell, one_v5e, monkeypatch):
+    """``_pages_per_step`` at what each serve cell's decode call shows
+    it: 8 pages a step (512 tokens of bf16 rows; the dense pool's f32
+    pages of 2,048 lanes by the VMEM budget, where 16 would not fit),
+    and the kernel at that chunk compiles for a v5e: Mosaic takes the
+    buffers, the 3-D semaphore array and the products as written."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    q_shape, g, page, dtype, slots, window = CELL_CALLS[cell]
+    isz = jnp.dtype(dtype).itemsize
+    if cell == "latent":
+        b, h, w = q_shape
+        assert pa._pages_per_step(page, w * isz, h,
+                                  h * (w * isz + 512 * 4), slots) == 8
+        shapes = [(q_shape, dtype), ((2048, page, w), dtype)]
+        call = lambda q, pages, t, n: pa.latent_decode_attention(
+            q, pages, t, n, 512, 0.07)
+    else:
+        b, t_q, h, d = q_shape
+        w = g * d
+        rule = lambda: pa._pages_per_step(
+            page, 2 * w * isz, t_q * h, t_q * h * w * (isz + 4), slots)
+        assert rule() == 8
+        # what bounds it: the budget for the dense pool, the 512 tokens
+        # for the routed ones
+        monkeypatch.setattr(pa, "DECODE_VMEM_BUDGET", 64 << 20)
+        assert rule() == (32 if cell == "dense" else 8)
+        monkeypatch.undo()
+        shapes = [(q_shape, "float32"), ((2048, page, w), dtype),
+                  ((2048, page, w), dtype)]
+        call = lambda q, k, v, t, n: pa.paged_decode_attention(
+            q, k, v, t, n, window=window, name="paged_decode")
+    monkeypatch.setattr(pa, "pallas_interpret", lambda: False)
+    shapes += [((b, slots), "int32"), ((b,), "int32")]
+    args = [jax.ShapeDtypeStruct(s, jnp.dtype(t), sharding=one_v5e)
+            for s, t in shapes]
+    # a compile for a described chip is written to the persistent cache
+    # and cannot be read back without one: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        text = jax.jit(call).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("kind", ["base", "gqa"])
 @pytest.mark.parametrize("t_q", [1, 4])
 def test_paged_decode_lane_dense_pool_is_the_same_pool(t_q, kind, rng):
@@ -536,7 +717,9 @@ def test_paged_decode_takes_the_pool_as_it_is_stored(rank):
     found = _pool_sized_eqns(closed.jaxpr, P * page * H * D, [])
     names = [name for name, _ in found]
     assert names.count("pallas_call") == 1, found
-    rest = [n for n in names if n != "pallas_call"]
+    # the call is a jit of its own (one trace for a step's layers): the
+    # pools pass through it, and XLA inlines it
+    rest = [n for n in names if n not in ("pallas_call", "jit")]
     assert rest == ([] if rank == 3 else ["reshape", "reshape"]), found
 
 
