@@ -57,6 +57,8 @@ PAGED_DECODE = "paged_decode"
 #: decode over a latent cache: one compressed row a token serves every
 #: query head as its key and, in its leading lanes, as its value
 LATENT_DECODE = "latent_decode"
+# ops/pallas_ssm.py — Mamba's selective scan over whole prompts
+SSM_SCAN = "ssm_scan"
 # ops/pallas_moe.py
 MOE_GMM = "moe_gmm"                      # grouped matmul, rows by expert
 # ops/pallas_embedding.py

@@ -40,13 +40,15 @@ update runs under ``OPTIMIZER`` and its checks under ``HEALTH``.
 from __future__ import annotations
 
 EMBED = "embed"                          # token and position rows
-# L<i>/mixer: attention, or the gated short convolution in its place
+# L<i>/mixer: attention, or the gated short convolution or the mamba
+# mixer in its place
 MIXER_NORM = "L{}/mixer/norm"            # the pre-norm
 QKV = "L{}/mixer/qkv"                    # projections, rotary, head norms
 CACHE_WRITE = "L{}/mixer/cache_write"    # the rows into their pages
 ATTEND = "L{}/mixer/attend"              # the kernel and what feeds only it
 MIXER_OUT = "L{}/mixer/out"              # gate, wo, post-norm, residual
 CONV = "L{}/mixer/conv"                  # a conv layer whole, state ops too
+SSM = "L{}/mixer/ssm"                    # a mamba layer whole, state ops too
 # L<i>/ffn and, inside it:
 FFN = "L{}/ffn"
 FFN_NORM = "norm"                        # pre-norm (post-norm: its last op)

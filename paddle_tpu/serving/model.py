@@ -10,11 +10,14 @@ float32 or bfloat16.  The empty plan is the dense block this module
 started as.  A plan of ``latent`` layers caches ONE compressed row a
 token and layer in one pool where the others cache per-head K and V in
 two; the pools hold the layers that attend and no others.  A ``conv``
-layer caches nothing a token: it keeps ``conv_taps - 1`` rows of
-``dim`` numbers a **sequence**, whatever its length, in one more cache
-beside the pools, at the number of the sequence's first page
-(:meth:`DecoderModel.new_pools` says what the plan needs, and the steps
-take and donate whatever it gave).
+or ``mamba`` layer caches nothing a token: it keeps a fixed-size state
+a **sequence**, whatever its length (``conv_taps - 1`` rows of its
+convolution's input, and a ``mamba`` layer its scan's state besides),
+in caches beside the pools, in the sequence's **slot**: a step takes
+each row's slot as an input (where its caller names none, the number of
+the row's first page), and a slot holds one sequence from its prefill
+to its end (:meth:`DecoderModel.new_pools` says what the plan needs,
+and the steps take and donate whatever it gave).
 
 The two entry points mirror the two serving kernels from PR 14/15:
 
@@ -81,6 +84,7 @@ from ..ops.pallas_attention import (flash_attention_packed,
                                     paged_row_write, segments_from_lengths)
 from ..ops.pallas_moe import (routed_experts, weight_einsum,
                               weight_matmul)
+from ..ops.pallas_ssm import selective_scan, ssm_step
 from ..utils import enforce
 from . import export as _export
 from . import loader as _loader
@@ -132,13 +136,28 @@ class DecoderConfig(NamedTuple):
     storage dtype (prefill writes them, a decode step rolls them), and
     the sum is float32 over z as stored.
 
+    ``mamba`` is Mamba-1's selective state-space mixer, alone on its
+    side like ``conv``.  ``in_proj`` gives u and z of ``ssm_inner``
+    lanes (in this order); u' = silu(causal depthwise convolution of u
+    over ``conv_taps`` + ``conv_bias``); ``x_proj`` of u' gives δ, B, C
+    (``dt_rank``, ``ssm_state``, ``ssm_state`` lanes), each RMS-normed
+    with a gain (``dt_norm``, ``b_norm``, ``c_norm``); Δ =
+    softplus(δ·``dt_proj`` + ``dt_bias``), A = −exp(``A_log``), and the
+    scan h_t = exp(Δ_t ⊙ A) ⊙ h_{t−1} + (Δ_t ⊙ u'_t) ⊗ B_t, y_t =
+    h_t·C_t + D ⊙ u'_t runs in float32 (``ops/pallas_ssm.py``); the
+    layer adds (y ⊙ silu(z))·``out_proj``.  What it keeps of a sequence
+    is the newest ``conv_taps - 1`` u as stored and ``h``
+    [``ssm_state``, ``ssm_inner``] in float32.
+
     The empty plan is the default one, ``full/gelu`` in every layer
     (with ``pos_embed`` the decoder this module started as).  ``heads``
     query heads share ``kv_heads`` K/V heads of ``head_dim`` (0: one
     K/V head a query head, ``dim // heads`` wide).  ``storage`` is the
     dtype of the matrices, the embedding and the K/V pool; matrix
     products take operands in it and accumulate in float32, and norms,
-    router scores, softmax and the residual stream stay float32."""
+    router scores, softmax and the residual stream stay float32.
+    ``tied_head``: the logits are the final norm's output times the
+    embedding's transpose, and there is no ``lm_head``."""
     vocab: int
     dim: int
     heads: int
@@ -166,9 +185,15 @@ class DecoderConfig(NamedTuple):
     rope_dim: int = 0
     v_dim: int = 0
     conv_taps: int = 3
+    ssm_inner: int = 0
+    ssm_state: int = 0
+    dt_rank: int = 0
+    tied_head: bool = False
 
 
-KINDS = frozenset({"full", "window", "latent", "conv"})
+KINDS = frozenset({"full", "window", "latent", "conv", "mamba"})
+#: the mixers that keep a fixed-size state a sequence in its slot
+STATE_KINDS = ("conv", "mamba")
 ATTENTION_WORDS = KINDS | {"rope", "qknorm", "gate", "postnorm"}
 FFN_WORDS = frozenset({"gelu", "swiglu", "routed", "shared"})
 
@@ -188,10 +213,10 @@ def layer_plan(cfg: DecoderConfig
         attn, ffn = frozenset(attn.split("+")), frozenset(ffn.split("+"))
         enforce(attn <= ATTENTION_WORDS and len(attn & KINDS) == 1
                 and ("latent" not in attn or attn <= {"latent", "rope"})
-                and ("conv" not in attn or attn == {"conv"}),
+                and all(k not in attn or attn == {k} for k in STATE_KINDS),
                 f"plan entry {entry!r}: attention is full, window or "
                 f"latent[+rope], then any of {sorted(ATTENTION_WORDS)}; "
-                "or conv alone")
+                "or conv alone, or mamba alone")
         enforce(ffn <= FFN_WORDS
                 and len(ffn & {"gelu", "swiglu", "routed"}) == 1
                 and ("shared" not in ffn or "routed" in ffn),
@@ -204,9 +229,12 @@ def layer_plan(cfg: DecoderConfig
                 f"plan entry {entry!r} needs experts >= top_k >= 1 and "
                 "expert_ffn > 0")
         out.append((attn, ffn))
-    enforce(all("conv" not in attn for attn, _ in out)
+    enforce(all(attn.isdisjoint(STATE_KINDS) for attn, _ in out)
             or cfg.conv_taps >= 2,
-            "a conv layer needs conv_taps >= 2")
+            "a conv or mamba layer needs conv_taps >= 2")
+    enforce(all("mamba" not in attn for attn, _ in out)
+            or min(cfg.ssm_inner, cfg.ssm_state, cfg.dt_rank) > 0,
+            "a mamba layer needs ssm_inner, ssm_state and dt_rank > 0")
     latent = sum("latent" in attn for attn, _ in out)
     enforce(latent in (0, cfg.layers),
             "a plan is latent in every layer or in none: one kind of "
@@ -237,14 +265,16 @@ def head_dim(cfg: DecoderConfig) -> int:
 def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
     """Every weight of the decoder by its artifact name → its shape:
     ``embed``, ``pos_embed`` (if ``cfg.pos_embed``), ``ln_f``,
-    ``lm_head`` and per layer ``l{i}.{ln1,ln2,wq,wk,wv,wo}`` plus what
-    its plan entry adds: ``w1 w2`` (gelu) | ``w_gate w_up w_down``
+    ``lm_head`` (unless ``cfg.tied_head``) and per layer
+    ``l{i}.{ln1,ln2,wq,wk,wv,wo}`` plus what its plan entry adds: ``w1 w2`` (gelu) | ``w_gate w_up w_down``
     (swiglu) | ``router router_bias e_gate e_up e_down`` (routed) and
     ``s_gate s_up s_down`` (shared); ``qn kn`` (qknorm), ``wg`` (gate),
     ``ln1p ln2p`` (postnorm).  A ``latent`` layer has ``w_dq q_ln w_uq
     w_dkv kv_ln w_ukv`` in the place of ``wq wk wv``, and its ``wo``
     takes ``heads · v_dim``; a ``conv`` layer has ``in_proj conv
-    out_proj`` in the place of all four."""
+    out_proj`` in the place of all four, a ``mamba`` layer ``in_proj
+    conv conv_bias x_proj dt_norm b_norm c_norm dt_proj dt_bias A_log D
+    out_proj``."""
     d, h, g = cfg.dim, cfg.heads, kv_heads(cfg)
     dh = 0 if cfg.kv_rank else head_dim(cfg)
     e, f = cfg.experts, cfg.expert_ffn
@@ -252,7 +282,8 @@ def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
     if cfg.pos_embed:
         out["pos_embed"] = (cfg.max_context, d)
     out["ln_f"] = (d,)
-    out["lm_head"] = (d, cfg.vocab)
+    if not cfg.tied_head:
+        out["lm_head"] = (d, cfg.vocab)
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
         leaves = {"ln1": (d,), "ln2": (d,)}
         if "latent" in attn:
@@ -266,6 +297,13 @@ def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
         elif "conv" in attn:
             leaves.update(in_proj=(d, 3 * d), conv=(d, cfg.conv_taps),
                           out_proj=(d, d))
+        elif "mamba" in attn:
+            di, n, r = cfg.ssm_inner, cfg.ssm_state, cfg.dt_rank
+            leaves.update(
+                in_proj=(d, 2 * di), conv=(di, cfg.conv_taps),
+                conv_bias=(di,), x_proj=(di, r + 2 * n), dt_norm=(r,),
+                b_norm=(n,), c_norm=(n,), dt_proj=(r, di), dt_bias=(di,),
+                A_log=(di, n), D=(di,), out_proj=(di, d))
         else:
             leaves.update(wq=(d, h * dh), wk=(d, g * dh), wv=(d, g * dh),
                           wo=(h * dh, d))
@@ -294,9 +332,10 @@ def leaf_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
 def _stored_as(name: str, shape, cfg: DecoderConfig) -> str:
     """The dtype a leaf is kept in on the device: ``cfg.storage`` for
     the matrices and the embeddings; float32 for gains, the router and
-    its bias (a score that rounds differently picks another expert) and
-    a conv layer's taps (its sum is float32)."""
-    if len(shape) < 2 or name.endswith((".router", ".conv")):
+    its bias (a score that rounds differently picks another expert), a
+    conv or mamba layer's taps (its sum is float32) and a mamba layer's
+    ``A_log`` (its scan is)."""
+    if len(shape) < 2 or name.endswith((".router", ".conv", ".A_log")):
         return "float32"
     return cfg.storage
 
@@ -306,13 +345,24 @@ def init_decoder_params(cfg: DecoderConfig, seed: int = 0
     """Random fp32 decoder weights (scaled normal init) under the
     artifact's names (:func:`leaf_shapes`): matrices N(0, 1/fan_in),
     the embedding N(0, 1), positions N(0, 0.02²), gains 1, the router's
-    selection bias N(0, 0.1²), a conv layer's taps N(0, 1/taps)."""
+    selection bias N(0, 0.1²), a conv layer's taps N(0, 1/taps); a mamba
+    layer as Mamba inits it: ``A_log`` = log(1..N) a channel, ``D`` 1,
+    ``dt_bias`` = softplus⁻¹(Δ₀) with Δ₀ log-uniform in [0.001, 0.1],
+    ``conv_bias`` N(0, 0.1²)."""
     rng = np.random.default_rng(seed)
     p: Dict[str, np.ndarray] = {}
     for name, shape in leaf_shapes(cfg).items():
+        if name.endswith(".dt_bias"):
+            dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), shape))
+            p[name] = (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+            continue
+        if name.endswith(".A_log"):
+            p[name] = np.broadcast_to(np.log(np.arange(1, shape[1] + 1)),
+                                      shape).astype(np.float32)
+            continue
         if len(shape) == 1:
             p[name] = (0.1 * rng.standard_normal(shape)
-                       if name.endswith("router_bias")
+                       if name.endswith(("router_bias", "conv_bias"))
                        else np.ones(shape)).astype(np.float32)
             continue
         z = rng.standard_normal(shape)
@@ -498,35 +548,144 @@ def _conv_out(x, gate, c, params, i):
     return x + weight_matmul(gate * c, params[f"l{i}.out_proj"])
 
 
-def _conv_prefill(x, held, place, lengths, params, i, cfg: DecoderConfig):
+def _causal_windows(z, taps: int):
+    """``z`` [B, T, W] with ``taps - 1`` zeros before each row's start
+    and the ``taps`` shifted views whose sum is a causal convolution:
+    (padded z, [the view ending at each position, oldest first])."""
+    t = z.shape[1]
+    zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    return zp, [zp[:, j:j + t] for j in range(taps)]
+
+
+def _newest(zp, lengths, taps: int):
+    """The ``taps - 1`` newest rows of each prompt in the padded ``zp``
+    (zeros where the prompt is shorter): what a sequence keeps."""
+    at = lengths[:, None] + jnp.arange(taps - 1)[None, :]
+    return jnp.take_along_axis(zp, at[:, :, None], axis=1)
+
+
+def _keep(state, slot, rows, value):
+    """``value`` written whole into the ``slot`` [B] of each row where
+    ``rows`` [B] holds; the other rows write nothing (a slot needs no
+    clearing: a prefill writes all of it)."""
+    return state.at[jnp.where(rows, slot, state.shape[0])].set(
+        value.astype(state.dtype), mode="drop")
+
+
+def _conv_prefill(x, held, slot, lengths, params, i, cfg: DecoderConfig):
     """A conv layer over whole prompts ``x`` [B, T, dim]: every
     position's sum in one pass over the row, zeros before its start
     (a row is one sequence, so no sum reaches into another).  What a
-    sequence keeps is its newest ``conv_taps - 1`` z (zeros where the
-    prompt is shorter), written whole into its ``place`` [B] of the
-    state ``held``: a place needs no clearing.  → (stream, state)."""
-    taps, t = cfg.conv_taps, x.shape[1]
+    sequence keeps is its newest ``conv_taps - 1`` z, written whole into
+    its ``slot`` [B] of the state ``held``.  → (stream, state)."""
     z, gate = _conv_gates(x, params, i, cfg)
-    zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
-    conv = _conv_window([zp[:, j:j + t] for j in range(taps)],
-                        params[f"l{i}.conv"])
-    newest = lengths[:, None] + jnp.arange(taps - 1)[None, :]
-    held = held.at[jnp.where(lengths > 0, place, held.shape[0])].set(
-        jnp.take_along_axis(zp, newest[:, :, None], axis=1), mode="drop")
+    zp, views = _causal_windows(z, cfg.conv_taps)
+    conv = _conv_window(views, params[f"l{i}.conv"])
+    held = _keep(held, slot, lengths > 0, _newest(zp, lengths, cfg.conv_taps))
     return _conv_out(x, gate, conv, params, i), held
 
 
-def _conv_decode(x, held, place, active, params, i, cfg: DecoderConfig):
+def _conv_decode(x, held, slot, active, params, i, cfg: DecoderConfig):
     """A conv layer over one new token a row, ``x`` [B, 1, dim]: the
-    row's state is read at its ``place``, rolled by the token's z and
-    written back where it lay; an idle slot reads the scratch place and
+    row's state is read at its ``slot``, rolled by the token's z and
+    written back where it lay; an idle row reads the scratch slot and
     writes nothing.  → (stream, state)."""
     z, gate = _conv_gates(x[:, 0], params, i, cfg)
-    window = jnp.concatenate([held[place], z[:, None]], axis=1)
-    held = held.at[jnp.where(active, place, held.shape[0])].set(
-        window[:, 1:], mode="drop")
+    window = jnp.concatenate([held[slot], z[:, None]], axis=1)
+    held = _keep(held, slot, active, window[:, 1:])
     conv = _conv_window(jnp.moveaxis(window, 1, 0), params[f"l{i}.conv"])
     return _conv_out(x, gate[:, None], conv[:, None], params, i), held
+
+
+def _mamba_in(x, params, i, cfg: DecoderConfig):
+    """A mamba layer's inputs from the stream: u as the state stores it
+    (the storage dtype, which prefill's convolution and a later decode
+    step's both read) and the gate z, float32."""
+    xn = _rms(x, params[f"l{i}.ln1"], cfg.norm_eps)
+    u, z = jnp.split(weight_matmul(xn, params[f"l{i}.in_proj"]), 2, axis=-1)
+    return u.astype(cfg.storage), z
+
+
+def _mamba_conv(views, params, i):
+    """u' = silu(Σ_j taps[:, j] ⊙ u_{…j} + bias), float32."""
+    return jax.nn.silu(_conv_window(views, params[f"l{i}.conv"])
+                       + params[f"l{i}.conv_bias"])
+
+
+def _mamba_dbc(uc, params, i, cfg: DecoderConfig):
+    """``x_proj`` of u' → (Δ over ``ssm_inner`` lanes, B, C): δ, B and C
+    each RMS-normed with its gain, Δ = softplus(δ·dt_proj + dt_bias)."""
+    p = lambda leaf: params[f"l{i}.{leaf}"]
+    r, n = cfg.dt_rank, cfg.ssm_state
+    dbc = weight_matmul(uc, p("x_proj"))
+    rms = lambda a, g: _rms(a, p(g), cfg.norm_eps)
+    delta = rms(dbc[..., :r], "dt_norm")
+    b, c = rms(dbc[..., r:r + n], "b_norm"), rms(dbc[..., r + n:], "c_norm")
+    return jax.nn.softplus(weight_matmul(delta, p("dt_proj"))
+                           + p("dt_bias")), b, c
+
+
+def _mamba_a(params, i):
+    """A = −exp(A_log), [ssm_state, ssm_inner]: channels on the lanes."""
+    return -jnp.exp(params[f"l{i}.A_log"]).T
+
+
+def _mamba_out(x, y, z, params, i):
+    return x + weight_matmul(y * jax.nn.silu(z), params[f"l{i}.out_proj"])
+
+
+def _mamba_prefill(x, win, hs, slot, lengths, params, i,
+                   cfg: DecoderConfig):
+    """A mamba layer over whole prompts ``x`` [B, T, dim]: the
+    convolution over each row with zeros before its start, then the
+    scan from a zero state with Δ = 0 past each row's length, so the
+    state the scan ends in is the one after the row's own last token.
+    The newest ``conv_taps - 1`` u and that state go whole into the
+    row's ``slot`` of ``win`` and ``hs``.  → (stream, win, hs)."""
+    b, t, _ = x.shape
+    u, z = _mamba_in(x, params, i, cfg)
+    up, views = _causal_windows(u, cfg.conv_taps)
+    uc = _mamba_conv(views, params, i)
+    delta, bm, cm = _mamba_dbc(uc, params, i, cfg)
+    inside = jnp.arange(t)[None, :, None] < lengths[:, None, None]
+    h0 = jnp.zeros((b, cfg.ssm_state, cfg.ssm_inner), jnp.float32)
+    y, h = selective_scan(uc, jnp.where(inside, delta, 0.0),
+                          _mamba_a(params, i), bm, cm,
+                          params[f"l{i}.D"], h0)
+    # what the slot keeps is taken before the next layer starts: left to
+    # the scheduler, every layer's [T, ssm_inner] u stayed live until
+    # the state's writes at the step's end (at T = 16,384 and 26
+    # layers, 4.4 GB of the prefill's scratch)
+    x, kept, h = jax.lax.optimization_barrier(
+        (_mamba_out(x, y, z, params, i), _newest(up, lengths, cfg.conv_taps),
+         h))
+    rows = lengths > 0
+    return x, _keep(win, slot, rows, kept), _keep(hs, slot, rows, h)
+
+
+def _mamba_decode(x, win, hs, slot, active, params, i, cfg: DecoderConfig):
+    """A mamba layer over one new token a row, ``x`` [B, 1, dim]: the
+    row's window and state are read at its ``slot``, advanced by the
+    token and written back where they lay; an idle row reads the
+    scratch slot and writes nothing.  → (stream, win, hs)."""
+    u, z = _mamba_in(x[:, 0], params, i, cfg)
+    window = jnp.concatenate([win[slot], u[:, None]], axis=1)
+    uc = _mamba_conv(jnp.moveaxis(window, 1, 0), params, i)
+    delta, bm, cm = _mamba_dbc(uc, params, i, cfg)
+    h, y = ssm_step(hs[slot], uc, delta, _mamba_a(params, i), bm, cm,
+                    params[f"l{i}.D"])
+    win = _keep(win, slot, active, window[:, 1:])
+    hs = _keep(hs, slot, active, h)
+    return _mamba_out(x, y[:, None], z[:, None], params, i), win, hs
+
+
+def _head(x, params, cfg: DecoderConfig):
+    """The final norm and the logits [B, V], float32: times the
+    embedding's transpose where the head is tied."""
+    x = _rms(x, params["ln_f"], cfg.norm_eps)
+    if cfg.tied_head:
+        return weight_einsum("bd,vd->bv", x, params["embed"])
+    return weight_matmul(x, params["lm_head"])
 
 
 def _attend_out(x, o, gate, params, i, cfg: DecoderConfig, attn):
@@ -593,34 +752,46 @@ def _layers_end_to_end(pool):
     pages (a free reshape): layer ``i``'s page ``p`` is page
     ``i·P + p``, so a page table offset by ``i·P`` addresses the
     layer's share and ``paged_kv_write``'s "past the pool" for a
-    dropped row lies past the last layer, never in the next one.  The
-    conv state ``[L, places, taps - 1, dim]`` is viewed the same way."""
+    dropped row lies past the last layer, never in the next one.  A
+    state ``[L, slots, …]`` is viewed the same way: layer ``i``'s slot
+    ``s`` is ``i·S + s``."""
     return pool.reshape(pool.shape[0] * pool.shape[1], *pool.shape[2:])
 
 
 def _kv_and_state(pools, cfg: DecoderConfig):
     """A step's caches as :meth:`DecoderModel.new_pools` orders them →
-    (their shapes, the K/V or latent pools layers end to end, the conv
-    state layers end to end or None)."""
+    (their shapes, the K/V or latent pools layers end to end, the
+    states layers end to end: a dict by what keeps them, ``conv``
+    [held] and ``mamba`` [window, h], each as the plan has such
+    layers)."""
     shapes = [pool.shape for pool in pools]
     flat = [_layers_end_to_end(pool) for pool in pools]
     n = n_kv_pools(cfg)
-    return shapes, flat[:n], (flat[n] if len(flat) > n else None)
+    states = {}
+    for (kind, _, _), state in zip(state_shapes(cfg), flat[n:]):
+        states.setdefault(kind, []).append(state)
+    return shapes, flat[:n], states
 
 
-def _as_stored(shapes, pools, held):
+def _as_stored(shapes, pools, states):
     """The step's caches back in the shapes and the order they came."""
-    if held is not None:
-        pools = [*pools, held]
+    pools = [*pools, *(a for kept in states.values() for a in kept)]
     return tuple(pool.reshape(shape) for pool, shape in zip(pools, shapes))
 
 
-def _prefill_impl(params, pools, tokens, lengths, page_indices,
+def _slot_count(shapes, cfg: DecoderConfig) -> int:
+    """Slots of the step's states (0 where the plan keeps none)."""
+    n = n_kv_pools(cfg)
+    return shapes[n][1] if len(shapes) > n else 0
+
+
+def _prefill_impl(params, pools, tokens, lengths, page_indices, slots,
                   cfg: DecoderConfig):
     """[B, T] padded prompts → ([B] first generated tokens, [B, V]
     logits, the updated pools).  Packed causal attention: the batch is
     ONE [1, B*T] row; segment ids keep rows from attending across each
-    other and mask padding outright."""
+    other and mask padding outright.  ``slots`` [B]: where each row's
+    sequence keeps its state (conv and mamba layers)."""
     b, t = tokens.shape
     h, g, dh = cfg.heads, kv_heads(cfg), head_dim(cfg)
     with jax.named_scope(S.EMBED):
@@ -631,17 +802,22 @@ def _prefill_impl(params, pools, tokens, lengths, page_indices,
         valid = pos < lengths[:, None]
         zero = jnp.zeros((b,), jnp.int32)
     with jax.named_scope(S.CACHE_LAYOUT):
-        shapes, pools, held = _kv_and_state(pools, cfg)
-    n_places = shapes[0][1]
-    a = c = 0            # the layer's index among its kind: its share
+        shapes, pools, states = _kv_and_state(pools, cfg)
+    n_places, n_slots = shapes[0][1], _slot_count(shapes, cfg)
+    a = c = m = 0        # the layer's index among its kind: its share
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
         if "conv" in attn:
-            # a sequence's state lies at the number of its first page
             with jax.named_scope(S.CONV.format(i)):
-                x, held = _conv_prefill(
-                    x, held, page_indices[:, 0] + c * n_places, lengths,
+                x, *states["conv"] = _conv_prefill(
+                    x, *states["conv"], slots + c * n_slots, lengths,
                     params, i, cfg)
             c += 1
+        elif "mamba" in attn:
+            with jax.named_scope(S.SSM.format(i)):
+                x, *states["mamba"] = _mamba_prefill(
+                    x, *states["mamba"], slots + m * n_slots, lengths,
+                    params, i, cfg)
+            m += 1
         else:
             table = page_indices + a * n_places
             a += 1
@@ -677,22 +853,23 @@ def _prefill_impl(params, pools, tokens, lengths, page_indices,
     with jax.named_scope(S.HEAD):
         last = jnp.take_along_axis(
             x, jnp.clip(lengths - 1, 0, t - 1)[:, None, None], axis=1)[:, 0]
-        logits = weight_matmul(_rms(last, params["ln_f"], cfg.norm_eps),
-                               params["lm_head"])
+        logits = _head(last, params, cfg)
         active = lengths > 0
         nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
         nxt = nxt.astype(jnp.int32)
     with jax.named_scope(S.CACHE_LAYOUT):
-        return nxt, logits, *_as_stored(shapes, pools, held)
+        return nxt, logits, *_as_stored(shapes, pools, states)
 
 
 def _decode_impl(params, pools, tokens, page_indices, lengths, active,
-                 cfg: DecoderConfig):
+                 slots, cfg: DecoderConfig):
     """One decode step for a fixed-width batch.  ``lengths`` INCLUDE the
     token being fed (its position is ``lengths - 1``); ``active`` masks
     padded slots — their write count is zero and their kernel length
     clamps to 1 over the scratch page, so padding can neither write nor
-    read real pool state.  The first result is the [B] next tokens,
+    read real pool state; ``slots`` [B] says where each row's state lies
+    (an idle row's: the scratch slot).  The first result is the [B] next
+    tokens,
     followed by :func:`_route_counts`' integers where the plan has
     routed layers; then the logits and the updated pools."""
     b = tokens.shape[0]
@@ -709,18 +886,22 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
     name = K.PAGED_DECODE if cfg.plan else None
     sizes = []
     with jax.named_scope(S.CACHE_LAYOUT):
-        shapes, pools, held = _kv_and_state(pools, cfg)
-    n_places = shapes[0][1]
-    a = c = 0
+        shapes, pools, states = _kv_and_state(pools, cfg)
+    n_places, n_slots = shapes[0][1], _slot_count(shapes, cfg)
+    a = c = m = 0
     for i, (attn, ffn) in enumerate(layer_plan(cfg)):
         if "conv" in attn:
-            # the row's state lies at its first page's number, an idle
-            # slot's at the scratch page's
             with jax.named_scope(S.CONV.format(i)):
-                x, held = _conv_decode(
-                    x, held, page_indices[:, 0] + c * n_places, active,
+                x, *states["conv"] = _conv_decode(
+                    x, *states["conv"], slots + c * n_slots, active,
                     params, i, cfg)
             c += 1
+        elif "mamba" in attn:
+            with jax.named_scope(S.SSM.format(i)):
+                x, *states["mamba"] = _mamba_decode(
+                    x, *states["mamba"], slots + m * n_slots, active,
+                    params, i, cfg)
+            m += 1
         else:
             table = page_indices + a * n_places
             a += 1
@@ -752,12 +933,11 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
         if routed is not None:
             sizes.append(routed)
     with jax.named_scope(S.HEAD):
-        logits = weight_matmul(_rms(x[:, 0], params["ln_f"], cfg.norm_eps),
-                               params["lm_head"])
+        logits = _head(x[:, 0], params, cfg)
         nxt = jnp.argmax(eos_frozen_logits(logits, active, cfg.eos_id), -1)
         ids = jnp.concatenate([nxt.astype(jnp.int32), _route_counts(sizes)])
     with jax.named_scope(S.CACHE_LAYOUT):
-        return ids, logits, *_as_stored(shapes, pools, held)
+        return ids, logits, *_as_stored(shapes, pools, states)
 
 
 def n_kv_pools(cfg: DecoderConfig) -> int:
@@ -766,15 +946,32 @@ def n_kv_pools(cfg: DecoderConfig) -> int:
     return 1 if "latent" in layer_plan(cfg)[0][0] else 2
 
 
-def conv_layers(cfg: DecoderConfig) -> int:
-    return sum("conv" in attn for attn, _ in layer_plan(cfg))
+def layers_of(cfg: DecoderConfig, kind: str) -> int:
+    """Layers of the plan whose mixer is ``kind`` (conv, mamba …)."""
+    return sum(kind in attn for attn, _ in layer_plan(cfg))
+
+
+def state_shapes(cfg: DecoderConfig):
+    """[(kind, shape less the slots' axis, dtype)] of the states the plan
+    keeps, in their order: the conv layers' window ``[layers, taps - 1,
+    dim]``; the mamba layers' window ``[layers, taps - 1, ssm_inner]``,
+    then their scan's state ``[layers, ssm_state, ssm_inner]`` in
+    float32."""
+    keep, out = cfg.conv_taps - 1, []
+    conv, mamba = layers_of(cfg, "conv"), layers_of(cfg, "mamba")
+    if conv:
+        out.append(("conv", (conv, keep, cfg.dim), cfg.storage))
+    if mamba:
+        out += [("mamba", (mamba, keep, cfg.ssm_inner), cfg.storage),
+                ("mamba", (mamba, cfg.ssm_state, cfg.ssm_inner), "float32")]
+    return out
 
 
 def n_pools(cfg: DecoderConfig) -> int:
     """Caches a step of this plan takes and donates: the pools of the
-    layers that attend and, where the plan has conv layers, their
-    state."""
-    return n_kv_pools(cfg) + (1 if conv_layers(cfg) else 0)
+    layers that attend and, where the plan has conv or mamba layers,
+    their states."""
+    return n_kv_pools(cfg) + len(state_shapes(cfg))
 
 
 @functools.lru_cache(maxsize=None)
@@ -793,6 +990,7 @@ def _jitted_steps(cfg: DecoderConfig):
     # step's scatters write into the buffers that came in, and the
     # arrays the caller passed are deleted (KVPool keeps the only
     # reference)
+    # The last input of both is each row's slot
     n = n_pools(cfg)
     donated = tuple(range(1, 1 + n))
     prefill = jax.jit(
@@ -856,9 +1054,15 @@ class DecoderModel:
         self.plan = layer_plan(cfg)          # checks the config too
         self.routed_layers = sum("routed" in ffn for _, ffn in self.plan)
         self._window_layers = sum("window" in attn for attn, _ in self.plan)
-        self.conv_layers = conv_layers(cfg)
+        self.conv_layers = layers_of(cfg, "conv")
+        self.mamba_layers = layers_of(cfg, "mamba")
+        # the layers that keep a state a sequence in its slot
+        self.state_layers = self.conv_layers + self.mamba_layers
         # the layers that attend: the pools hold these and no others
-        self._cached_layers = cfg.layers - self.conv_layers
+        self._cached_layers = cfg.layers - self.state_layers
+        # slots of the states new_pools last gave (what a step that
+        # names none is handed: _pools_of)
+        self.slots = 0
         self.n_kv_pools = n_kv_pools(cfg)
         self.n_pools = n_pools(cfg)
         shapes = leaf_shapes(cfg)
@@ -879,35 +1083,37 @@ class DecoderModel:
         self._prefill, self._decode = _jitted_steps(cfg)
 
     # ----------------------------------------------------------- pools
-    def new_pools(self, n_pages: int, page_size: int
-                  ) -> Tuple[KVPool, ...]:
+    def new_pools(self, n_pages: int, page_size: int,
+                  slots: Optional[int] = None) -> Tuple[KVPool, ...]:
         """The zeroed caches this plan needs, the caller's to keep and
         to hand to every step in this order: a K and a V pool, or the
-        one pool of a latent plan, then the conv layers' state where
-        the plan has such layers.  A pool is a :class:`KVPool` over
-        ``[L, P, page, W]`` in the storage dtype, ``L`` the layers that
-        attend (a conv layer has no share), one lane-dense row a token
+        one pool of a latent plan, then the states of the conv and the
+        mamba layers where the plan has such layers.  A pool is a
+        :class:`KVPool` over ``[L, P, page, W]`` in the storage dtype,
+        ``L`` the layers that attend (a conv or mamba layer has no
+        share), one lane-dense row a token
         (``W`` = G·Dh, or :func:`latent_row_width`), the layout the
         decode kernels fetch pages in and ``paged_row_write`` scatters
         rows into.  (Stored ``[…, G, Dh]`` with Dh < 128 the TPU lays
         the page axis along the lanes, and every use of a layer's pool
-        is a relayout copy of it: PERF.md §6, PR 26.)  The state is one
-        more :class:`KVPool`, ``[conv layers, P, conv_taps - 1, dim]``:
-        a sequence's rows lie at the number of its first page, so a
-        state follows its request's page table wherever the row moves
-        in the batch, the scratch page gives idle slots a place, and no
-        second allocator exists."""
+        is a relayout copy of it: PERF.md §6, PR 26.)  A state is a
+        :class:`KVPool` over ``[layers of its kind, slots, …]``
+        (:func:`state_shapes`).  A sequence's state lies in the slot
+        its caller gives its row in every step, so it follows the
+        request wherever its row moves in the batch: ``slots`` of them
+        (the server gives a mamba plan's sequences one each from
+        admission to release, and keeps the last for idle rows); none
+        given, one a page, where a step that names no slots finds a
+        row's state at the number of its first page."""
         shape = (self._cached_layers, n_pages, page_size, self._row_width())
         pools = tuple(KVPool(jnp.zeros(shape, self.cfg.storage))
                       for _ in range(self.n_kv_pools))
-        return pools + self._new_state(n_pages)
+        self.slots = slots or n_pages
+        return pools + self._new_state(self.slots)
 
-    def _new_state(self, n_places: int) -> Tuple[KVPool, ...]:
-        if not self.conv_layers:
-            return ()
-        shape = (self.conv_layers, n_places, self.cfg.conv_taps - 1,
-                 self.cfg.dim)
-        return (KVPool(jnp.zeros(shape, self.cfg.storage)),)
+    def _new_state(self, slots: int) -> Tuple[KVPool, ...]:
+        return tuple(KVPool(jnp.zeros((shape[0], slots, *shape[1:]), dtype))
+                     for _, shape, dtype in state_shapes(self.cfg))
 
     def _row_width(self) -> int:
         return latent_row_width(self.cfg) if self.n_kv_pools == 1 \
@@ -917,15 +1123,15 @@ class DecoderModel:
         """A step's arguments, the pools first: → (the plan's pools,
         the rest).  A caller that names a K and a V pool where the plan
         has one latent pool names that one twice: its rows are both;
-        one that names no conv state where the plan keeps one gets a
-        zeroed state of the pools' places for the call, the same
+        one that names no states where the plan keeps them gets zeroed
+        states of as many slots as :meth:`new_pools` last gave, the same
         program at the same shapes (the benchmark's warm-up is such a
         caller: PERF.md §7)."""
         n = next((i for i, a in enumerate(args)
                   if not isinstance(a, KVPool)), len(args))
         pools = tuple(dict.fromkeys(args[:n]))
         if len(pools) == self.n_kv_pools:
-            pools += self._new_state(pools[0].shape[1])
+            pools += self._new_state(self.slots)
         enforce(len(pools) == self.n_pools,
                 f"{len(pools)} pools handed to a step of a plan with "
                 f"{self.n_pools} (see new_pools)")
@@ -969,10 +1175,10 @@ class DecoderModel:
             * jnp.dtype(self.cfg.storage).itemsize
 
     def state_bytes_per_sequence(self) -> int:
-        """What a sequence holds in the conv layers' state, whatever
-        its length."""
-        return self.conv_layers * (self.cfg.conv_taps - 1) * self.cfg.dim \
-            * jnp.dtype(self.cfg.storage).itemsize
+        """What a sequence holds in the conv and mamba layers' states,
+        whatever its length."""
+        return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                   for _, shape, dtype in state_shapes(self.cfg))
 
     def pages_behind_window(self, lengths, page_size: int) -> int:
         """Layer-pages (one layer's K and V of one page) that these
@@ -995,14 +1201,18 @@ class DecoderModel:
     # the Python traceback into every op's location, and one frame more
     # above the call cost a 24-layer program a second or more of
     # lowering on the chip's host (PERF.md §6, PR 30).
-    def prefill(self, *args, collect: bool = True):
-        """``prefill(*pools, tokens, lengths, page_indices)``: prompts
+    def prefill(self, *args, slots=None, collect: bool = True):
+        """``prefill(*pools, tokens, lengths, page_indices,
+        slots=None)``: prompts
         in, first generated token out (plus the logits and the pools,
         the same objects, updated in place).  ``pools`` are
         :meth:`new_pools`' in their order; ``tokens`` [B, T] int32
         padded, ``lengths`` [B], ``page_indices`` [B, max_pages]
         physical page tables covering each prompt PLUS the tokens to be
-        generated.  The pools hold the launch's result as soon as it is
+        generated, ``slots`` [B] where each row's sequence keeps its
+        state (none named: the number of its first page, the same
+        program).  The
+        pools hold the launch's result as soon as it is
         queued; ``collect=False`` (:meth:`launch_prefill`) returns
         there, with the launch for :meth:`collect_prefill`."""
         pools, (tokens, lengths, page_indices) = self._pools_of(args)
@@ -1015,7 +1225,8 @@ class DecoderModel:
                 self.params, *(p.array for p in pools),
                 jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
-                jnp.asarray(page_indices, jnp.int32))
+                jnp.asarray(page_indices, jnp.int32),
+                self._slots_of(slots, page_indices))
             for pool, array in zip(pools, arrays):
                 pool.array = array
         if not collect:
@@ -1033,15 +1244,16 @@ class DecoderModel:
             nxt = np.asarray(nxt)
         return nxt, logits
 
-    def decode(self, *args, collect: bool = True):
+    def decode(self, *args, slots=None, collect: bool = True):
         """``decode(*pools, tokens, page_indices, lengths, active[,
-        prev, src])``: one continuous-batching decode step over the
-        page pool.  → (next tokens, logits, the pools (the same
+        prev, src], slots=None)``: one continuous-batching decode step
+        over the page pool.  → (next tokens, logits, the pools (the same
         objects, updated in place), the routed counts of
         :meth:`collect_decode`).  ``prev`` is an earlier decode launch
         of the same width, collected or not, and ``src`` [B] says per
         row which of its ids the row is fed (−1: the host's
-        ``tokens``); the same program runs with or without it.
+        ``tokens``); the same program runs with or without it, and with
+        or without ``slots`` (as :meth:`prefill`'s).
         ``collect=False`` (:meth:`launch_decode`) returns once the step
         is queued, with the launch for :meth:`collect_decode`."""
         pools, (tokens, page_indices, lengths, active, *feed) = \
@@ -1061,7 +1273,8 @@ class DecoderModel:
                 jnp.asarray(src, jnp.int32),
                 jnp.asarray(page_indices, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
-                jnp.asarray(active, bool))
+                jnp.asarray(active, bool),
+                self._slots_of(slots, page_indices))
             for pool, array in zip(pools, arrays):
                 pool.array = array
         if not collect:
@@ -1070,6 +1283,12 @@ class DecoderModel:
         return (nxt, logits, *pools, routed)
 
     launch_decode = functools.partialmethod(decode, collect=False)
+
+    @staticmethod
+    def _slots_of(slots, page_indices):
+        """A step's slot input: the caller's, or each row's first page."""
+        return jnp.asarray(np.asarray(page_indices)[:, 0] if slots is None
+                           else slots, jnp.int32)
 
     @staticmethod
     def collect_decode(launch):

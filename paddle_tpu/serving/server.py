@@ -80,7 +80,7 @@ before it: a few hundred microseconds).  Under span k::
 
     serve_loop_iter
     ├─ serve_prefill{n, t_pad, prompt_tokens, moe_tokens, conv_tokens,
-    │                attn_pairs, requests, queued}
+    │                scan_tokens, attn_pairs, requests, queued}
     │  or serve_decode_step{batch, live_tokens, live_pages, attended_tokens,
     │                       state_rows, queued, experts_hit, expert_load_max
     │                       [, discarded]}
@@ -104,13 +104,20 @@ the layers that attend (a window layer reads a row's newest ``window``
 only, a conv layer none);
 ``batch`` the rows whose token was emitted (``discarded`` the others).
 Where the plan has conv layers, ``conv_tokens`` is the prompt tokens
-times those layers and ``state_rows`` the launched rows times them: the
-sequences whose fixed-size state the step reads and writes back.  That
-state lies at the number of the request's first page, so it follows the
-request's page table from admission to release whatever batch row the
-request has in a launch; an idle slot's lies at the scratch page, a row
-riding one launch past its EOS still owns its pages and so its place,
-and a freed place needs no clearing because a prefill writes all of it.
+times those layers, where it has mamba layers ``scan_tokens`` the
+prompt tokens times those, and ``state_rows`` the launched rows times
+the two: the sequences whose fixed-size state the step reads and writes
+back.  Under a plan with mamba layers that state lies in the request's
+**slot**, one of ``width + 1``: a request takes one at admission and
+gives it back when it finishes or fails, every launch hands each row's
+slot to the step, and the last slot is the idle rows'.  A conv layer's
+smaller state lies at the number of the request's first page (the step
+is handed no slots: PERF.md §7), an idle row's at the scratch page's.
+Either way a state follows its request whatever batch row the request
+has in a launch.  A row riding one launch past its EOS writes its old
+place on the device before the prefill that a new owner is queued
+behind, and a prefill writes all of a place: a freed one needs no
+clearing.
 Where the plan has routed layers, ``moe_tokens`` is the prompt tokens
 times those layers, and the step's own counts come back with its tokens
 and are set before the span closes: ``experts_hit`` (experts with a
@@ -195,11 +202,11 @@ class Request:
     """One generation request and its lifecycle state.  ``tokens`` holds
     the generated ids (prompt excluded); ``length`` counts tokens whose
     K/V the launches queued so far write to this request's pages (the
-    one in flight included); ``table`` is its page-table row once
-    admitted."""
+    one in flight included); ``table`` is its page-table row and
+    ``slot`` where its fixed-size state lies, once admitted."""
 
     __slots__ = ("id", "prompt", "max_new_tokens", "tokens", "state",
-                 "error", "done", "length", "next_token", "table",
+                 "error", "done", "length", "next_token", "table", "slot",
                  "t_submit", "t_admit", "t_first", "t_done", "trace_id")
 
     def __init__(self, prompt: Sequence[int], max_new_tokens: int):
@@ -213,6 +220,7 @@ class Request:
         self.length = 0                  # tokens materialized in pages
         self.next_token = -1             # newest token the host has read
         self.table: Optional[np.ndarray] = None
+        self.slot = -1
         self.t_submit = time.perf_counter()
         self.t_admit: Optional[float] = None
         self.t_first: Optional[float] = None
@@ -328,9 +336,15 @@ class InferenceServer:
         self._width = self.max_batch if self.continuous else 1
         self.snapshot_path = snapshot_path
         self.pool = self._make_pool(n_pages, page_size, snapshot_path)
+        # where a mamba plan's sequence keeps its state: a slot a row of
+        # the decode batch, the last for idle rows (a conv plan's lies at
+        # its first page: PERF.md §7)
+        self._slotted = bool(model.mamba_layers)
+        self._scratch_slot = self._width
+        self._free_slots = list(range(self._width))
         # the cache pools the model's plan needs, as new_pools gave
         # them: every launch hands them on and donates their arrays
-        self._pools = model.new_pools(n_pages, page_size)
+        self._pools = model.new_pools(n_pages, page_size, self._n_slots())
         # one page-table width for every request: enough pages to cover
         # a max_context-long sequence (or the whole pool if smaller)
         self.max_pages = min(self.pool.capacity,
@@ -376,8 +390,11 @@ class InferenceServer:
             "layers, as stored")
         self._m_state = None if _gauge is None else _gauge(
             "serve_state_bytes_per_sequence",
-            "what one sequence holds in the conv layers' state, "
-            "whatever its length")
+            "what one sequence holds over the layers that keep a "
+            "fixed-size state (conv, mamba), whatever its length")
+        self._m_slots = None if _gauge is None else _gauge(
+            "serve_state_slots_used",
+            "state slots held by admitted requests")
         self._publish_cache_sizes(model)
         self._m_launch = None if _counter is None else _counter(
             "serve_launch_total",
@@ -401,8 +418,8 @@ class InferenceServer:
     # what a caller that knows a K and a V pool reaches for (the
     # benchmark's warm-up): the first and the last of the pools of the
     # layers that attend, which for a latent plan are the one pool whose
-    # rows are both.  The conv state it does not know of the steps
-    # supply (DecoderModel._pools_of)
+    # rows are both.  The states it does not know of the steps supply
+    # (DecoderModel._pools_of)
     _k_pool = property(lambda self: self._pools[0])
     _v_pool = property(
         lambda self: self._pools[self.model.n_kv_pools - 1])
@@ -469,7 +486,7 @@ class InferenceServer:
             self._active = []
             swap, self._pending_swap = self._pending_swap, None
         for r in pending:
-            self.pool.release(r.id)
+            self._release(r)
             r.state = "failed"
             r.error = "server stopped"
             r.done.set()
@@ -616,7 +633,8 @@ class InferenceServer:
         old_version = self.model_version
         try:
             pools = ticket.model.new_pools(self.pool.n_pages,
-                                           self.pool.page_size)
+                                           self.pool.page_size,
+                                           self._n_slots())
         except Exception as e:  # noqa: BLE001 - rollback, keep serving
             self._pending_swap = None
             self.rollout_state = "rolled_back"
@@ -811,12 +829,15 @@ class InferenceServer:
                 except PagePoolExhausted:
                     break            # backpressure: retry after retires
                 self._queue.popleft()
+                if self._slotted:
+                    r.slot = self._free_slots.pop()
                 r.t_admit = time.perf_counter()
                 r.state = "active"
                 self._active.append(r)
                 admitted.append(r)
         if admitted:
             self._publish_queue_locked()
+            self._publish_slots()
         return admitted
 
     def _queued(self) -> str:
@@ -840,6 +861,7 @@ class InferenceServer:
             n=len(admitted), t_pad=t_pad, prompt_tokens=prompt_tokens,
             moe_tokens=prompt_tokens * self.model.routed_layers,
             conv_tokens=prompt_tokens * self.model.conv_layers,
+            scan_tokens=prompt_tokens * self.model.mamba_layers,
             attn_pairs=self.model.attn_pairs(
                 [len(r.prompt) for r in admitted]),
             requests=",".join(r.id for r in admitted),
@@ -875,7 +897,7 @@ class InferenceServer:
             batch=len(rows), live_tokens=sum(fed),
             live_pages=sum(map(self.pool.pages_needed, fed)),
             attended_tokens=self.model.attended_tokens(fed),
-            state_rows=len(rows) * self.model.conv_layers,
+            state_rows=len(rows) * self.model.state_layers,
             queued=self._queued()), src)
 
     def _launch(self, launch: _Launch) -> None:
@@ -893,6 +915,7 @@ class InferenceServer:
                     r.length = len(r.prompt)
                 lengths = np.array([r.length for r in rows], np.int32)
                 tables = np.stack([r.table for r in rows])
+                slots = self._slots_kw(rows, n)
             # testing knob: a seeded-slow artifact (manifest
             # debug_prefill_delay_ms) inflates TTFT here — inside the
             # TTFT stamp, before the launch — so a canary bake has a
@@ -903,7 +926,7 @@ class InferenceServer:
                 time.sleep(delay)
             self._marks.append(("dispatch", time.perf_counter()))
             launch.handle = self.model.launch_prefill(
-                *self._pools, tokens, lengths, tables)
+                *self._pools, tokens, lengths, tables, **slots)
         else:
             with _span("serve_step_build"):
                 b = self._width
@@ -913,6 +936,7 @@ class InferenceServer:
                 active = np.zeros((b,), bool)
                 tables = np.full((b, self.max_pages), SCRATCH_PAGE,
                                  np.int32)
+                slots = self._slots_kw(rows, b)
                 for i, r in enumerate(rows):
                     if launch.src[i] < 0:
                         tokens[i] = r.next_token
@@ -932,7 +956,8 @@ class InferenceServer:
                 else None
             self._marks.append(("dispatch", time.perf_counter()))
             launch.handle = self.model.launch_decode(
-                *self._pools, tokens, tables, lengths, active, prev, src)
+                *self._pools, tokens, tables, lengths, active, prev, src,
+                **slots)
         self._inflight.append(launch)
 
     def _collect(self, step) -> None:
@@ -989,7 +1014,7 @@ class InferenceServer:
         with self._cond:
             failed, self._active = self._active, []
         for r in failed:
-            self.pool.release(r.id)
+            self._release(r)
             r.state = "failed"
             r.error = f"{type(e).__name__}: {e}"
             r.done.set()
@@ -1017,10 +1042,34 @@ class InferenceServer:
                 or len(r.tokens) >= r.max_new_tokens:
             self._finish(r)
 
+    def _n_slots(self) -> Optional[int]:
+        return self._width + 1 if self._slotted else None
+
+    def _slots_kw(self, rows: List[Request], b: int) -> Dict:
+        """The ``slots`` a launch of ``b`` rows hands the step (idle rows
+        the scratch slot's), or nothing where the plan keeps no state in
+        slots."""
+        if not self._slotted:
+            return {}
+        slots = np.full((b,), self._scratch_slot, np.int32)
+        slots[:len(rows)] = [r.slot for r in rows]
+        return {"slots": slots}
+
+    def _release(self, r: Request) -> None:
+        """A request's pages and slot go back: it finished or failed."""
+        self.pool.release(r.id)
+        if r.slot >= 0:          # released once: it finished or failed
+            self._free_slots.append(r.slot)
+            self._publish_slots()
+
+    def _publish_slots(self) -> None:
+        if self._m_slots is not None:
+            self._m_slots.set(self._width - len(self._free_slots))
+
     def _finish(self, r: Request) -> None:
         r.t_done = time.perf_counter()
         r.state = "done"
-        self.pool.release(r.id)
+        self._release(r)
         with self._cond:
             if r in self._active:
                 self._active.remove(r)

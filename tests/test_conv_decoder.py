@@ -58,15 +58,23 @@ def _reference_logits(bench, seqs):
         return ref.logits_at(weights, sizes, tokens, at)[:, 0]
 
 
-def _serve(model, prompts, steps, page=4, width=5, between=None):
+def _serve(model, prompts, steps, page=4, width=5, between=None,
+           slotted=False):
     """Prefill the prompts as one batch, then ``steps`` decode steps at
     a fixed width with an idle slot, through page tables that are
     neither contiguous nor in order (so a sequence's state lies at no
     place its row index would give).  ``between(pools)`` runs after the
-    prefill.  → [(sequences so far, the program's logits for each)]."""
+    prefill.  ``slotted``: the states in ``width + 1`` slots of their
+    own, the rows' in no order, the idle rows' the last, handed to every
+    step; else one a page, at each row's first page.  → [(sequences so
+    far, the program's logits for each)]."""
     b = len(prompts)
     slots = model.cfg.max_context // page
-    pools = model.new_pools(1 + b * slots, page)
+    pools = model.new_pools(1 + b * slots, page,
+                            width + 1 if slotted else None)
+    given = {}
+    if slotted:
+        given["slots"] = np.array((3, 0, 1)[:b], np.int32)
     tables = 1 + np.random.default_rng(5).permutation(b * slots) \
         .reshape(b, slots).astype(np.int32)         # page 0: scratch
     tokens = np.zeros((b, 32), np.int32)
@@ -74,11 +82,14 @@ def _serve(model, prompts, steps, page=4, width=5, between=None):
         tokens[i, :len(p)] = p
     nxt, logits, *_ = model.prefill(
         *pools, tokens, np.array([len(p) for p in prompts], np.int32),
-        tables)
+        tables, **given)
     if between is not None:
         between(pools)
     seqs = [list(p) for p in prompts]
     out = [([list(s) for s in seqs], np.asarray(logits))]
+    if slotted:
+        given["slots"] = np.concatenate(
+            [given["slots"], np.full((width - b,), width, np.int32)])
     for _ in range(steps):
         for i in range(b):
             seqs[i].append(int(nxt[i]))
@@ -89,7 +100,7 @@ def _serve(model, prompts, steps, page=4, width=5, between=None):
         fed[:b], active[:b], tab[:b] = nxt[:b], True, tables
         lengths[:b] = [len(s) for s in seqs]
         nxt, logits, *_, counts = model.decode(*pools, fed, tab, lengths,
-                                               active)
+                                               active, **given)
         assert 8 * 4 <= counts["experts_hit"] <= 8 * min(16, 4 * b)
         out.append(([list(s) for s in seqs], np.asarray(logits)[:b]))
     return out
@@ -119,13 +130,16 @@ def _gaps(bench, model, steps=5, lengths=PROMPTS, **kw):
                      for seqs, logits in _serve(model, prompts, steps, **kw)])
 
 
+@pytest.mark.parametrize("slotted", [False, True])
 def test_prefill_then_decode_through_the_state_is_the_reference(
-        bench, decoder):
+        bench, decoder, slotted):
     """Prefill (step 0: every position's sum in one pass, the state
     written) and five decode steps (the state read, rolled and written
     back in place; K/V through page tables on two of nine layers)
-    against the reference's one full forward of the same sequence."""
-    gaps = _gaps(bench, decoder)
+    against the reference's one full forward of the same sequence: with
+    the state at each row's first page, as the server keeps a conv
+    plan's, and in slots of its own, as it keeps a mamba plan's."""
+    gaps = _gaps(bench, decoder, slotted=slotted)
     assert gaps.shape == (6, 3)
     assert gaps.max() < TOLERANCE["float32"], gaps
 

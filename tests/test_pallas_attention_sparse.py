@@ -589,6 +589,7 @@ CELL_CALLS = {
     "dense": ((16, 1, 32, 64), 32, 16, "float32", 128, 0),
     "trinity": ((16, 1, 32, 128), 4, 64, "bfloat16", 128, 2048),
     "lfm2": ((16, 1, 32, 64), 8, 64, "bfloat16", 128, 0),
+    "jamba": ((16, 1, 20, 128), 1, 64, "bfloat16", 260, 0),
     "latent": ((16, 32, 640), 0, 64, "bfloat16", 128, 0),
 }
 
@@ -656,6 +657,32 @@ def test_the_rule_gives_8_pages_at_the_cells_shapes_and_mosaic_takes_them(
         jax.config.update("jax_enable_compilation_cache", True)
         cc.reset_cache()
     assert "tpu_custom_call" in text
+
+
+def test_the_scan_kernel_at_the_jamba_cells_widths_compiles(one_v5e,
+                                                            monkeypatch):
+    """``ops/pallas_ssm.py``'s kernel at the longest prompt of
+    ``jamba_serve_closed_c12``: 16,384 positions of 5,120 channels and
+    16 state numbers, compiled for a v5e: Mosaic takes the [N, 512]
+    state, the [N, 8] tiles of B and C and their lane slices."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from paddle_tpu.ops import pallas_ssm
+
+    monkeypatch.setattr(pallas_ssm, "pallas_interpret", lambda: False)
+    t, ch, n = 16384, 5120, 16
+    shapes = [(1, t, ch), (1, t, ch), (n, ch), (1, t, n), (1, t, n),
+              (ch,), (1, n, ch)]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_v5e)
+            for s in shapes]
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        text = jax.jit(lambda *a: pallas_ssm.selective_scan(
+            *a, impl="pallas")).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    assert "tpu_custom_call" in text and "ssm_scan" in text
 
 
 @pytest.mark.parametrize("kind", ["base", "gqa"])

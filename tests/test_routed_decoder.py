@@ -536,8 +536,10 @@ def _recorded(impl, cfg):
             return paged_kv_write(k_pool, v_pool, k_new, v_new, *rest)
         real, decoder_module.paged_kv_write = \
             decoder_module.paged_kv_write, write
+        # the slots of a plan that keeps no state: read by nothing
+        slots = jnp.zeros(inputs[0].shape[:1], jnp.int32)
         try:
-            return impl(params, (k_pool, v_pool), *inputs, cfg), rows
+            return impl(params, (k_pool, v_pool), *inputs, slots, cfg), rows
         finally:
             decoder_module.paged_kv_write = real
     return jax.jit(run, donate_argnums=(1, 2))

@@ -165,6 +165,10 @@ PLANS = {
     "conv": M.DecoderConfig(
         **BASE, kv_heads=2, pos_embed=False, experts=4, top_k=2,
         expert_ffn=16, plan=("conv/swiglu", "full+rope+qknorm/routed")),
+    "mamba": M.DecoderConfig(
+        **BASE, kv_heads=1, pos_embed=False, conv_taps=4, ssm_inner=64,
+        ssm_state=16, dt_rank=4, tied_head=True,
+        plan=("mamba/swiglu", "full/swiglu")),
 }
 #: the scopes a plan word brings, beside those every plan has
 EVERY = {"embed", "head", "cache_layout", "L1/ffn/norm"}
@@ -180,6 +184,8 @@ EXPECTED = {
                                          "L1/mixer/attend"},
     "conv": EVERY | ATTENDS | ROUTED | {"L0/mixer/conv", "L0/ffn/dense",
                                        "L1/mixer/attend"},
+    "mamba": EVERY | ATTENDS | {"L0/mixer/ssm", "L0/ffn/dense",
+                                "L1/ffn/dense", "L1/mixer/attend"},
 }
 
 
@@ -188,10 +194,11 @@ def mosaic(monkeypatch):
     """Lower the kernels as the chip gets them, one ``tpu_custom_call``
     each, where the CPU would unroll the Pallas interpreter into the
     step's text."""
-    from paddle_tpu.ops import pallas_attention, pallas_moe
+    from paddle_tpu.ops import pallas_attention, pallas_moe, pallas_ssm
 
-    for module in (pallas_attention, pallas_moe):
+    for module in (pallas_attention, pallas_moe, pallas_ssm):
         monkeypatch.setattr(module, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(pallas_ssm, "is_tpu", lambda: True)
     M._jitted_steps.cache_clear()       # the steps are cached a config
     pallas_attention._decode_call.clear_cache()     # and so is this call
     yield
@@ -206,14 +213,15 @@ def _lowered(cfg, step):
     of 8) over pools of 6 pages of 4, lowered for the TPU."""
     model = M.DecoderModel(M.init_decoder_params(cfg, 0), cfg)
     prefill, decode = M._jitted_steps(cfg)
-    pools = [p.array for p in model.new_pools(6, 4)]
+    pools = [p.array for p in model.new_pools(6, 4, 3)]
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
     if step == "prefill":
         traced = prefill.trace(model.params, *pools, i32(2, 8), i32(2),
-                               i32(2, 2))
+                               i32(2, 2), i32(2))
     else:
         traced = decode.trace(model.params, *pools, i32(2), i32(2), i32(2),
-                              i32(2, 2), i32(2), jnp.zeros((2,), bool))
+                              i32(2, 2), i32(2), jnp.zeros((2,), bool),
+                              i32(2))
     return traced.lower(lowering_platforms=("tpu",))
 
 
