@@ -10,7 +10,10 @@ seed, every file written under ``--out`` (default
     python chip_smoke.py --all        # + one train step each of ResNet-50,
                                       # seq2seq, LSTM hidden 1280, sparse CTR;
                                       # the paged decode kernel timed at the
-                                      # serve cells' rows (decode_walk)
+                                      # serve cells' rows (decode_walk);
+                                      # the fused 3×3 conv+BN kernels timed
+                                      # by tile at ResNet-50's stage
+                                      # shapes (conv_walk)
     python chip_smoke.py --only decode_walk   # just the named phases
     python chip_smoke.py --fsdp --hlo # transformer under FSDP; dump the
                                       # compiled steps and print what each
@@ -105,6 +108,10 @@ FULL = {
             "lfm2": (32, 8, 64, 64, "bfloat16", 0,
                      (1024, 2048, 2048, 3072, 4096), 192)}},
     "resnet": {"depth": 50, "image": 224, "batch": 128, "classes": 1000},
+    # the fused 3×3 conv+BN kernels at ResNet-50's stage shapes
+    # (conv_walk): (map side, channels), the cell's batch
+    "conv_walk": {"batch": 128, "layers": 8, "reps": 5, "seed": 38,
+                  "shapes": ((56, 64), (28, 128), (14, 256), (7, 512))},
     "seq2seq": {"B": 128, "S_LEN": 30, "T_LEN": 30, "V": 30000, "E": 512,
                 "H": 512},
     "lstm1280": {"vocab": 30000, "hidden": 1280, "batch": 128, "seq": 100},
@@ -132,6 +139,8 @@ REHEARSAL = {
         "shapes": {
             "trinity_window": (4, 2, 32, 4, "bfloat16", 8, (8, 12, 20), 8)}},
     "resnet": {"depth": 8, "image": 32, "batch": 8, "classes": 10},
+    "conv_walk": {"batch": 4, "layers": 1, "reps": 1, "seed": 38,
+                  "shapes": ((8, 64), (4, 128))},
     "seq2seq": {"B": 8, "S_LEN": 6, "T_LEN": 6, "V": 200, "E": 128,
                 "H": 128},
     "lstm1280": {"vocab": 500, "hidden": 640, "batch": 8, "seq": 6},
@@ -1016,6 +1025,137 @@ def phase_decode_walk(S, ctx):
     return {"decode_walk": table}, None
 
 
+def conv_tile_of(kernel, h, w, cin, cout):
+    """The tile a fused 3×3 conv+BN kernel took at these shapes, as the
+    last call traced there told its ``conv_bn_tile`` gauge."""
+    from paddle_tpu import observe
+    from paddle_tpu.ops import pallas_conv as pc
+
+    want = {"kernel": kernel, "h": str(h), "w": str(w), "cin": str(cin),
+            "cout": str(cout)}
+    for smp in observe.REGISTRY.find("conv_bn_tile").samples():
+        lab = smp["labels"]
+        if all(lab[k] == v for k, v in want.items()):
+            return pc.ConvTile(int(smp["value"]), lab["k"], int(lab["rows"]))
+    raise SmokeFailure(f"no conv_bn_tile for {want}")
+
+
+def chained_call_us(call, layers, reps, x, *rest):
+    """Microseconds one ``call(x, *rest)`` takes as one of ``layers`` in
+    a row inside one program, each fed the result of the one before (of
+    x's shape and dtype): nothing else runs between the calls."""
+    import jax
+
+    f = jax.jit(lambda x, *rest: jax.lax.fori_loop(
+        0, layers, lambda _, o: call(o, *rest), x))
+    jax.block_until_ready(f(x, *rest))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = f(x, *rest)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps / layers * 1e6
+
+
+def conv_walk_variants(rule, h, n):
+    """Tiles of the walk: the rule's, and the rule's with each of its
+    choices moved one step (the other contraction forms, half and twice
+    the images a step, half and twice the band)."""
+    out = {"rule": rule}
+    for k in ("c", "9c"):
+        out[f"rule_k={k}"] = rule._replace(k=k)
+    for nb in (rule.nb // 2, rule.nb * 2):
+        if 1 <= nb <= n and n % nb == 0:
+            out[f"rule_nb={nb}"] = rule._replace(nb=nb)
+    for rows in (rule.rows // 2, rule.rows * 2):
+        if 1 <= rows <= h:
+            out[f"rule_rows={rows}"] = rule._replace(rows=rows)
+    seen, uniq = set(), {}             # one entry a distinct tile
+    for name, t in out.items():
+        if t not in seen:
+            seen.add(t)
+            uniq[name] = t
+    return uniq
+
+
+@contextlib.contextmanager
+def conv_tile_fixed(tile):
+    """Every fused 3×3 call traced inside takes ``tile`` in place of the
+    rule's."""
+    from paddle_tpu.ops import pallas_conv as pc
+
+    rule = pc._conv_tile
+    pc._conv_tile = lambda *a, **kw: tile
+    try:
+        yield
+    finally:
+        pc._conv_tile = rule
+
+
+def phase_conv_walk(S, ctx):
+    """Microseconds a call of the fused 3×3 conv+BN kernels
+    (``conv_bn_fwd``, ``conv_bn_fwd_bwd``) at ResNet-50's four stage
+    shapes, by tile (kept beside decode_walk): the tiles of
+    :func:`conv_walk_variants` and XLA's unfused composition, each
+    against that composition's numbers and the MXU's floor."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.observe import costmodel
+    from paddle_tpu.ops import kernels as K
+    from paddle_tpu.ops import pallas_conv as pc
+
+    peak = costmodel.detect_peaks()["flops"]
+    n, bf16 = S["batch"], jnp.bfloat16
+    rng = np.random.RandomState(S["seed"])
+    table = []
+
+    def composed(z, ci, w):
+        x = jnp.maximum(ci[0] * z.astype(jnp.float32) + ci[1], 0.0)
+        return pc._conv3x3(x.astype(w.dtype), w).astype(z.dtype)
+
+    for hw, c in S["shapes"]:
+        z = jnp.asarray(rng.randn(n, hw, hw, c) * 0.5, bf16)
+        dy = jnp.asarray(rng.randn(n, hw, hw, c), bf16)
+        w = jnp.asarray(rng.randn(3, 3, c, c) / math.sqrt(9 * c), bf16)
+        ci = jnp.zeros((8, c), jnp.float32).at[0].set(
+            jnp.asarray(rng.rand(c) + 0.5, jnp.float32)).at[1].set(
+            jnp.asarray(rng.randn(c) * 0.3, jnp.float32))
+        calls = {
+            K.CONV_BN_FWD: (
+                lambda z, ci, w: pc._fwd_call(z, ci, w, z.dtype, True),
+                composed, (z, ci)),
+            K.CONV_BN_FWD_BWD: (
+                lambda g, z, ci, w: pc._fwd_bwd_call(g, z, ci, w, True)[0],
+                lambda g, z, ci, w: jax.vjp(
+                    lambda z_: composed(z_, ci, w), z)[1](g)[0],
+                (dy, z, ci))}
+        for kernel, (fused, xla, args) in calls.items():
+            jax.eval_shape(fused, *args, w)
+            rule = conv_tile_of(kernel, hw, hw, c, c)
+            row = {"shape": f"{hw}x{hw}x{c}", "kernel": kernel, "batch": n,
+                   "rule": rule._asdict(),
+                   "floor_us": round(pc._conv_flops(n, hw, hw, c, c)
+                                     / peak * 1e6, 2),
+                   "us_a_call": {}, "rel_err": {}}
+            want = jax.jit(lambda *a: xla(*a, w))(*args)
+            row["us_a_call"]["xla"] = round(chained_call_us(
+                xla, S["layers"], S["reps"], *args, w), 2)
+            for name, t in conv_walk_variants(rule, hw, n).items():
+                with conv_tile_fixed(t):        # a new function: traced anew
+                    row["rel_err"][name] = round(_rel_err(
+                        jax.jit(lambda *a: fused(*a))(*args, w), want), 5)
+                    row["us_a_call"][name] = round(chained_call_us(
+                        fused, S["layers"], S["reps"], *args, w), 2)
+            table.append(row)
+            print(json.dumps(row), flush=True)
+            bad = {k: e for k, e in row["rel_err"].items()
+                   if not e <= REL_TOL}
+            check(not bad, f"{row['shape']} {kernel}: kernel != XLA's "
+                           f"composition beyond {REL_TOL} at {bad}")
+    return {"conv_walk": table}, None
+
+
 # ------------------------------------------------------ --all phases
 def _one_step(trainer, feed, ctx, name):
     losses, secs = train_steps(trainer, feed, 2)
@@ -1210,7 +1350,8 @@ DEFAULT_PHASES = (("lstm_cli", phase_lstm_cli),
                   ("kernels", phase_kernels))
 ALL_PHASES = (("resnet", phase_resnet), ("seq2seq", phase_seq2seq),
               ("lstm1280", phase_lstm1280), ("sparse", phase_sparse),
-              ("decode_walk", phase_decode_walk))
+              ("decode_walk", phase_decode_walk),
+              ("conv_walk", phase_conv_walk))
 
 
 class Ctx:
@@ -1248,8 +1389,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--all", action="store_true",
                     help="also one train step each of ResNet-50, seq2seq, "
-                         "LSTM hidden 1280 and a sparse CTR table, and the "
-                         "paged decode kernel's microseconds a call")
+                         "LSTM hidden 1280 and a sparse CTR table, the "
+                         "paged decode kernel's microseconds a call and "
+                         "the fused 3x3 conv+BN kernels' by tile")
     ap.add_argument("--fsdp", action="store_true",
                     help="transformer phase under FSDP with "
                          "zoo_fsdp_rules('transformer')")

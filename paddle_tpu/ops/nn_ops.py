@@ -515,7 +515,7 @@ def affine_act_conv2d(z, a, c, w, conv_bias=None, act: str = "relu",
     if is_training and fusable_act and pallas_conv.fusable_fwd(
             zs, ws, stride, padding, dilation, groups, data_format):
         _record_conv_dispatch("affine_act_conv2d", "pallas3x3")
-        # one image per grid step: batch-local (the dA/dC/dW sums over
+        # whole images a grid step: batch-local (the dA/dC/dW sums over
         # the batch come back through the replicated-input transpose)
         out = batch_local(
             lambda z_, a_, c_, w_: pallas_conv._affine_conv_core(

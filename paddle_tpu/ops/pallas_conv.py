@@ -46,26 +46,36 @@ chain variant (``_chain_core``) composes the forward prologue with the
 round-6 fused backward-data kernel so a BN→conv→BN sandwich runs both
 affines through one backward kernel pass.
 
-Kernel shape (all kernels): grid = (N,) with one image per step
-("arbitrary" semantics, pallas double-buffers the streaming blocks).
-The 3×3 stride-1 conv — forward or backward-data — is decomposed into
-9 shifted [H·W, Cin] @ [Cin, Cout] (resp. [H·W, Cout] @ [Cout, Cin])
-MXU matmuls over a zero-padded VMEM scratch tile — no halo exchange,
-no [T, T]-style intermediate, one HBM read of each operand.  For the
-backward-data direction the spatially-flipped, I/O-transposed weight
-``wT[a, b] = w[2−a, 2−b].T`` stays resident in VMEM (≤ 9.4 MB f32 at
-C=512, inside the 16 MB budget with the stage-4 7×7 tiles).
+**The 3×3 product (all kernels, :func:`_conv3x3_bands`).**  A grid step takes
+``nb`` images ("arbitrary" semantics; pallas double-buffers the image
+blocks).  Their operand — x, or the cotangent for backward-data — is
+staged once into a zero-padded VMEM copy whose rows have a pitch P of
+whole sublane tiles (the map's width rounded up), so tap (a, b) over a
+band of map rows is one slice of the copy that reshapes to
+``[nb·rows·P, C]`` without a relayout; the P − W columns past the map
+come out of the product as rows that are dropped.  The nine taps are
+multiplied in the dtype the weights arrive in (bfloat16 under the
+default policy) and accumulate in float32: as nine products of K = C,
+or, where C is narrower than the MXU, as one of K = 9·C (the nine
+shifted views side by side on the lanes).  :func:`_conv_tile` picks the
+form, ``nb`` and the band from the call's shapes so that a product
+streams about :data:`_DOT_ROWS` rows past each weight tile with K
+filling the MXU: several images a step where the map is small, bands of
+rows where it is large, taps stacked where the channels are narrow.  For
+the backward-data direction the spatially-flipped, I/O-transposed
+weight ``wT[a, b] = w[2−a, 2−b].T`` stays resident in VMEM.
 
-Shapes that don't tile (channels not a multiple of 64, VMEM overflow)
-dispatch to the plain ``conv2d`` + ``batch_norm`` composition in
-:mod:`paddle_tpu.ops.nn_ops` — same contract, same results.  On
-non-TPU backends the kernel runs in Pallas interpret mode so CPU tests
-exercise the exact dispatch used on hardware.
+Shapes that don't tile (channels not a multiple of 64, no tile inside
+the VMEM budget) dispatch to the plain ``conv2d`` + ``batch_norm``
+composition in :mod:`paddle_tpu.ops.nn_ops` — same contract, same
+results.  On non-TPU backends the kernel runs in Pallas interpret mode
+so CPU tests exercise the exact dispatch used on hardware.
 """
 
 from __future__ import annotations
 
 from functools import partial as _partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -75,27 +85,130 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.device import pallas_interpret
+from ..core.device import is_tpu, pallas_interpret
+from ..observe import gauge
 from . import kernels as K
 
-# VMEM budget for the gate: tiles + resident weights must fit under the
-# 16 MB scoped-vmem cap with headroom for double-buffering.
-_VMEM_BUDGET = 12 * 1024 * 1024
+
+def _vmem_limit() -> int:
+    """Scoped VMEM the kernels ask Mosaic for: half the chip's VMEM, at
+    most 64 MiB (as the routed experts' kernels ask on a v5e).  Where no
+    TPU is attached (the interpreter, a compile for a described chip) a
+    v5e's 128 MiB is assumed."""
+    cap = pltpu.get_tpu_info().vmem_capacity_bytes if is_tpu() \
+        else 128 << 20
+    return min(64 << 20, cap // 2)
+
+
+def _vmem_budget() -> int:
+    """What the tile rule lets a grid step hold: three quarters of
+    :func:`_vmem_limit`, leaving room for Mosaic's own temporaries."""
+    return _vmem_limit() * 3 // 4
+
+
+#: rows of the 3×3 product streamed past each weight tile: loading a
+#: 128×128 tile into the MXU costs about as much as streaming 128 rows
+#: through it, so a product of fewer rows mostly waits on its weights
+_DOT_ROWS = 512
+
+#: taps one product contracts, by contraction form
+_TAPS = {"c": 1, "9c": 9}
+
+
+class ConvTile(NamedTuple):
+    """How a fused 3×3 kernel takes its product (:func:`_conv_tile`)."""
+    nb: int      # images a grid step
+    k: str       # contraction form: "c" (9 products), "9c" (1)
+    rows: int    # map rows a product covers (a band)
+
+
+def _rup(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _sublanes(itemsize: int) -> int:
+    """Rows of one VMEM tile of that item size (8 f32, 16 bf16)."""
+    return 32 // itemsize
+
+
+def _pitch(w: int) -> int:
+    """Row pitch of the float32 padded copy: the map's width in whole
+    8-row tiles."""
+    return _rup(w, 8)
+
+
+def _lanes(c: int) -> int:
+    return _rup(c, 128)
+
+
+def _tile_bytes(t: ConvTile, h, w, ck, cn, isz, img) -> int:
+    """VMEM a grid step holds at tile ``t``: the image blocks (``img``
+    bytes an image over every streamed input and output as VMEM tiles
+    them, two buffers each), the weights (two buffers), the padded copy,
+    the float32 prologue, and one band's taps, their stacked operand and
+    its float32 product and epilogue."""
+    p = _pitch(w)
+    m = t.nb * t.rows * p
+    return (2 * t.nb * img
+            + 2 * 9 * ck * _lanes(cn) * isz
+            + t.nb * (h + 2) * (p + 8) * _lanes(ck) * 4
+            + t.nb * h * _rup(w, 8) * _lanes(ck) * 4
+            + m * ((9 + _TAPS[t.k]) * _lanes(ck) * isz
+                   + 2 * _lanes(cn) * 4))
+
+
+def _conv_tile(h: int, w: int, ck: int, cn: int, n: int, isz: int = 2,
+               img: int = 0):
+    """The tile of a fused 3×3 kernel over ``n`` maps of ``h × w``,
+    contracting ``ck`` channels into ``cn`` (Cin → Cout forward, Cout →
+    Cin backward-data), operands of ``isz`` bytes, ``img`` bytes an
+    image streamed (:func:`_tile_bytes`): the nine taps stacked into one
+    product where ``ck`` fills less than the MXU's 128 rows (at 128
+    channels a stack measured level with nine products on a v5e, at 64
+    a quarter faster forward, PERF.md §6); as many images a step (a
+    divisor of ``n``) as keep the product within :data:`_DOT_ROWS` rows
+    where one map is smaller, bands of rows of about that many where it
+    is larger.  Where that does not fit :func:`_vmem_budget`, fewer
+    images, narrower bands, then nine separate taps; None where nothing
+    fits."""
+    budget = _vmem_budget()
+    k = "9c" if ck < 128 else "c"
+    per_image = h * _pitch(w)
+    if per_image >= _DOT_ROWS:
+        nb, rows = 1, -(-h // -(-per_image // _DOT_ROWS))
+    else:
+        nb = max(d for d in range(1, _DOT_ROWS // per_image + 1)
+                 if n % d == 0)
+        rows = h
+    nbs = [d for d in range(nb, 0, -1) if n % d == 0]
+    bands = sorted({max(1, rows >> s) for s in range(rows.bit_length())},
+                   reverse=True)
+    for form in dict.fromkeys((k, "c")):
+        for b in nbs:
+            for r in bands:
+                t = ConvTile(b, form, r)
+                if _tile_bytes(t, h, w, ck, cn, isz, img) <= budget:
+                    return t
+    return None
+
+
+def _img32(h, w, *widths) -> int:
+    """Bytes an image over float32 blocks of these channel widths: what
+    the gates assume, float32 being the widest operand a caller passes."""
+    return h * _rup(w, 8) * sum(map(_lanes, widths)) * 4
 
 
 def fused_ok(h: int, w: int, cin: int, cout: int) -> bool:
     """Mosaic tiling gate, checked on every backend so interpret-mode
     tests exercise the hardware dispatch.  Channels must land on the
     128-lane minor dimension in at most two tiles (multiples of 64 —
-    covers ResNet-50's 3×3 family: 64/128/256/512); the per-image tile
-    set (dy, z, dz f32, padded-dz scratch, dx accumulator) plus the
-    resident flipped weight must fit the VMEM budget."""
+    covers ResNet-50's 3×3 family: 64/128/256/512); the backward-data
+    kernel (dy, z in; dx, dz out) must find a tile at float32 operands,
+    the widest any caller passes."""
     if cin % 64 or cout % 64 or h < 1 or w < 1:
         return False
-    f32 = 4
-    tile = h * w * (4 * cout + cin) * f32 \
-        + (h + 2) * (w + 2) * cout * f32
-    return tile + 9 * cout * cin * f32 <= _VMEM_BUDGET
+    return _conv_tile(h, w, cout, cin, 1, 4,
+                      _img32(h, w, cout, cout, cin, cout)) is not None
 
 
 def _pair(v):
@@ -140,18 +253,14 @@ def fusable(x_shape, w_shape, stride, padding, dilation, groups,
 def fused_fwd_ok(h: int, w: int, cin: int, cout: int) -> bool:
     """Mosaic tiling gate for the FORWARD fused conv (affine+ReLU input
     pipeline) and its backward twin — same 64-multiple channel rule as
-    :func:`fused_ok`; the VMEM estimate covers whichever of the two
-    kernels' tile sets is larger (fwd: z + padded-x scratch + out acc;
-    bwd: dy + padded-dy scratch + z/du/dz/x tiles + the dA/dC
-    accumulator block) plus the resident weights."""
+    :func:`fused_ok`; both kernels must find a tile at float32 operands
+    (forward: z in, y out; backward: dy, z in, dz, x out)."""
     if cin % 64 or cout % 64 or h < 1 or w < 1:
         return False
-    f32 = 4
-    fwd = h * w * (2 * cin + 2 * cout) * f32 \
-        + (h + 2) * (w + 2) * cin * f32
-    bwd = h * w * (4 * cin + 2 * cout) * f32 \
-        + (h + 2) * (w + 2) * cout * f32 + 8 * cin * f32
-    return max(fwd, bwd) + 9 * cin * cout * f32 <= _VMEM_BUDGET
+    return (_conv_tile(h, w, cin, cout, 1, 4,
+                       _img32(h, w, cin, cout)) is not None
+            and _conv_tile(h, w, cout, cin, 1, 4,
+                           _img32(h, w, cout, cin, cin, cin)) is not None)
 
 
 def fusable_fwd(z_shape, w_shape, stride, padding, dilation, groups,
@@ -169,14 +278,10 @@ def fusable_fwd(z_shape, w_shape, stride, padding, dilation, groups,
 def fused_chain_ok(h: int, w: int, cin: int, cout: int) -> bool:
     """VMEM gate for the chain kernel (forward affine prologue × round-6
     BN-backward affine in ONE backward-data pass): its backward streams
-    (dy, z2, z1) and writes (dz2, dz1, x1) with both affine blocks and
-    the padded-dz2 scratch resident."""
-    if not fused_fwd_ok(h, w, cin, cout):
-        return False
-    f32 = 4
-    tile = h * w * (4 * cin + 3 * cout) * f32 \
-        + (h + 2) * (w + 2) * cout * f32 + 8 * (cin + cout) * f32
-    return tile + 9 * cin * cout * f32 <= _VMEM_BUDGET
+    (dy, z2, z1) and writes (dz2, dz1, x1)."""
+    return fused_fwd_ok(h, w, cin, cout) and _conv_tile(
+        h, w, cout, cin, 1, 4,
+        _img32(h, w, cout, cout, cin, cout, cin, cin)) is not None
 
 
 def _conv3x3(x, w):
@@ -196,39 +301,125 @@ def _conv_flops(n, h, w, cin, cout) -> float:
     return 2.0 * n * h * w * 9 * cin * cout
 
 
-# ------------------------------------------------------------- dX kernel
-def _dx_kernel(g_ref, z_ref, co_ref, wt_ref, dx_ref, dz_ref, pad_s, *,
-               hh, ww):
-    """One image per grid step: form dz = A·dy + B·z + C in VMEM, write
-    it out for the filter-grad conv, then accumulate the 9 shifted
-    matmuls of the 3×3 backward-data conv from the zero-padded scratch.
-    All compute in f32 (the affine coefficients mix magnitudes; the MXU
-    accumulates f32 natively)."""
-    g = g_ref[0].astype(jnp.float32)                 # [H, W, Cout]
-    z = z_ref[0].astype(jnp.float32)
-    co = co_ref[...].astype(jnp.float32)             # [8, Cout]
-    dz = co[0] * g + co[1] * z + co[2]               # per-channel affine
-    dz_ref[0] = dz.astype(dz_ref.dtype)
+# ------------------------------------------------------- the 3×3 product
+def _conv3x3_bands(pad_s, x, w_ref, tile: ConvTile):
+    """Inside a kernel: stage ``x`` (``[nb, H, W, C]``, any float dtype)
+    into the zero-padded float32 copy ``pad_s`` and yield ``(i0, i1,
+    acc)`` for each band of map rows, ``acc`` the float32 3×3 product
+    over rows ``i0:i1``, ``[nb, i1 − i0, W, Cout]``.  ``w_ref`` holds the
+    weights as ``[9·C, Cout]`` (HWIO reshaped), in the operands' dtype:
+    each tap is cast to it as it is read.  (Staged in bfloat16 the copy
+    costs more than it saves: Mosaic shifts packed rows by one sublane
+    far more slowly than 32-bit ones, PERF.md §6.)"""
+    nb, hh, ww, c = x.shape
+    pitch = pad_s.shape[2] - 8
 
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _zero_borders():
-        # interior is overwritten every step; borders must read as the
-        # implicit SAME zero-padding and only need zeroing once
+        # the map is overwritten every step; the borders and the columns
+        # past the map read as the SAME zero padding, zeroed once
         pad_s[...] = jnp.zeros_like(pad_s)
 
-    pad_s[1:hh + 1, 1:ww + 1, :] = dz
-    wt = wt_ref[...].astype(jnp.float32)             # [3, 3, Cout, Cin]
-    cin = wt.shape[-1]
-    acc = jnp.zeros((hh * ww, cin), jnp.float32)
-    for a in range(3):
-        for b in range(3):
-            sl = pad_s[a:a + hh, b:b + ww, :].reshape(hh * ww, -1)
-            acc = acc + jax.lax.dot_general(
-                sl, wt[a, b], (((1,), (0,)), ((), ())),
+    pad_s[:, 1:hh + 1, 1:ww + 1, :] = x.astype(jnp.float32)
+    g = _TAPS[tile.k]
+    for i0 in range(0, hh, tile.rows):
+        i1 = min(i0 + tile.rows, hh)
+        m = nb * (i1 - i0) * pitch
+        tap = lambda t: pad_s[:, t // 3 + i0:t // 3 + i1,
+                              t % 3:t % 3 + pitch, :].reshape(m, c) \
+            .astype(w_ref.dtype)
+        acc = None
+        for j in range(0, 9, g):
+            lhs = tap(j) if g == 1 else jnp.concatenate(
+                [tap(t) for t in range(j, j + g)], 1)
+            part = jax.lax.dot_general(
+                lhs, w_ref[j * c:(j + g) * c, :], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-    dx_ref[0] = acc.reshape(hh, ww, cin).astype(dx_ref.dtype)
+            acc = part if acc is None else acc + part
+        yield i0, i1, acc.reshape(nb, i1 - i0, pitch, -1)[:, :, :ww, :]
+
+
+def _affine_bwd(t, z, ci, relu):
+    """The forward prologue x = act(A·z + C)'s backward on the
+    cotangent ``t`` wrt x, float32: (dz, the recomputed x, dA, dC)."""
+    z = z.astype(jnp.float32)
+    u = ci[0] * z + ci[1]
+    if relu:
+        du = jnp.where(u > 0, t, 0.0)
+        x = jnp.maximum(u, 0.0)
+    else:
+        du, x = t, u
+    return (ci[0] * du, x, jnp.sum(z * du, axis=(0, 1, 2)),
+            jnp.sum(du, axis=(0, 1, 2)))
+
+
+def _fused_call(kernel, name, tile, operands, out_shape):
+    """The ``pallas_call`` of a fused 3×3 kernel at ``tile``: 4-D
+    operands and outputs move ``tile.nb`` images a grid step, 2-D ones
+    (affine blocks, weights) stay resident.  The last operand is the
+    weights, ``[9·C, Cout]``: the padded copy holds C channels."""
+    n, h, w = operands[0].shape[:3]
+    ck = operands[-1].shape[0] // 9
+
+    def spec(shape):
+        if len(shape) == 4:
+            return pl.BlockSpec((tile.nb, h, w, shape[3]),
+                                lambda i: (i, 0, 0, 0))
+        return pl.BlockSpec(tuple(shape), lambda i: (0, 0))
+
+    return pl.pallas_call(
+        _partial(kernel, tile=tile),
+        grid=(n // tile.nb,),
+        in_specs=[spec(a.shape) for a in operands],
+        out_specs=[spec(s.shape) for s in out_shape],
+        out_shape=out_shape,
+        scratch_shapes=[                    # the padded copy
+            pltpu.VMEM((tile.nb, h + 2, _pitch(w) + 8, ck), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit()),
+        interpret=pallas_interpret(),
+        name=name,
+    )(*operands)
+
+
+def _tile_for(name, n, h, w, cin, cout, operands, out_shape):
+    """The rule's tile for the call's shapes, told to the
+    ``conv_bn_tile`` gauge.  The forward kernel contracts Cin, the
+    backward-data ones Cout."""
+    ck, cn = (cin, cout) if name == K.CONV_BN_FWD else (cout, cin)
+    img = sum(h * _rup(w, _sublanes(a.dtype.itemsize))
+              * _lanes(a.shape[3]) * a.dtype.itemsize
+              for a in (*operands, *out_shape) if len(a.shape) == 4)
+    tile = _conv_tile(h, w, ck, cn, n, operands[-1].dtype.itemsize, img)
+    gauge("conv_bn_tile",
+          "images a grid step of a fused 3x3 conv+BN kernel, by the "
+          "contraction form and band _conv_tile chose from the call's "
+          "shapes (trace-time)"
+          ).set(tile.nb, kernel=name, h=str(h), w=str(w), cin=str(cin),
+                cout=str(cout), k=tile.k, rows=str(tile.rows))
+    return tile
+
+
+def _flipped(w):
+    """Backward-data weights ``[9·Cout, Cin]``: the spatial flip and I/O
+    transpose of the forward HWIO weights."""
+    cin, cout = w.shape[2:]
+    return jnp.flip(w, (0, 1)).transpose(0, 1, 3, 2).reshape(9 * cout, cin)
+
+
+# ------------------------------------------------------------- dX kernel
+def _dx_kernel(g_ref, z_ref, co_ref, wt_ref, dx_ref, dz_ref, pad_s, *,
+               tile):
+    """Form dz = A·dy + B·z + C in VMEM (float32; the affine
+    coefficients mix magnitudes), write it out for the filter-grad conv,
+    then run the 3×3 backward-data product on it."""
+    co = co_ref[...]                                 # [8, Cout]
+    dz = (co[0] * g_ref[...].astype(jnp.float32)
+          + co[1] * z_ref[...].astype(jnp.float32) + co[2])
+    dz_ref[...] = dz.astype(dz_ref.dtype)
+    for i0, i1, acc in _conv3x3_bands(pad_s, dz, wt_ref, tile):
+        dx_ref[:, i0:i1] = acc.astype(dx_ref.dtype)
 
 
 def _dx_call(dy, z, coeffs, w, dx_dtype, dz_dtype):
@@ -237,39 +428,17 @@ def _dx_call(dy, z, coeffs, w, dx_dtype, dz_dtype):
     Returns (dx [N, H, W, Cin], dz [N, H, W, Cout])."""
     n, h, ww, cout = dy.shape
     cin = w.shape[2]
-    # backward-data kernel: spatial flip + I/O transpose of the forward
-    # weights (constant-folded outside the step loop by XLA)
-    wt = jnp.flip(w, (0, 1)).transpose(0, 1, 3, 2)   # [3, 3, Cout, Cin]
-    kernel = _partial(_dx_kernel, hh=h, ww=ww)
+    operands = (dy, z, coeffs, _flipped(w))
     out_shape = [
         jax.ShapeDtypeStruct((n, h, ww, cin), dx_dtype),
         jax.ShapeDtypeStruct((n, h, ww, cout), dz_dtype),
     ]
     # the op: one 3×3 backward-data conv
     K.record_kernel_work(K.CONV_BN_DX, _conv_flops(n, h, ww, cin, cout),
-                         (dy, z, coeffs, wt), out_shape)
-    return pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),  # dy
-            pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),  # z
-            pl.BlockSpec((8, cout), lambda i: (0, 0)),          # coeffs
-            pl.BlockSpec((3, 3, cout, cin), lambda i: (0, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # dx
-            pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),  # dz
-        ],
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dz
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=pallas_interpret(),
-        name=K.CONV_BN_DX,
-    )(dy, z, coeffs, wt)
+                         operands, out_shape)
+    tile = _tile_for(K.CONV_BN_DX, n, h, ww, cin, cout, operands,
+                     out_shape)
+    return _fused_call(_dx_kernel, K.CONV_BN_DX, tile, operands, out_shape)
 
 
 # ------------------------------------------------------------ custom vjp
@@ -347,37 +516,17 @@ def _pack_affine(a, c, n):
 
 
 # ------------------------------------------------------ forward kernel
-def _fwd_kernel(z_ref, ci_ref, w_ref, o_ref, pad_s, *, hh, ww, relu):
-    """One image per grid step: form x = act(A·z + C) in VMEM from the
-    upstream BN's folded per-channel affine, stage it into the
-    zero-padded scratch, and run the 3×3 stride-1 forward conv as 9
-    shifted [H·W, Cin] @ [Cin, Cout] MXU matmuls (weights resident) —
-    the normalized activation never exists in HBM."""
-    z = z_ref[0].astype(jnp.float32)                 # [H, W, Cin]
-    ci = ci_ref[...].astype(jnp.float32)             # [8, Cin]
-    x = ci[0] * z + ci[1]
+def _fwd_kernel(z_ref, ci_ref, w_ref, o_ref, pad_s, *, tile, relu):
+    """Form x = act(A·z + C) in VMEM from the upstream BN's folded
+    per-channel affine (float32) and run the 3×3 forward product on it
+    (weights resident) — the normalized activation never exists in
+    HBM."""
+    ci = ci_ref[...]                                 # [8, Cin]
+    x = ci[0] * z_ref[...].astype(jnp.float32) + ci[1]
     if relu:
         x = jnp.maximum(x, 0.0)
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _zero_borders():
-        # interior is overwritten every step; borders must read as the
-        # implicit SAME zero-padding and only need zeroing once
-        pad_s[...] = jnp.zeros_like(pad_s)
-
-    pad_s[1:hh + 1, 1:ww + 1, :] = x
-    w = w_ref[...].astype(jnp.float32)               # [3, 3, Cin, Cout]
-    cout = w.shape[-1]
-    acc = jnp.zeros((hh * ww, cout), jnp.float32)
-    for a in range(3):
-        for b in range(3):
-            sl = pad_s[a:a + hh, b:b + ww, :].reshape(hh * ww, -1)
-            acc = acc + jax.lax.dot_general(
-                sl, w[a, b], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-    o_ref[0] = acc.reshape(hh, ww, cout).astype(o_ref.dtype)
+    for i0, i1, acc in _conv3x3_bands(pad_s, x, w_ref, tile):
+        o_ref[:, i0:i1] = acc.astype(o_ref.dtype)
 
 
 def _fwd_call(z, ci, w, out_dtype, relu):
@@ -385,72 +534,38 @@ def _fwd_call(z, ci, w, out_dtype, relu):
     Cout] HWIO forward weights.  Returns conv(act(A·z+C), w)."""
     n, h, ww, cin = z.shape
     cout = w.shape[3]
-    kernel = _partial(_fwd_kernel, hh=h, ww=ww, relu=relu)
-    out_shape = jax.ShapeDtypeStruct((n, h, ww, cout), out_dtype)
+    operands = (z, ci, w.reshape(9 * cin, cout))
+    out_shape = [jax.ShapeDtypeStruct((n, h, ww, cout), out_dtype)]
     # the op: one 3×3 forward conv
     K.record_kernel_work(K.CONV_BN_FWD, _conv_flops(n, h, ww, cin, cout),
-                         (z, ci, w), (out_shape,))
-    return pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # z
-            pl.BlockSpec((8, cin), lambda i: (0, 0)),            # affine
-            pl.BlockSpec((3, 3, cin, cout), lambda i: (0, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((h + 2, ww + 2, cin), jnp.float32),   # padded x
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=pallas_interpret(),
-        name=K.CONV_BN_FWD,
-    )(z, ci, w)
+                         operands, out_shape)
+    tile = _tile_for(K.CONV_BN_FWD, n, h, ww, cin, cout, operands,
+                     out_shape)
+    return _fused_call(_partial(_fwd_kernel, relu=relu), K.CONV_BN_FWD,
+                       tile, operands, out_shape)[0]
 
 
 # ----------------------------------------------------- forward backward
 def _fwd_bwd_kernel(g_ref, z_ref, ci_ref, wt_ref, dz_ref, x_ref, dac_ref,
-                    pad_s, *, hh, ww, relu):
+                    pad_s, *, tile, relu):
     """Backward of the affine(+ReLU)→conv forward: the 3×3 backward-data
-    matmuls over the zero-padded cotangent (flipped weights), then the
-    prologue's backward applied on-chip — du = mask·t, dz = A·du — while
+    product over the cotangent (flipped weights), then the prologue's
+    backward applied on-chip — du = mask·t, dz = A·du — while
     x = act(A·z + C) is RECOMPUTED from the raw residual z and written
     once for the XLA filter-grad conv.  dA/dC accumulate across the
     sequential grid directly in their constant-block output ref (the
     pallas_lstm dW idiom)."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        pad_s[...] = jnp.zeros_like(pad_s)
         dac_ref[...] = jnp.zeros_like(dac_ref)
 
-    g = g_ref[0].astype(jnp.float32)                 # [H, W, Cout]
-    pad_s[1:hh + 1, 1:ww + 1, :] = g
-    wt = wt_ref[...].astype(jnp.float32)             # [3, 3, Cout, Cin]
-    cin = wt.shape[-1]
-    acc = jnp.zeros((hh * ww, cin), jnp.float32)
-    for a in range(3):
-        for b in range(3):
-            sl = pad_s[a:a + hh, b:b + ww, :].reshape(hh * ww, -1)
-            acc = acc + jax.lax.dot_general(
-                sl, wt[a, b], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-    t = acc.reshape(hh, ww, cin)                     # cotangent wrt x
-    z = z_ref[0].astype(jnp.float32)
-    ci = ci_ref[...].astype(jnp.float32)
-    u = ci[0] * z + ci[1]
-    if relu:
-        du = jnp.where(u > 0, t, 0.0)
-        x = jnp.maximum(u, 0.0)
-    else:
-        du, x = t, u
-    dz_ref[0] = (ci[0] * du).astype(dz_ref.dtype)
-    x_ref[0] = x.astype(x_ref.dtype)
-    dac_ref[0] = dac_ref[0] + jnp.sum(z * du, axis=(0, 1))
-    dac_ref[1] = dac_ref[1] + jnp.sum(du, axis=(0, 1))
+    ci = ci_ref[...]
+    for i0, i1, t in _conv3x3_bands(pad_s, g_ref[...], wt_ref, tile):
+        dz, x, da, dc = _affine_bwd(t, z_ref[:, i0:i1], ci, relu)
+        dz_ref[:, i0:i1] = dz.astype(dz_ref.dtype)
+        x_ref[:, i0:i1] = x.astype(x_ref.dtype)
+        dac_ref[0] = dac_ref[0] + da
+        dac_ref[1] = dac_ref[1] + dc
 
 
 def _fwd_bwd_call(dy, z, ci, w, relu):
@@ -459,8 +574,7 @@ def _fwd_bwd_call(dy, z, ci, w, relu):
     Returns (dz, x, dac[8, Cin] with rows dA/dC)."""
     n, h, ww, cout = dy.shape
     cin = w.shape[2]
-    wt = jnp.flip(w, (0, 1)).transpose(0, 1, 3, 2)   # [3, 3, Cout, Cin]
-    kernel = _partial(_fwd_bwd_kernel, hh=h, ww=ww, relu=relu)
+    operands = (dy, z, ci, _flipped(w))
     out_shape = [
         jax.ShapeDtypeStruct((n, h, ww, cin), z.dtype),
         jax.ShapeDtypeStruct((n, h, ww, cin), z.dtype),
@@ -469,30 +583,11 @@ def _fwd_bwd_call(dy, z, ci, w, relu):
     # the op: one 3×3 backward-data conv (x is recomputed, not work)
     K.record_kernel_work(K.CONV_BN_FWD_BWD,
                          _conv_flops(n, h, ww, cin, cout),
-                         (dy, z, ci, wt), out_shape)
-    return pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),  # dy
-            pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # z
-            pl.BlockSpec((8, cin), lambda i: (0, 0)),            # affine
-            pl.BlockSpec((3, 3, cout, cin), lambda i: (0, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # dz
-            pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # x
-            pl.BlockSpec((8, cin), lambda i: (0, 0)),             # dA/dC
-        ],
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dy
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=pallas_interpret(),
-        name=K.CONV_BN_FWD_BWD,
-    )(dy, z, ci, wt)
+                         operands, out_shape)
+    tile = _tile_for(K.CONV_BN_FWD_BWD, n, h, ww, cin, cout, operands,
+                     out_shape)
+    return _fused_call(_partial(_fwd_bwd_kernel, relu=relu),
+                       K.CONV_BN_FWD_BWD, tile, operands, out_shape)
 
 
 # --------------------------------------------- standalone forward core
@@ -530,50 +625,29 @@ _affine_conv_core.defvjp(_affine_core_fwd, _affine_core_bwd)
 # ------------------------------------------------- chain backward kernel
 def _chain_bwd_kernel(g_ref, z2_ref, co_ref, z1_ref, ci_ref, wt_ref,
                       dz2_ref, dz1_ref, x1_ref, dac_ref, pad_s, *,
-                      hh, ww, relu):
+                      tile, relu):
     """BOTH affines in one backward-data pass (the composed fwd-fusion ×
     round-6 path): form dz2 = A₂·dy + B₂·z2 + C₂ on-chip (the BN2
-    backward, exactly the round-6 input pipeline), run the 9 shifted
-    backward-data matmuls on it, then apply the forward prologue's
+    backward, exactly the round-6 input pipeline), run the 3×3
+    backward-data product on it, then apply the forward prologue's
     backward on the result — du = mask·t, dz1 = A₁·du — recomputing
     x1 = act(A₁·z1 + C₁) for the filter grad, with dA₁/dC₁ accumulating
     in their constant-block output ref."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        pad_s[...] = jnp.zeros_like(pad_s)
         dac_ref[...] = jnp.zeros_like(dac_ref)
 
-    g = g_ref[0].astype(jnp.float32)                 # [H, W, Cout]
-    z2 = z2_ref[0].astype(jnp.float32)
-    co = co_ref[...].astype(jnp.float32)             # [8, Cout]
-    dz2 = co[0] * g + co[1] * z2 + co[2]             # BN2 backward affine
-    dz2_ref[0] = dz2.astype(dz2_ref.dtype)
-
-    pad_s[1:hh + 1, 1:ww + 1, :] = dz2
-    wt = wt_ref[...].astype(jnp.float32)             # [3, 3, Cout, Cin]
-    cin = wt.shape[-1]
-    acc = jnp.zeros((hh * ww, cin), jnp.float32)
-    for a in range(3):
-        for b in range(3):
-            sl = pad_s[a:a + hh, b:b + ww, :].reshape(hh * ww, -1)
-            acc = acc + jax.lax.dot_general(
-                sl, wt[a, b], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-    t = acc.reshape(hh, ww, cin)                     # cotangent wrt x1
-    z1 = z1_ref[0].astype(jnp.float32)
-    ci = ci_ref[...].astype(jnp.float32)             # [8, Cin]
-    u = ci[0] * z1 + ci[1]
-    if relu:
-        du = jnp.where(u > 0, t, 0.0)
-        x1 = jnp.maximum(u, 0.0)
-    else:
-        du, x1 = t, u
-    dz1_ref[0] = (ci[0] * du).astype(dz1_ref.dtype)
-    x1_ref[0] = x1.astype(x1_ref.dtype)
-    dac_ref[0] = dac_ref[0] + jnp.sum(z1 * du, axis=(0, 1))
-    dac_ref[1] = dac_ref[1] + jnp.sum(du, axis=(0, 1))
+    co = co_ref[...]                                 # [8, Cout]
+    dz2 = (co[0] * g_ref[...].astype(jnp.float32)
+           + co[1] * z2_ref[...].astype(jnp.float32) + co[2])
+    dz2_ref[...] = dz2.astype(dz2_ref.dtype)
+    ci = ci_ref[...]                                 # [8, Cin]
+    for i0, i1, t in _conv3x3_bands(pad_s, dz2, wt_ref, tile):
+        dz1, x1, da, dc = _affine_bwd(t, z1_ref[:, i0:i1], ci, relu)
+        dz1_ref[:, i0:i1] = dz1.astype(dz1_ref.dtype)
+        x1_ref[:, i0:i1] = x1.astype(x1_ref.dtype)
+        dac_ref[0] = dac_ref[0] + da
+        dac_ref[1] = dac_ref[1] + dc
 
 
 def _chain_bwd_call(dy, z2, co, z1, ci, w, relu):
@@ -582,8 +656,7 @@ def _chain_bwd_call(dy, z2, co, z1, ci, w, relu):
     dac rows = dA₁/dC₁."""
     n, h, ww, cout = dy.shape
     cin = w.shape[2]
-    wt = jnp.flip(w, (0, 1)).transpose(0, 1, 3, 2)
-    kernel = _partial(_chain_bwd_kernel, hh=h, ww=ww, relu=relu)
+    operands = (dy, z2, co, z1, ci, _flipped(w))
     out_shape = [
         jax.ShapeDtypeStruct((n, h, ww, cout), z2.dtype),
         jax.ShapeDtypeStruct((n, h, ww, cin), z1.dtype),
@@ -593,33 +666,11 @@ def _chain_bwd_call(dy, z2, co, z1, ci, w, relu):
     # the op: one 3×3 backward-data conv between two BN affines
     K.record_kernel_work(K.CONV_BN_CHAIN_BWD,
                          _conv_flops(n, h, ww, cin, cout),
-                         (dy, z2, co, z1, ci, wt), out_shape)
-    return pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),  # dy
-            pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),  # z2
-            pl.BlockSpec((8, cout), lambda i: (0, 0)),             # BN2
-            pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # z1
-            pl.BlockSpec((8, cin), lambda i: (0, 0)),          # prologue
-            pl.BlockSpec((3, 3, cout, cin), lambda i: (0, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, ww, cout), lambda i: (i, 0, 0, 0)),  # dz2
-            pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # dz1
-            pl.BlockSpec((1, h, ww, cin), lambda i: (i, 0, 0, 0)),   # x1
-            pl.BlockSpec((8, cin), lambda i: (0, 0)),             # dA/dC
-        ],
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((h + 2, ww + 2, cout), jnp.float32),  # padded dz2
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=pallas_interpret(),
-        name=K.CONV_BN_CHAIN_BWD,
-    )(dy, z2, co, z1, ci, wt)
+                         operands, out_shape)
+    tile = _tile_for(K.CONV_BN_CHAIN_BWD, n, h, ww, cin, cout, operands,
+                     out_shape)
+    return _fused_call(_partial(_chain_bwd_kernel, relu=relu),
+                       K.CONV_BN_CHAIN_BWD, tile, operands, out_shape)
 
 
 # ------------------------------------------------------------ chain core

@@ -174,3 +174,54 @@ def test_chain_op_level_matches_composition(rng):
             else dict(rtol=3e-4, atol=3e-4)
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    err_msg=name, **tol)
+
+
+#: bfloat16 operands against the float32 composition on the same values:
+#: a few roundings of 2^-9, the worst element within 2 % of the largest
+#: (tests/test_pallas_conv.py::BF16_TOL says why)
+BF16_TOL = 2e-2
+
+
+@pytest.mark.parametrize("shape", [
+    (2, 5, 7, 64, 64),      # 64 channels: nine taps a product
+    (2, 4, 4, 128, 128),    # 128: nine products
+    (3, 7, 7, 128, 64),     # a 7×7 map, Cin ≠ Cout
+])
+def test_chain_kernel_in_bf16_matches_the_composition(rng, shape):
+    """conv_bn_fwd then conv_bn_chain_bwd (``_chain_core``) on bfloat16
+    z1 and w: the value and the gradients wrt z1, a1, c1, w, scale and
+    bias.  The conv bias's gradient is analytically 0 (compared at
+    float32 above)."""
+    n, h, w, cin, cout = shape
+    z = jnp.asarray(rng.randn(n, h, w, cin).astype(np.float32)) * 0.5
+    a = jnp.asarray(rng.rand(cin).astype(np.float32) + 0.5)
+    c = jnp.asarray(rng.randn(cin).astype(np.float32)) * 0.3
+    wt = jnp.asarray(rng.randn(3, 3, cin, cout).astype(np.float32)) * 0.1
+    cb = jnp.asarray(rng.randn(cout).astype(np.float32)) * 0.1
+    scale = jnp.asarray(rng.rand(cout).astype(np.float32) + 0.5)
+    bias = jnp.asarray(rng.randn(cout).astype(np.float32)) * 0.2
+    zb, wb = z.astype(jnp.bfloat16), wt.astype(jnp.bfloat16)
+    cot = jnp.asarray(rng.randn(n, h, w, cout).astype(np.float32))
+
+    def fused(z, a, c, w, s, b):
+        y, _m, _v = pallas_conv._chain_core(z, a, c, w, cb, s, b, EPS, True)
+        return y.astype(jnp.float32)
+
+    def comp(z, a, c, w, s, b):
+        x = jax.nn.relu(z.astype(jnp.float32) * a + c)
+        z2 = nn_ops.conv2d(x, w.astype(jnp.float32), stride=1,
+                           padding=1) + cb
+        m = jnp.mean(z2, (0, 1, 2))
+        v = jnp.mean(jnp.square(z2), (0, 1, 2)) - m * m
+        return (z2 - m) * jax.lax.rsqrt(v + EPS) * s + b
+
+    res = []
+    for fn in (fused, comp):
+        y, vjp = jax.vjp(fn, zb, a, c, wb, scale, bias)
+        res.append((y, *vjp(cot)))
+    for name, g, r in zip(["y", "dz1", "da1", "dc1", "dw", "dscale",
+                           "dbias"], *res):
+        got = np.asarray(jnp.asarray(g, jnp.float32))
+        want = np.asarray(jnp.asarray(r, jnp.float32))
+        err = np.max(np.abs(got - want)) / np.max(np.abs(want))
+        assert err <= BF16_TOL, (name, err)

@@ -685,6 +685,39 @@ def test_the_scan_kernel_at_the_jamba_cells_widths_compiles(one_v5e,
     assert "tpu_custom_call" in text and "ssm_scan" in text
 
 
+@pytest.mark.parametrize("hw,ch", [(56, 64), (28, 128), (14, 256),
+                                   (7, 512)])
+def test_the_fused_conv_kernels_at_resnet50s_stage_shapes_compile(
+        hw, ch, one_v5e, monkeypatch):
+    """``ops/pallas_conv.py``'s ``conv_bn_fwd`` and ``conv_bn_fwd_bwd``
+    at a batch of 128 at each of ResNet-50's four 3×3 stage shapes, in
+    the tile ``_conv_tile`` picks there, compiled for a v5e: Mosaic
+    takes the padded copy's shifted slices, the taps stacked on the
+    lanes, bands of rows and several images a step.  This file keeps the
+    repository's compiles for a described chip in one place."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from paddle_tpu.ops import pallas_conv
+
+    monkeypatch.setattr(pallas_conv, "pallas_interpret", lambda: False)
+    img = jax.ShapeDtypeStruct((128, hw, hw, ch), jnp.bfloat16,
+                               sharding=one_v5e)
+    w = jax.ShapeDtypeStruct((3, 3, ch, ch), jnp.bfloat16, sharding=one_v5e)
+    ci = jax.ShapeDtypeStruct((8, ch), jnp.float32, sharding=one_v5e)
+    calls = [(lambda z, ci, w: pallas_conv._fwd_call(
+                  z, ci, w, jnp.bfloat16, True), (img, ci, w)),
+             (lambda g, z, ci, w: pallas_conv._fwd_bwd_call(
+                  g, z, ci, w, True), (img, img, ci, w))]
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        texts = [jax.jit(f).lower(*a).compile().as_text() for f, a in calls]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    for text, name in zip(texts, ("conv_bn_fwd", "conv_bn_fwd_bwd")):
+        assert "tpu_custom_call" in text and name in text
+
+
 @pytest.mark.parametrize("kind", ["base", "gqa"])
 @pytest.mark.parametrize("t_q", [1, 4])
 def test_paged_decode_lane_dense_pool_is_the_same_pool(t_q, kind, rng):

@@ -111,55 +111,32 @@ def test_fused_forward_and_stats_match_reference(rng, shape):
         _assert_close(g, r)
 
 
-def test_fused_gradients_match_reference(rng):
-    n, h, w, cin, cout = 2, 5, 7, 64, 64
-    x, wt, cb, scale, bias, rm, rv = _inputs(rng, n, h, w, cin, cout)
+@pytest.mark.parametrize("with_cb", [True, False])
+def test_fused_gradients_match_reference(rng, with_cb):
+    n, h, w, cin, cout = (2, 5, 7, 64, 64) if with_cb else (1, 4, 6, 64, 64)
+    x, wt, cb, scale, bias, rm, rv = _inputs(rng, n, h, w, cin, cout,
+                                             with_cb=with_cb)
     cot = jnp.asarray(rng.randn(n, h, w, cout).astype(np.float32))
 
-    def loss_fused(x, wt, cb, scale, bias):
-        y, _, _ = nn_ops.conv2d_bn(x, wt, cb, scale, bias, rm, rv,
-                                   eps=EPS, is_training=True, padding=1)
+    def loss(fn, x, wt, scale, bias, cb=None):
+        y, _, _ = fn(x, wt, cb, scale, bias, rm, rv)
         return jnp.sum(y * cot)
 
-    def loss_ref(x, wt, cb, scale, bias):
-        y, _, _ = _reference(x, wt, cb, scale, bias, rm, rv)
-        return jnp.sum(y * cot)
-
-    args = (x, wt, cb, scale, bias)
-    g_fused = jax.grad(loss_fused, argnums=(0, 1, 2, 3, 4))(*args)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2, 3, 4))(*args)
+    fused = lambda *a: nn_ops.conv2d_bn(*a, eps=EPS, is_training=True,
+                                        padding=1)
+    args = (x, wt, scale, bias) + ((cb,) if with_cb else ())
+    argnums = tuple(range(len(args)))
+    g_fused = jax.grad(lambda *a: loss(fused, *a), argnums=argnums)(*args)
+    g_ref = jax.grad(lambda *a: loss(_reference, *a), argnums=argnums)(*args)
     # conv bias pre-BN is analytically gradient-free (BN subtracts the
     # mean), so both sides are f32 noise around 0 — compare by atol
     # scaled to the other gradients' magnitude
-    names = ["dx", "dw", "dconv_bias", "dscale", "dbias"]
+    names = ["dx", "dw", "dscale", "dbias", "dconv_bias"]
     for name, gf, gr in zip(names, g_fused, g_ref):
         tol = dict(rtol=3e-4, atol=1e-3) if name == "dconv_bias" \
             else dict(rtol=3e-4, atol=3e-5)
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    err_msg=name, **tol)
-
-
-def test_fused_gradients_no_conv_bias(rng):
-    n, h, w, cin, cout = 1, 4, 6, 64, 64
-    x, wt, _, scale, bias, rm, rv = _inputs(rng, n, h, w, cin, cout,
-                                            with_cb=False)
-    cot = jnp.asarray(rng.randn(n, h, w, cout).astype(np.float32))
-
-    def loss(fn, x, wt, scale, bias):
-        y, _, _ = fn(x, wt, None, scale, bias, rm, rv)
-        return jnp.sum(y * cot)
-
-    fused = lambda *a: nn_ops.conv2d_bn(*a, eps=EPS, is_training=True,
-                                        padding=1)
-    ref = lambda *a: _reference(*a)
-    argnums = (0, 1, 2, 3)
-    g_fused = jax.grad(lambda *a: loss(fused, *a), argnums=argnums)(
-        x, wt, scale, bias)
-    g_ref = jax.grad(lambda *a: loss(ref, *a), argnums=argnums)(
-        x, wt, scale, bias)
-    for gf, gr in zip(g_fused, g_ref):
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                   rtol=3e-4, atol=3e-5)
 
 
 # ------------------------------------------------- fallback equivalence
@@ -637,3 +614,147 @@ def test_fwd_peephole_network_matches_unfused(rng):
     v1, _ = run(params, True, training=False)
     v0, _ = run(params, False, training=False)
     _assert_close(v1["c2"], v0["c2"], rtol=1e-6, atol=1e-6)
+
+
+# ============================================ bfloat16 operands, by tile
+#: The kernels multiply in the weights' dtype and accumulate in float32;
+#: the composition they are held to here runs in float32 on the same
+#: bfloat16 values.  bfloat16 keeps 8 significant bits (a rounding is
+#: 2^-9 of a value) and the fused path rounds x = act(A·z + C), the
+#: cotangent's affine and its outputs where the float32 one does not, so
+#: they agree to a few roundings: the worst element within 2 % of the
+#: largest (a missing tap or a shifted column is off by 10–100 %).
+BF16_TOL = 2e-2
+
+BF16_SHAPES = [
+    (2, 5, 7, 64, 64),      # 64 channels: nine taps a product
+    (2, 4, 4, 128, 128),    # 128: nine products
+    (3, 7, 7, 64, 128),     # a 7×7 map, Cin ≠ Cout
+]
+
+
+def _worst(got, want):
+    got = np.asarray(jnp.asarray(got, jnp.float32))
+    want = np.asarray(jnp.asarray(want, jnp.float32))
+    assert np.all(np.isfinite(got))
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+def _f32(*arrays):
+    return [a.astype(jnp.float32) for a in arrays]
+
+
+def _fwd_pair_grads(rng, n, h, w, cin, cout):
+    """conv_bn_fwd and conv_bn_fwd_bwd (through ``_affine_conv_core``)
+    and the float32 composition on the same bfloat16 z and w: the value
+    and the gradients wrt z, a, c, w."""
+    z, a, c, wt = _fwd_inputs(rng, n, h, w, cin, cout)
+    zb, wb = z.astype(jnp.bfloat16), wt.astype(jnp.bfloat16)
+    cot = jnp.asarray(rng.randn(n, h, w, cout).astype(np.float32))
+    fused = lambda z, a, c, w: pallas_conv._affine_conv_core(
+        z, a, c, w, True).astype(jnp.float32)
+    comp = lambda z, a, c, w: _fwd_reference(*_f32(z, a, c, w))
+    out = []
+    for fn in (fused, comp):
+        y, vjp = jax.vjp(fn, zb, a, c, wb)
+        out.append((y, *vjp(cot)))
+    return out
+
+
+@pytest.mark.parametrize("shape", BF16_SHAPES)
+def test_fwd_kernels_in_bf16_match_the_composition(rng, shape):
+    got, want = _fwd_pair_grads(rng, *shape)
+    for name, g, r in zip(["y", "dz", "da", "dc", "dw"], got, want):
+        assert _worst(g, r) <= BF16_TOL, name
+
+
+@pytest.mark.parametrize("shape", BF16_SHAPES)
+def test_dx_kernel_in_bf16_matches_the_composition(rng, shape):
+    """conv_bn_dx (``_conv_bn_core``'s backward) on bfloat16 x and w
+    against the float32 conv + batch norm on the same values.  The conv
+    bias's gradient is analytically 0 and compared at float32 above."""
+    n, h, w, cin, cout = shape
+    x, wt, cb, scale, bias, rm, rv = _inputs(rng, n, h, w, cin, cout)
+    xb, wb = x.astype(jnp.bfloat16), wt.astype(jnp.bfloat16)
+    cot = jnp.asarray(rng.randn(n, h, w, cout).astype(np.float32))
+    fused = lambda x, w, s, b: pallas_conv._conv_bn_core(
+        x, w, cb, s, b, EPS).astype(jnp.float32)
+    comp = lambda x, w, s, b: _reference(*_f32(x, w), cb, s, b, rm, rv)[0]
+    res = []
+    for fn in (fused, comp):
+        y, vjp = jax.vjp(fn, xb, wb, scale, bias)
+        res.append((y, *vjp(cot)))
+    for name, g, r in zip(["y", "dx", "dw", "dscale", "dbias"], *res):
+        assert _worst(g, r) <= BF16_TOL, name
+
+
+def _tile_told(kernel, h, w, cin, cout):
+    """(nb, k, rows) the last call at these shapes told ``conv_bn_tile``."""
+    from paddle_tpu import observe
+
+    for smp in observe.REGISTRY.find("conv_bn_tile").samples():
+        lab = smp["labels"]
+        if (lab["kernel"], lab["h"], lab["w"], lab["cin"], lab["cout"]) \
+                == (kernel, str(h), str(w), str(cin), str(cout)):
+            return int(smp["value"]), lab["k"], int(lab["rows"])
+    raise AssertionError(f"no conv_bn_tile for {kernel}")
+
+
+@pytest.mark.parametrize("n,dot_rows,tile", [
+    (6, 192, (3, "9c", 8)),    # 3 images a step of 6: 192 rows a product
+    (7, 192, (1, "9c", 8)),    # 7 has no divisor between 1 and 3
+    (2, 32, (1, "9c", 4)),     # a map over the rows: two bands of 4
+])
+def test_images_a_step_and_bands_match_the_composition(
+        rng, monkeypatch, n, dot_rows, tile):
+    """The rule's images a grid step (dA/dC summed over steps of several
+    images) and bands of map rows, at an 8×8 map of 64 channels (64 rows
+    an image at a pitch of 8), the rule's row target scaled down to it."""
+    monkeypatch.setattr(pallas_conv, "_DOT_ROWS", dot_rows)
+    got, want = _fwd_pair_grads(rng, n, 8, 8, 64, 64)
+    for name, g, r in zip(["y", "dz", "da", "dc", "dw"], got, want):
+        assert _worst(g, r) <= BF16_TOL, name
+    from paddle_tpu.ops import kernels as K
+
+    for kernel in (K.CONV_BN_FWD, K.CONV_BN_FWD_BWD):
+        assert _tile_told(kernel, 8, 8, 64, 64) == tile, kernel
+
+
+#: ResNet-50's four 3×3 stage shapes at the cell's batch, bfloat16: the
+#: tile each fused kernel takes (forward: z in, y out; backward-data:
+#: dy, z in, dz, x out), by ``_conv_tile``
+STAGE_TILES = {
+    (56, 64): (1, "9c", 8),     # 8 rows of 56 (at a pitch of 56): 448
+    (28, 128): (1, "c", 14),    # two bands of 14 rows of 32: 448
+    (14, 256): (2, "c", 14),    # two images of 14 × 16: 448
+    (7, 512): (8, "c", 7),      # eight images of 7 × 8: 448
+}
+
+
+@pytest.mark.parametrize("hw,ch", sorted(STAGE_TILES))
+def test_the_tile_rule_at_resnet50s_stage_shapes(hw, ch):
+    img = hw * pallas_conv._rup(hw, 16) * pallas_conv._lanes(ch) * 2
+    for streams in (2, 4):          # forward, backward-data
+        t = pallas_conv._conv_tile(hw, hw, ch, ch, 128, 2, streams * img)
+        assert (t.nb, t.k, t.rows) == STAGE_TILES[hw, ch], streams
+
+
+def test_the_tile_rule_keeps_to_a_smaller_chips_vmem(monkeypatch):
+    """On a chip of 32 MiB of VMEM the kernels ask Mosaic for 16 MiB and
+    a grid step holds at most 12: a 64-channel kernel in bfloat16 takes
+    bands of half the rows it takes on a v5e; the gate, which asks for a
+    tile at float32 operands, passes the 128-channel pair and sends the
+    512-channel one, whose weights alone take 9.4 MB in two buffers, to
+    the unfused composition."""
+    class Info:
+        vmem_capacity_bytes = 32 << 20
+
+    monkeypatch.setattr(pallas_conv, "is_tpu", lambda: True)
+    monkeypatch.setattr(pallas_conv.pltpu, "get_tpu_info", lambda: Info)
+    assert pallas_conv._vmem_limit() == 16 << 20
+    img = 4 * 56 * 64 * 128 * 2         # backward-data: four streams
+    t = pallas_conv._conv_tile(56, 56, 64, 64, 128, 2, img)
+    assert (t.nb, t.k, t.rows) == (1, "9c", 4)
+    assert pallas_conv._tile_bytes(t, 56, 56, 64, 64, 2, img) <= 12 << 20
+    assert pallas_conv.fused_fwd_ok(28, 28, 128, 128)
+    assert not pallas_conv.fused_fwd_ok(7, 7, 512, 512)
