@@ -54,6 +54,9 @@ FLASH_BWD_DKV_GRID = "flash_bwd_dkv_grid"
 #: kernel by the ``_lambda_`` of ``serving/model.py``'s jit (see the
 #: call site)
 PAGED_DECODE = "paged_decode"
+#: its block mode: the queries of a diffusion step's block see the whole
+#: block and every position before it
+BLOCK_DECODE = "block_decode"
 #: decode over a latent cache: one compressed row a token serves every
 #: query head as its key and, in its leading lanes, as its value
 LATENT_DECODE = "latent_decode"

@@ -108,6 +108,15 @@ def _choose_block(t: int, want: int) -> int:
 
 
 # --------------------------------------------------- shared mask helpers
+def _sees_up_to(qi, causal_block: int):
+    """The newest position query ``qi`` sees under a causal mask: itself
+    (``causal_block`` 0 or 1), or the last position of its block of
+    ``causal_block`` positions (a power of two; blocks counted from
+    position 0), so the queries of a block see the whole block and every
+    block before it.  Python ints and traced values alike."""
+    return qi | (causal_block - 1) if causal_block > 1 else qi
+
+
 def _causal_block_live(q_off, k_off, block_q):
     """Block-level causal liveness: the (q, k) tile contains at least
     one pair on or below the diagonal.  THE shared predicate — the
@@ -125,34 +134,37 @@ def _window_block_live(q_off, k_off, block_k, window):
     return k_off + block_k - 1 > q_off - window
 
 
-def _block_interior(q_off, k_off, block_q, block_k, causal, window):
+def _block_interior(q_off, k_off, block_q, block_k, causal, window,
+                    causal_block=0):
     """Block-level: EVERY (query, key) of the tile lies on or under the
-    diagonal and, where there is a window, inside it, so neither
-    compare of :func:`_tile_mask` can hide an element (python ints at
-    table build).  What only the data shows (key padding, segments) the
-    kernel adds from scalars: see ``_fa_pair_kernel``."""
+    diagonal (of ``causal_block`` blocks: :func:`_sees_up_to`) and,
+    where there is a window, inside it, so neither compare of
+    :func:`_tile_mask` can hide an element (python ints at table build).
+    What only the data shows (key padding, segments) the kernel adds
+    from scalars: see ``_fa_pair_kernel``."""
     if not causal:
         return True
-    under = k_off + block_k - 1 <= q_off
+    under = k_off + block_k - 1 <= _sees_up_to(q_off, causal_block)
     return under and (not window or q_off + block_q - 1 - k_off < window)
 
 
 def _tile_mask(q_off, k_off, kv_len, causal, block_q, block_k,
-               seg_q=None, seg_k=None, window=0):
+               seg_q=None, seg_k=None, window=0, causal_block=0):
     """[block_q, block_k] element validity for one tile — THE shared
     masking helper for the forward kernel, both backward kernels and
-    the packed variants: key-padding (``kv_len``), causal diagonal,
-    (packed) segment-id equality with −1 = padding, and a sliding
-    ``window`` (a query sees the ``window`` newest keys up to itself;
-    causal only).  ``seg_q`` is a column ``[block_q, 1]``, ``seg_k`` a
-    row ``[1, block_k]``."""
+    the packed variants: key-padding (``kv_len``), causal diagonal
+    (token by token, or block by block with ``causal_block``:
+    :func:`_sees_up_to`), (packed) segment-id equality with −1 =
+    padding, and a sliding ``window`` (a query sees the ``window``
+    newest keys up to itself; causal only).  ``seg_q`` is a column
+    ``[block_q, 1]``, ``seg_k`` a row ``[1, block_k]``."""
     ki = k_off + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
     valid = ki < kv_len
     if causal:
         qi = q_off + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
-        valid = jnp.logical_and(valid, qi >= ki)
+        valid = jnp.logical_and(valid, _sees_up_to(qi, causal_block) >= ki)
         if window:
             valid = jnp.logical_and(valid, qi - ki < window)
     if seg_q is not None:
@@ -164,7 +176,7 @@ def _tile_mask(q_off, k_off, kv_len, causal, block_q, block_k,
 # ----------------------------------------------------------- pair tables
 @functools.lru_cache(maxsize=None)
 def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
-                 slot: int = 0, window: int = 0):
+                 slot: int = 0, window: int = 0, causal_block: int = 0):
     """Static block-sparse iteration tables.
 
     Returns ``(tab_q, tab_k)`` — int32 ``[5, n_pairs]`` arrays with
@@ -195,6 +207,11 @@ def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
     the pair (:func:`_block_interior`); the forward kernel runs such a
     pair without a mask when its scalars show no padding and one
     segment.  At 7,168 tokens in blocks of 512 that is 91 of 105.
+
+    ``causal_block`` (a divisor of ``bq``): the diagonal is one of blocks
+    of positions (:func:`_sees_up_to`); a q tile's last query sees up to
+    its own last position either way, so the live pairs are the same
+    and only ``is_interior`` moves.
     """
     nq, nk = tq // bq, tk // bk
     if slot and (slot % bq or slot % bk):
@@ -223,7 +240,8 @@ def _pair_tables(tq: int, tk: int, bq: int, bk: int, causal: bool,
                 rows[2].append(1 if t == 0 else 0)
                 rows[3].append(1 if t == len(members) - 1 else 0)
                 rows[4].append(1 if _block_interior(
-                    j * bq, s * bk, bq, bk, causal, window) else 0)
+                    j * bq, s * bk, bq, bk, causal, window,
+                    causal_block) else 0)
         # ptpu: lint-ok[PT-TRACE] python ints: the static table itself
         return np.asarray(rows, np.int32)
 
@@ -346,7 +364,7 @@ def _heads_per_step(h: int, per_group: int, bq: int, bk: int, d: int,
 
 
 def _fa_pair_kernel(*refs, scale, causal, block_q, block_k, n_heads,
-                    packed, window=0):
+                    packed, window=0, causal_block=0):
     """Grid (B·H / heads a step, n_pairs) over the q-major pair table:
     the online softmax carries in VMEM scratch across one q block's
     pairs, initialized at its first table entry and flushed at its
@@ -396,7 +414,8 @@ def _fa_pair_kernel(*refs, scale, causal, block_q, block_k, n_heads,
         valid = _tile_mask(
             q_off, k_off, kv_len, causal, block_q, block_k,
             sq_ref[0] if packed else None,
-            sk_ref[0, 0] if packed else None, window) if masked else None
+            sk_ref[0, 0] if packed else None, window,
+            causal_block) if masked else None
 
         def one_head(hh):
             kh = hh if k_ref.shape[0] > 1 else 0
@@ -482,17 +501,18 @@ def packed_tileable(t_total: int, block_q: int, block_k: int) -> bool:
     return _tiling_ok(t_total, t_total, bq, bk)
 
 
-def _mask_scores(s, causal, lengths, segments=None, window=0):
+def _mask_scores(s, causal, lengths, segments=None, window=0,
+                 causal_block=0):
     """Apply causal / key-padding / packed-segment masks to
     [B, H, Tq, Tk] scores — the dense-path twin of :func:`_tile_mask`
     (same semantics at full-matrix granularity)."""
     tq, tk = s.shape[-2], s.shape[-1]
     if causal:
-        behind = (jnp.arange(tq)[None, None, :, None]
-                  - jnp.arange(tk)[None, None, None, :])
-        seen = behind >= 0
+        qi = jnp.arange(tq)[None, None, :, None]
+        ki = jnp.arange(tk)[None, None, None, :]
+        seen = _sees_up_to(qi, causal_block) >= ki
         if window:
-            seen = jnp.logical_and(seen, behind < window)
+            seen = jnp.logical_and(seen, qi - ki < window)
         s = jnp.where(seen, s, NEG_INF)
     if lengths is not None:
         valid = jnp.arange(tk)[None, :] < lengths[:, None]   # [B, Tk]
@@ -504,7 +524,8 @@ def _mask_scores(s, causal, lengths, segments=None, window=0):
     return s
 
 
-def _dense_forward(q, k, v, lengths, causal, segments=None, window=0):
+def _dense_forward(q, k, v, lengths, causal, segments=None, window=0,
+                   causal_block=0):
     """Fallback for shapes the kernel can't tile (and the exact
     unfused reference the kill switches restore): plain XLA attention,
     same (out, lse) contract so the shared backward rule applies.
@@ -515,7 +536,7 @@ def _dense_forward(q, k, v, lengths, causal, segments=None, window=0):
                 for a in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    s = _mask_scores(s, causal, lengths, segments, window)
+    s = _mask_scores(s, causal, lengths, segments, window, causal_block)
     m = s.max(axis=-1)
     # fully-masked rows (query past a zero-length sequence): emit 0
     m_safe = jnp.maximum(m, NEG_INF / 2)
@@ -528,7 +549,8 @@ def _dense_forward(q, k, v, lengths, causal, segments=None, window=0):
 
 
 def _record_attn_work(kernel, bh, tq, tk, bq, bk, d, causal, slot,
-                      operands, results, window=0, d_v=None):
+                      operands, results, window=0, d_v=None,
+                      causal_block=0):
     """The work account of one flash kernel (``ops/kernels.py``): 4·d
     FLOPs per (query, key) position of the statically live blocks —
     QKᵀ and PV forward; dP and dQ, or dV and dK, backward (the scores
@@ -537,7 +559,8 @@ def _record_attn_work(kernel, bh, tq, tk, bq, bk, d, causal, slot,
     forward pair kernels also tick ``kind=pairs`` and
     ``kind=pairs_interior``: the block pairs of the table, and those
     the diagonal and the window leave whole."""
-    tab = _pair_tables(tq, tk, bq, bk, causal, slot, window)[0]
+    tab = _pair_tables(tq, tk, bq, bk, causal, slot, window,
+                       causal_block)[0]
     n_pairs = tab.shape[1]
     # how often the forward pair kernel's unmasked body can engage
     more = dict(pairs=n_pairs, pairs_interior=int(tab[4].sum())) \
@@ -553,7 +576,7 @@ def _heads_first(a, b, t, h, d):
 
 
 def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
-                       segments=None, slot=0, window=0):
+                       segments=None, slot=0, window=0, causal_block=0):
     """Pair-table (block-sparse) forward: the call's work account, then
     :func:`_fa_sparse_call`.  With grouped KV heads (``k``/``v`` hold
     G < H heads) a grid row's k/v blocks are its group's: no copy of K
@@ -570,15 +593,16 @@ def _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
         K.FLASH_FWD if segments is None else K.FLASH_FWD_PACKED, b * h,
         tq, tk, bq, bk, d, causal, slot, operands,
         [arr((b * h, tq, d_v), q.dtype), arr((b * h, 8, tq), jnp.float32)],
-        window, d_v)
+        window, d_v, causal_block)
     return _fa_sparse_call(q, k, v, lengths, segments, causal=causal,
-                           bq=bq, bk=bk, slot=slot, window=window)
+                           bq=bq, bk=bk, slot=slot, window=window,
+                           causal_block=causal_block)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "slot",
-                                             "window"))
+                                             "window", "causal_block"))
 def _fa_sparse_call(q, k, v, lengths, segments, *, causal, bq, bk, slot,
-                    window):
+                    window, causal_block=0):
     """The forward pair kernel's call: grid (B·H / n, n_pairs), n heads
     a step (:func:`_heads_per_step`).  Jitted on its own: the layers of
     a decoder call it with one set of shapes, and the kernel is traced
@@ -593,7 +617,7 @@ def _fa_sparse_call(q, k, v, lengths, segments, *, causal, bq, bk, slot,
     vh = _heads_first(v, b, tk, g, d_v)
     nq, nk = tq // bq, tk // bk
     tab = jnp.asarray(_pair_tables(tq, tk, bq, bk, causal, slot,
-                                   window)[0])
+                                   window, causal_block)[0])
     n_pairs = tab.shape[1]
     if segments is None:
         lo, hi = _length_windows(lengths, b, nq, bk)
@@ -660,7 +684,7 @@ def _fa_sparse_call(q, k, v, lengths, segments, *, causal, bq, bk, slot,
     kernel = functools.partial(
         _fa_pair_kernel, scale=scale, causal=causal, block_q=bq,
         block_k=bk, n_heads=h, packed=segments is not None,
-        window=window)
+        window=window, causal_block=causal_block)
     out_shape = [
         jax.ShapeDtypeStruct((b * h, tq, d_v), q.dtype),
         jax.ShapeDtypeStruct((b * h, 8, tq), jnp.float32),
@@ -796,11 +820,15 @@ def _flash_enabled() -> bool:
 
 
 def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
-                segments=None, slot=0, window=0):
+                segments=None, slot=0, window=0, causal_block=0):
     b, tq, h, d = q.shape
     tk = k.shape[1]
     enforce(not window or causal,
             "a sliding window is a causal mask's: pass causal=True")
+    enforce(causal_block <= 1 or (causal and not window and causal_block
+                                  & (causal_block - 1) == 0),
+            f"causal_block {causal_block}: a power of two, causal, and "
+            "no window beside it")
     enforce(k.shape[2] == h or (segments is not None
                                 and h % k.shape[2] == 0),
             f"K/V heads {k.shape[2]} must equal the {h} query heads "
@@ -814,6 +842,8 @@ def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
                 f"causal attention needs Tq == Tk, got {tq}/{tk}")
     bq = _choose_block(tq, block_q)
     bk = _choose_block(tk, block_k)
+    enforce(bq % max(causal_block, 1) == 0,
+            f"a query tile of {bq} cuts blocks of {causal_block}")
     if lengths is None:
         lengths = jnp.full((b,), tk, jnp.int32)
     packed = segments is not None
@@ -823,12 +853,14 @@ def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
     if not _flash_enabled():
         record_attention_dispatch(
             "dense", "kill_switch:flash_kernel")
-        return _dense_forward(q, k, v, lengths, causal, segments, window)
+        return _dense_forward(q, k, v, lengths, causal, segments, window,
+                              causal_block)
     if not _tiling_ok(tq, tk, bq, bk):
         reason = "untileable shape (lse/kv block constraints)"
         record_attention_dispatch("dense", reason)
         _warn_dense_fallback(reason, tq, tk, bq, bk)
-        return _dense_forward(q, k, v, lengths, causal, segments, window)
+        return _dense_forward(q, k, v, lengths, causal, segments, window,
+                              causal_block)
     if _block_sparse():
         reason = ""
         if packed and slot and (slot % bq or slot % bk) \
@@ -849,12 +881,13 @@ def _fa_forward(q, k, v, lengths, causal, block_q, block_k,
         record_attention_dispatch("packed" if packed
                                    else "block_sparse", reason)
         return _fa_forward_sparse(q, k, v, lengths, causal, bq, bk,
-                                  segments, slot, window)
+                                  segments, slot, window, causal_block)
     if packed:
         # the legacy grid has no segment plumbing: exact dense fallback
         record_attention_dispatch(
             "dense", "kill_switch:flash_block_sparse(packed)")
-        return _dense_forward(q, k, v, lengths, causal, segments, window)
+        return _dense_forward(q, k, v, lengths, causal, segments, window,
+                              causal_block)
     record_attention_dispatch("legacy_grid",
                                "kill_switch:flash_block_sparse")
     return _fa_forward_grid(q, k, v, lengths, causal, bq, bk)
@@ -1359,10 +1392,11 @@ flash_attention.defvjp(_fa_fwd_rule, _fa_bwd_rule)
 
 
 # ------------------------------------------------------ sequence packing
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def flash_attention_packed(q, k, v, segments, causal: bool = False,
                            block_q: int = 512, block_k: int = 512,
-                           slot: int = 0, window: int = 0):
+                           slot: int = 0, window: int = 0,
+                           causal_block: int = 0):
     """Packed (ragged-batch) attention: tokens attend only within
     their segment.
 
@@ -1381,32 +1415,41 @@ def flash_attention_packed(q, k, v, segments, causal: bool = False,
     across slots leave the iteration space entirely (see
     :func:`_pair_tables`).  ``window`` (causal only): a query sees the
     ``window`` newest keys of its segment up to itself; blocks wholly
-    behind it leave the iteration space too.  ``v`` may be
+    behind it leave the iteration space too.  ``causal_block`` (causal
+    only, a power of two): **block-causal** — a query sees every key of
+    its own block of ``causal_block`` positions and of the blocks before
+    (:func:`_sees_up_to`), blocks counted from position 0 of the packed
+    axis, so a segment that starts off a block boundary shares its
+    first block with the one before (the serving prefill's rows start at
+    multiples of their padded width, a multiple of the block).  ``v`` may be
     ``[B, T_total, G, Dv]`` with ``Dv`` other than ``D`` (latent
     attention expands keys of 192 and values of 128 lanes a head): the
     result is ``[B, T_total, H, Dv]``.  Grouped heads, a window and
-    unequal widths are the serving prefill's: forward only (their
-    backward raises).
+    unequal widths and blocks are the serving prefill's: forward only
+    (their backward raises).
     """
     out, _lse = _fa_forward(q, k, v, None, causal, block_q, block_k,
-                            segments=segments, slot=slot, window=window)
+                            segments=segments, slot=slot, window=window,
+                            causal_block=causal_block)
     return out
 
 
 def _fa_packed_fwd_rule(q, k, v, segments, causal, block_q, block_k,
-                        slot, window):
+                        slot, window, causal_block):
     out, lse = _fa_forward(q, k, v, None, causal, block_q, block_k,
-                           segments=segments, slot=slot, window=window)
+                           segments=segments, slot=slot, window=window,
+                           causal_block=causal_block)
     return out, (q, k, v, segments, out, lse)
 
 
-def _fa_packed_bwd_rule(causal, block_q, block_k, slot, window, res, do):
+def _fa_packed_bwd_rule(causal, block_q, block_k, slot, window,
+                        causal_block, res, do):
     q, k, v, segments, out, lse = res
-    enforce(not window and k.shape[2] == q.shape[2]
+    enforce(not window and causal_block <= 1 and k.shape[2] == q.shape[2]
             and v.shape[3] == q.shape[3],
             "flash_attention_packed: no backward for a sliding window, "
-            "grouped KV heads or values of another width yet (serving "
-            "is forward only)")
+            "blocks, grouped KV heads or values of another width yet "
+            "(serving is forward only)")
     lengths = jnp.full((q.shape[0],), k.shape[1], jnp.int32)
     dq, dk, dv = _fa_backward(q, k, v, lengths, out, lse, do, causal,
                               block_q, block_k, segments=segments,
@@ -1466,7 +1509,7 @@ def _pages_per_step(page: int, token_bytes: int, rows: int,
 def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                    o_ref, kbuf, vbuf, sem, qe_s, m_s, l_s, acc_s, *,
                    scale, page, chunk, t_q, n_heads, kv_heads, d,
-                   n_pages_max, window):
+                   n_pages_max, window, block=False):
     """One invocation; a loop step takes ``chunk`` pages of one row, all
     heads in it, so the time follows the K/V that is live, not the
     table's width.  The pools stay in HBM as they are stored
@@ -1490,7 +1533,11 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
     heads out of its lanes (``o_ref`` is then ``[B, Tq, H, D]``).
     ``window`` > 0: a query sees only the ``window`` newest positions
     up to its own, and a row's walk starts at the first page that holds
-    one of them, so the pages behind the window are not read."""
+    one of them, so the pages behind the window are not read.
+    ``block``: every query of the tile sees every position up to the
+    tile's end (a block of a diffusion step sees itself whole); else
+    query t sees up to its own position, ``Tq - 1 - t`` before the
+    newest."""
     n_rows, span = q_ref.shape[0], chunk * page
     rep = n_heads // kv_heads
     hd = kbuf.shape[-1]
@@ -1532,7 +1579,8 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
     own = (lanes >= group * d) & (lanes < (group + 1) * d)   # [H, G·D]
     ki = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
     # query t of the tile sits t_q - 1 - t positions before the newest
-    back = 0 if t_q == 1 else t_q - 1 - jax.lax.broadcasted_iota(
+    # and sees up to itself; a block's queries all see up to the newest
+    back = 0 if t_q == 1 or block else t_q - 1 - jax.lax.broadcasted_iota(
         jnp.int32, (rows, 1), 0) // n_heads
 
     def _row(b, n):
@@ -1554,9 +1602,10 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                     qt = jnp.pad(qt, ((0, 0), (0, hd - qt.shape[1])))
             qe_s[pl.ds(t * n_heads, n_heads), :] = jnp.where(
                 own, qt.astype(qe_s.dtype), 0)
-        # a query attends every key at or before itself (the ragged
-        # causal tail), which also masks the last page's slots past the
-        # row's length and the chunk's slots that were not fetched
+        # a query attends every key at or before the newest it sees
+        # (itself, or the tile's end: ``back``), which also masks the last
+        # page's slots past the row's length and the chunk's slots that
+        # were not fetched
         newest = jnp.minimum(kv_len, used * page) - 1
 
         def _chunk(j, n):
@@ -1629,11 +1678,12 @@ def _next_live_row(lengths):
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
-                           window: int = 0, name=None):
+                           window: int = 0, name=None, block: bool = False):
     """Decode-step attention over a block-paged KV cache.
 
     - ``q``: ``[B, Tq, H, D]`` — the row's newest ``Tq`` tokens (Tq is
-      small: 1 for plain decode, >1 for speculative/chunked steps);
+      small: 1 for plain decode, the block's length for a diffusion
+      step, >1 for speculative or chunked steps);
     - ``k_pages`` / ``v_pages``: the physical page pools shared by
       every row, ``[P, page_size, G·D]`` — one lane-dense row a token,
       as the server stores them, read where they lie — or
@@ -1645,11 +1695,18 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
     - ``lengths``: int32 ``[B]`` valid cached tokens per row — the
       query tile occupies positions ``length - Tq … length - 1``, so
       the current step's K/V must already be written to the pages;
+      query t sees every position up to its own, the tile's ragged
+      causal tail;
     - ``window``: 0, or the number of newest positions (its own
       included) a query sees; pages wholly behind it are not read;
+    - ``block``: every query of the tile sees every position up to the
+      tile's end instead (a diffusion step's block, bidirectional within
+      itself, over the blocks before it); no window beside it.  Its
+      work is counted under ``K.BLOCK_DECODE``, the name its caller
+      gives it;
     - ``name``: the kernel's name in a device trace
-      (``K.PAGED_DECODE``), or None for the instruction name the call
-      inherits (see the call below).
+      (``K.PAGED_DECODE``, ``K.BLOCK_DECODE``), or None for the
+      instruction name the call inherits (see the call below).
 
     Returns ``[B, Tq, H, D]``.  Inference-only (no custom VJP): this is
     the serving decode primitive (ROADMAP item 1) exercised standalone.
@@ -1668,6 +1725,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
     enforce(page_indices.shape[0] == b and lengths.shape == (b,),
             f"page_indices/lengths batch mismatch: "
             f"{page_indices.shape}/{lengths.shape} vs B={b}")
+    enforce(not (block and window), "a block's tile takes no window")
     n_pages_max = page_indices.shape[1]
     record_attention_dispatch("decode")
     width, isz = gd + -gd % 128, k_pages.dtype.itemsize
@@ -1682,14 +1740,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
     # say: the serve loop's span carries live_pages / attended_tokens)
     reach = b * n_pages_max * page
     kv = jax.ShapeDtypeStruct((reach, gd), k_pages.dtype)
-    K.record_kernel_work(K.PAGED_DECODE, 4.0 * t_q * h * d * reach,
-                         (q, kv, kv), (q,))
+    K.record_kernel_work(K.BLOCK_DECODE if block else K.PAGED_DECODE,
+                         4.0 * t_q * h * d * reach, (q, kv, kv), (q,))
     return _paged_decode(q, k_pages, v_pages, page_indices, lengths,
-                         int(window), chunk, name)
+                         int(window), chunk, name, bool(block))
 
 
 def _paged_decode(q, k_pages, v_pages, page_indices, lengths, window,
-                  chunk, name=None):
+                  chunk, name=None, block=False):
     """:func:`paged_decode_attention`'s call at ``chunk`` pages a loop
     step (its rule's; ``chip_smoke.py`` times the others)."""
     lengths = lengths.astype(jnp.int32)
@@ -1698,11 +1756,12 @@ def _paged_decode(q, k_pages, v_pages, page_indices, lengths, window,
     call = _decode_call if name is None else _decode_pallas
     return call(
         lengths, page_indices.astype(jnp.int32), _next_live_row(lengths),
-        q, k_pages, v_pages, window=window, chunk=chunk, name=name)
+        q, k_pages, v_pages, window=window, chunk=chunk, name=name,
+        block=block)
 
 
 def _decode_pallas(lengths, page_indices, live, q, k_pages, v_pages, *,
-                   window, chunk, name=None):
+                   window, chunk, name=None, block=False):
     """The ``pallas_call`` of :func:`_decode_kernel`: int32 ``lengths``,
     page table and next-live-row list first (scalar-prefetched), the
     pools left in HBM."""
@@ -1726,7 +1785,7 @@ def _decode_pallas(lengths, page_indices, live, q, k_pages, v_pages, *,
         functools.partial(_decode_kernel, scale=1.0 / np.sqrt(d),
                           page=page, chunk=chunk, t_q=t_q, n_heads=h,
                           kv_heads=g, d=d, n_pages_max=n_pages_max,
-                          window=window),
+                          window=window, block=block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
@@ -1775,11 +1834,11 @@ def _decode_pallas(lengths, page_indices, live, q, k_pages, v_pages, *,
 #: (``chip_smoke.py::decode_step_checks`` holds it).
 _decode_call = jax.jit(
     lambda *a, **kw: _decode_pallas(*a, **kw),
-    static_argnames=("window", "chunk", "name"))
+    static_argnames=("window", "chunk", "name", "block"))
 
 
 def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths,
-                           window: int = 0):
+                           window: int = 0, block: bool = False):
     """Dense one-step reference for :func:`paged_decode_attention`
     (tests; also the numerics contract): gather each row's pages into
     a contiguous [B, max_pages·page, G, D] cache, give every query head
@@ -1798,6 +1857,8 @@ def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths,
     ki = jnp.arange(n_max * page, dtype=jnp.int32)
     qpos = (lengths[:, None] - t_q
             + jnp.arange(t_q, dtype=jnp.int32)[None, :])     # [B, Tq]
+    if block:                     # every query sees the tile's end
+        qpos = jnp.broadcast_to(lengths[:, None] - 1, qpos.shape)
     valid = ki[None, None, :] <= qpos[:, :, None]            # [B,Tq,K]
     if window:
         valid &= ki[None, None, :] > qpos[:, :, None] - window

@@ -30,7 +30,8 @@ have to share one pass over its weights.  Both are one walk here:
   hit.
 
 Router mathematics (the configuration's, ``chipbench/reference``):
-``s = sigmoid(x·W_r)`` in float32; the ``top_k`` largest of ``s + b``
+``s = sigmoid(x·W_r)`` in float32, or with ``score="softmax"`` ``s =
+softmax(x·W_r)`` over all E experts; the ``top_k`` largest of ``s + b``
 are chosen (the bias chooses, it does not weigh); weights
 ``scale · s_e / (Σ_chosen s + 1e-20)``.  No token is dropped and there
 is no capacity factor.
@@ -250,13 +251,22 @@ def grouped_glu(lhs, w_gate, w_up, group_sizes, out_dtype):
                          out_dtype)
 
 
-def route(x, router_w, router_bias, top_k: int, route_scale: float):
+#: how a router turns its logits into scores: every expert on its own,
+#: or one distribution over all of them
+SCORES = {"sigmoid": jax.nn.sigmoid,
+          "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
+def route(x, router_w, router_bias, top_k: int, route_scale: float,
+          score: str = "sigmoid"):
     """``(experts [T, top_k] int32, weights [T, top_k] float32)``: the
-    ``top_k`` largest of ``sigmoid(x·W_r) + bias``, weighed by their
-    sigmoid scores alone, normalised and scaled.  float32 at the
-    highest matmul precision: a score that rounds differently picks
-    another expert."""
-    s = jax.nn.sigmoid(jnp.dot(
+    ``top_k`` largest of ``s + bias``, weighed by their scores ``s``
+    alone, normalised and scaled; ``s`` is the ``score`` of ``x·W_r``
+    (:data:`SCORES`).  float32 at the highest matmul precision: a score
+    that rounds differently picks another expert."""
+    enforce(score in SCORES, f"router score {score!r} is none of "
+            f"{sorted(SCORES)}")
+    s = SCORES[score](jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, experts = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
@@ -267,7 +277,8 @@ def route(x, router_w, router_bias, top_k: int, route_scale: float):
 
 
 def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, *,
-                   top_k: int, route_scale: float, valid=None):
+                   top_k: int, route_scale: float, valid=None,
+                   score: str = "sigmoid"):
     """The routed half of a mixture-of-experts feed-forward.
 
     - ``x``: ``[T, d]`` float32 (the block's normed input);
@@ -276,7 +287,8 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, *,
       their storage dtype: each expert is ``(silu(x·Wg) ⊙ x·Wu)·Wd``;
     - ``valid``: optional bool ``[T]``; a token that is not valid
       (padding, an idle batch slot) is routed nowhere: it reads no
-      expert, counts in no group and gets zeros.
+      expert, counts in no group and gets zeros;
+    - ``score``: how the router scores (:func:`route`).
 
     Returns ``(y [T, d] float32, group_sizes [E] int32)``.
     """
@@ -285,7 +297,7 @@ def routed_experts(x, router_w, router_bias, w_gate, w_up, w_down, *,
     record_moe_dispatch("grouped")
     with jax.named_scope(S.ROUTE):
         experts, weights = route(x, router_w, router_bias, top_k,
-                                 route_scale)
+                                 route_scale, score)
     with jax.named_scope(S.SORT):
         # the flat list of choices is choice-major, token t's choice c
         # at c·T + t: the results back in that order are ``top_k`` slabs

@@ -29,7 +29,15 @@ The two entry points mirror the two serving kernels from PR 14/15:
 - :meth:`DecoderModel.decode` advances a fixed-width decode batch one
   token with ``paged_decode_attention`` over the shared page pool —
   inactive (padded) slots carry a scratch page table, zero write count,
-  and length 1, so the kernel touches no memory the slot does not own.
+  and length 1, so the kernel touches no memory the slot does not own;
+- :meth:`DecoderModel.block_step` is decode's counterpart for a model
+  that generates by **diffusion over blocks** (``block_length`` > 0):
+  each row's block of positions, masked where nothing is revealed yet,
+  goes through the layers at once, writes its K/V at the block's place
+  and attends its own block whole and every block before it; the step
+  reveals the row's most confident masked positions on the device, or
+  none (a **commit**: the finished block's K/V written once more, from
+  its final ids).  Its prefill is block-causal.
 
 Both update the pools **in place**: a step donates the stacked
 pools (:class:`KVPool` holds the one reference to each) and every layer
@@ -82,7 +90,7 @@ from ..ops.pallas_attention import (flash_attention_packed,
                                     latent_decode_attention,
                                     paged_decode_attention, paged_kv_write,
                                     paged_row_write, segments_from_lengths)
-from ..ops.pallas_moe import (routed_experts, weight_einsum,
+from ..ops.pallas_moe import (SCORES, routed_experts, weight_einsum,
                               weight_matmul)
 from ..ops.pallas_ssm import selective_scan, ssm_step
 from ..utils import enforce
@@ -157,7 +165,22 @@ class DecoderConfig(NamedTuple):
     products take operands in it and accumulate in float32, and norms,
     router scores, softmax and the residual stream stay float32.
     ``tied_head``: the logits are the final norm's output times the
-    embedding's transpose, and there is no ``lm_head``."""
+    embedding's transpose, and there is no ``lm_head``.
+    ``route_score``: how a routed layer scores its experts, ``sigmoid``
+    or ``softmax`` over all of them (``ops/pallas_moe.py::route``).
+
+    ``block_length`` > 0 (a power of two) makes the model generate by
+    diffusion over blocks of that many positions, counted from position
+    0: position i sees position j iff ⌊j/B⌋ ≤ ⌊i/B⌋, in the prefill and
+    in every step; the logits at a position predict the token AT it.
+    A block starts with its positions masked (the embedding row
+    ``mask_id``; which positions are masked is tracked by position, an
+    id of −1, never by id) but for prompt ids it carries, and each of
+    ``denoise_steps`` steps reveals a share of the masked positions
+    (:func:`reveal_schedule`): those whose top softmax probability is
+    highest, each set to its argmax.  Every layer is ``full`` with per-
+    head K/V.  0 is generation one token a row a step: every other
+    configuration."""
     vocab: int
     dim: int
     heads: int
@@ -189,6 +212,10 @@ class DecoderConfig(NamedTuple):
     ssm_state: int = 0
     dt_rank: int = 0
     tied_head: bool = False
+    route_score: str = "sigmoid"
+    block_length: int = 0
+    denoise_steps: int = 0
+    mask_id: int = -1
 
 
 KINDS = frozenset({"full", "window", "latent", "conv", "mamba"})
@@ -251,6 +278,16 @@ def layer_plan(cfg: DecoderConfig
             f"{kv_heads(cfg)} K/V heads")
     enforce(cfg.storage in ("float32", "bfloat16"),
             f"storage {cfg.storage!r} is neither float32 nor bfloat16")
+    enforce(cfg.route_score in SCORES,
+            f"route_score {cfg.route_score!r} is none of {sorted(SCORES)}")
+    b = cfg.block_length
+    enforce(b == 0 or (b >= 2 and b & (b - 1) == 0
+                       and cfg.denoise_steps >= 1
+                       and 0 <= cfg.mask_id < cfg.vocab
+                       and all(attn & KINDS == {"full"} for attn, _ in out)),
+            f"block_length {b}: a power of two of 2 or more, with "
+            "denoise_steps >= 1, a mask_id in the vocabulary and full "
+            "attention in every layer")
     return tuple(out)
 
 
@@ -725,7 +762,8 @@ def _ffn(x, valid, params, i, cfg: DecoderConfig, attn, ffn):
             y, sizes = routed_experts(
                 m.reshape(b * t, d), p("router"), p("router_bias"),
                 p("e_gate"), p("e_up"), p("e_down"), top_k=cfg.top_k,
-                route_scale=cfg.route_scale, valid=valid.reshape(-1))
+                route_scale=cfg.route_scale, valid=valid.reshape(-1),
+                score=cfg.route_score)
             y = y.reshape(b, t, d)
             if "shared" in ffn:
                 with jax.named_scope(S.SHARED):
@@ -788,12 +826,19 @@ def _slot_count(shapes, cfg: DecoderConfig) -> int:
 def _prefill_impl(params, pools, tokens, lengths, page_indices, slots,
                   cfg: DecoderConfig):
     """[B, T] padded prompts → ([B] first generated tokens, [B, V]
-    logits, the updated pools).  Packed causal attention: the batch is
-    ONE [1, B*T] row; segment ids keep rows from attending across each
-    other and mask padding outright.  ``slots`` [B]: where each row's
-    sequence keeps its state (conv and mamba layers)."""
+    logits, the updated pools).  Packed causal attention (block-causal
+    where the model has a ``block_length``: its prompts then are whole
+    blocks, and the ids and logits of the last position are no
+    prediction): the batch is ONE [1, B*T] row; segment ids keep rows
+    from attending across each other and mask padding outright.
+    ``slots`` [B]: where each row's sequence keeps its state (conv and
+    mamba layers)."""
     b, t = tokens.shape
     h, g, dh = cfg.heads, kv_heads(cfg), head_dim(cfg)
+    # a row of the packed axis starts at a multiple of t: its blocks are
+    # the packed axis's
+    enforce(t % max(cfg.block_length, 1) == 0,
+            f"a prefill of {t} positions cuts blocks of {cfg.block_length}")
     with jax.named_scope(S.EMBED):
         pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None, :],
                                (b, t))
@@ -845,7 +890,8 @@ def _prefill_impl(params, pools, tokens, lengths, page_indices, slots,
                         k.reshape(1, b * t, g, dh),
                         v.reshape(1, b * t, g, dh), segments, causal=True,
                         slot=t,
-                        window=cfg.window if "window" in attn else 0)
+                        window=cfg.window if "window" in attn else 0,
+                        causal_block=cfg.block_length)
                     o = o.reshape(b, t, h * dh).astype(jnp.float32)
             with jax.named_scope(S.MIXER_OUT.format(i)):
                 x = _attend_out(x, o, gate, params, i, cfg, attn)
@@ -940,6 +986,84 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
         return ids, logits, *_as_stored(shapes, pools, states)
 
 
+def reveal_schedule(masked: int, steps: int) -> Tuple[int, ...]:
+    """How many of a block's ``masked`` positions each of ``steps``
+    denoising steps reveals: ⌊m/steps⌋ + (s < m mod steps) at step s, a
+    step that would reveal none left out (4 over 2 steps: 2, 2; 3: 2, 1;
+    1: 1)."""
+    return tuple(n for n in (masked // steps + (s < masked % steps)
+                             for s in range(steps)) if n)
+
+
+def _reveal(block, logits, reveal):
+    """(The block after a step, each position's confidence): of each
+    row's masked positions (id < 0) the ``reveal`` [W] whose top softmax
+    probability (float32) is highest, the lower position first on a
+    tie, take their argmax; the rest stay as they are.  The confidence
+    is that probability's log, float32 [W, B]."""
+    conf = logits.max(axis=-1) - jax.nn.logsumexp(logits, axis=-1)
+    pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)       # [W, B]
+    score = jnp.where(block < 0, conf, -jnp.inf)
+    at = jnp.arange(block.shape[1])
+    # how many of the row's positions rank before each position
+    ahead = (score[:, None, :] > score[:, :, None]) | (
+        (score[:, None, :] == score[:, :, None])
+        & (at[None, None, :] < at[None, :, None]))
+    chosen = (block < 0) & (ahead.sum(axis=-1) < reveal[:, None])
+    return jnp.where(chosen, pred, block), conf
+
+
+def _block_impl(params, pools, block, page_indices, starts, active, reveal,
+                cfg: DecoderConfig):
+    """One diffusion step for a fixed-width batch of blocks: ``block``
+    [W, B] ids (−1 where masked), each row's block at positions
+    ``starts`` [W] … + B.  Every layer writes the block's K/V at its
+    place and attends through the block mode of the paged kernel (the
+    block whole and everything before it); ``reveal`` [W] positions a
+    row are revealed (:func:`_reveal`; 0 is the commit).  An ``active``
+    row's pages and positions are its own; an idle row writes nothing
+    and reads the scratch page.  → (the [W·B] blocks after the step,
+    their [W·B] confidences' float32 bits and :func:`_route_counts`'
+    integers in one int32 vector, the logits [W, B, V], the updated
+    pools)."""
+    w, bl = block.shape
+    with jax.named_scope(S.EMBED):
+        pos = jnp.clip(starts[:, None] + jnp.arange(bl)[None, :], 0,
+                       cfg.max_context - 1)
+        x = _embed(params, jnp.where(block >= 0, block, cfg.mask_id), pos,
+                   cfg)
+        counts = jnp.where(active, bl, 0).astype(jnp.int32)
+        klen = jnp.where(active, starts + bl, 1).astype(jnp.int32)
+        valid = jnp.broadcast_to(active[:, None], (w, bl))
+    sizes = []
+    with jax.named_scope(S.CACHE_LAYOUT):
+        shapes, pools, _ = _kv_and_state(pools, cfg)
+    n_places = shapes[0][1]
+    for i, (attn, ffn) in enumerate(layer_plan(cfg)):
+        table = page_indices + i * n_places
+        q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
+        with jax.named_scope(S.CACHE_WRITE.format(i)):
+            pools = paged_kv_write(*pools, k, v, table, starts, counts)
+        with jax.named_scope(S.ATTEND.format(i)):
+            o = paged_decode_attention(
+                q, *pools, table, klen, name=K.BLOCK_DECODE,
+                block=True).reshape(w, bl, -1)
+        with jax.named_scope(S.MIXER_OUT.format(i)):
+            x = _attend_out(x, o, gate, params, i, cfg, attn)
+        x, routed = _ffn(x, valid, params, i, cfg, attn, ffn)
+        if routed is not None:
+            sizes.append(routed)
+    with jax.named_scope(S.HEAD):
+        logits = _head(x, params, cfg)
+        after, conf = _reveal(block, logits, reveal)
+        ids = jnp.concatenate([
+            after.reshape(-1),
+            jax.lax.bitcast_convert_type(conf.reshape(-1), jnp.int32),
+            _route_counts(sizes)])
+    with jax.named_scope(S.CACHE_LAYOUT):
+        return ids, logits, *_as_stored(shapes, pools, {})
+
+
 def n_kv_pools(cfg: DecoderConfig) -> int:
     """Pools of cache rows a model of this plan keeps for the layers
     that attend: one of latent rows, or a K and a V pool."""
@@ -1014,6 +1138,28 @@ def _fed_ids(tokens, prev, src):
         return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
 
 
+@functools.lru_cache(maxsize=None)
+def _jitted_block_step(cfg: DecoderConfig):
+    """The jitted :func:`_block_impl` of a config, shared as
+    :func:`_jitted_steps`' pair is; it takes ``(params, *pools, blocks,
+    prev_ids, src, tables, starts, active, reveal)`` and donates the
+    pools.  A row's block is the host's ``blocks`` row or, where ``src``
+    >= 0, block ``src`` of ``prev``: the blocks an earlier step left,
+    still on the device."""
+    n = n_pools(cfg)
+    return jax.jit(
+        lambda p, *a: _block_impl(p, a[:n], _fed_blocks(*a[n:n + 3]),
+                                  *a[n + 3:], cfg),
+        donate_argnums=tuple(range(1, 1 + n)))
+
+
+def _fed_blocks(blocks, prev, src):
+    w, bl = blocks.shape
+    with jax.named_scope(S.EMBED):
+        held = prev[:w * bl].reshape(w, bl)[jnp.maximum(src, 0)]
+        return jnp.where((src >= 0)[:, None], held, blocks)
+
+
 class KVPool:
     """One stacked cache on the device (K, V or latent rows, ``[L, P,
     page, W]``, or the conv layers' state, ``[L, places, taps - 1,
@@ -1081,6 +1227,8 @@ class DecoderModel:
                     f"{shapes[name]}")
             self.params[name] = a.astype(_stored_as(name, a.shape, cfg))
         self._prefill, self._decode = _jitted_steps(cfg)
+        self.block_length = cfg.block_length
+        self._block = _jitted_block_step(cfg) if cfg.block_length else None
 
     # ----------------------------------------------------------- pools
     def new_pools(self, n_pages: int, page_size: int,
@@ -1159,7 +1307,12 @@ class DecoderModel:
         """Visible (query, key) pairs of a prefill over prompts of these
         lengths, summed over the layers that attend: the causal
         triangle, of which a window layer counts what its window
-        leaves."""
+        leaves, or the staircase of a block-causal mask."""
+        b = self.cfg.block_length
+        if b:       # block-causal: position i sees up to its block's end
+            return self._cached_layers * sum(
+                b * b * (n // b) * (n // b + 1) // 2 + (n % b) * n
+                for n in prompts)
         full = sum(n * (n + 1) // 2 for n in prompts)
         if not self._window_layers:
             return full * self._cached_layers
@@ -1304,6 +1457,63 @@ class DecoderModel:
         routed = dict(zip(("experts_hit", "expert_load_max"),
                           map(int, ids[b:])))
         return ids[:b], logits, routed
+
+    def block_step(self, *args, collect: bool = True):
+        """``block_step(*pools, blocks, page_indices, starts, active,
+        reveal[, prev, src])``: one diffusion step of a fixed-width batch
+        of blocks (a model with a ``block_length``).  ``blocks`` [W, B]
+        int32 ids, −1 where a position is masked; ``starts`` [W] each
+        row's block's first position (its K/V are written at starts …
+        starts + B, into pages the table covers); ``reveal`` [W] how many
+        masked positions the step reveals, 0 for the commit.  ``prev`` is
+        an earlier block step of the same width, collected or not, and
+        ``src`` [W] says per row which of its blocks the row is fed (−1:
+        the host's ``blocks``).  → (the blocks after the step [W, B],
+        each position's confidence [W, B] (:func:`_reveal`), the logits
+        [W, B, V], a device array, the pools, the routed counts as
+        :meth:`collect_decode`'s); ``collect=False``
+        (:meth:`launch_block_step`) returns once the step is queued."""
+        pools, (blocks, page_indices, starts, active, reveal, *feed) = \
+            self._pools_of(args)
+        prev, src = feed or (None, None)
+        w, bl = np.shape(blocks)
+        if prev is None:       # the program's shapes, fed by nobody
+            counts = 2 if self.routed_layers else 0
+            ids = np.zeros((2 * w * bl + counts,), np.int32)
+            src = np.full((w,), -1, np.int32)
+        else:
+            ids = prev[0]
+        with _span("decode_dispatch"):        # host→device + launch
+            ids, logits, *arrays = self._block(
+                self.params, *(p.array for p in pools),
+                jnp.asarray(blocks, jnp.int32), jnp.asarray(ids, jnp.int32),
+                jnp.asarray(src, jnp.int32),
+                jnp.asarray(page_indices, jnp.int32),
+                jnp.asarray(starts, jnp.int32), jnp.asarray(active, bool),
+                jnp.asarray(reveal, jnp.int32))
+            for pool, array in zip(pools, arrays):
+                pool.array = array
+        if not collect:
+            return ids, logits
+        after, conf, logits, routed = self.collect_block_step((ids, logits))
+        return (after, conf, logits, *pools, routed)
+
+    launch_block_step = functools.partialmethod(block_step, collect=False)
+
+    @staticmethod
+    def collect_block_step(launch):
+        """→ (the blocks after the step [W, B] and each position's
+        confidence [W, B], on the host, in the one fetch; the logits, a
+        device array; the routed counts, as :meth:`collect_decode`)."""
+        ids, logits = launch
+        with _span("decode_fetch"):           # blocks on the device
+            ids = np.asarray(ids)
+        w, bl = logits.shape[:2]
+        n = w * bl
+        routed = dict(zip(("experts_hit", "expert_load_max"),
+                          map(int, ids[2 * n:])))
+        return (ids[:n].reshape(w, bl), ids[n:2 * n].view(np.float32)
+                .reshape(w, bl), logits, routed)
 
     # -------------------------------------------------------- artifacts
     @classmethod

@@ -54,6 +54,31 @@ row:
   applied only once everything the old model launched is collected,
   ``stop()`` and a failing turn collect what is queued first.
 
+**A model that generates by diffusion over blocks** (``block_length``
+B > 0) takes **block launches** where another takes decode launches:
+every row of one computes its request's current block, B positions,
+and either reveals the share of its masked positions that the static
+schedule gives the step (``model.reveal_schedule``: which positions is
+the device's choice) or, once every position is revealed, **commits**
+the block: one more pass that writes its K/V from its final ids.  A
+prompt of L ids is prefilled through its first ⌊L/B⌋·B positions; the
+L mod B ids left ride, unmasked, in the first block.  The schedule is
+static, so the host knows each row's phase without reading the device:
+a row's block comes from the host where it starts (all masked, or the
+prompt's last ids) and from the block launch it is queued behind
+otherwise (the block the device left, ``src``), and a row's prefill in
+flight holds nothing it needs, so it joins the launch queued behind
+that prefill.  A block's tokens are emitted when its last denoising
+step is collected, in order, up to an EOS or the budget, and the
+request ends there: a request ends by its budget at the end of the
+block that reaches it, which takes no commit (nothing reads its K/V),
+and by an EOS one launch late, its commit riding dead as a row past its
+EOS does.  Pages cover the prompt and the budget rounded up to B.
+Each request keeps the record of its finished blocks
+(``Request.blocks``: the block as each denoising step found it and as
+the last left it, whether it was committed), which is what a reference
+replays.
+
 The kill switch ``--serve_continuous=false`` degrades the same loop to
 sequential single-request serving (admit one, run to completion, batch
 width 1).  Because every per-request computation in
@@ -83,7 +108,8 @@ before it: a few hundred microseconds).  Under span k::
     │                scan_tokens, attn_pairs, requests, queued}
     │  or serve_decode_step{batch, live_tokens, live_pages, attended_tokens,
     │                       state_rows, queued, experts_hit, expert_load_max
-    │                       [, discarded]}
+    │                       [, discarded]
+    │                       [, block_rows, commit_rows, revealed, emitted]}
     │    (from idle: serve_step_build · *_dispatch of launch k itself)
     │    serve_admit{queued} · serve_step_build · *_dispatch  of launch k+1
     │    *_fetch · serve_step_emit                            of launch k
@@ -103,6 +129,12 @@ the step attends over — and ``live_pages`` the pages they occupy;
 the layers that attend (a window layer reads a row's newest ``window``
 only, a conv layer none);
 ``batch`` the rows whose token was emitted (``discarded`` the others).
+A block launch also states ``block_rows`` (the rows it computes),
+``commit_rows`` (those that commit), ``revealed`` (the positions its
+denoising rows reveal) and ``emitted`` (the tokens its collect emits);
+there ``batch`` is the rows still live when it was collected, and
+``serve_block_passes_total{kind=denoise|commit}`` and the gauge
+``serve_tokens_per_launch`` count the same.
 Where the plan has conv layers, ``conv_tokens`` is the prompt tokens
 times those layers, where it has mamba layers ``scan_tokens`` the
 prompt tokens times those, and ``state_rows`` the launched rows times
@@ -153,7 +185,7 @@ import numpy as np
 from ..analysis.lockorder import named_condition
 from ..core.device import ensure_compile_cache
 from ..utils import FLAGS, enforce, get_logger
-from .model import DecoderModel
+from .model import DecoderModel, reveal_schedule
 from .pagepool import PagePool, PagePoolExhausted, SCRATCH_PAGE, TornSnapshot
 
 try:                         # telemetry optional, as in loader.py
@@ -203,11 +235,25 @@ class Request:
     the generated ids (prompt excluded); ``length`` counts tokens whose
     K/V the launches queued so far write to this request's pages (the
     one in flight included); ``table`` is its page-table row and
-    ``slot`` where its fixed-size state lies, once admitted."""
+    ``slot`` where its fixed-size state lies, once admitted.
+
+    Under a model with a ``block_length``: ``at`` and ``todo`` are
+    where the planning stands (the start of the block its next pass
+    works on, and that block's passes still to plan as reveal counts, 0
+    the commit), ``fresh`` the block that block starts from until its
+    first pass is planned, ``block`` the block as
+    the newest collected pass left it (−1 where masked), ``states`` the
+    current block as it started and after each of its collected
+    denoising steps and ``confs`` each step's confidences (the log of
+    a position's top probability), and ``blocks`` the finished blocks:
+    ``{"start", "states", "confs", "committed"}`` (the last state is the
+    block's ids)."""
 
     __slots__ = ("id", "prompt", "max_new_tokens", "tokens", "state",
                  "error", "done", "length", "next_token", "table", "slot",
-                 "t_submit", "t_admit", "t_first", "t_done", "trace_id")
+                 "t_submit", "t_admit", "t_first", "t_done", "trace_id",
+                 "at", "todo", "fresh", "block", "states",
+                 "confs", "blocks")
 
     def __init__(self, prompt: Sequence[int], max_new_tokens: int):
         self.id = f"req{next(_REQ_IDS)}"
@@ -221,6 +267,12 @@ class Request:
         self.next_token = -1             # newest token the host has read
         self.table: Optional[np.ndarray] = None
         self.slot = -1
+        self.at = 0
+        self.todo: List[int] = []
+        self.fresh = self.block = None
+        self.states: List[np.ndarray] = []
+        self.confs: List[np.ndarray] = []
+        self.blocks: List[Dict] = []
         self.t_submit = time.perf_counter()
         self.t_admit: Optional[float] = None
         self.t_first: Optional[float] = None
@@ -255,17 +307,21 @@ class _Launch:
     index each row had in the decode launch this one is queued behind,
     from which it takes its id on the device (−1: from the host),
     ``attrs`` what its span states, ``handle`` the model's launch once
-    queued."""
+    queued.  A block launch (a decode step of a model with a
+    ``block_length``) has ``passes``: per row (block start, positions it
+    reveals (0: the commit), whether it finishes the block, the block it
+    starts from or None)."""
 
-    __slots__ = ("kind", "rows", "src", "attrs", "handle")
+    __slots__ = ("kind", "rows", "src", "attrs", "handle", "passes")
 
     def __init__(self, kind: str, rows: List[Request], attrs: Dict,
-                 src: Sequence[int] = ()):
+                 src: Sequence[int] = (), passes: Sequence = ()):
         self.kind = kind                 # prefill|decode
         self.rows = rows
         self.src = src
         self.attrs = attrs
         self.handle = None
+        self.passes = passes
 
 
 class SwapTicket:
@@ -401,6 +457,14 @@ class InferenceServer:
             "programs queued on the device by kind (decode | prefill) "
             "and by what the device had when they were queued: behind "
             "= a launch the host had not collected yet, idle = nothing")
+        self._m_passes = None if _counter is None else _counter(
+            "serve_block_passes_total",
+            "rows a block launch computed, by kind: denoise (reveals "
+            "positions of its block) | commit (writes a finished block's "
+            "K/V)")
+        self._m_emitted = None if _gauge is None else _gauge(
+            "serve_tokens_per_launch",
+            "tokens the most recent block launch emitted when collected")
         self._m_discarded = None if _counter is None else _counter(
             "serve_rows_discarded_total",
             "rows a decode launch computed for a request that EOS had "
@@ -511,7 +575,7 @@ class InferenceServer:
         enforce(len(prompt) >= 1, "empty prompt")
         enforce(max_new_tokens >= 1,
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        total = len(prompt) + max_new_tokens
+        total = self._positions(len(prompt), max_new_tokens)
         enforce(total <= self.model.cfg.max_context,
                 f"prompt + max_new_tokens = {total} exceeds max_context "
                 f"{self.model.cfg.max_context}")
@@ -824,8 +888,8 @@ class InferenceServer:
                     and len(self._active) + len(admitted) < self._width:
                 r = self._queue[0]
                 try:
-                    self.pool.alloc(
-                        r.id, len(r.prompt) + r.max_new_tokens)
+                    self.pool.alloc(r.id, self._positions(
+                        len(r.prompt), r.max_new_tokens))
                 except PagePoolExhausted:
                     break            # backpressure: retry after retires
                 self._queue.popleft()
@@ -840,41 +904,61 @@ class InferenceServer:
             self._publish_slots()
         return admitted
 
+    def _positions(self, prompt: int, budget: int) -> int:
+        """Positions a request writes: its prompt and budget, up to the
+        end of the last block where the model generates by blocks."""
+        b = self.model.block_length or 1
+        return -(-(prompt + budget) // b) * b
+
     def _queued(self) -> str:
         """``behind`` a launch the host has not collected, or on an
         ``idle`` device (start, after a drain, after a lone prefill)."""
         return "behind" if self._inflight else "idle"
 
+    def _prefilled(self, r: Request) -> int:
+        """The prompt positions a prefill runs: all of them, or the
+        whole blocks of a model that generates by blocks."""
+        b = self.model.block_length
+        return len(r.prompt) // b * b if b else len(r.prompt)
+
     def _plan_prefill(self, admitted: List[Request]) -> _Launch:
         """One packed launch for every request admitted this round;
-        produces each request's first generated token (TTFT)."""
-        t_pad = max(len(r.prompt) for r in admitted)
+        produces each request's first generated token (TTFT), or, where
+        the model generates by blocks, sets each request's first block
+        (:meth:`_start_blocks`)."""
+        t_pad = max(map(self._prefilled, admitted))
         # bucket the pad length: bounded set of compiled prefill shapes
-        t_pad = -(-t_pad // 16) * 16
+        t_pad = max(-(-t_pad // 16) * 16, 16)
         t_pad = min(t_pad, self.model.cfg.max_context)
-        prompt_tokens = sum(len(r.prompt) for r in admitted)
+        prompt_tokens = sum(map(self._prefilled, admitted))
         for r in admitted:
             t = self.pool.table_of(r.id)
             r.table = np.array(
                 t + [SCRATCH_PAGE] * (self.max_pages - len(t)), np.int32)
+            if self.model.block_length:
+                self._start_blocks(r)
         return _Launch("prefill", admitted, dict(
             n=len(admitted), t_pad=t_pad, prompt_tokens=prompt_tokens,
             moe_tokens=prompt_tokens * self.model.routed_layers,
             conv_tokens=prompt_tokens * self.model.conv_layers,
             scan_tokens=prompt_tokens * self.model.mamba_layers,
             attn_pairs=self.model.attn_pairs(
-                [len(r.prompt) for r in admitted]),
+                [self._prefilled(r) for r in admitted]),
             requests=",".join(r.id for r in admitted),
             queued=self._queued()))
 
     def _plan_decode(self) -> Optional[_Launch]:
-        """The active rows advance one token in a single fixed-width
-        paged-attention launch.  A row of the decode launch in flight is
-        fed from it on the device, unless that launch ends it by its
-        budget; whether it ended by EOS the host learns one launch late,
-        and such a row rides along dead (:meth:`_collect` drops its
-        token).  A row whose prefill is in flight joins at the launch
-        after this one, from the host."""
+        """The active rows advance in a single fixed-width paged-
+        attention launch: one token a row, or, where the model generates
+        by blocks, one pass over each row's block (:meth:`_plan_blocks`).
+        A row of the decode launch in flight is fed from it on the
+        device, unless that launch ends it by its budget; whether it
+        ended by EOS the host learns one launch late, and such a row
+        rides along dead (:meth:`_collect` drops its token).  A row
+        whose prefill is in flight joins at the launch after this one,
+        from the host."""
+        if self.model.block_length:
+            return self._plan_blocks()
         behind = self._inflight[-1] if self._inflight else None
         flying = {} if behind is None \
             else {id(r): i for i, r in enumerate(behind.rows)}
@@ -900,6 +984,64 @@ class InferenceServer:
             state_rows=len(rows) * self.model.state_layers,
             queued=self._queued()), src)
 
+    def _start_blocks(self, r: Request) -> None:
+        """``r``'s first block: it starts at its prompt's last whole
+        block, carrying the ids past it unmasked."""
+        at = self._prefilled(r)
+        fresh = np.full((self.model.block_length,), -1, np.int32)
+        fresh[:len(r.prompt) - at] = r.prompt[at:]
+        r.block, r.states, r.confs, r.blocks = None, [], [], []
+        self._enter_block(r, at, fresh)
+
+    def _enter_block(self, r: Request, at: int, fresh: np.ndarray) -> None:
+        """``r``'s block at ``at``, starting from ``fresh``: its
+        denoising steps, then its commit unless it is the request's last
+        block."""
+        last = self._positions(len(r.prompt), r.max_new_tokens) \
+            - self.model.block_length
+        r.at, r.fresh = at, fresh
+        r.todo = list(reveal_schedule(int((fresh < 0).sum()),
+                                      self.model.cfg.denoise_steps))
+        if at < last:
+            r.todo.append(0)
+
+    def _plan_blocks(self) -> Optional[_Launch]:
+        """One block launch: every active row with a pass to go takes
+        its next one.  A row's block comes from the host where the block
+        starts or where the launch before it was collected, and else
+        from the launch in flight, on the device."""
+        b = self.model.block_length
+        behind = self._inflight[-1] if self._inflight else None
+        flying = {} if behind is None or behind.kind != "decode" \
+            else {id(r): i for i, r in enumerate(behind.rows)}
+        rows, src, passes = [], [], []
+        for r in self._active:
+            if not r.todo:              # its last pass is launched
+                continue
+            reveal = r.todo.pop(0)
+            finishes = reveal > 0 and (not r.todo or r.todo[0] == 0)
+            passes.append((r.at, reveal, finishes, r.fresh))
+            rows.append(r)
+            src.append(-1 if r.fresh is not None
+                       else flying.get(id(r), -1))
+            r.fresh = None
+            if not r.todo and r.at + b < self._positions(
+                    len(r.prompt), r.max_new_tokens):
+                self._enter_block(r, r.at + b, np.full((b,), -1, np.int32))
+        if not rows:
+            return None
+        enforce(len(rows) <= self._width,
+                f"active {len(rows)} exceeds batch width {self._width}")
+        fed = [at + b for at, *_ in passes]
+        reveals = [p[1] for p in passes]
+        return _Launch("decode", rows, dict(
+            batch=len(rows), live_tokens=sum(fed),
+            live_pages=sum(map(self.pool.pages_needed, fed)),
+            attended_tokens=self.model.attended_tokens(fed), state_rows=0,
+            queued=self._queued(), block_rows=len(rows),
+            commit_rows=reveals.count(0), revealed=sum(reveals)), src,
+            passes)
+
     def _launch(self, launch: _Launch) -> None:
         """Build the launch's inputs and queue it on the device."""
         rows, n = launch.rows, len(launch.rows)
@@ -911,8 +1053,8 @@ class InferenceServer:
             with _span("serve_step_build"):
                 tokens = np.zeros((n, launch.attrs["t_pad"]), np.int32)
                 for i, r in enumerate(rows):
-                    tokens[i, :len(r.prompt)] = r.prompt
-                    r.length = len(r.prompt)
+                    r.length = self._prefilled(r)
+                    tokens[i, :r.length] = r.prompt[:r.length]
                 lengths = np.array([r.length for r in rows], np.int32)
                 tables = np.stack([r.table for r in rows])
                 slots = self._slots_kw(rows, n)
@@ -927,6 +1069,9 @@ class InferenceServer:
             self._marks.append(("dispatch", time.perf_counter()))
             launch.handle = self.model.launch_prefill(
                 *self._pools, tokens, lengths, tables, **slots)
+        elif launch.passes:
+            self._launch_blocks(launch)
+            return
         else:
             with _span("serve_step_build"):
                 b = self._width
@@ -960,6 +1105,40 @@ class InferenceServer:
                 **slots)
         self._inflight.append(launch)
 
+    def _launch_blocks(self, launch: _Launch) -> None:
+        """Build a block launch's inputs and queue it on the device."""
+        rows, n, b = launch.rows, len(launch.rows), self.model.block_length
+        with _span("serve_step_build"):
+            w = self._width
+            blocks = np.full((w, b), -1, np.int32)
+            src = np.full((w,), -1, np.int32)
+            starts = np.zeros((w,), np.int32)
+            active = np.zeros((w,), bool)
+            reveal = np.zeros((w,), np.int32)
+            tables = np.full((w, self.max_pages), SCRATCH_PAGE, np.int32)
+            for i, (r, (at, rv, _, fed)) in enumerate(
+                    zip(rows, launch.passes)):
+                if launch.src[i] < 0:
+                    blocks[i] = r.block if fed is None else fed
+                starts[i], reveal[i], tables[i] = at, rv, r.table
+                r.length = at + b
+            src[:n] = launch.src
+            active[:n] = True
+        if self._m_batch is not None:
+            self._m_batch.set(n)
+        if self._m_passes is not None:
+            commits = launch.attrs["commit_rows"]
+            self._m_passes.inc(n - commits, kind="denoise")
+            if commits:
+                self._m_passes.inc(commits, kind="commit")
+        # rows with ``src`` >= 0 take their blocks from the launch this
+        # one is queued behind, on the device
+        prev = self._inflight[-1].handle if max(launch.src) >= 0 else None
+        self._marks.append(("dispatch", time.perf_counter()))
+        launch.handle = self.model.launch_block_step(
+            *self._pools, blocks, tables, starts, active, reveal, prev, src)
+        self._inflight.append(launch)
+
     def _collect(self, step) -> None:
         """Wait for the oldest launch in flight, bring its ids back and
         emit them; ``step`` is its span.  A row whose request ended
@@ -968,8 +1147,17 @@ class InferenceServer:
         launch = self._inflight.popleft()
         handle, launch.handle = launch.handle, None
         self._marks.append(("fetch", time.perf_counter()))
+        if launch.passes:
+            after, conf, _, routed = self.model.collect_block_step(handle)
+            step.set(**routed)
+            with _span("serve_step_emit"):
+                self._marks.append(("emit", time.perf_counter()))
+                self._emit_blocks(launch, after, conf, step)
+            return
         if launch.kind == "prefill":
             ids, _ = self.model.collect_prefill(handle)
+            if self.model.block_length:     # tokens come from blocks
+                return
         else:
             ids, _, routed = self.model.collect_decode(handle)
             step.set(**routed)      # experts_hit, expert_load_max
@@ -992,6 +1180,61 @@ class InferenceServer:
                 if self._m_discarded is not None:
                     self._m_discarded.inc(dead)
                 step.set(batch=len(live), discarded=dead)
+
+    def _emit_blocks(self, launch: _Launch, after: np.ndarray,
+                     conf: np.ndarray, step) -> None:
+        """A block launch's rows as collected: each live row's block
+        becomes what the step left (a denoising step's is kept in the
+        block's states, with its confidences), a commit marks its block
+        committed, and a block
+        its last denoising step finished is logged and its tokens
+        emitted."""
+        emitted = live = 0
+        for i, r in enumerate(launch.rows):
+            if r.state != "active":
+                continue
+            live += 1
+            at, reveal, finishes, fed = launch.passes[i]
+            if fed is not None:
+                r.states, r.confs = [fed], []
+            if reveal:
+                r.states.append(after[i].copy())
+                r.confs.append(conf[i].copy())
+            else:
+                r.blocks[-1]["committed"] = True
+            r.block = after[i].copy()
+            if finishes:
+                emitted += self._emit_block(r, at)
+        self._count_tokens(emitted)
+        if self._m_emitted is not None:
+            self._m_emitted.set(emitted)
+        dead = len(launch.rows) - live
+        if dead and self._m_discarded is not None:
+            self._m_discarded.inc(dead)
+        step.set(batch=live, emitted=emitted,
+                 **({"discarded": dead} if dead else {}))
+
+    def _emit_block(self, r: Request, at: int) -> int:
+        """``r``'s block at ``at`` is finished: log it and emit its
+        generated positions in order until the request ends.  → the
+        tokens emitted."""
+        r.blocks.append({"start": at,
+                         "states": [a.tolist() for a in r.states],
+                         "confs": [c.tolist() for c in r.confs],
+                         "committed": False})
+        if r.t_first is None:
+            r.t_first = time.perf_counter()
+            if _histogram is not None:
+                _histogram("serve_ttft_seconds",
+                           "submit-to-first-token latency"
+                           ).observe(r.t_first - r.t_submit)
+        n = 0
+        for token in r.block[max(len(r.prompt) - at, 0):].tolist():
+            self._emit_token(r, token)
+            n += 1
+            if r.state != "active":
+                break
+        return n
 
     def _drain(self) -> None:
         """Collect whatever is still in flight, outside its span: after
@@ -1034,8 +1277,9 @@ class InferenceServer:
             self._m_tokens.inc(n)
 
     def _emit_token(self, r: Request, token: int) -> None:
-        """Record one generated token; finish the request on EOS or the
-        token budget, releasing its pages for immediate recycling."""
+        """Record one generated token, of a decode step or of a finished
+        block; finish the request on EOS or the token budget, releasing
+        its pages for immediate recycling."""
         r.tokens.append(token)
         r.next_token = token
         if token == self.model.cfg.eos_id \

@@ -100,11 +100,13 @@ def test_the_walk_finds_every_site():
             if kw.arg == "name":
                 used |= _table_constants(kw.value, fn)
     # a name nobody passes is a metric that can never read: only the
-    # one that the serving decoder hands down is given outside ops/
-    assert set(K.KERNEL_NAMES) - used == {"PAGED_DECODE"}
+    # two that the serving decoder hands down are given outside ops/
+    assert set(K.KERNEL_NAMES) - used == {"PAGED_DECODE", "BLOCK_DECODE"}
     with open(os.path.join(os.path.dirname(OPS), "serving",
                            "model.py")) as f:
-        assert "name = K.PAGED_DECODE if cfg.plan else None" in f.read()
+        text = f.read()
+    assert "name = K.PAGED_DECODE if cfg.plan else None" in text
+    assert "name=K.BLOCK_DECODE" in text
 
 
 @pytest.mark.parametrize(

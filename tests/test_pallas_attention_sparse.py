@@ -280,6 +280,67 @@ def test_packed_matches_per_row_dense(causal, kv_heads, window, rng):
         assert np.abs(np.asarray(a)[0, segn < 0]).max() == 0.0
 
 
+def _np_block_causal(q, k, v, b):
+    """:func:`_np_attention` under a block-causal mask: query i sees key
+    j iff ⌊j/b⌋ <= ⌊i/b⌋."""
+    t, h, d = q.shape
+    rep = h // k.shape[1]
+    k, v = np.repeat(k, rep, axis=1), np.repeat(v, rep, axis=1)
+    s = np.einsum("qhd,khd->hqk", q, k, dtype=np.float64) / np.sqrt(d)
+    blk = np.arange(t) // b
+    s = np.where((blk[None, :] <= blk[:, None])[None], s, -np.inf)
+    p = np.exp(s - s.max(axis=-1, keepdims=True))
+    p /= p.sum(axis=-1, keepdims=True)
+    return np.einsum("hqk,khd->qhd", p, v)
+
+
+@pytest.mark.parametrize("kv_heads,block_k", [(2, 16), (1, 16), (2, 128)])
+def test_packed_block_causal_matches_per_row_dense(kv_heads, block_k, rng):
+    """The block-causal mode of the packed kernel over one [1, B·T] axis
+    (rows of 128, blocks of 4 counted from each row's start, lengths
+    that end mid-block and on a tile's edge) ≡ per-row dense attention
+    under the block mask, and ≡ the dense path of the kernel's own
+    dispatch; padding emits exact zeros; no backward."""
+    D, H, B, T = 16, 4, 3, 128
+    lens = [101, 64, 30]
+    x = [rng.randn(B, T, n, D).astype(np.float32)
+         for n in (H, kv_heads, kv_heads)]
+    q, k, v = (jnp.asarray(a.reshape(1, B * T, -1, D)) for a in x)
+    seg = pa.segments_from_lengths(jnp.asarray(lens, jnp.int32), B, T)
+    out = np.asarray(pa.flash_attention_packed(
+        q, k, v, seg, True, 128, block_k, T, causal_block=4))
+    dense, _ = pa._dense_forward(q, k, v, jnp.full((1,), B * T, jnp.int32),
+                                 True, seg, causal_block=4)
+    np.testing.assert_allclose(out, np.asarray(dense), rtol=2e-4, atol=2e-5)
+    out = out.reshape(B, T, H, D)
+    for i, n in enumerate(lens):
+        ref = _np_block_causal(x[0][i, :n], x[1][i, :n], x[2][i, :n], 4)
+        np.testing.assert_allclose(out[i, :n], ref, rtol=2e-4, atol=2e-5)
+        assert np.abs(out[i, n:]).max() == 0.0
+    # a block's first query sees its whole block, unlike a causal tile's
+    causal = np.asarray(pa.flash_attention_packed(
+        q, k, v, seg, True, 128, block_k, T)).reshape(B, T, H, D)
+    assert np.abs(causal[0, 0] - out[0, 0]).max() > 1e-3
+    np.testing.assert_allclose(causal[0, 3], out[0, 3], rtol=2e-4,
+                               atol=2e-5)
+    with pytest.raises(PaddleTpuError, match="no backward"):
+        _grads(lambda *a: pa.flash_attention_packed(
+            *a, seg, True, 128, block_k, T, 0, 4), q, k, v,
+            jnp.ones((1, B * T, H, D)))
+
+
+def test_block_causal_pair_tables_keep_the_causal_pairs():
+    """Blocks of 4 inside tiles of 16: the live pairs are the causal
+    table's; a pair is interior once its keys end within the q tile's
+    first block, so the diagonal pairs stay masked."""
+    causal = pa._pair_tables(64, 64, 16, 16, True)[0]
+    block = pa._pair_tables(64, 64, 16, 16, True, causal_block=4)[0]
+    np.testing.assert_array_equal(causal[:4], block[:4])
+    assert causal[4].sum() == block[4].sum() == 6
+    assert pa._pair_tables(64, 64, 16, 4, True, causal_block=4)[0][4].sum() \
+        == pa._pair_tables(64, 64, 16, 4, True)[0][4].sum() + 4
+
+
 def test_packed_window_at_the_served_shape(rng):
     """The routed decoder's longest prefill as the server launches it:
     one row of 6144 tokens in blocks of 512, 2 query heads over 1 K/V
@@ -452,6 +513,42 @@ def _decode_case(case, rng):
             jnp.asarray(lengths, jnp.int32), jnp.asarray(safe), window)
 
 
+@pytest.mark.parametrize("case", ["base", "wide_garbage", "page_boundary",
+                                  "inactive_rows", "gqa", "hd2048"])
+def test_paged_decode_block_mode_matches_dense_reference(case, rng):
+    """The block mode: every query of a 4-row tile sees every position up
+    to the tile's end, through the same pages as the causal tail (whose
+    first query it differs from) and under the same guards; the dense
+    reference's block mode is the plain attention over the row's
+    positions."""
+    H, D, kpg, vpg, pidx, lengths, safe, _ = _decode_case(case, rng)
+    q = jnp.asarray(rng.randn(pidx.shape[0], 4, H, D).astype(np.float32))
+    out = np.asarray(pa.paged_decode_attention(q, kpg, vpg, pidx, lengths,
+                                               block=True))
+    ref = np.asarray(pa.paged_decode_reference(q, kpg, vpg, safe, lengths,
+                                               block=True))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
+    row = int(np.argmax(np.asarray(lengths)))
+    n, page = int(lengths[row]), kpg.shape[1]
+    keys = lambda pool: np.asarray(pool)[np.asarray(safe)[row]].reshape(
+        -1, *pool.shape[2:])[:n].reshape(n, -1, D)
+    kr, vr = (np.repeat(keys(pool), H // keys(pool).shape[1], axis=1)
+              for pool in (kpg, vpg))
+    sc = np.einsum("qhd,khd->hqk", np.asarray(q[row]), kr,
+                   dtype=np.float64) / np.sqrt(D)
+    w = np.exp(sc - sc.max(axis=-1, keepdims=True))
+    dense = np.einsum("hqk,khd->qhd", w / w.sum(axis=-1, keepdims=True), vr)
+    np.testing.assert_allclose(out[row], dense, rtol=2e-4, atol=2e-5)
+    tail = np.asarray(pa.paged_decode_attention(q, kpg, vpg, pidx, lengths))
+    assert np.abs(tail[row, 0] - out[row, 0]).max() > 1e-3
+    np.testing.assert_allclose(tail[row, 3], out[row, 3], rtol=2e-4,
+                               atol=2e-5)
+    with pytest.raises(PaddleTpuError, match="no window"):
+        pa.paged_decode_attention(q, kpg, vpg, pidx, lengths, window=8,
+                                  block=True)
+
+
 @pytest.mark.parametrize("t_q", [1, 4])
 @pytest.mark.parametrize("case", ["base", "wide_scratch", "wide_garbage",
                                   "page_boundary", "inactive_rows",
@@ -591,6 +688,8 @@ CELL_CALLS = {
     "lfm2": ((16, 1, 32, 64), 8, 64, "bfloat16", 128, 0),
     "jamba": ((16, 1, 20, 128), 1, 64, "bfloat16", 260, 0),
     "latent": ((16, 32, 640), 0, 64, "bfloat16", 128, 0),
+    # the block mode: 16 rows of a block of 4 queries (SDAR's)
+    "sdar": ((16, 4, 32, 128), 4, 64, "bfloat16", 64, 0),
 }
 
 
@@ -642,7 +741,8 @@ def test_the_rule_gives_8_pages_at_the_cells_shapes_and_mosaic_takes_them(
         shapes = [(q_shape, "float32"), ((2048, page, w), dtype),
                   ((2048, page, w), dtype)]
         call = lambda q, k, v, t, n: pa.paged_decode_attention(
-            q, k, v, t, n, window=window, name="paged_decode")
+            q, k, v, t, n, window=window, block=cell == "sdar",
+            name="block_decode" if cell == "sdar" else "paged_decode")
     monkeypatch.setattr(pa, "pallas_interpret", lambda: False)
     shapes += [((b, slots), "int32"), ((b,), "int32")]
     args = [jax.ShapeDtypeStruct(s, jnp.dtype(t), sharding=one_v5e)
