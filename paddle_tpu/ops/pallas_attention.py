@@ -1509,7 +1509,7 @@ def _pages_per_step(page: int, token_bytes: int, rows: int,
 def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
                    o_ref, kbuf, vbuf, sem, qe_s, m_s, l_s, acc_s, *,
                    scale, page, chunk, t_q, n_heads, kv_heads, d,
-                   n_pages_max, window, block=False):
+                   n_pages_max, window, block=0):
     """One invocation; a loop step takes ``chunk`` pages of one row, all
     heads in it, so the time follows the K/V that is live, not the
     table's width.  The pools stay in HBM as they are stored
@@ -1534,10 +1534,10 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
     ``window`` > 0: a query sees only the ``window`` newest positions
     up to its own, and a row's walk starts at the first page that holds
     one of them, so the pages behind the window are not read.
-    ``block``: every query of the tile sees every position up to the
-    tile's end (a block of a diffusion step sees itself whole); else
-    query t sees up to its own position, ``Tq - 1 - t`` before the
-    newest."""
+    Query t sits ``Tq - 1 - t`` before the newest position and sees up
+    to itself, or, with ``block`` B > 0, up to the end of its own block
+    of B positions (blocks counted from position 0: a diffusion step's
+    block sees itself whole), never past the row's length."""
     n_rows, span = q_ref.shape[0], chunk * page
     rep = n_heads // kv_heads
     hd = kbuf.shape[-1]
@@ -1579,7 +1579,7 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
     own = (lanes >= group * d) & (lanes < (group + 1) * d)   # [H, G·D]
     ki = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
     # query t of the tile sits t_q - 1 - t positions before the newest
-    # and sees up to itself; a block's queries all see up to the newest
+    # and sees up to itself
     back = 0 if t_q == 1 or block else t_q - 1 - jax.lax.broadcasted_iota(
         jnp.int32, (rows, 1), 0) // n_heads
 
@@ -1603,10 +1603,15 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
             qe_s[pl.ds(t * n_heads, n_heads), :] = jnp.where(
                 own, qt.astype(qe_s.dtype), 0)
         # a query attends every key at or before the newest it sees
-        # (itself, or the tile's end: ``back``), which also masks the last
-        # page's slots past the row's length and the chunk's slots that
-        # were not fetched
+        # (itself: ``back``, or its block's end: ``ends``), which also
+        # masks the last page's slots past the row's length and the
+        # chunk's slots that were not fetched
         newest = jnp.minimum(kv_len, used * page) - 1
+        ends = None
+        if block:
+            pos = kv_len - t_q + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, 1), 0) // n_heads
+            ends = jnp.minimum(newest, pos | (block - 1))
 
         def _chunk(j, n):
             slot = n % 2
@@ -1621,7 +1626,8 @@ def _decode_kernel(len_ref, pidx_ref, live_ref, q_ref, k_hbm, v_hbm,
             s = jax.lax.dot_general(
                 qe_s[...], kbuf[slot], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [Tq·H, span]
-            at = newest - back - (first + j * chunk) * page
+            at = (newest - back if ends is None else ends) \
+                - (first + j * chunk) * page
             seen = ki <= at             # ki: positions within the chunk
             if window:
                 seen = seen & (ki > at + (kv_len - 1 - newest) - window)
@@ -1678,7 +1684,7 @@ def _next_live_row(lengths):
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
-                           window: int = 0, name=None, block: bool = False):
+                           window: int = 0, name=None, block: int = 0):
     """Decode-step attention over a block-paged KV cache.
 
     - ``q``: ``[B, Tq, H, D]`` — the row's newest ``Tq`` tokens (Tq is
@@ -1699,11 +1705,15 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
       causal tail;
     - ``window``: 0, or the number of newest positions (its own
       included) a query sees; pages wholly behind it are not read;
-    - ``block``: every query of the tile sees every position up to the
-      tile's end instead (a diffusion step's block, bidirectional within
-      itself, over the blocks before it); no window beside it.  Its
-      work is counted under ``K.BLOCK_DECODE``, the name its caller
-      gives it;
+    - ``block``: 0, or a power of two B: each query sees every position
+      up to the end of its own block of B positions instead (blocks
+      counted from position 0: ``pos | (B - 1)``; a diffusion step's
+      block sees itself whole, over the blocks before it), never past
+      the row's length; no window beside it.  A tile of B queries ending
+      on a block's end sees up to the tile's end; a tile of 2B that
+      starts on a block's start holds a block and the next, the first
+      never seeing the second.  Its work is counted under
+      ``K.BLOCK_DECODE``, the name its caller gives it;
     - ``name``: the kernel's name in a device trace
       (``K.PAGED_DECODE``, ``K.BLOCK_DECODE``), or None for the
       instruction name the call inherits (see the call below).
@@ -1726,6 +1736,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
             f"page_indices/lengths batch mismatch: "
             f"{page_indices.shape}/{lengths.shape} vs B={b}")
     enforce(not (block and window), "a block's tile takes no window")
+    enforce(block == 0 or (block > 1 and block & (block - 1) == 0),
+            f"block {block}: 0, or a power of two of 2 or more")
     n_pages_max = page_indices.shape[1]
     record_attention_dispatch("decode")
     width, isz = gd + -gd % 128, k_pages.dtype.itemsize
@@ -1743,11 +1755,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_indices, lengths,
     K.record_kernel_work(K.BLOCK_DECODE if block else K.PAGED_DECODE,
                          4.0 * t_q * h * d * reach, (q, kv, kv), (q,))
     return _paged_decode(q, k_pages, v_pages, page_indices, lengths,
-                         int(window), chunk, name, bool(block))
+                         int(window), chunk, name, int(block))
 
 
 def _paged_decode(q, k_pages, v_pages, page_indices, lengths, window,
-                  chunk, name=None, block=False):
+                  chunk, name=None, block=0):
     """:func:`paged_decode_attention`'s call at ``chunk`` pages a loop
     step (its rule's; ``chip_smoke.py`` times the others)."""
     lengths = lengths.astype(jnp.int32)
@@ -1761,7 +1773,7 @@ def _paged_decode(q, k_pages, v_pages, page_indices, lengths, window,
 
 
 def _decode_pallas(lengths, page_indices, live, q, k_pages, v_pages, *,
-                   window, chunk, name=None, block=False):
+                   window, chunk, name=None, block=0):
     """The ``pallas_call`` of :func:`_decode_kernel`: int32 ``lengths``,
     page table and next-live-row list first (scalar-prefetched), the
     pools left in HBM."""
@@ -1838,7 +1850,7 @@ _decode_call = jax.jit(
 
 
 def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths,
-                           window: int = 0, block: bool = False):
+                           window: int = 0, block: int = 0):
     """Dense one-step reference for :func:`paged_decode_attention`
     (tests; also the numerics contract): gather each row's pages into
     a contiguous [B, max_pages·page, G, D] cache, give every query head
@@ -1857,8 +1869,8 @@ def paged_decode_reference(q, k_pages, v_pages, page_indices, lengths,
     ki = jnp.arange(n_max * page, dtype=jnp.int32)
     qpos = (lengths[:, None] - t_q
             + jnp.arange(t_q, dtype=jnp.int32)[None, :])     # [B, Tq]
-    if block:                     # every query sees the tile's end
-        qpos = jnp.broadcast_to(lengths[:, None] - 1, qpos.shape)
+    if block:                     # up to the end of the query's block
+        qpos = jnp.minimum(qpos | (block - 1), lengths[:, None] - 1)
     valid = ki[None, None, :] <= qpos[:, :, None]            # [B,Tq,K]
     if window:
         valid &= ki[None, None, :] > qpos[:, :, None] - window

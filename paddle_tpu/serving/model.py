@@ -35,9 +35,11 @@ The two entry points mirror the two serving kernels from PR 14/15:
   each row's block of positions, masked where nothing is revealed yet,
   goes through the layers at once, writes its K/V at the block's place
   and attends its own block whole and every block before it; the step
-  reveals the row's most confident masked positions on the device, or
-  none (a **commit**: the finished block's K/V written once more, from
-  its final ids).  Its prefill is block-causal.
+  reveals the row's most confident masked positions on the device.  A
+  row whose block is finished **commits** it in the same pass as the
+  next block's first step: its tile holds the finished block, whose K/V
+  it writes once more from its final ids, and the next block, all
+  masked, under the block-causal mask.  Its prefill is block-causal.
 
 Both update the pools **in place**: a step donates the stacked
 pools (:class:`KVPool` holds the one reference to each) and every layer
@@ -1014,27 +1016,39 @@ def _reveal(block, logits, reveal):
 
 
 def _block_impl(params, pools, block, page_indices, starts, active, reveal,
-                cfg: DecoderConfig):
+                commit, cfg: DecoderConfig):
     """One diffusion step for a fixed-width batch of blocks: ``block``
-    [W, B] ids (−1 where masked), each row's block at positions
-    ``starts`` [W] … + B.  Every layer writes the block's K/V at its
-    place and attends through the block mode of the paged kernel (the
-    block whole and everything before it); ``reveal`` [W] positions a
-    row are revealed (:func:`_reveal`; 0 is the commit).  An ``active``
+    [W, B] ids (−1 where masked), each row's tile of 2B positions at
+    ``starts`` [W] … + 2B, its live positions first.  A row whose
+    ``commit`` [W] is set holds its finished block there and the next
+    block, all masked, after it: one pass writes the finished block's
+    K/V from its final ids and takes the next block's first denoising
+    step.  Any other row holds its block and B dead positions, which
+    write nothing and reach no expert; their queries read what lies
+    there, and their outputs are dropped.  Every layer writes the live
+    positions' K/V at their place and attends through the block mode of
+    the paged kernel, every row's tile placed by its length, starts +
+    2B (each query its own block whole and every block before it: the
+    block-causal mask, so a finished block's queries do not see the
+    block after it); ``reveal`` [W] positions of the row's
+    current block (the second half of a committing row's tile, the
+    first of any other) are revealed (:func:`_reveal`).  An ``active``
     row's pages and positions are its own; an idle row writes nothing
-    and reads the scratch page.  → (the [W·B] blocks after the step,
-    their [W·B] confidences' float32 bits and :func:`_route_counts`'
-    integers in one int32 vector, the logits [W, B, V], the updated
-    pools)."""
+    and reads the scratch page.  → (the [W·B] current blocks after the
+    step, their [W·B] confidences' float32 bits and
+    :func:`_route_counts`' integers in one int32 vector, the logits
+    [W, B, V] of the current blocks, the updated pools)."""
     w, bl = block.shape
     with jax.named_scope(S.EMBED):
-        pos = jnp.clip(starts[:, None] + jnp.arange(bl)[None, :], 0,
+        tile = jnp.concatenate([block, jnp.full_like(block, -1)], axis=1)
+        pos = jnp.clip(starts[:, None] + jnp.arange(2 * bl)[None, :], 0,
                        cfg.max_context - 1)
-        x = _embed(params, jnp.where(block >= 0, block, cfg.mask_id), pos,
-                   cfg)
-        counts = jnp.where(active, bl, 0).astype(jnp.int32)
-        klen = jnp.where(active, starts + bl, 1).astype(jnp.int32)
-        valid = jnp.broadcast_to(active[:, None], (w, bl))
+        x = _embed(params, jnp.where(tile >= 0, tile, cfg.mask_id), pos, cfg)
+        counts = jnp.where(active, jnp.where(commit, 2 * bl, bl),
+                           0).astype(jnp.int32)
+        # the kernel places a tile by its row's length: 2B from starts
+        klen = jnp.where(active, starts + 2 * bl, 1).astype(jnp.int32)
+        valid = jnp.arange(2 * bl)[None, :] < counts[:, None]
     sizes = []
     with jax.named_scope(S.CACHE_LAYOUT):
         shapes, pools, _ = _kv_and_state(pools, cfg)
@@ -1047,15 +1061,17 @@ def _block_impl(params, pools, block, page_indices, starts, active, reveal,
         with jax.named_scope(S.ATTEND.format(i)):
             o = paged_decode_attention(
                 q, *pools, table, klen, name=K.BLOCK_DECODE,
-                block=True).reshape(w, bl, -1)
+                block=bl).reshape(w, 2 * bl, -1)
         with jax.named_scope(S.MIXER_OUT.format(i)):
             x = _attend_out(x, o, gate, params, i, cfg, attn)
         x, routed = _ffn(x, valid, params, i, cfg, attn, ffn)
         if routed is not None:
             sizes.append(routed)
     with jax.named_scope(S.HEAD):
-        logits = _head(x, params, cfg)
-        after, conf = _reveal(block, logits, reveal)
+        cur = jnp.where(commit[:, None, None], x[:, bl:], x[:, :bl])
+        logits = _head(cur, params, cfg)
+        after, conf = _reveal(jnp.where(commit[:, None], -1, block),
+                              logits, reveal)
         ids = jnp.concatenate([
             after.reshape(-1),
             jax.lax.bitcast_convert_type(conf.reshape(-1), jnp.int32),
@@ -1142,10 +1158,10 @@ def _fed_ids(tokens, prev, src):
 def _jitted_block_step(cfg: DecoderConfig):
     """The jitted :func:`_block_impl` of a config, shared as
     :func:`_jitted_steps`' pair is; it takes ``(params, *pools, blocks,
-    prev_ids, src, tables, starts, active, reveal)`` and donates the
-    pools.  A row's block is the host's ``blocks`` row or, where ``src``
-    >= 0, block ``src`` of ``prev``: the blocks an earlier step left,
-    still on the device."""
+    prev_ids, src, tables, starts, active, reveal, commit)`` and donates
+    the pools.  A row's block is the host's ``blocks`` row or, where
+    ``src`` >= 0, block ``src`` of ``prev``: the blocks an earlier step
+    left, still on the device."""
     n = n_pools(cfg)
     return jax.jit(
         lambda p, *a: _block_impl(p, a[:n], _fed_blocks(*a[n:n + 3]),
@@ -1460,23 +1476,34 @@ class DecoderModel:
 
     def block_step(self, *args, collect: bool = True):
         """``block_step(*pools, blocks, page_indices, starts, active,
-        reveal[, prev, src])``: one diffusion step of a fixed-width batch
-        of blocks (a model with a ``block_length``).  ``blocks`` [W, B]
-        int32 ids, −1 where a position is masked; ``starts`` [W] each
-        row's block's first position (its K/V are written at starts …
-        starts + B, into pages the table covers); ``reveal`` [W] how many
-        masked positions the step reveals, 0 for the commit.  ``prev`` is
-        an earlier block step of the same width, collected or not, and
+        reveal[, commit[, prev, src]])``: one diffusion step of a
+        fixed-width batch of blocks (a model with a ``block_length``).
+        ``blocks`` [W, B] int32 ids, −1 where a position is masked;
+        ``starts`` [W] each row's first position (its block's, or the
+        finished block's where the row commits); ``reveal`` [W]
+        how many masked positions of the row's current block the step
+        reveals; ``commit`` [W] (all false where not given) the rows
+        whose ``blocks`` row is a finished block: the step writes its
+        K/V from its final ids and takes the next block's first step,
+        that block all masked, so its K/V are written at starts …
+        starts + 2B where another row's are at starts … starts + B, into
+        pages the table covers; every active row reads through starts +
+        2B, so its table holds a page id (the scratch page past its own
+        pages) that far (:func:`_block_impl`).  ``prev`` is an
+        earlier block step of the same width, collected or not, and
         ``src`` [W] says per row which of its blocks the row is fed (−1:
-        the host's ``blocks``).  → (the blocks after the step [W, B],
-        each position's confidence [W, B] (:func:`_reveal`), the logits
-        [W, B, V], a device array, the pools, the routed counts as
-        :meth:`collect_decode`'s); ``collect=False``
+        the host's ``blocks``).  → (the current blocks after the step
+        [W, B], each position's confidence [W, B] (:func:`_reveal`), the
+        logits [W, B, V], a device array, the pools, the routed counts
+        as :meth:`collect_decode`'s); ``collect=False``
         (:meth:`launch_block_step`) returns once the step is queued."""
-        pools, (blocks, page_indices, starts, active, reveal, *feed) = \
+        pools, (blocks, page_indices, starts, active, reveal, *more) = \
             self._pools_of(args)
-        prev, src = feed or (None, None)
+        enforce(len(more) in (0, 1, 3), "block_step takes commit, or "
+                f"commit, prev and src, after reveal: {len(more)} more")
         w, bl = np.shape(blocks)
+        commit, *feed = more or (np.zeros((w,), bool),)
+        prev, src = feed or (None, None)
         if prev is None:       # the program's shapes, fed by nobody
             counts = 2 if self.routed_layers else 0
             ids = np.zeros((2 * w * bl + counts,), np.int32)
@@ -1490,7 +1517,7 @@ class DecoderModel:
                 jnp.asarray(src, jnp.int32),
                 jnp.asarray(page_indices, jnp.int32),
                 jnp.asarray(starts, jnp.int32), jnp.asarray(active, bool),
-                jnp.asarray(reveal, jnp.int32))
+                jnp.asarray(reveal, jnp.int32), jnp.asarray(commit, bool))
             for pool, array in zip(pools, arrays):
                 pool.array = array
         if not collect:

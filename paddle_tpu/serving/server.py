@@ -57,27 +57,30 @@ row:
 **A model that generates by diffusion over blocks** (``block_length``
 B > 0) takes **block launches** where another takes decode launches:
 every row of one computes its request's current block, B positions,
-and either reveals the share of its masked positions that the static
-schedule gives the step (``model.reveal_schedule``: which positions is
-the device's choice) or, once every position is revealed, **commits**
-the block: one more pass that writes its K/V from its final ids.  A
-prompt of L ids is prefilled through its first ⌊L/B⌋·B positions; the
-L mod B ids left ride, unmasked, in the first block.  The schedule is
-static, so the host knows each row's phase without reading the device:
-a row's block comes from the host where it starts (all masked, or the
-prompt's last ids) and from the block launch it is queued behind
-otherwise (the block the device left, ``src``), and a row's prefill in
-flight holds nothing it needs, so it joins the launch queued behind
-that prefill.  A block's tokens are emitted when its last denoising
-step is collected, in order, up to an EOS or the budget, and the
-request ends there: a request ends by its budget at the end of the
-block that reaches it, which takes no commit (nothing reads its K/V),
-and by an EOS one launch late, its commit riding dead as a row past its
-EOS does.  Pages cover the prompt and the budget rounded up to B.
-Each request keeps the record of its finished blocks
-(``Request.blocks``: the block as each denoising step found it and as
-the last left it, whether it was committed), which is what a reference
-replays.
+and reveals the share of its masked positions that the static schedule
+gives the step (``model.reveal_schedule``: which positions is the
+device's choice).  Once every position of a block is revealed, the
+block is **committed**: its K/V are written once more from its final
+ids, in the same row as the next block's first step (the row's tile
+holds both, 2B positions under the block-causal mask), so a block of
+two denoising steps takes two rows of two launches, and a commit never
+takes a row of its own.  A prompt of L ids is prefilled through its
+first ⌊L/B⌋·B positions; the L mod B ids left ride, unmasked, in the
+first block.  The schedule is static, so the host knows each row's
+phase without reading the device: a row's block comes from the host
+where it starts (all masked, or the prompt's last ids) and from the
+block launch it is queued behind otherwise (the block the device left,
+``src``), and a row's prefill in flight holds nothing it needs, so it
+joins the launch queued behind that prefill.  A block's tokens are
+emitted when its last denoising step is collected, in order, up to an
+EOS or the budget, and the request ends there: a request ends by its
+budget at the end of the block that reaches it, which takes no commit
+(nothing reads its K/V), and by an EOS one launch late, the row that
+carries its commit riding dead as a row past its EOS does.  Pages
+cover the prompt and the budget rounded up to B.  Each request keeps
+the record of its finished blocks (``Request.blocks``: the block as
+each denoising step found it and as the last left it, whether it was
+committed), which is what a reference replays.
 
 The kill switch ``--serve_continuous=false`` degrades the same loop to
 sequential single-request serving (admit one, run to completion, batch
@@ -129,10 +132,14 @@ the step attends over — and ``live_pages`` the pages they occupy;
 the layers that attend (a window layer reads a row's newest ``window``
 only, a conv layer none);
 ``batch`` the rows whose token was emitted (``discarded`` the others).
-A block launch also states ``block_rows`` (the rows it computes),
-``commit_rows`` (those that commit), ``revealed`` (the positions its
-denoising rows reveal) and ``emitted`` (the tokens its collect emits);
-there ``batch`` is the rows still live when it was collected, and
+A block launch also states ``block_rows`` (the rows it computes, each a
+denoising step), ``commit_rows`` (those whose launch carries their
+previous block's commit as well), ``revealed`` (the positions its rows
+reveal) and ``emitted`` (the tokens its collect emits); there ``batch``
+is the rows still live when it was collected, ``live_tokens`` and
+``attended_tokens`` count each row up to its tile's end, 2B from its
+start (what the kernel reads; a row that does not commit has B dead
+positions there), and
 ``serve_block_passes_total{kind=denoise|commit}`` and the gauge
 ``serve_tokens_per_launch`` count the same.
 Where the plan has conv layers, ``conv_tokens`` is the prompt tokens
@@ -308,9 +315,9 @@ class _Launch:
     from which it takes its id on the device (−1: from the host),
     ``attrs`` what its span states, ``handle`` the model's launch once
     queued.  A block launch (a decode step of a model with a
-    ``block_length``) has ``passes``: per row (block start, positions it
-    reveals (0: the commit), whether it finishes the block, the block it
-    starts from or None)."""
+    ``block_length``) has ``passes``: per row (its tile's start, the
+    positions it reveals, whether it finishes its block, the block it
+    starts from or None, whether it commits the block before it)."""
 
     __slots__ = ("kind", "rows", "src", "attrs", "handle", "passes")
 
@@ -459,9 +466,9 @@ class InferenceServer:
             "= a launch the host had not collected yet, idle = nothing")
         self._m_passes = None if _counter is None else _counter(
             "serve_block_passes_total",
-            "rows a block launch computed, by kind: denoise (reveals "
-            "positions of its block) | commit (writes a finished block's "
-            "K/V)")
+            "block launch rows, by kind: denoise (a row computed: it "
+            "reveals positions of its block) | commit (a row that also "
+            "writes the finished block before it's K/V)")
         self._m_emitted = None if _gauge is None else _gauge(
             "serve_tokens_per_launch",
             "tokens the most recent block launch emitted when collected")
@@ -995,8 +1002,9 @@ class InferenceServer:
 
     def _enter_block(self, r: Request, at: int, fresh: np.ndarray) -> None:
         """``r``'s block at ``at``, starting from ``fresh``: its
-        denoising steps, then its commit unless it is the request's last
-        block."""
+        denoising steps, then its commit (a 0) unless it is the
+        request's last block.  :meth:`_plan_blocks` never launches the
+        commit alone: it rides in the next block's first step."""
         last = self._positions(len(r.prompt), r.max_new_tokens) \
             - self.model.block_length
         r.at, r.fresh = at, fresh
@@ -1007,9 +1015,12 @@ class InferenceServer:
 
     def _plan_blocks(self) -> Optional[_Launch]:
         """One block launch: every active row with a pass to go takes
-        its next one.  A row's block comes from the host where the block
-        starts or where the launch before it was collected, and else
-        from the launch in flight, on the device."""
+        its next one, a row whose next pass is its block's commit in
+        one row with the next block's first denoising step.  A row's
+        block comes from the host where the block starts or where the
+        launch before it was collected, and else from the launch in
+        flight, on the device; a committing row's finished block comes
+        from there, and the block after it is masked on the device."""
         b = self.model.block_length
         behind = self._inflight[-1] if self._inflight else None
         flying = {} if behind is None or behind.kind != "decode" \
@@ -1018,13 +1029,17 @@ class InferenceServer:
         for r in self._active:
             if not r.todo:              # its last pass is launched
                 continue
+            start, fed = r.at, r.fresh
+            src.append(-1 if fed is not None else flying.get(id(r), -1))
             reveal = r.todo.pop(0)
-            finishes = reveal > 0 and (not r.todo or r.todo[0] == 0)
-            passes.append((r.at, reveal, finishes, r.fresh))
-            rows.append(r)
-            src.append(-1 if r.fresh is not None
-                       else flying.get(id(r), -1))
+            commit = reveal == 0
+            if commit:                  # _enter_block leaves a next block
+                self._enter_block(r, r.at + b, np.full((b,), -1, np.int32))
+                reveal = r.todo.pop(0)
             r.fresh = None
+            finishes = not r.todo or r.todo[0] == 0
+            passes.append((start, reveal, finishes, fed, commit))
+            rows.append(r)
             if not r.todo and r.at + b < self._positions(
                     len(r.prompt), r.max_new_tokens):
                 self._enter_block(r, r.at + b, np.full((b,), -1, np.int32))
@@ -1032,15 +1047,15 @@ class InferenceServer:
             return None
         enforce(len(rows) <= self._width,
                 f"active {len(rows)} exceeds batch width {self._width}")
-        fed = [at + b for at, *_ in passes]
-        reveals = [p[1] for p in passes]
+        # each row's kernel length: its tile of 2B, from its start
+        fed = [at + 2 * b for at, *_ in passes]
         return _Launch("decode", rows, dict(
             batch=len(rows), live_tokens=sum(fed),
             live_pages=sum(map(self.pool.pages_needed, fed)),
             attended_tokens=self.model.attended_tokens(fed), state_rows=0,
             queued=self._queued(), block_rows=len(rows),
-            commit_rows=reveals.count(0), revealed=sum(reveals)), src,
-            passes)
+            commit_rows=sum(p[4] for p in passes),
+            revealed=sum(p[1] for p in passes)), src, passes)
 
     def _launch(self, launch: _Launch) -> None:
         """Build the launch's inputs and queue it on the device."""
@@ -1115,20 +1130,22 @@ class InferenceServer:
             starts = np.zeros((w,), np.int32)
             active = np.zeros((w,), bool)
             reveal = np.zeros((w,), np.int32)
+            commit = np.zeros((w,), bool)
             tables = np.full((w, self.max_pages), SCRATCH_PAGE, np.int32)
-            for i, (r, (at, rv, _, fed)) in enumerate(
+            for i, (r, (at, rv, _, fed, cm)) in enumerate(
                     zip(rows, launch.passes)):
                 if launch.src[i] < 0:
                     blocks[i] = r.block if fed is None else fed
                 starts[i], reveal[i], tables[i] = at, rv, r.table
-                r.length = at + b
+                commit[i] = cm
+                r.length = at + b * (1 + cm)
             src[:n] = launch.src
             active[:n] = True
         if self._m_batch is not None:
             self._m_batch.set(n)
         if self._m_passes is not None:
+            self._m_passes.inc(n, kind="denoise")
             commits = launch.attrs["commit_rows"]
-            self._m_passes.inc(n - commits, kind="denoise")
             if commits:
                 self._m_passes.inc(commits, kind="commit")
         # rows with ``src`` >= 0 take their blocks from the launch this
@@ -1136,7 +1153,8 @@ class InferenceServer:
         prev = self._inflight[-1].handle if max(launch.src) >= 0 else None
         self._marks.append(("dispatch", time.perf_counter()))
         launch.handle = self.model.launch_block_step(
-            *self._pools, blocks, tables, starts, active, reveal, prev, src)
+            *self._pools, blocks, tables, starts, active, reveal, commit,
+            prev, src)
         self._inflight.append(launch)
 
     def _collect(self, step) -> None:
@@ -1184,24 +1202,26 @@ class InferenceServer:
     def _emit_blocks(self, launch: _Launch, after: np.ndarray,
                      conf: np.ndarray, step) -> None:
         """A block launch's rows as collected: each live row's block
-        becomes what the step left (a denoising step's is kept in the
-        block's states, with its confidences), a commit marks its block
-        committed, and a block
-        its last denoising step finished is logged and its tokens
+        becomes what the step left (kept in the block's states, with its
+        confidences), a commit marks the block the row finished
+        committed (collects are in launch order: the newest logged) and
+        starts the new block's states from the all-masked block, and a
+        block its last denoising step finished is logged and its tokens
         emitted."""
+        b = self.model.block_length
         emitted = live = 0
         for i, r in enumerate(launch.rows):
             if r.state != "active":
                 continue
             live += 1
-            at, reveal, finishes, fed = launch.passes[i]
+            at, _, finishes, fed, commit = launch.passes[i]
+            if commit:
+                r.blocks[-1]["committed"] = True
+                at, fed = at + b, np.full((b,), -1, np.int32)
             if fed is not None:
                 r.states, r.confs = [fed], []
-            if reveal:
-                r.states.append(after[i].copy())
-                r.confs.append(conf[i].copy())
-            else:
-                r.blocks[-1]["committed"] = True
+            r.states.append(after[i].copy())
+            r.confs.append(conf[i].copy())
             r.block = after[i].copy()
             if finishes:
                 emitted += self._emit_block(r, at)

@@ -131,8 +131,8 @@ def test_prefill_then_block_steps_through_pages_are_the_reference(bench):
         active = np.arange(width) < 4
         launch = model.launch_block_step(
             k, v, fed, tables, at, active,
-            np.array(reveal + [0, 0], np.int32), prev,
-            np.array(src + [-1, -1], np.int32))
+            np.array(reveal + [0, 0], np.int32), np.zeros(width, bool),
+            prev, np.array(src + [-1, -1], np.int32))
         if prev is not None:
             model.collect_block_step(prev)
         want = _reference_steps(bench, [
@@ -154,6 +154,86 @@ def test_prefill_then_block_steps_through_pages_are_the_reference(bench):
     assert model.attn_pairs([8, 13]) \
         == model.cfg.layers * (16 * 3 + 16 * 6 + 1 * 13)
     assert sizes["block_length"] == B
+
+
+def _pools_equal(a, b):
+    for x, y in zip(a, b):
+        np.testing.assert_allclose(np.asarray(x.array), np.asarray(y.array),
+                                   rtol=0, atol=1e-5)
+
+
+def test_a_fused_launch_is_a_commit_then_a_first_step(bench):
+    """Four rows at width 6 through two sets of pools, prefilled alike.
+    Rows 0-2 take their first block's two steps, row 3 one launch late;
+    row 2's block is its last and takes no commit.  Then, split: a
+    launch of commits (reveal 0) for rows 0 and 1 beside row 3's second
+    step, and a launch of their next blocks' first steps; fused: one
+    launch in which rows 0 and 1 commit and step, beside row 3's second
+    step, row 0's finished block fed from the launch it is queued
+    behind and row 1's from the host.  Both then take the second steps,
+    fed on the device.  The ids, the confidences and the pages agree."""
+    model = _model(bench)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(2, 255, n).tolist() for n in (8, 12, 16, 8)]
+    width, slots, page = 6, 12, 4
+    tables = np.zeros((width, slots), np.int32)
+    tables[:4] = 1 + rng.permutation(4 * slots).reshape(4, slots)
+    starts = np.zeros(width, np.int32)
+    starts[:4] = [len(p) for p in prompts]
+    tokens = np.zeros((4, 16), np.int32)
+    for i, p in enumerate(prompts):
+        tokens[i, :len(p)] = p
+    sets = [model.new_pools(1 + 4 * slots, page) for _ in range(2)]
+    for pools in sets:
+        model.prefill(*pools, tokens, starts[:4], tables[:4])
+    masked = np.full((width, B), -1, np.int32)
+    rows = lambda *r: np.isin(np.arange(width), r)
+
+    def step(pools, live, reveal, src=(), prev=None, fed=masked, at=starts,
+             commit=rows()):
+        pad = lambda a, v: np.array(list(a) + [v] * (width - len(a)),
+                                    np.int32)
+        return model.launch_block_step(
+            *pools, fed, tables, at, live, pad(reveal, 0), commit, prev,
+            pad(src, -1))
+
+    seconds = []
+    for pools in sets:
+        first = step(pools, rows(0, 1, 2), [2, 2, 2])
+        seconds.append(step(pools, rows(0, 1, 2, 3), [2, 2, 2, 2],
+                            [0, 1, 2, -1], first))
+        model.collect_block_step(first)
+        done = model.collect_block_step(seconds[-1])[0]
+    assert (done[:3] >= 0).all() and (done[3] >= 0).sum() == 2
+    fed = masked.copy()
+    fed[1] = done[1]                    # row 1's finished block: the host's
+    # split
+    commits = step(sets[0], rows(0, 1, 3), [0, 0, 0, 2], [0, -1, -1, 3],
+                   seconds[0], fed)
+    split = step(sets[0], rows(0, 1), [2, 2], at=starts + B)
+    after_c, conf_c = model.collect_block_step(commits)[:2]
+    after_s, conf_s = model.collect_block_step(split)[:2]
+    again_a = step(sets[0], rows(0, 1), [2, 2], [0, 1], split,
+                   at=starts + B)
+    # fused
+    fused = step(sets[1], rows(0, 1, 3), [2, 2, 0, 2], [0, -1, -1, 3],
+                 seconds[1], fed, commit=rows(0, 1))
+    after_f, conf_f = model.collect_block_step(fused)[:2]
+    again_b = step(sets[1], rows(0, 1), [2, 2], [0, 1], fused,
+                   at=starts + B)
+    np.testing.assert_array_equal(after_c[:2], done[:2])   # nothing revealed
+    np.testing.assert_array_equal(after_f[:2], after_s[:2])
+    np.testing.assert_allclose(conf_f[:2], conf_s[:2], rtol=0, atol=1e-5)
+    np.testing.assert_array_equal(after_f[3], after_c[3])
+    np.testing.assert_allclose(conf_f[3], conf_c[3], rtol=0, atol=1e-5)
+    assert ((after_f[:2] >= 0).sum(axis=1) == 2).all()
+    assert (after_f[3] >= 0).all()
+    (after_a, conf_a), (after_b, conf_b) = (
+        model.collect_block_step(h)[:2] for h in (again_a, again_b))
+    np.testing.assert_array_equal(after_b[:2], after_a[:2])
+    np.testing.assert_allclose(conf_b[:2], conf_a[:2], rtol=0, atol=1e-5)
+    assert (after_b[:2] >= 0).all()
+    _pools_equal(*sets)
 
 
 def _served(server, prompts, budgets):
@@ -222,6 +302,66 @@ def test_a_server_emits_what_the_reference_reveals(bench):
     assert again == tokens[:cut] and cut < len(tokens)
     seen = lambda rec: [b["states"] for b in rec.blocks]
     assert seen(r2) == seen(r)[:len(r2.blocks)]
+
+
+def test_a_commit_rides_in_the_next_blocks_first_step(bench, monkeypatch):
+    """Through the server: every row a block launch computes reveals at
+    least one position, so no launch holds a commit alone; every block
+    but a request's last is committed, each commit in a row of its own
+    launch's denoising, and a block of two steps takes two rows: the
+    rows computed per token emitted (``row_passes_per_token``) come to
+    0.5 over whole blocks."""
+    launched = []
+    real = InferenceServer._launch_blocks
+
+    def launch(self, lch):
+        launched.append([(p[1], p[4]) for p in lch.passes])
+        return real(self, lch)
+
+    monkeypatch.setattr(InferenceServer, "_launch_blocks", launch)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(2, 255, n).tolist() for n in (8, 12, 16, 4, 20)]
+    budgets = [32, 24, 40, 16, 28]
+    flat = lambda: observe.REGISTRY.flat(kinds=("counter",))
+    key = 'serve_block_passes_total{kind="%s"}'
+    before = {k: flat().get(key % k, 0.0) for k in ("denoise", "commit")}
+    with InferenceServer(_model(bench, eos_id=1), max_batch=4, n_pages=96,
+                         page_size=4) as server:
+        served = _served(server, prompts, budgets)
+        generated = server.generated_tokens
+    assert [len(t) for t, _ in served] == budgets
+    assert all(r.blocks[-1]["committed"] is False
+               and all(b["committed"] for b in r.blocks[:-1])
+               for _, r in served)
+    passes = [p for lch in launched for p in lch]
+    assert min(reveal for reveal, _ in passes) >= 1
+    commits = sum(commit for _, commit in passes)
+    assert commits == sum(len(r.blocks) - 1 for _, r in served)
+    # commits beside rows that commit nothing
+    assert any(any(c for _, c in lch) and not all(c for _, c in lch)
+               for lch in launched)
+    after = {k: flat()[key % k] - before[k] for k in before}
+    assert after == {"denoise": len(passes), "commit": commits}
+    assert generated == sum(budgets)
+    assert len(passes) / generated <= 0.52
+    assert len(passes) == 2 * sum(len(r.blocks) for _, r in served)
+    # an EOS in the third block of a request of nine: the row that
+    # carries its commit and the fourth block's first step was queued
+    # before the host saw it, and is discarded
+    tokens, r = served[2]
+    eos = tokens[9]
+    cut = tokens.index(eos) + 1
+    dropped = flat().get("serve_rows_discarded_total", 0.0)
+    launched.clear()
+    with InferenceServer(_model(bench, eos_id=eos), max_batch=4,
+                         n_pages=96, page_size=4) as server:
+        (again, r2), = _served(server, prompts[2:3], budgets[2:3])
+    assert again == tokens[:cut] and cut <= 12
+    assert [b["states"] for b in r2.blocks] \
+        == [b["states"] for b in r.blocks[:len(r2.blocks)]]
+    assert not r2.blocks[-1]["committed"]
+    assert flat()["serve_rows_discarded_total"] - dropped == 1
+    assert launched[-1] == [(2, True)]
 
 
 def test_softmax_routing_is_a_plain_top_k(bench):

@@ -513,40 +513,63 @@ def _decode_case(case, rng):
             jnp.asarray(lengths, jnp.int32), jnp.asarray(safe), window)
 
 
+@pytest.mark.parametrize("t_q", [4, 8])
 @pytest.mark.parametrize("case", ["base", "wide_garbage", "page_boundary",
                                   "inactive_rows", "gqa", "hd2048"])
-def test_paged_decode_block_mode_matches_dense_reference(case, rng):
-    """The block mode: every query of a 4-row tile sees every position up
-    to the tile's end, through the same pages as the causal tail (whose
-    first query it differs from) and under the same guards; the dense
-    reference's block mode is the plain attention over the row's
-    positions."""
+def test_paged_decode_block_mode_matches_dense_reference(case, t_q, rng):
+    """The block mode in blocks of 4, over a tile of 4 queries or of 8
+    (a diffusion step's tile of 2B: where its length, start + 8, puts
+    its start on a block's start, it holds a finished block and the
+    next, and the first never sees the second): each query sees every
+    position up to the end of its own block of 4 (counted from position
+    0), never past the row's length, through the same pages as the
+    causal tail and under the same guards; a tile that ends on a block's
+    end sees up to it whole.  The dense reference's block mode is the
+    plain attention over the positions each query sees; every row as
+    long as the tile is held to it query by query."""
     H, D, kpg, vpg, pidx, lengths, safe, _ = _decode_case(case, rng)
-    q = jnp.asarray(rng.randn(pidx.shape[0], 4, H, D).astype(np.float32))
+    q = jnp.asarray(rng.randn(pidx.shape[0], t_q, H, D).astype(np.float32))
     out = np.asarray(pa.paged_decode_attention(q, kpg, vpg, pidx, lengths,
-                                               block=True))
+                                               block=4))
     ref = np.asarray(pa.paged_decode_reference(q, kpg, vpg, safe, lengths,
-                                               block=True))
+                                               block=4))
     assert np.isfinite(out).all()
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
-    row = int(np.argmax(np.asarray(lengths)))
-    n, page = int(lengths[row]), kpg.shape[1]
-    keys = lambda pool: np.asarray(pool)[np.asarray(safe)[row]].reshape(
-        -1, *pool.shape[2:])[:n].reshape(n, -1, D)
-    kr, vr = (np.repeat(keys(pool), H // keys(pool).shape[1], axis=1)
-              for pool in (kpg, vpg))
-    sc = np.einsum("qhd,khd->hqk", np.asarray(q[row]), kr,
-                   dtype=np.float64) / np.sqrt(D)
-    w = np.exp(sc - sc.max(axis=-1, keepdims=True))
-    dense = np.einsum("hqk,khd->qhd", w / w.sum(axis=-1, keepdims=True), vr)
-    np.testing.assert_allclose(out[row], dense, rtol=2e-4, atol=2e-5)
     tail = np.asarray(pa.paged_decode_attention(q, kpg, vpg, pidx, lengths))
-    assert np.abs(tail[row, 0] - out[row, 0]).max() > 1e-3
-    np.testing.assert_allclose(tail[row, 3], out[row, 3], rtol=2e-4,
-                               atol=2e-5)
+    held = 0
+    for row, n in enumerate(np.asarray(lengths).tolist()):
+        if n < t_q:
+            continue
+        keys = lambda pool: np.asarray(pool)[np.asarray(safe)[row]].reshape(
+            -1, *pool.shape[2:])[:n].reshape(n, -1, D)
+        kr, vr = (np.repeat(keys(pool), H // keys(pool).shape[1], axis=1)
+                  for pool in (kpg, vpg))
+        sc = np.einsum("qhd,khd->hqk", np.asarray(q[row]), kr,
+                       dtype=np.float64) / np.sqrt(D)
+        pos = n - t_q + np.arange(t_q)
+        sees = np.minimum(pos | 3, n - 1)
+        if t_q == 8 and n % 4 == 0:       # a finished block and the next
+            assert (sees[:4] == n - 5).all() and (sees[4:] == n - 1).all()
+        sc = np.where(np.arange(n)[None, None, :] <= sees[None, :, None],
+                      sc, -np.inf)
+        w = np.exp(sc - sc.max(axis=-1, keepdims=True))
+        dense = np.einsum("hqk,khd->qhd", w / w.sum(axis=-1, keepdims=True),
+                          vr)
+        np.testing.assert_allclose(out[row], dense, rtol=2e-4, atol=2e-5)
+        # the causal tail: the same where a query's block ends at itself
+        for t in range(t_q):
+            if sees[t] == pos[t]:
+                np.testing.assert_allclose(tail[row, t], out[row, t],
+                                           rtol=2e-4, atol=2e-5)
+            else:
+                assert np.abs(tail[row, t] - out[row, t]).max() > 1e-3
+        held += 1
+    assert held
     with pytest.raises(PaddleTpuError, match="no window"):
         pa.paged_decode_attention(q, kpg, vpg, pidx, lengths, window=8,
-                                  block=True)
+                                  block=4)
+    with pytest.raises(PaddleTpuError, match="power of two"):
+        pa.paged_decode_attention(q, kpg, vpg, pidx, lengths, block=3)
 
 
 @pytest.mark.parametrize("t_q", [1, 4])
@@ -688,8 +711,9 @@ CELL_CALLS = {
     "lfm2": ((16, 1, 32, 64), 8, 64, "bfloat16", 128, 0),
     "jamba": ((16, 1, 20, 128), 1, 64, "bfloat16", 260, 0),
     "latent": ((16, 32, 640), 0, 64, "bfloat16", 128, 0),
-    # the block mode: 16 rows of a block of 4 queries (SDAR's)
-    "sdar": ((16, 4, 32, 128), 4, 64, "bfloat16", 64, 0),
+    # the block mode: 16 rows of a tile of 8 queries in blocks of 4
+    # (SDAR's: a block and the one it commits)
+    "sdar": ((16, 8, 32, 128), 4, 64, "bfloat16", 64, 0),
 }
 
 
@@ -741,7 +765,7 @@ def test_the_rule_gives_8_pages_at_the_cells_shapes_and_mosaic_takes_them(
         shapes = [(q_shape, "float32"), ((2048, page, w), dtype),
                   ((2048, page, w), dtype)]
         call = lambda q, k, v, t, n: pa.paged_decode_attention(
-            q, k, v, t, n, window=window, block=cell == "sdar",
+            q, k, v, t, n, window=window, block=4 if cell == "sdar" else 0,
             name="block_decode" if cell == "sdar" else "paged_decode")
     monkeypatch.setattr(pa, "pallas_interpret", lambda: False)
     shapes += [((b, slots), "int32"), ((b,), "int32")]
