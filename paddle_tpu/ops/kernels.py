@@ -60,6 +60,9 @@ BLOCK_DECODE = "block_decode"
 #: decode over a latent cache: one compressed row a token serves every
 #: query head as its key and, in its leading lanes, as its value
 LATENT_DECODE = "latent_decode"
+#: a chunk of a prompt that continues a prefill: its queries see every
+#: position of their row up to their own, through the row's pages
+CHUNK_PREFILL = "chunk_prefill"
 # ops/pallas_ssm.py — Mamba's selective scan over whole prompts
 SSM_SCAN = "ssm_scan"
 # ops/pallas_moe.py

@@ -1946,6 +1946,141 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, page_indices,
                             counts))
 
 
+# ------------------------------------------------- a chunk of a prompt
+#: keys a grid step of the chunk kernel takes at most: whole pages
+CHUNK_KEYS = 512
+
+
+def _chunk_kernel(at_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
+                  scale, block_q, block_k):
+    """Grid (head blocks, query blocks, key blocks), keys innermost: a
+    query block's online softmax carries in VMEM scratch across the key
+    blocks, as :func:`_fa_pair_kernel`'s does, its heads looped in the
+    step over one K and V block (a group's, or each head's own).  The
+    query at row t of the chunk sits at ``at[0] + t`` and sees every
+    key at or before it, never one at or past ``at[0] + at[1]``; a key
+    block past a query block's newest key is neither computed nor,
+    by the clamped index map, fetched."""
+    qb, kb = pl.program_id(1), pl.program_id(2)
+    start, n = at_ref[0], at_ref[1]
+    q_off = start + qb * block_q
+    newest = jnp.minimum(q_off + block_q, start + n) - 1
+    heads, d_v = acc_s.shape[0], acc_s.shape[2]
+
+    @pl.when(kb == 0)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    @pl.when(kb * block_k <= newest)
+    def _step():
+        qi = q_off + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k),
+                                              0)
+        ki = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        seen = (ki <= qi) & (ki < start + n)
+
+        def one_head(hh, _):
+            kh = hh if k_ref.shape[0] > 1 else 0
+            s = jax.lax.dot_general(
+                q_ref[hh], k_ref[kh], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = jnp.where(seen, s, NEG_INF)
+            m_prev = m_s[hh]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            m_base = jnp.maximum(m_new, NEG_INF / 2)
+            p = jnp.exp((s - _lanes(m_base, block_k)) * scale)
+            alpha = jnp.exp((m_prev - m_base) * scale)
+            m_s[hh] = m_new
+            l_s[hh] = l_s[hh] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_s[hh] = acc_s[hh] * _lanes(alpha, d_v) + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[kh], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return 0
+
+        jax.lax.fori_loop(0, heads, one_head, 0)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _flush():
+        def one_head(hh, _):
+            l = l_s[hh]
+            o_ref[hh] = (acc_s[hh] / _lanes(jnp.where(l == 0.0, 1.0, l), d_v)
+                         ).astype(o_ref.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, heads, one_head, 0)
+
+
+def chunk_prefill_attention(q, k_pages, v_pages, page_indices, at):
+    """Attention of a chunk of one prompt that continues its prefill:
+    every position of its row before the chunk is in the pages already,
+    and the chunk's own K/V are written there first.
+
+    - ``q``: ``[C, H, D]``, the chunk's queries at positions ``at[0]``
+      … ``at[0] + C − 1``, the first ``at[1]`` of them the prompt's
+      (those past it see what a query at their place would, and are the
+      caller's to drop);
+    - ``k_pages`` / ``v_pages``: ``[P, page, G·D]`` as the server stores
+      them; ``page_indices``: int32 ``[max_pages]``, the row's table;
+    - ``at``: int32 ``[2]``, (the chunk's start, its real positions).
+
+    The row's pages are gathered whole, ``[G, max_pages·page, D]`` (8.5
+    MB a layer at 16,640 positions of one K/V head of 128 in bf16), and
+    go through :func:`_chunk_kernel` in blocks of :data:`CHUNK_KEYS`
+    keys: query t sees position p iff p ≤ at[0] + t and p < at[0] +
+    at[1].  Returns ``[C, H, D]`` in q's dtype.  Its work is counted at
+    the table's capacity, 4·C·H·D FLOPs a position (what is live is the
+    caller's span's to state)."""
+    c, h, d = q.shape
+    page, n_pages = k_pages.shape[1], page_indices.shape[0]
+    gd = math.prod(k_pages.shape[2:])
+    g = gd // d
+    enforce(g >= 1 and h % g == 0 and v_pages.shape == k_pages.shape,
+            f"page pools {k_pages.shape}/{v_pages.shape}: G·{d} lanes a "
+            f"row with G dividing the {h} query heads")
+    per = max(1, min(CHUNK_KEYS // page, n_pages))   # pages a key block
+    blocks = -(-n_pages // per)
+    # the pad's pages lie past every length: masked, never a NaN source
+    table = jnp.pad(page_indices.astype(jnp.int32), (0, blocks * per
+                                                     - n_pages))
+    bk, tk = per * page, blocks * per * page
+    k, v = (a[table].reshape(tk, g, d).transpose(1, 0, 2)
+            for a in (k_pages, v_pages))                    # [G, Tk, D]
+    bq = _choose_block(c, 512)
+    rep = h // g
+    n = _heads_per_step(h, rep, bq, bk, d, d, q.dtype.itemsize)
+    n_kv = 1 if rep > 1 else n
+    K.record_kernel_work(K.CHUNK_PREFILL, 4.0 * c * h * d * tk, (q, k, v),
+                         (q,))
+
+    def kv_idx(i, j, kb, at_ref):
+        last = (jnp.minimum(at_ref[0] + (j + 1) * bq,
+                            at_ref[0] + at_ref[1]) - 1) // bk
+        return (i if rep == 1 else i * n // rep, jnp.minimum(kb, last), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, scale=1.0 / np.sqrt(d),
+                          block_q=bq, block_k=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h // n, c // bq, blocks),
+            in_specs=[pl.BlockSpec((n, bq, d), lambda i, j, kb, _: (i, j, 0)),
+                      pl.BlockSpec((n_kv, bk, d), kv_idx),
+                      pl.BlockSpec((n_kv, bk, d), kv_idx)],
+            out_specs=pl.BlockSpec((n, bq, d), lambda i, j, kb, _: (i, j, 0)),
+            scratch_shapes=[pltpu.VMEM((n, bq, _LANES), jnp.float32),
+                            pltpu.VMEM((n, bq, _LANES), jnp.float32),
+                            pltpu.VMEM((n, bq, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((h, c, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=pallas_interpret(),
+        name=K.CHUNK_PREFILL,
+    )(at.astype(jnp.int32), q.transpose(1, 0, 2), k, v)
+    return out.transpose(1, 0, 2)
+
+
 # ------------------------------------------------- latent-cache decode
 def _latent_decode_kernel(len_ref, pidx_ref, live_ref, q_ref, pages_hbm,
                           o_ref, buf, sem, m_s, l_s, acc_s, *, scale,

@@ -39,7 +39,14 @@ The two entry points mirror the two serving kernels from PR 14/15:
   row whose block is finished **commits** it in the same pass as the
   next block's first step: its tile holds the finished block, whose K/V
   it writes once more from its final ids, and the next block, all
-  masked, under the block-causal mask.  Its prefill is block-causal.
+  masked, under the block-causal mask.  Its prefill is block-causal;
+- :meth:`DecoderModel.step_chunk` continues a prompt's prefill by one
+  chunk of :data:`CHUNK` positions where every layer of the plan can
+  (:func:`continues`: mamba layers from the state in the sequence's
+  slot, full attention over the row's pages), with a decode step's rows
+  riding in the same launch, one token each: every row goes through a
+  layer's products at once, so the weights are read once for both.
+  :meth:`DecoderModel.prefill` runs a prompt longer than a chunk so.
 
 Both update the pools **in place**: a step donates the stacked
 pools (:class:`KVPool` holds the one reference to each) and every layer
@@ -88,7 +95,8 @@ from ..layers.beam_search import eos_frozen_logits
 from ..observe.trace import span as _span
 from ..ops import kernels as K
 from ..ops import scopes as S
-from ..ops.pallas_attention import (flash_attention_packed,
+from ..ops.pallas_attention import (chunk_prefill_attention,
+                                    flash_attention_packed,
                                     latent_decode_attention,
                                     paged_decode_attention, paged_kv_write,
                                     paged_row_write, segments_from_lengths)
@@ -225,6 +233,34 @@ KINDS = frozenset({"full", "window", "latent", "conv", "mamba"})
 STATE_KINDS = ("conv", "mamba")
 ATTENTION_WORDS = KINDS | {"rope", "qknorm", "gate", "postnorm"}
 FFN_WORDS = frozenset({"gelu", "swiglu", "routed", "shared"})
+
+#: prompt positions a chunk launch takes, where the plan can continue a
+#: prefill from what a sequence holds (:func:`continues`).  Every
+#: decoding row rides in each chunk launch, one token a row, so the
+#: weights a decode step reads alone are read once for both.  From the
+#: Jamba cell's closed loop (PERF.md §6): a request costs the
+#: device 0.383 s of prefill and 0.166 s of decode; with decode riding,
+#: the device spends its time on chunks, ≈ 2.3–2.5 requests/s × 9,830
+#: prompt tokens ÷ c launches a second, and the output is the 9–11
+#: rows carried times that.  c = 512 gives ≈ 45 launches/s, room for
+#: 400–490 tokens/s; c = 1,024 caps it at ≈ 23 × 11 = 253, under the
+#: 291 of whole-prompt launches; c = 256 puts a launch's products at
+#: the chip's ridge (197 T / 819 G ≈ 240 FLOP a weight byte, a product
+#: of c rows doing c) and its 7.4 ms of weight reads would stop hiding
+#: under the compute.
+CHUNK = 512
+
+
+def continues(cfg: DecoderConfig) -> bool:
+    """Can every layer of the plan continue a prefill from what a
+    sequence already holds (its pages, its slot)?  A ``mamba`` layer
+    (its scan's state and the convolution's newest inputs) and a
+    ``full`` layer with per-head K/V can; so can the dense feed-forward,
+    row by row.  A window, a latent row, a conv or routed layer and a
+    model that generates by blocks are not taught to."""
+    return not cfg.block_length and all(
+        attn & KINDS <= {"full", "mamba"} and ffn & {"gelu", "swiglu"}
+        and "routed" not in ffn for attn, ffn in layer_plan(cfg))
 
 
 @functools.lru_cache(maxsize=None)
@@ -718,6 +754,47 @@ def _mamba_decode(x, win, hs, slot, active, params, i, cfg: DecoderConfig):
     return _mamba_out(x, y[:, None], z[:, None], params, i), win, hs
 
 
+def _mamba_chunk(x, win, hs, slot, at, slots, active, params, i,
+                 cfg: DecoderConfig):
+    """A mamba layer over a chunk launch's stream ``x`` [1, C + W, dim]:
+    C positions of one prompt from ``at[0]`` (``at[1]`` of them real),
+    whose sequence lies in ``slot``, then W rows one new token each, as
+    :func:`_mamba_decode` takes them (``slots``, ``active``).
+    ``in_proj`` and ``out_proj`` take every row at once (``x_proj`` and
+    ``dt_proj``, a few MB, take each kind apart: no copy of the chunk's
+    rows is made for them).  The chunk's convolution starts
+    from the newest ``conv_taps - 1`` u its slot holds and its scan from
+    the slot's state, both zeros where the chunk starts the prompt; what
+    the chunk ends in goes back into the slot, as :func:`_mamba_prefill`
+    leaves it.  → (stream, win, hs)."""
+    c, taps = x.shape[1] - slots.shape[0], cfg.conv_taps
+    first = at[0] == 0
+    u, z = _mamba_in(x, params, i, cfg)
+    up = jnp.concatenate(
+        [jnp.where(first, jnp.zeros_like(win[0]), win[slot])[None],
+         u[:, :c]], axis=1)
+    window = jnp.concatenate([win[slots], u[0, c:, None]], axis=1)
+    uc = _mamba_conv([up[:, j:j + c] for j in range(taps)], params, i)
+    uc_rows = _mamba_conv(jnp.moveaxis(window, 1, 0), params, i)
+    delta, bm, cm = _mamba_dbc(uc, params, i, cfg)
+    a, d = _mamba_a(params, i), params[f"l{i}.D"]
+    inside = jnp.arange(c)[None, :, None] < at[1]
+    h0 = jnp.where(first, jnp.zeros_like(hs[0]), hs[slot])[None]
+    y, h = selective_scan(uc, jnp.where(inside, delta, 0.0), a, bm, cm, d,
+                          h0)
+    dr, br, cr = _mamba_dbc(uc_rows, params, i, cfg)
+    h_rows, y_rows = ssm_step(hs[slots], uc_rows, dr, a, br, cr, d)
+    y = jnp.concatenate([y, y_rows[None]], axis=1)
+    x, kept, h = jax.lax.optimization_barrier(
+        (_mamba_out(x, y, z, params, i), _newest(up, at[1:], taps), h))
+    # the chunk's slot first, then the rows': one scatter a state
+    every = jnp.concatenate([slot[None], slots])
+    rows = jnp.concatenate([jnp.ones((1,), bool), active])
+    win = _keep(win, every, rows, jnp.concatenate([kept, window[:, 1:]]))
+    hs = _keep(hs, every, rows, jnp.concatenate([h, h_rows]))
+    return x, win, hs
+
+
 def _head(x, params, cfg: DecoderConfig):
     """The final norm and the logits [B, V], float32: times the
     embedding's transpose where the head is tied."""
@@ -988,6 +1065,74 @@ def _decode_impl(params, pools, tokens, page_indices, lengths, active,
         return ids, logits, *_as_stored(shapes, pools, states)
 
 
+def _chunk_impl(params, pools, chunk, at, table, slot, tokens, page_indices,
+                lengths, active, slots, cfg: DecoderConfig):
+    """One chunk launch of a plan that :func:`continues`: ``chunk`` [C]
+    ids of one prompt at positions ``at[0]`` … (``at[1]`` of them real,
+    the rest padding), its row's page table ``table`` [max_pages] and
+    ``slot``, beside a decode step's W rows (``tokens`` … ``slots``, as
+    :func:`_decode_impl` takes them, idle rows included).  Every row
+    goes through a layer's products as one [1, C + W] stream, so the
+    weights are read once a launch; then the chunk continues its
+    prefill (a mamba layer from its slot: :func:`_mamba_chunk`; a full
+    layer writes the chunk's K/V and attends the row's pages through
+    :func:`chunk_prefill_attention`) and the rows take their decode step
+    (``_mamba_decode``'s arithmetic, the paged decode kernel).  → (the
+    rows' [W] next tokens, [1] the token after the chunk's last real
+    position (the first generated one where the chunk ends the prompt),
+    the logits [W + 1, V] of both, the updated pools)."""
+    c, w = chunk.shape[0], tokens.shape[0]
+    h, g, dh = cfg.heads, kv_heads(cfg), head_dim(cfg)
+    with jax.named_scope(S.EMBED):
+        pos = jnp.concatenate([at[0] + jnp.arange(c, dtype=jnp.int32),
+                               jnp.clip(lengths - 1, 0,
+                                        cfg.max_context - 1)])[None]
+        x = _embed(params, jnp.concatenate([chunk, tokens])[None], pos, cfg)
+        counts = active.astype(jnp.int32)
+        klen = jnp.where(active, lengths, 1).astype(jnp.int32)
+        valid = jnp.concatenate([jnp.arange(c) < at[1], active])[None]
+    # as _decode_impl names the rows' kernel
+    name = K.PAGED_DECODE if cfg.plan else None
+    with jax.named_scope(S.CACHE_LAYOUT):
+        shapes, pools, states = _kv_and_state(pools, cfg)
+    n_places, n_slots = shapes[0][1], _slot_count(shapes, cfg)
+    a = m = 0
+    for i, (attn, ffn) in enumerate(layer_plan(cfg)):
+        if "mamba" in attn:
+            with jax.named_scope(S.SSM.format(i)):
+                x, *states["mamba"] = _mamba_chunk(
+                    x, *states["mamba"], slot + m * n_slots, at,
+                    slots + m * n_slots, active, params, i, cfg)
+            m += 1
+        else:
+            mine, rows = table + a * n_places, page_indices + a * n_places
+            a += 1
+            q, k, v, gate = _qkv(x, pos, params, i, cfg, attn)
+            with jax.named_scope(S.CACHE_WRITE.format(i)):
+                pools = paged_kv_write(*pools, k[:, :c], v[:, :c],
+                                       mine[None], at[:1], at[1:])
+                pools = paged_kv_write(*pools, k[0, c:, None], v[0, c:, None],
+                                       rows, lengths - 1, counts)
+            with jax.named_scope(S.ATTEND.format(i)):
+                o = chunk_prefill_attention(
+                    q[0, :c].astype(pools[0].dtype), *pools, mine, at)
+                o_rows = paged_decode_attention(
+                    q[0, c:, None], *pools, rows, klen, name=name)
+                o = jnp.concatenate([o.reshape(c, h * dh).astype(jnp.float32),
+                                     o_rows.reshape(w, h * dh)])[None]
+            with jax.named_scope(S.MIXER_OUT.format(i)):
+                x = _attend_out(x, o, gate, params, i, cfg, attn)
+        x, _ = _ffn(x, valid, params, i, cfg, attn, ffn)
+    with jax.named_scope(S.HEAD):
+        last = x[0, jnp.clip(at[1] - 1, 0, c - 1)][None]
+        logits = _head(jnp.concatenate([x[0, c:], last]), params, cfg)
+        live = jnp.concatenate([active, jnp.ones((1,), bool)])
+        nxt = jnp.argmax(eos_frozen_logits(logits, live, cfg.eos_id), -1)
+        nxt = nxt.astype(jnp.int32)
+    with jax.named_scope(S.CACHE_LAYOUT):
+        return nxt[:w], nxt[w:], logits, *_as_stored(shapes, pools, states)
+
+
 def reveal_schedule(masked: int, steps: int) -> Tuple[int, ...]:
     """How many of a block's ``masked`` positions each of ``steps``
     denoising steps reveals: ⌊m/steps⌋ + (s < m mod steps) at step s, a
@@ -1155,6 +1300,20 @@ def _fed_ids(tokens, prev, src):
 
 
 @functools.lru_cache(maxsize=None)
+def _jitted_chunk(cfg: DecoderConfig):
+    """The jitted :func:`_chunk_impl` of a config, shared as
+    :func:`_jitted_steps`' pair is; it takes ``(params, *pools, chunk,
+    at, table, slot, tokens, prev_ids, src, tables, lengths, active,
+    slots)``, the rows fed as a decode step's are (``prev`` the rows'
+    ids of the decode or chunk launch before), and donates the pools."""
+    n = n_pools(cfg)
+    return jax.jit(
+        lambda p, *a: _chunk_impl(p, a[:n], *a[n:n + 4],
+                                  _fed_ids(*a[n + 4:n + 7]), *a[n + 7:], cfg),
+        donate_argnums=tuple(range(1, 1 + n)))
+
+
+@functools.lru_cache(maxsize=None)
 def _jitted_block_step(cfg: DecoderConfig):
     """The jitted :func:`_block_impl` of a config, shared as
     :func:`_jitted_steps`' pair is; it takes ``(params, *pools, blocks,
@@ -1243,12 +1402,19 @@ class DecoderModel:
                     f"{shapes[name]}")
             self.params[name] = a.astype(_stored_as(name, a.shape, cfg))
         self._prefill, self._decode = _jitted_steps(cfg)
+        self._chunk = _jitted_chunk(cfg)
+        # prompt positions a chunk launch takes (CHUNK), or 0 where the
+        # plan cannot continue a prefill: every prompt one launch
+        self.chunk = CHUNK if continues(cfg) else 0
+        # rows of the decode batch a chunk launch carries (new_pools)
+        self.width = 1
         self.block_length = cfg.block_length
         self._block = _jitted_block_step(cfg) if cfg.block_length else None
 
     # ----------------------------------------------------------- pools
     def new_pools(self, n_pages: int, page_size: int,
-                  slots: Optional[int] = None) -> Tuple[KVPool, ...]:
+                  slots: Optional[int] = None,
+                  width: int = 1) -> Tuple[KVPool, ...]:
         """The zeroed caches this plan needs, the caller's to keep and
         to hand to every step in this order: a K and a V pool, or the
         one pool of a latent plan, then the states of the conv and the
@@ -1268,11 +1434,16 @@ class DecoderModel:
         (the server gives a mamba plan's sequences one each from
         admission to release, and keeps the last for idle rows); none
         given, one a page, where a step that names no slots finds a
-        row's state at the number of its first page."""
+        row's state at the number of its first page.  ``width``: the
+        rows of the decode batch the pools serve, which a chunk launch
+        carries beside its chunk (:meth:`step_chunk`; :meth:`prefill`
+        runs a long prompt's chunks at that width too, so the server's
+        chunk launches find the program compiled)."""
         shape = (self._cached_layers, n_pages, page_size, self._row_width())
         pools = tuple(KVPool(jnp.zeros(shape, self.cfg.storage))
                       for _ in range(self.n_kv_pools))
         self.slots = slots or n_pages
+        self.width = width
         return pools + self._new_state(self.slots)
 
     def _new_state(self, slots: int) -> Tuple[KVPool, ...]:
@@ -1383,12 +1554,21 @@ class DecoderModel:
         program).  The
         pools hold the launch's result as soon as it is
         queued; ``collect=False`` (:meth:`launch_prefill`) returns
-        there, with the launch for :meth:`collect_prefill`."""
+        there, with the launch for :meth:`collect_prefill`.  Where the
+        plan :func:`continues` and ``tokens`` is wider than a chunk,
+        each row's prompt runs as chunk launches in order, no row riding
+        (:meth:`step_chunk`): the one program whatever the width."""
         pools, (tokens, lengths, page_indices) = self._pools_of(args)
         shape = np.shape(tokens)
         enforce(len(shape) == 2 and shape[1] <= self.cfg.max_context,
                 f"prompt batch {shape} exceeds max_context "
                 f"{self.cfg.max_context}")
+        if 0 < self.chunk < shape[1]:
+            nxt, logits = self._prefill_by_chunks(
+                pools, tokens, lengths, page_indices, slots)
+            if not collect:
+                return nxt, logits
+            return (*self.collect_prefill((nxt, logits)), *pools)
         with _span("prefill_dispatch"):       # host→device + launch
             nxt, logits, *arrays = self._prefill(
                 self.params, *(p.array for p in pools),
@@ -1403,6 +1583,83 @@ class DecoderModel:
         return (*self.collect_prefill((nxt, logits)), *pools)
 
     launch_prefill = functools.partialmethod(prefill, collect=False)
+
+    def _prefill_by_chunks(self, pools, tokens, lengths, page_indices,
+                           slots):
+        """:meth:`prefill`'s rows as chunk launches with :attr:`width`
+        idle rows beside each.  → ([B] first tokens, [B, V] logits),
+        device arrays."""
+        tokens, tables = np.asarray(tokens), np.asarray(page_indices, np.int32)
+        slots = np.asarray(self._slots_of(slots, tables))
+        w, c = self.width, self.chunk
+        idle = (np.zeros((w,), np.int32),
+                np.zeros((w, tables.shape[1]), np.int32),
+                np.ones((w,), np.int32), np.zeros((w,), bool))
+        firsts, logits = [], []
+        for row, length in enumerate(np.asarray(lengths).tolist()):
+            for start in range(0, max(length, 1), c):
+                n = min(c, length - start)
+                chunk = np.zeros((c,), np.int32)
+                chunk[:n] = tokens[row, start:start + n]
+                _, first, out = self.launch_chunk(
+                    *pools, chunk, np.array([start, n], np.int32),
+                    tables[row], slots[row], *idle)
+            firsts.append(first)
+            logits.append(out[w:])
+        return jnp.concatenate(firsts), jnp.concatenate(logits)
+
+    def step_chunk(self, *args, slots=None, collect: bool = True):
+        """``step_chunk(*pools, chunk, at, table, slot, tokens,
+        page_indices, lengths, active[, prev, src], slots=None)``: one
+        chunk launch of a plan that :func:`continues`.  ``chunk`` [C]
+        int32 ids of one prompt from position ``at[0]``, ``at[1]`` of
+        them real (C is :attr:`chunk`; the launch writes their K/V and
+        its slot's state, which the next chunk continues from), ``table``
+        [max_pages] and ``slot`` the prompt's row's; beside it the rows of
+        a decode step, taken as :meth:`decode` takes them (``prev`` an
+        earlier decode or chunk launch of the same width).  → (the rows'
+        next tokens, on the host; the token after the chunk's last real
+        position, the prompt's first generated token where the chunk
+        ends it; the logits [W + 1, V], a device array, the chunk's
+        last; the pools).  ``collect=False`` (:meth:`launch_chunk`)
+        returns once the launch is queued, with the launch for
+        :meth:`collect_chunk`."""
+        pools, (chunk, at, table, slot, tokens, page_indices, lengths,
+                active, *feed) = self._pools_of(args)
+        prev, src = feed or (None, None)
+        w = np.shape(tokens)[0]
+        if prev is None:       # the program's shapes, fed by nobody
+            ids = np.zeros((w,), np.int32)
+            src = np.full((w,), -1, np.int32)
+        else:
+            ids = prev[0]
+        with _span("prefill_dispatch"):       # host→device + launch
+            ids, first, logits, *arrays = self._chunk(
+                self.params, *(p.array for p in pools),
+                jnp.asarray(chunk, jnp.int32), jnp.asarray(at, jnp.int32),
+                jnp.asarray(table, jnp.int32), jnp.asarray(slot, jnp.int32),
+                jnp.asarray(tokens, jnp.int32), jnp.asarray(ids, jnp.int32),
+                jnp.asarray(src, jnp.int32),
+                jnp.asarray(page_indices, jnp.int32),
+                jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(active, bool),
+                self._slots_of(slots, page_indices))
+            for pool, array in zip(pools, arrays):
+                pool.array = array
+        if not collect:
+            return ids, first, logits
+        return (*self.collect_chunk((ids, first, logits)), *pools)
+
+    launch_chunk = functools.partialmethod(step_chunk, collect=False)
+
+    @staticmethod
+    def collect_chunk(launch):
+        """→ (the rows' next tokens and the chunk's token, on the host,
+        in one fetch; the logits, a device array)."""
+        ids, first, logits = launch
+        with _span("prefill_fetch"):          # blocks on the device
+            ids, first = jax.device_get((ids, first))
+        return np.asarray(ids), int(first[0]), logits
 
     @staticmethod
     def collect_prefill(launch):

@@ -17,7 +17,15 @@ Request lifecycle — admission → prefill → continuous-batch decode loop
    ``flash_attention_packed`` launch (mixed prompt lengths packed into
    a single [1, B·T] row), which also writes the prompt K/V into the
    request's pages and yields the first generated token — the TTFT
-   moment.
+   moment.  Where the model's plan can continue a prefill from what a
+   sequence holds (``model.chunk`` > 0: ``model.continues``), a prompt
+   longer than a chunk is prefilled instead a chunk a launch, one prompt
+   at a time in admission order, and **every decoding row rides in each
+   chunk launch** (``DecoderModel.launch_chunk``): one program advances
+   both, so the weights a decode step would read alone are read once
+   for both, and no row waits behind a long prefill.  A decode launch
+   runs only when no chunk is pending.  The last chunk yields the first
+   token.
 3. **Decode loop**: one ``paged_decode_attention`` launch a step over a
    fixed-width batch (``--serve_max_batch``; inactive slots are
    padded with scratch-page tables so there is exactly one compiled
@@ -108,7 +116,9 @@ before it: a few hundred microseconds).  Under span k::
 
     serve_loop_iter
     ├─ serve_prefill{n, t_pad, prompt_tokens, moe_tokens, conv_tokens,
-    │                scan_tokens, attn_pairs, requests, queued}
+    │                scan_tokens, attn_pairs, requests, queued
+    │                [, riding, chunk_start]}
+    │  [└─ serve_decode_step{…}: a chunk launch's riding rows]
     │  or serve_decode_step{batch, live_tokens, live_pages, attended_tokens,
     │                       state_rows, queued, experts_hit, expert_load_max
     │                       [, discarded]
@@ -118,6 +128,13 @@ before it: a few hundred microseconds).  Under span k::
     │    *_fetch · serve_step_emit                            of launch k
     └─ serve_snapshot
 
+A chunk launch is both over the same period: ``serve_prefill`` states
+its chunk (``prompt_tokens`` its real positions, ``attn_pairs`` the
+pairs its queries see, earlier chunks' keys included, ``chunk_start``
+its first position, ``riding`` the rows it carries; ``n`` 1, ``t_pad``
+the chunk) and, where rows ride, ``serve_decode_step`` inside it states
+theirs as a decode launch would; ``serve_launch_total`` counts it as
+``kind=chunk``.
 ``serve_step_build`` is the host's numpy input build, ``*_dispatch`` the
 host→device copy of the inputs and the jitted call's return (the
 launch), ``*_fetch`` the ``np.asarray`` that waits for the device and
@@ -309,26 +326,34 @@ class Request:
 
 
 class _Launch:
-    """One program the device is given: a prefill or a decode step.
-    ``rows`` are its requests in batch order, ``src`` (decode) the batch
-    index each row had in the decode launch this one is queued behind,
-    from which it takes its id on the device (−1: from the host),
-    ``attrs`` what its span states, ``handle`` the model's launch once
-    queued.  A block launch (a decode step of a model with a
+    """One program the device is given: a prefill, a decode step or a
+    chunk launch.  ``rows`` are its requests in batch order (a chunk
+    launch's: the decoding rows it carries), ``src`` (decode, chunk) the
+    batch index each row had in the decode or chunk launch this one is
+    queued behind, from which it takes its id on the device (−1: from
+    the host), ``attrs`` what its span states, ``handle`` the model's
+    launch once queued.  A block launch (a decode step of a model with a
     ``block_length``) has ``passes``: per row (its tile's start, the
     positions it reveals, whether it finishes its block, the block it
-    starts from or None, whether it commits the block before it)."""
+    starts from or None, whether it commits the block before it).  A
+    chunk launch has ``chunk``: (the request whose prompt it continues,
+    the chunk's start, its positions) and ``ride``, what the decode
+    step span of its rows states (None when no row rides)."""
 
-    __slots__ = ("kind", "rows", "src", "attrs", "handle", "passes")
+    __slots__ = ("kind", "rows", "src", "attrs", "handle", "passes",
+                 "chunk", "ride")
 
     def __init__(self, kind: str, rows: List[Request], attrs: Dict,
-                 src: Sequence[int] = (), passes: Sequence = ()):
-        self.kind = kind                 # prefill|decode
+                 src: Sequence[int] = (), passes: Sequence = (),
+                 chunk=None, ride: Optional[Dict] = None):
+        self.kind = kind                 # prefill|decode|chunk
         self.rows = rows
         self.src = src
         self.attrs = attrs
         self.handle = None
         self.passes = passes
+        self.chunk = chunk
+        self.ride = ride
 
 
 class SwapTicket:
@@ -407,7 +432,8 @@ class InferenceServer:
         self._free_slots = list(range(self._width))
         # the cache pools the model's plan needs, as new_pools gave
         # them: every launch hands them on and donates their arrays
-        self._pools = model.new_pools(n_pages, page_size, self._n_slots())
+        self._pools = model.new_pools(n_pages, page_size, self._n_slots(),
+                                      self._width)
         # one page-table width for every request: enough pages to cover
         # a max_context-long sequence (or the whole pool if smaller)
         self.max_pages = min(self.pool.capacity,
@@ -415,6 +441,10 @@ class InferenceServer:
         self._cond = named_condition("serve.admission")
         self._queue: collections.deque = collections.deque()
         self._active: List[Request] = []
+        # admitted requests whose prompt is prefilled a chunk a launch
+        # (the model's ``chunk``), in admission order: the first has its
+        # chunks launched, the others wait for theirs
+        self._chunking: collections.deque = collections.deque()
         # launches queued on the device and not collected yet, oldest
         # first: the decode thread's alone, at most two (one being
         # collected, one ahead of the host)
@@ -555,6 +585,7 @@ class InferenceServer:
             pending = list(self._queue) + list(self._active)
             self._queue.clear()
             self._active = []
+            self._chunking.clear()
             swap, self._pending_swap = self._pending_swap, None
         for r in pending:
             self._release(r)
@@ -705,7 +736,7 @@ class InferenceServer:
         try:
             pools = ticket.model.new_pools(self.pool.n_pages,
                                            self.pool.page_size,
-                                           self._n_slots())
+                                           self._n_slots(), self._width)
         except Exception as e:  # noqa: BLE001 - rollback, keep serving
             self._pending_swap = None
             self.rollout_state = "rolled_back"
@@ -732,6 +763,7 @@ class InferenceServer:
                 r.next_token = -1
                 r.t_first = None
             reprefill = list(self._active)
+            self._chunking.clear()
             ticket.report["reprefilled"] = [r.id for r in reprefill]
         self.model = ticket.model
         self._pools = pools
@@ -813,8 +845,7 @@ class InferenceServer:
         cur = self._next_launch() if idle else self._inflight[0]
         if cur is None:
             return False
-        with (_span("serve_prefill", **cur.attrs) if cur.kind == "prefill"
-              else _span("serve_decode_step", **cur.attrs)) as step:
+        with self._spans(cur) as step:
             if idle:
                 self._launch(cur)
             ahead = self._next_launch()
@@ -823,6 +854,25 @@ class InferenceServer:
             self._collect(step)
         self._note_period(cur.kind, time.thread_time() - cpu)
         return True
+
+    @staticmethod
+    @contextlib.contextmanager
+    def _spans(launch: _Launch):
+        """The launch's span over its turn: ``serve_prefill`` or
+        ``serve_decode_step``; a chunk launch is both over the same
+        period, ``serve_prefill`` with its chunk's counts and, where rows
+        ride in it, ``serve_decode_step`` with theirs.  Yields the span
+        the collect states the rows' outcome on."""
+        if launch.kind == "decode":
+            with _span("serve_decode_step", **launch.attrs) as step:
+                yield step
+        elif launch.ride is None:
+            with _span("serve_prefill", **launch.attrs) as step:
+                yield step
+        else:
+            with _span("serve_prefill", **launch.attrs), \
+                    _span("serve_decode_step", **launch.ride) as step:
+                yield step
 
     def _note_period(self, kind: str, cpu_s: float) -> None:
         """The turn that just ended, if it took longer than
@@ -853,8 +903,10 @@ class InferenceServer:
     def _next_launch(self) -> Optional[_Launch]:
         """What to queue now, behind whatever is in flight: a parked
         swap is applied first (only with nothing in flight, so no launch
-        straddles the flip), then the admitted are prefilled, else the
-        active rows advance one token."""
+        straddles the flip), then the admitted are prefilled (a prompt
+        longer than the model's chunk joins the prompts prefilled a
+        chunk a launch), then the next chunk with the active rows riding
+        in it, else the active rows advance one token."""
         reprefill: List[Request] = []
         swapped = False
         self._marks.append(("admit", time.perf_counter()))
@@ -881,8 +933,17 @@ class InferenceServer:
             self._publish_serving_info()
         for r in admitted:
             r.record_span("serve_queue_wait", r.t_admit)
-        if reprefill or admitted:
-            return self._plan_prefill(reprefill + admitted)
+        whole = []
+        for r in reprefill + admitted:
+            if 0 < self.model.chunk < len(r.prompt):
+                r.table = self._table(r)
+                self._chunking.append(r)
+            else:
+                whole.append(r)
+        if whole:
+            return self._plan_prefill(whole)
+        if self._chunking:
+            return self._plan_chunk()
         return self._plan_decode()
 
     def _admit_locked(self) -> List[Request]:
@@ -939,9 +1000,7 @@ class InferenceServer:
         t_pad = min(t_pad, self.model.cfg.max_context)
         prompt_tokens = sum(map(self._prefilled, admitted))
         for r in admitted:
-            t = self.pool.table_of(r.id)
-            r.table = np.array(
-                t + [SCRATCH_PAGE] * (self.max_pages - len(t)), np.int32)
+            r.table = self._table(r)
             if self.model.block_length:
                 self._start_blocks(r)
         return _Launch("prefill", admitted, dict(
@@ -953,6 +1012,12 @@ class InferenceServer:
                 [self._prefilled(r) for r in admitted]),
             requests=",".join(r.id for r in admitted),
             queued=self._queued()))
+
+    def _table(self, r: Request) -> np.ndarray:
+        """``r``'s page table at the one width every request's has."""
+        t = self.pool.table_of(r.id)
+        return np.array(t + [SCRATCH_PAGE] * (self.max_pages - len(t)),
+                        np.int32)
 
     def _plan_decode(self) -> Optional[_Launch]:
         """The active rows advance in a single fixed-width paged-
@@ -969,11 +1034,16 @@ class InferenceServer:
         behind = self._inflight[-1] if self._inflight else None
         flying = {} if behind is None \
             else {id(r): i for i, r in enumerate(behind.rows)}
+        ending = behind.chunk[0] if behind is not None and behind.chunk \
+            else None
         rows, src = [], []
         for r in self._active:
             i = flying.get(id(r), -1)
-            if i >= 0 and (behind.kind == "prefill"
-                           or len(r.tokens) + 1 >= r.max_new_tokens):
+            # a prompt not yet whole in its pages, or whose last chunk
+            # is in flight, has no token to feed
+            if r.length < len(r.prompt) or r is ending \
+                    or i >= 0 and (behind.kind == "prefill"
+                                   or len(r.tokens) + 1 >= r.max_new_tokens):
                 continue
             rows.append(r)
             src.append(i)
@@ -990,6 +1060,26 @@ class InferenceServer:
             attended_tokens=self.model.attended_tokens(fed),
             state_rows=len(rows) * self.model.state_layers,
             queued=self._queued()), src)
+
+    def _plan_chunk(self) -> _Launch:
+        """The next chunk of the oldest prompt still being prefilled, with
+        every row :meth:`_plan_decode` would advance riding in it: one
+        launch advances both."""
+        r = self._chunking[0]
+        start = r.length
+        n = min(self.model.chunk, len(r.prompt) - start)
+        ride = self._plan_decode()
+        rows, src = (ride.rows, ride.src) if ride is not None else ([], [])
+        pairs = self.model.attn_pairs([start + n]) \
+            - self.model.attn_pairs([start])
+        return _Launch("chunk", rows, dict(
+            n=1, t_pad=self.model.chunk, prompt_tokens=n,
+            moe_tokens=n * self.model.routed_layers,
+            conv_tokens=n * self.model.conv_layers,
+            scan_tokens=n * self.model.mamba_layers, attn_pairs=pairs,
+            requests=r.id, queued=self._queued(), riding=len(rows),
+            chunk_start=start), src, chunk=(r, start, n),
+            ride=None if ride is None else ride.attrs)
 
     def _start_blocks(self, r: Request) -> None:
         """``r``'s first block: it starts at its prompt's last whole
@@ -1089,22 +1179,15 @@ class InferenceServer:
             return
         else:
             with _span("serve_step_build"):
-                b = self._width
-                tokens = np.zeros((b,), np.int32)
-                src = np.full((b,), -1, np.int32)
-                lengths = np.ones((b,), np.int32)
-                active = np.zeros((b,), bool)
-                tables = np.full((b, self.max_pages), SCRATCH_PAGE,
-                                 np.int32)
-                slots = self._slots_kw(rows, b)
-                for i, r in enumerate(rows):
-                    if launch.src[i] < 0:
-                        tokens[i] = r.next_token
-                    r.length += 1
-                    lengths[i] = r.length
-                    tables[i] = r.table
-                src[:n] = launch.src
-                active[:n] = True
+                tokens, tables, lengths, active, src, slots = \
+                    self._rows_in(launch)
+                if launch.chunk is not None:
+                    r, start, c = launch.chunk
+                    chunk = np.zeros((self.model.chunk,), np.int32)
+                    chunk[:c] = r.prompt[start:start + c]
+                    r.length = start + c
+                    if r.length == len(r.prompt):
+                        self._chunking.popleft()
             if self._m_batch is not None:
                 self._m_batch.set(n)
             if self._m_behind is not None and self.model.cfg.window:
@@ -1112,13 +1195,40 @@ class InferenceServer:
                     lengths[:n].tolist(), self.pool.page_size))
             # rows with ``src`` >= 0 take their ids from the launch this
             # one is queued behind, on the device
-            prev = self._inflight[-1].handle if max(launch.src) >= 0 \
-                else None
+            prev = self._inflight[-1].handle if max(launch.src, default=-1) \
+                >= 0 else None
             self._marks.append(("dispatch", time.perf_counter()))
-            launch.handle = self.model.launch_decode(
-                *self._pools, tokens, tables, lengths, active, prev, src,
-                **slots)
+            if launch.chunk is None:
+                launch.handle = self.model.launch_decode(
+                    *self._pools, tokens, tables, lengths, active, prev,
+                    src, **slots)
+            else:
+                launch.handle = self.model.launch_chunk(
+                    *self._pools, chunk, np.array([start, c], np.int32),
+                    r.table, r.slot if self._slotted else r.table[0],
+                    tokens, tables, lengths, active, prev, src, **slots)
         self._inflight.append(launch)
+
+    def _rows_in(self, launch: _Launch):
+        """A decode step's inputs for the launch's rows at the batch's
+        width, each row's length advanced by the token it feeds: (fed
+        ids, page tables, lengths, active, ``src``, the slots keyword)."""
+        rows, n, b = launch.rows, len(launch.rows), self._width
+        tokens = np.zeros((b,), np.int32)
+        src = np.full((b,), -1, np.int32)
+        lengths = np.ones((b,), np.int32)
+        active = np.zeros((b,), bool)
+        tables = np.full((b, self.max_pages), SCRATCH_PAGE, np.int32)
+        slots = self._slots_kw(rows, b)
+        for i, r in enumerate(rows):
+            if launch.src[i] < 0:
+                tokens[i] = r.next_token
+            r.length += 1
+            lengths[i] = r.length
+            tables[i] = r.table
+        src[:n] = launch.src
+        active[:n] = True
+        return tokens, tables, lengths, active, src, slots
 
     def _launch_blocks(self, launch: _Launch) -> None:
         """Build a block launch's inputs and queue it on the device."""
@@ -1172,32 +1282,43 @@ class InferenceServer:
                 self._marks.append(("emit", time.perf_counter()))
                 self._emit_blocks(launch, after, conf, step)
             return
-        if launch.kind == "prefill":
+        rows, firsts = launch.rows, 0   # the last ``firsts`` rows' token
+        if launch.kind == "prefill":    # is the first after its prompt
             ids, _ = self.model.collect_prefill(handle)
             if self.model.block_length:     # tokens come from blocks
                 return
+            firsts = len(rows)
+        elif launch.kind == "chunk":
+            ids, token, _ = self.model.collect_chunk(handle)
+            r, start, n = launch.chunk
+            ids = ids[:len(rows)]
+            if start + n == len(r.prompt):
+                rows, ids, firsts = rows + [r], np.append(ids, token), 1
         else:
             ids, _, routed = self.model.collect_decode(handle)
             step.set(**routed)      # experts_hit, expert_load_max
         with _span("serve_step_emit"):
             now = time.perf_counter()
             self._marks.append(("emit", now))
-            live = [(r, t) for r, t in zip(launch.rows, ids.tolist())
+            live = [(r, t, k >= len(rows) - firsts) for k, (r, t)
+                    in enumerate(zip(rows, ids.tolist()))
                     if r.state == "active"]
             self._count_tokens(len(live))
-            for r, token in live:
-                if launch.kind == "prefill":
+            for r, token, first in live:
+                if first:
                     r.t_first = now
                     if _histogram is not None:
                         _histogram("serve_ttft_seconds",
                                    "submit-to-first-token latency"
                                    ).observe(now - r.t_submit)
                 self._emit_token(r, token)
-            dead = len(launch.rows) - len(live)
+            dead = len(rows) - len(live)
             if dead:
                 if self._m_discarded is not None:
                     self._m_discarded.inc(dead)
-                step.set(batch=len(live), discarded=dead)
+                # a chunk launch's span states its riding rows alone
+                step.set(batch=len(live) - firsts * (launch.kind == "chunk"),
+                         discarded=dead)
 
     def _emit_blocks(self, launch: _Launch, after: np.ndarray,
                      conf: np.ndarray, step) -> None:
@@ -1276,6 +1397,7 @@ class InferenceServer:
         self._drain()
         with self._cond:
             failed, self._active = self._active, []
+            self._chunking.clear()
         for r in failed:
             self._release(r)
             r.state = "failed"

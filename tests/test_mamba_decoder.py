@@ -503,3 +503,41 @@ def test_continuous_and_sequential_serving_give_the_same_tokens(small):
                           observe.REGISTRY.find(name).samples()]
     assert gauge("serve_state_bytes_per_sequence") == [
         3 * (3 * 64 * 4 + 16 * 64 * 4)]
+
+
+# ---------------------------------------------------- prefill in chunks
+@pytest.fixture(scope="module")
+def chunked(bench):
+    """The decoder with a chunk of 8 positions (the server's is 512)."""
+    model = _model(bench)
+    model.chunk = 8
+    return model
+
+
+@pytest.mark.parametrize("length", [5, 8, 13, 19])
+def test_a_prefill_in_chunks_continues_from_the_slot(bench, decoder, chunked,
+                                                     length):
+    """A prompt prefilled as chunk launches of 8 positions, then decoded:
+    at 13 and 19 the convolution's three leading taps and a page of 4
+    straddle a chunk boundary, and the scan continues from the state the
+    chunk before left in the slot (5 and 8 are one chunk).  Every step
+    reads the whole prefill's logits and the reference's at the float32
+    tolerance, and after the last chunk the slot holds the whole
+    prefill's window and scan state, the pages its K/V."""
+    prompt = np.random.default_rng(length).integers(2, 256, length).tolist()
+    held, logits = [], []
+    for model in (decoder, chunked):
+        steps = _serve(model, [prompt], 3, between=lambda pools: held.append(
+            [np.asarray(p.array) for p in pools]))
+        logits.append(np.stack([lg[0] for _, lg in steps]))
+        seqs = [s[0] for s, _ in steps]
+    want = np.stack([_reference_logits(bench, [s])[0] for s in seqs])
+    assert np.abs(logits[1] - want).max() < TOLERANCE["float32"]
+    assert np.abs(logits[1] - logits[0]).max() < TOLERANCE["float32"]
+    whole, parts = held
+    slot = SLOTS[0]
+    for a, b in zip(whole[:2], parts[:2]):               # K and V pages
+        assert np.abs(a - b).max() < TOLERANCE["float32"]
+    for a, b in zip(whole[2:], parts[2:]):               # window, h
+        assert np.abs(a[:, slot] - b[:, slot]).max() < TOLERANCE["float32"]
+        assert np.abs(b[:, slot]).max() > 0

@@ -809,6 +809,54 @@ def test_the_scan_kernel_at_the_jamba_cells_widths_compiles(one_v5e,
     assert "tpu_custom_call" in text and "ssm_scan" in text
 
 
+def test_the_chunk_kernel_at_the_jamba_cells_widths_compiles(one_v5e,
+                                                             monkeypatch):
+    """``chunk_prefill_attention`` as ``jamba_serve_closed_c12``'s chunk
+    launch calls it: 512 queries of 20 heads over one K/V head of 128 in
+    bf16, a pool of 4,096 pages of 64 a layer (two layers end to end),
+    a table of 260 pages, compiled for a v5e."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(pa, "pallas_interpret", lambda: False)
+    shapes = [((512, 20, 128), "bfloat16"), ((8192, 64, 128), "bfloat16"),
+              ((8192, 64, 128), "bfloat16"), ((260,), "int32"),
+              ((2,), "int32")]
+    args = [jax.ShapeDtypeStruct(s, jnp.dtype(t), sharding=one_v5e)
+            for s, t in shapes]
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        text = jax.jit(pa.chunk_prefill_attention).lower(
+            *args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    assert "tpu_custom_call" in text and "chunk_prefill" in text
+
+
+@pytest.mark.parametrize("kv_heads,start,n", [
+    (1, 0, 8), (1, 12, 5), (4, 20, 8), (2, 33, 11), (1, 61, 3)])
+def test_the_chunk_kernel_is_the_dense_reference(kv_heads, start, n, rng):
+    """A chunk of 8 (16 where it ends off a page) queries of 4 heads
+    over 1, 2 or 4 K/V heads, at starts on and off a page and a block of
+    keys, with padding past its real queries: each real query sees every
+    position of its row up to its own through the page table, as the
+    dense reference of a tile of ``n`` queries ending at start + n."""
+    h, d, page, n_pages, pool = 4, 16, 4, 20, 48
+    c = 16 if n > 8 else 8
+    kp, vp = (jnp.asarray(rng.randn(pool, page, kv_heads * d)
+                          .astype(np.float32)) for _ in range(2))
+    table = jnp.asarray(rng.permutation(pool)[:n_pages].astype(np.int32))
+    q = jnp.asarray(rng.randn(c, h, d).astype(np.float32))
+    got = pa.chunk_prefill_attention(q, kp, vp, table,
+                                     jnp.array([start, n], jnp.int32))
+    want = pa.paged_decode_reference(q[None, :n], kp, vp, table[None],
+                                     jnp.array([start + n]))[0]
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got)[:n], np.asarray(want),
+                               atol=2e-5)
+
+
 @pytest.mark.parametrize("hw,ch", [(56, 64), (28, 128), (14, 256),
                                    (7, 512)])
 def test_the_fused_conv_kernels_at_resnet50s_stage_shapes_compile(
